@@ -124,21 +124,20 @@ def _device_edges(rt, snap, block_keys, weight_prop,
     """Device-resident flat edge arrays, uploaded once per (snapshot,
     block set, weight) and reused by every iteration and every run.
 
-    Edges go up DST-SORTED (AlgoGraph.by_dst): PageRank's combine is
-    then a prefix-sum segment reduction and the min-combines pass
-    indices_are_sorted — min is exactly order-independent, so the
-    sort can never change WCC/SSSP results."""
+    Edges go up DST-SORTED (AlgoGraph.by_dst): PageRank's segment sum
+    and the min-combines pass indices_are_sorted — min is exactly
+    order-independent, so the sort can never change WCC/SSSP
+    results."""
     import jax
     key = (id(snap), tuple(block_keys), weight_prop)
     ent = _lru_get(_dev_cache, key)
     if ent is not None:
         return ent[1]
-    order, esrc_s, edst_s, starts = g.by_dst()
+    order, esrc_s, edst_s = g.by_dst()
     dev0 = rt.mesh.devices.reshape(-1)[0]
     arrs = {
         "esrc": jax.device_put(esrc_s.astype(np.int32), dev0),
         "edst": jax.device_put(edst_s.astype(np.int32), dev0),
-        "starts": jax.device_put(starts, dev0),
         "vmask": jax.device_put(g.vmask, dev0),
     }
     if g.weight is not None:
@@ -248,7 +247,7 @@ def _device_pagerank(rt, snap, block_keys, g, params, live,
     out_inv = np.zeros(g.n_slots)
     nz = outdeg > 0
     out_inv[nz] = 1.0 / outdeg[nz]
-    _order, esrc_s, _edst_s, _starts = g.by_dst()
+    _order, esrc_s, _edst_s = g.by_dst()
     dev0 = rt.mesh.devices.reshape(-1)[0]
     # per-edge 1/outdeg pre-gathered once (static within a run): the
     # iteration kernel then needs ONE gather per edge, not two
@@ -261,7 +260,7 @@ def _device_pagerank(rt, snap, block_keys, g, params, live,
     def body(it):
         (rank, delta, active), _us = rt.algo_dispatch(
             "algo.pagerank", step, state["rank"], dev["esrc"],
-            dev["starts"], out_inv_e, dmask_d, dev["vmask"], n)
+            dev["edst"], out_inv_e, dmask_d, dev["vmask"], n)
         state["rank"] = rank
         return int(active), float(delta) < tol
 
@@ -361,8 +360,8 @@ def run_algorithm(func: str, params: Dict[str, Any], snap, sd,
     -> (rows, info) where rows are full-width [vid, value] rows in the
     canonical vid order and info = {'mode', 'iterations', 'n_edges',
     'n_vertices'}.  `iter_us` collects per-iteration wall µs on the
-    device path (the bench's A/B probe); `on_fallback(exc)` observes
-    an auto-mode device failure before the oracle takes over."""
+    device path (the bench's A/B probe); `on_fallback(cause)` is told
+    why an auto-mode device run failed before the oracle takes over."""
     from ..utils import cancel as _cancel
     from ..utils.stats import stats
 
@@ -408,7 +407,7 @@ def run_algorithm(func: str, params: Dict[str, Any], snap, sd,
 
     state, iters, ran_mode = None, 0, "host"
     if mode != "host" and rt is not None:
-        from ..tpu.device import TpuUnavailable
+        from ..tpu.device import TpuUnavailable, note_host_fallback
         from ..tpu.traverse import _JAX_RT_ERRORS
         try:
             if func == "pagerank":
@@ -429,8 +428,9 @@ def run_algorithm(func: str, params: Dict[str, Any], snap, sd,
             stats().inc_labeled(
                 "algo_fallback",
                 {"algo": func, "reason": type(ex).__name__})
+            cause = note_host_fallback(f"algo.{func}", ex)
             if on_fallback is not None:
-                on_fallback(ex)
+                on_fallback(cause)
             state = None
 
     if state is None:                   # host oracle (mode or fallback)
@@ -466,8 +466,8 @@ def run_call_algo(node, qctx, ectx):
     func = a["algo"]
     snap, sd = _host_snapshot(qctx, a["space"])
 
-    def note_fallback(ex):
-        qctx.last_tpu_fallback = f"{type(ex).__name__}: {ex}"
+    def note_fallback(cause):
+        qctx.last_tpu_fallback = cause
 
     rows, _info = run_algorithm(
         func, a["params"], snap, sd,

@@ -46,20 +46,16 @@ class AlgoGraph:
 
     def by_dst(self):
         """Destination-sorted edge view (computed once, cached):
-        -> (order, esrc_sorted, edst_sorted, starts) where starts is
-        the (n_slots+1,) CSC-style segment index into the sorted
-        arrays.  The device kernels run on THIS order — PageRank's
-        combine becomes a prefix-sum segment reduction (5× the XLA CPU
-        scatter-add) and the min-combines pass indices_are_sorted
-        (min is exactly order-independent, so sorting never changes
-        WCC/SSSP results)."""
+        -> (order, esrc_sorted, edst_sorted).  The device kernels run
+        on THIS order — every combine (PageRank's segment sum, the
+        WCC/SSSP min-combines) passes indices_are_sorted, and a slot's
+        updates arrive in one fixed order (min is exactly
+        order-independent, so sorting never changes WCC/SSSP
+        results)."""
         cached = getattr(self, "_by_dst", None)
         if cached is None:
             order = np.argsort(self.edst, kind="stable")
-            edst_s = self.edst[order]
-            starts = np.searchsorted(
-                edst_s, np.arange(self.n_slots + 1, dtype=np.int64))
-            cached = (order, self.esrc[order], edst_s, starts)
+            cached = (order, self.esrc[order], self.edst[order])
             self._by_dst = cached
         return cached
 

@@ -47,25 +47,29 @@ def _cached(key, build):
 
 
 def pagerank_step(n_slots: int, damping: float, tol: float):
-    """(rank, esrc_s, starts, out_inv_e, dangling_mask, vmask, n) →
+    """(rank, esrc_s, edst_s, out_inv_e, dangling_mask, vmask, n) →
     (rank', l1_delta, active) — active counts vertices whose rank
     moved more than tol this iteration (the live-progress number).
 
-    Edges arrive DST-SORTED (AlgoGraph.by_dst), so the per-vertex
-    combine is a prefix-sum segment reduction — cs[starts[v+1]] -
-    cs[starts[v]] — instead of a scatter-add, which XLA CPU
-    serializes (measured 5×).  The prefix-sum order is deterministic
-    (same graph → bit-identical ranks run-to-run); vs the oracle's
-    np.add.at order it differs within the documented 1e-8 tolerance."""
+    Edges arrive DST-SORTED (AlgoGraph.by_dst) and the per-vertex
+    combine is a sorted segment sum (scatter-add by destination,
+    `indices_are_sorted`) — the same shape as the WCC/SSSP
+    min-combines.  It replaced a float64 `jnp.cumsum` prefix-sum
+    reduction: x64 is on package-wide and the TPU emulates f64, and
+    the compiler needed 194 s for that cumsum at 600,000 edges and did
+    not finish in 10 minutes at 30,000,000 (a log-depth
+    `lax.associative_scan` did no better), while this step compiles
+    in about a second at 1,000,000 slots / 30,000,000 edges
+    (tests/unit/test_tpu_compile.py keeps that so).  Updates to one
+    slot are applied in edge order, so the same graph gives
+    bit-identical ranks run-to-run; vs the oracle's np.add.at order
+    the result stays within the documented 1e-8 tolerance."""
     def build():
-        def step(rank, esrc_s, starts, out_inv_e, dmask, vmask, n):
+        def step(rank, esrc_s, edst_s, out_inv_e, dmask, vmask, n):
             contrib = rank[esrc_s] * out_inv_e
-            cs = jnp.cumsum(contrib)          # inclusive prefix
-
-            def at(idx):                      # exclusive-prefix gather
-                return jnp.where(idx > 0, cs[jnp.maximum(idx - 1, 0)],
-                                 0.0)
-            acc = at(starts[1:]) - at(starts[:-1])
+            acc = jax.ops.segment_sum(contrib, edst_s,
+                                      num_segments=n_slots,
+                                      indices_are_sorted=True)
             base = (1.0 - damping
                     + damping * jnp.sum(jnp.where(dmask, rank, 0.0))) / n
             new = jnp.where(vmask, base + damping * acc, 0.0)

@@ -73,7 +73,15 @@ def main(argv=None):
             from .graph_service import GraphService
             rt = None
             if args.tpu:
+                # the device plane says which platform it got and
+                # refuses a non-TPU host unless the operator set
+                # JAX_PLATFORMS=cpu on purpose; compiled programs are
+                # kept across restarts (enable_compile_cache)
+                from ..tpu.device import (enable_compile_cache,
+                                          require_tpu)
                 from ..tpu.runtime import TpuRuntime
+                require_tpu("graphd --tpu")
+                enable_compile_cache()
                 rt = TpuRuntime()
             svc = GraphService(args.addr, mc, server=server, tpu_runtime=rt)
 

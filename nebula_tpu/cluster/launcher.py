@@ -26,6 +26,10 @@ class LocalCluster:
     def __init__(self, n_meta: int = 1, n_storage: int = 2, n_graph: int = 1,
                  data_dir: Optional[str] = None, tpu_runtime=None):
         self.data_dir = data_dir or tempfile.mkdtemp(prefix="nebula_tpu_")
+        if tpu_runtime is not None:
+            # same compile-cache placement as `daemons.py graphd --tpu`
+            from ..tpu.device import enable_compile_cache
+            enable_compile_cache()
         self.meta_servers: List[RpcServer] = []
         self.metads: List[MetaService] = []
         self.storage_servers: List[RpcServer] = []

@@ -186,7 +186,7 @@ def _device_frames(qctx, space: str, starts, etypes, direction: str,
         sd.dense_id
     except AttributeError:
         return None
-    from ..tpu.device import TpuUnavailable
+    from ..tpu.device import TpuUnavailable, note_host_fallback
     from ..tpu.exprjit import CannotCompile, compilable
     from ..tpu.traverse import _JAX_RT_ERRORS
     dev_pred = filt if (filt is not None
@@ -196,7 +196,7 @@ def _device_frames(qctx, space: str, starts, etypes, direction: str,
                                          direction, hops,
                                          edge_filter=dev_pred)
     except (CannotCompile, TpuUnavailable) + _JAX_RT_ERRORS as ex:
-        qctx.last_tpu_fallback = f"{type(ex).__name__}: {ex}"
+        qctx.last_tpu_fallback = note_host_fallback("path_frames", ex)
         return None
     qctx.last_tpu_stats = stats
     host_check = filt is not None and dev_pred is None

@@ -417,13 +417,9 @@ def _traverse_device(node, qctx, ectx, ds, ci, sp, etypes, direction,
     from ..utils.config import get_config
     if not get_config().get("tpu_match_device"):
         return None
-    from ..tpu.device import TpuUnavailable
+    from ..tpu.device import TpuUnavailable, note_host_fallback
     from ..tpu.exprjit import CannotCompile, compilable
-    try:
-        import jax
-        _rt_errors = (jax.errors.JaxRuntimeError,)
-    except (ImportError, AttributeError):
-        _rt_errors = ()
+    from ..tpu.traverse import _JAX_RT_ERRORS as _rt_errors
 
     store = qctx.store
     try:
@@ -451,7 +447,7 @@ def _traverse_device(node, qctx, ectx, ds, ci, sp, etypes, direction,
                                          direction, max_hop,
                                          edge_filter=dev_pred)
     except (CannotCompile, TpuUnavailable) + _rt_errors as ex:
-        qctx.last_tpu_fallback = f"{type(ex).__name__}: {ex}"
+        qctx.last_tpu_fallback = note_host_fallback("match_expand", ex)
         return None
     qctx.last_tpu_stats = stats
     host_check = edge_filter is not None and dev_pred is None
@@ -1151,7 +1147,7 @@ def _find_path(node, qctx, ectx, space):
     rt = getattr(qctx, "tpu_runtime", None)
     a = node.args
     if rt is not None and a["kind"] == "shortest":
-        from ..tpu.device import TpuUnavailable
+        from ..tpu.device import TpuUnavailable, note_host_fallback
         from ..tpu.exprjit import CannotCompile
         from ..tpu.paths import find_shortest_device
         from ..tpu.traverse import _JAX_RT_ERRORS
@@ -1159,8 +1155,9 @@ def _find_path(node, qctx, ectx, space):
             return find_shortest_device(node, qctx, ectx)
         except (CannotCompile, TpuUnavailable) + _JAX_RT_ERRORS as ex:
             # device can't serve this space/config/filter; host has
-            # identical semantics — record the cause, don't swallow it
-            qctx.last_tpu_fallback = f"{type(ex).__name__}: {ex}"
+            # identical semantics — count and record the cause, don't
+            # swallow it
+            qctx.last_tpu_fallback = note_host_fallback("find_path", ex)
     if a["kind"] in ("all", "noloop"):
         ds = find_path_device(node, qctx, ectx)
         if ds is not None:

@@ -8,10 +8,13 @@ native path is the fast path, never the only path.
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import subprocess
 import threading
 from typing import Optional
+
+_log = logging.getLogger(__name__)
 
 _dir = os.path.join(os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__)))), "native")
@@ -21,17 +24,30 @@ _lib: Optional[ctypes.CDLL] = None
 _tried = False
 
 
-def _build() -> bool:
+def build() -> bool:
+    """Compile native/nebula_native.cc into the shared library (the
+    library is git-ignored: a fresh checkout has only the source).
+    A failed build is logged, never silent — callers that can live
+    without the native path fall back to Python, chip_smoke.py does
+    not."""
     src = os.path.join(_dir, "nebula_native.cc")
     if not os.path.exists(src):
+        _log.warning("native build: %s is missing", src)
         return False
+    tmp = f"{_so}.{os.getpid()}.tmp"
     try:
         subprocess.run(
-            ["g++", "-O3", "-std=c++17", "-fPIC", "-shared", "-o", _so, src],
+            ["g++", "-O3", "-std=c++17", "-fPIC", "-shared", "-o", tmp, src],
             check=True, capture_output=True, timeout=120)
+        # atomic: another process may be loading the library right now
+        os.replace(tmp, _so)
         return True
-    except (subprocess.SubprocessError, FileNotFoundError, OSError):
-        return False
+    except subprocess.CalledProcessError as ex:
+        _log.warning("native build failed: %s",
+                     ex.stderr.decode(errors="replace")[-2000:])
+    except (subprocess.SubprocessError, FileNotFoundError, OSError) as ex:
+        _log.warning("native build failed: %r", ex)
+    return False
 
 
 def get_lib() -> Optional[ctypes.CDLL]:
@@ -45,7 +61,7 @@ def get_lib() -> Optional[ctypes.CDLL]:
                 os.path.exists(os.path.join(_dir, "nebula_native.cc"))
                 and os.path.getmtime(_so) <
                 os.path.getmtime(os.path.join(_dir, "nebula_native.cc"))):
-            if not _build() and not os.path.exists(_so):
+            if not build() and not os.path.exists(_so):
                 return None
         try:
             lib = ctypes.CDLL(_so)

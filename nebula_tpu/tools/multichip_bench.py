@@ -14,31 +14,24 @@ Measures what the mesh-native sharded plane actually buys:
     same snapshot, per-shard HBM bytes, and the bit-packed frontier
     all_to_all payload per hop (TraverseStats.exchange_bytes).
 
-The sweep runs the measurement in a THROWAWAY subprocess with a hard
-deadline (the same wedge-containment contract as probe_device): the
-virtual arm forces `JAX_PLATFORMS=cpu` + 8 host devices so the A/B
-always lands in the bench JSON even with no accelerator attached, and
-a real-device arm runs additionally when the structured probe verdict
-is "ok" — bench.py embeds the verdict verbatim as `probe_status`, so a
-missing device arm is always attributable (ok / no_devices / timeout).
+The measurement runs IN THIS PROCESS on `jax.devices()`: a chip
+belongs to one process, so there is no probe child and no second arm.
+It fails when the platform is not `tpu` unless the caller set
+JAX_PLATFORMS=cpu (then `XLA_FLAGS=
+--xla_force_host_platform_device_count=8` gives the rehearsal mesh),
+and it needs at least two devices.
 
 CLI:
-  python -m nebula_tpu.tools.multichip_bench            # parent sweep
-  python -m nebula_tpu.tools.multichip_bench --child    # one arm
+  python -m nebula_tpu.tools.multichip_bench [--persons N] [--repeats R]
 """
 from __future__ import annotations
 
 import json
 import os
 import statistics
-import subprocess
 import sys
 import time
 
-_SENTINEL = "NEBULA_MULTICHIP:"
-
-
-# -- child: one bounded in-process measurement ------------------------------
 
 def _run_measurement(persons: int, degree: int, steps: int,
                      repeats: int) -> dict:
@@ -51,15 +44,15 @@ def _run_measurement(persons: int, degree: int, steps: int,
     from ..utils.config import get_config
     from ..utils.stats import stats
 
-    import jax
-    devs = jax.devices()
-    N = min(8, len(devs))
-    out: dict = {"platform": devs[0].platform, "n_devices": len(devs),
-                 "shards": N, "persons": persons, "degree": degree,
-                 "steps": steps}
+    from ..tpu.device import require_tpu
+    ident = require_tpu("multichip_bench")
+    N = min(8, ident["count"])
+    out: dict = {"device": ident, "shards": N, "persons": persons,
+                 "degree": degree, "steps": steps}
     if N < 2:
-        out["error"] = "need >= 2 devices for a sharded arm"
-        return out
+        raise RuntimeError(
+            f"multichip_bench needs >= 2 devices for a sharded arm, "
+            f"jax.devices() has {ident['count']}")
 
     arrs = make_social_arrays(persons, degree, seed=7)
     snap = snapshot_from_arrays(arrs, parts=N, space="mc")
@@ -142,88 +135,41 @@ def _run_measurement(persons: int, degree: int, steps: int,
     return out
 
 
-def _child_main(args) -> int:
-    try:
-        res = _run_measurement(args.persons, args.degree, args.steps,
-                               args.repeats)
-    except Exception as ex:  # noqa: BLE001 — verdict, not traceback
-        res = {"error": repr(ex)[:400]}
-    print(_SENTINEL + json.dumps(res))
-    return 0 if "error" not in res else 1
-
-
-# -- parent: bounded subprocess arms + probe verdict ------------------------
-
-def _run_child(force_cpu: bool, persons: int, degree: int, steps: int,
-               repeats: int, timeout_s: float) -> dict:
-    env = dict(os.environ)
-    if force_cpu:
-        env["JAX_PLATFORMS"] = "cpu"
-        env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                            + " --xla_force_host_platform_device_count=8"
-                            ).strip()
-    cmd = [sys.executable, "-m", "nebula_tpu.tools.multichip_bench",
-           "--child", "--persons", str(persons), "--degree", str(degree),
-           "--steps", str(steps), "--repeats", str(repeats)]
-    try:
-        out = subprocess.run(cmd, capture_output=True, text=True,
-                             timeout=timeout_s, env=env)
-    except subprocess.TimeoutExpired:
-        return {"status": "timeout", "timeout_s": timeout_s}
-    for line in out.stdout.splitlines():
-        if line.startswith(_SENTINEL):
-            try:
-                res = json.loads(line[len(_SENTINEL):])
-                res["status"] = "ok" if "error" not in res else "error"
-                return res
-            except ValueError:
-                pass
-    return {"status": "error", "rc": out.returncode,
-            "stderr": (out.stderr or "").strip()[-400:]}
-
-
 def multichip_sweep(persons: int = 120_000, degree: int = 6,
-                    steps: int = 3, repeats: int = 5,
-                    timeout_s: float = 600.0) -> dict:
-    """The bench.py `multichip` block: structured probe verdict + the
-    always-available virtual-mesh A/B + a real-device A/B when the
-    probe lands ok.  Never raises, never hangs past its deadlines."""
-    from .probe_device import probe
-    verdict = probe()
-    result = {"probe_status": verdict["probe_status"],
-              "probe": verdict,
-              "virtual": _run_child(True, persons, degree, steps,
-                                    repeats, timeout_s)}
-    if verdict["probe_status"] == "ok" and verdict["n_devices"] >= 2:
-        result["device"] = _run_child(False, persons, degree, steps,
-                                      repeats, timeout_s)
-    v = result["virtual"]
-    if v.get("status") == "ok":
-        result["speedup_Nshard_vs_1"] = round(
-            v["sharded"]["edges_per_s"]
-            / max(v["single_chip"]["edges_per_s"], 1), 3)
-    return result
+                    steps: int = 3, repeats: int = 5) -> dict:
+    """The bench.py `multichip` block: the 1-vs-N-shard A/B on the
+    devices this process holds.  Raises when a parity or HBM proof
+    fails — a failed phase must fail the run."""
+    res = _run_measurement(persons, degree, steps, repeats)
+    proof = res["hbm_scaleout"]
+    failed = [k for k, ok in (
+        ("single_chip_refused", proof["single_chip_refused"]),
+        ("shard_sum_matches_total", proof["shard_sum_matches_total"]),
+        ("rows_identical_1_vs_N", res["rows_identical_1_vs_N"]),
+        ("rows_identical_vs_numpy", res["rows_identical_vs_numpy"]),
+    ) if not ok]
+    if failed:
+        raise AssertionError(f"multichip proofs failed: {failed}: "
+                             f"{json.dumps(res)[:2000]}")
+    res["speedup_Nshard_vs_1"] = round(
+        res["sharded"]["edges_per_s"]
+        / max(res["single_chip"]["edges_per_s"], 1), 3)
+    return res
 
 
 def main(argv=None) -> int:
     import argparse
     ap = argparse.ArgumentParser(
         description="1-vs-N-shard mesh execution A/B")
-    ap.add_argument("--child", action="store_true")
     ap.add_argument("--persons", type=int,
                     default=int(os.environ.get(
                         "NEBULA_BENCH_MULTICHIP_PERSONS", 120_000)))
     ap.add_argument("--degree", type=int, default=6)
     ap.add_argument("--steps", type=int, default=3)
     ap.add_argument("--repeats", type=int, default=5)
-    ap.add_argument("--timeout", type=float,
-                    default=float(os.environ.get(
-                        "NEBULA_BENCH_MULTICHIP_TIMEOUT", 600)))
     args = ap.parse_args(argv)
-    if args.child:
-        return _child_main(args)
     res = multichip_sweep(args.persons, args.degree, args.steps,
-                          args.repeats, args.timeout)
+                          args.repeats)
     print(json.dumps(res, indent=2))
     return 0
 

@@ -8,27 +8,94 @@ src/kvstore/NebulaStore [UNVERIFIED — empty mount, SURVEY §0]).
 """
 from __future__ import annotations
 
+import logging
+import os
 from dataclasses import dataclass, field
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import numpy as np
+from jax import shard_map  # noqa: F401 — re-exported to hop.py / bfs.py
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from ..graphstore.csr import CsrSnapshot, StringPool
 from ..graphstore.schema import PropType
 
-# jax moved shard_map out of experimental at ~0.6; export the resolved
-# callable so every kernel module (hop, bfs, future ones) shares ONE
-# version shim instead of re-probing
-shard_map = getattr(jax, "shard_map", None)
-if shard_map is None:
-    from jax.experimental.shard_map import shard_map
+_log = logging.getLogger(__name__)
+
+#: the checkout root (parent of the `nebula_tpu` package): the default
+#: home of the persistent compile cache
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
 
 
 class TpuUnavailable(Exception):
     """The device plane cannot serve this space/config; callers fall back
     to the host execution path."""
+
+
+def note_host_fallback(site: str, ex: BaseException) -> str:
+    """Record ONE execute-time device→host fallback: the statement is
+    about to be answered by the host engine with identical rows (the
+    "never wrong, only absent" contract), which on a chip is the
+    difference between "runs on the TPU" and "answers from Python".
+    Counted in `tpu_host_fallback{site,error}` and logged at WARNING
+    so a served graphd's operator — not only the handler thread's
+    `last_tpu_fallback` — can see it.  A `CannotCompile` at PLAN time
+    is routine and never comes through here.  Returns the cause string
+    callers keep in `qctx.last_tpu_fallback`."""
+    from ..utils.stats import stats
+    cause = f"{type(ex).__name__}: {ex}"
+    stats().inc_labeled("tpu_host_fallback",
+                        {"site": site, "error": type(ex).__name__})
+    _log.warning("device plane fell back to the host engine at %s: %s",
+                 site, cause)
+    return cause
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its
+    directory.  The directory is placed from OUTSIDE: where
+    JAX_COMPILATION_CACHE_DIR is set jax reads it itself and no code
+    sets another; where it is not, the cache is `<checkout>/.jax_cache`
+    — a fixed path, because the path is part of the cache key (a
+    temporary name, a pid or a time would never hit).  Every entry is
+    kept (no size or compile-time floor): a served graphd restarts
+    into the programs it already compiled."""
+    d = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not d:
+        d = os.path.join(_CHECKOUT, ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", d)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    _log.info("persistent compile cache at %s", d)
+    return d
+
+
+def device_identity() -> Dict[str, Any]:
+    """The device as jax reports it — what every bench/smoke result
+    line names."""
+    devs = jax.devices()
+    return {"platform": devs[0].platform, "kind": devs[0].device_kind,
+            "count": len(devs)}
+
+
+def require_tpu(who: str) -> Dict[str, Any]:
+    """Log the device identity and refuse a platform other than `tpu`
+    — unless the operator chose the host backend EXPLICITLY with
+    JAX_PLATFORMS=cpu (tests, rehearsals).  A device plane that landed
+    on the CPU without anyone asking for it must fail at start, not
+    serve."""
+    ident = device_identity()
+    _log.warning("%s: device plane on platform=%s kind=%s count=%d",
+                 who, ident["platform"], ident["kind"], ident["count"])
+    if ident["platform"] != "tpu" and \
+            os.environ.get("JAX_PLATFORMS", "").strip().lower() != "cpu":
+        raise RuntimeError(
+            f"{who}: jax found platform {ident['platform']!r} "
+            f"({ident['kind']}), not 'tpu' — set JAX_PLATFORMS=cpu to "
+            f"run the device plane on the host backend on purpose")
+    return ident
 
 
 def init_multihost():
@@ -42,7 +109,6 @@ def init_multihost():
     Controlled by NEBULA_COORDINATOR (host:port of process 0) plus
     NEBULA_NUM_PROCESSES / NEBULA_PROCESS_ID; no-op when unset,
     idempotent when called twice."""
-    import os
     coord = os.environ.get("NEBULA_COORDINATOR")
     if not coord:
         return False
@@ -79,27 +145,14 @@ def init_multihost():
 
 
 def make_mesh(n_devices: Optional[int] = None, devices=None) -> Mesh:
-    """A 1-D 'part' mesh: one graph partition per device slot."""
-    explicit = devices is not None
+    """A 1-D 'part' mesh: one graph partition per device slot, built
+    from `jax.devices()` (or the explicit list) and nothing else."""
     if devices is None:
         init_multihost()
         devices = jax.devices()
     if n_devices is None:
         n_devices = len(devices)
-    if n_devices > len(devices) and not explicit:
-        # 1-chip host asked for an N-way mesh: the CPU platform may carry
-        # virtual devices (--xla_force_host_platform_device_count)
-        try:
-            cpu = jax.devices("cpu")
-        except RuntimeError:
-            cpu = []
-        if len(cpu) >= n_devices:
-            devices = cpu
-        else:
-            raise ValueError(
-                f"need {n_devices} devices, have {len(devices)} "
-                f"(and {len(cpu)} cpu)")
-    elif n_devices > len(devices):
+    if n_devices > len(devices):
         raise ValueError(f"need {n_devices} devices, have {len(devices)}")
     return Mesh(np.asarray(devices[:n_devices]), ("part",))
 
@@ -120,12 +173,6 @@ def make_mesh2(lanes: int = 1, parts: Optional[int] = None,
     if devices is None:
         init_multihost()
         devices = jax.devices()
-        try:
-            cpu = jax.devices("cpu")
-        except RuntimeError:
-            cpu = []
-        if len(cpu) > len(devices):
-            devices = cpu
     devices = list(devices)
     if parts is None:
         parts = max(len(devices) // max(lanes, 1), 1)

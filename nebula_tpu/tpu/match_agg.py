@@ -49,15 +49,10 @@ from ..core.value import DataSet, Vertex, is_null
 from ..exec.executors import executor, _make_edge
 from ..query import optimizer as opt
 from ..query.plan import PlanNode
-from .device import TpuUnavailable
+from .device import TpuUnavailable, note_host_fallback
 from .exprjit import (CannotCompile, compile_vertex_predicate_np,
                       vertex_compilable)
-
-try:
-    import jax
-    _JAX_RT_ERRORS = (jax.errors.JaxRuntimeError,)
-except (ImportError, AttributeError):
-    _JAX_RT_ERRORS = ()
+from .traverse import _JAX_RT_ERRORS
 
 
 # ---------------------------------------------------------------------------
@@ -453,7 +448,8 @@ def _tpu_match_agg(node, qctx, ectx, space):
             try:
                 return _device_match_agg(node, qctx, ectx, a, rt)
             except (CannotCompile, TpuUnavailable) + _JAX_RT_ERRORS as ex:
-                qctx.last_tpu_fallback = f"{type(ex).__name__}: {ex}"
+                qctx.last_tpu_fallback = note_host_fallback(
+                    "match_agg", ex)
     return _host_match_agg(node, qctx, a)
 
 

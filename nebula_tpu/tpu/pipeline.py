@@ -58,16 +58,11 @@ from ..utils import trace
 from ..utils.failpoints import FailpointError, fail
 from ..utils.config import define_flag, get_config
 from ..utils.stats import stats
-from .device import TpuUnavailable
+from .device import TpuUnavailable, note_host_fallback
 from .exprjit import (CannotCompile, compilable,
                       compile_vertex_predicate_np, vertex_compilable)
 from .match_agg import _exists_flat, _seed_vids, _tag_flat
-
-try:
-    import jax
-    _JAX_RT_ERRORS = (jax.errors.JaxRuntimeError,)
-except (ImportError, AttributeError):
-    _JAX_RT_ERRORS = ()
+from .traverse import _JAX_RT_ERRORS
 
 define_flag("tpu_match_pipeline", True,
             "fuse multi-clause MATCH pipelines into one columnar "
@@ -1247,7 +1242,8 @@ def _tpu_match_pipeline(node, qctx, ectx, space):
             # runtime fault — fall back to the stashed row subplan.
             # QueryKilled/DeadlineExceeded are NOT in this tuple: a
             # killed statement must die, not fall back.
-            qctx.last_tpu_fallback = f"{type(ex).__name__}: {ex}"
+            qctx.last_tpu_fallback = note_host_fallback(
+                "match_pipeline", ex)
             reason = f"runtime:{type(ex).__name__}"
     elif rt is not None:
         reason = "device-flag-off"

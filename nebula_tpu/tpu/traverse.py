@@ -19,18 +19,17 @@ from __future__ import annotations
 
 from typing import Any, Dict, List, Optional, Tuple
 
+import jax
+
 from ..core.value import DataSet, Edge, is_null
 from ..exec.executors import executor
 from ..query import optimizer as opt
 from ..query.plan import PlanNode, walk_plan
-from .device import TpuUnavailable
+from .device import TpuUnavailable, note_host_fallback
 from .exprjit import CannotCompile, compilable, yieldable
 
-try:
-    import jax
-    _JAX_RT_ERRORS = (jax.errors.JaxRuntimeError,)
-except (ImportError, AttributeError):
-    _JAX_RT_ERRORS = ()
+# a TPU compile refusal or an HBM RESOURCE_EXHAUSTED raises this
+_JAX_RT_ERRORS = (jax.errors.JaxRuntimeError,)
 
 # ---------------------------------------------------------------------------
 # Fusion rule
@@ -171,9 +170,9 @@ def _tpu_traverse(node, qctx, ectx, space):
             # JaxRuntimeError covers device-capacity failures (e.g. HBM
             # RESOURCE_EXHAUSTED on pin); escalation non-convergence
             # raises TpuUnavailable.  The host path below has identical
-            # semantics; the fallback cause is recorded for PROFILE/debug
-            # rather than silently swallowed.
-            qctx.last_tpu_fallback = f"{type(ex).__name__}: {ex}"
+            # semantics; the fallback is counted, logged and recorded
+            # for PROFILE/debug rather than silently swallowed.
+            qctx.last_tpu_fallback = note_host_fallback("traverse", ex)
     return _host_traverse(node, qctx, sp, vids)
 
 

@@ -1,0 +1,152 @@
+"""The main path's device programs, compiled for a DESCRIBED TPU v5e at
+the real (north-star) shapes — no chip attached, nothing runs.
+
+What interpret-mode and CPU-backend tests cannot show: a program the
+TPU compiler refuses, one that does not fit a chip's 16 GB, a sharded
+program that lost its collective, or a kernel whose compile takes
+minutes (the float64 `jnp.cumsum` that `pagerank_step` used to carry
+needed 194 s at 600,000 edges).  A compile that passes here is not a
+chip run; `chip_smoke.py` is.
+
+The topology is described inside a module-scoped fixture (only the
+xdist worker that runs THIS file loads libtpu, and only after a test
+has started), never at import, and all of these tests live in this one
+file for the same reason.  The persistent compile cache is off around
+them: an entry compiled for a described device cannot be read back.
+"""
+import os
+import time
+
+import numpy as np
+import pytest
+
+# north-star shapes: 1,000,000 persons x degree 30 over 8 parts
+P8, VMAX8, E8 = 8, 125_000, 4_194_304
+# the same graph sharded one partition per chip of a 2x2 host
+P4, VMAX4, E4 = 4, 250_000, 8_388_608
+N_SLOTS, N_EDGES = 1_000_000, 30_000_000
+HBM_BYTES = 16 * 1024 ** 3
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    import nebula_tpu.tpu  # noqa: F401 — enables x64 like every caller
+    try:
+        t = topologies.get_topology_desc(platform="tpu",
+                                         topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "not here"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield t
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _struct(shape, dtype, sharding):
+    import jax
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _block(P, vmax, E, sharding, props=("w",), rev=False):
+    """One CSR block's kernel leaves as shapes (runtime.py `_bd`)."""
+    b = {"indptr": _struct((P, vmax + 1), np.int32, sharding),
+         "nbr": _struct((P, E), np.int32, sharding),
+         "rank": _struct((P, E), np.int32, sharding),
+         "props": {n: _struct((P, E), np.int64, sharding) for n in props}}
+    if rev:
+        b.update(rev_indptr=b["indptr"], rev_nbr=b["nbr"],
+                 rev_rank=b["rank"], rev_props={})
+    return b
+
+
+def _compile(fn, *args):
+    t0 = time.perf_counter()
+    compiled = fn.lower(*args).compile()
+    ma = compiled.memory_analysis()
+    need = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    assert need < HBM_BYTES, f"program needs {need:,} bytes of HBM"
+    return compiled, time.perf_counter() - t0
+
+
+def test_go_three_steps_capture_compiles(one_chip):
+    """The north-star statement: 3-step GO, final hop captured with an
+    int64 prop gathered on device (YIELD dst(edge), KNOWS.w)."""
+    from nebula_tpu.tpu.hop import build_traverse_fn_local
+    fn = build_traverse_fn_local(P8, (1 << 12, 1 << 17, 1 << 22), 3,
+                                 n_blocks=1, capture=True,
+                                 yield_cols=("w",))
+    _compile(fn, (_block(P8, VMAX8, E8, one_chip),),
+             _struct((P8, VMAX8), np.bool_, one_chip))
+
+
+def test_match_var_len_capture_hops_compiles(one_chip):
+    """MATCH *1..4: four hops, every hop's frame captured."""
+    from nebula_tpu.tpu.hop import build_traverse_fn_local
+    fn = build_traverse_fn_local(P8, 1 << 20, 4, n_blocks=1,
+                                 capture=True, capture_hops=True)
+    _compile(fn, (_block(P8, VMAX8, E8, one_chip, props=()),),
+             _struct((P8, VMAX8), np.bool_, one_chip))
+
+
+def test_sharded_go_compiles_with_all_to_all(topo):
+    """One partition per chip on the 2x2 host: the frontier exchange
+    must still be an all-to-all in the compiled program."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from nebula_tpu.tpu.hop import build_traverse_fn
+    mesh = Mesh(np.asarray(topo.devices[:P4]), ("part",))
+    part = NamedSharding(mesh, PartitionSpec("part"))
+    fn = build_traverse_fn(mesh, P4, (1 << 12, 1 << 17, 1 << 21), 3,
+                           n_blocks=1, capture=True, yield_cols=("w",))
+    compiled, _ = _compile(fn, (_block(P4, VMAX4, E4, part),),
+                           _struct((P4, VMAX4), np.bool_, part))
+    assert "all-to-all" in compiled.as_text()
+
+
+def test_bfs_compiles(one_chip):
+    """FIND SHORTEST PATH's direction-optimizing single-chip BFS."""
+    from nebula_tpu.tpu.bfs import build_bfs_fn_local
+    fn = build_bfs_fn_local(P8, 1 << 22, 5, 1, VMAX8, have_rev=True)
+    _compile(fn, (_block(P8, VMAX8, E8, one_chip, props=(), rev=True),),
+             _struct((P8, VMAX8), np.bool_, one_chip))
+
+
+@pytest.mark.parametrize("algo", ["pagerank", "wcc", "sssp"])
+def test_algo_step_compiles_fast(one_chip, algo):
+    """The CALL algo.* iteration kernels at 1,000,000 slots /
+    30,000,000 edges.  The bound is the issue's: under two minutes
+    (each takes about a second; the old float64 cumsum in
+    pagerank_step did not finish in ten minutes)."""
+    from nebula_tpu.algo import kernels
+
+    def v(dt):
+        return _struct((N_SLOTS,), dt, one_chip)
+
+    def e(dt):
+        return _struct((N_EDGES,), dt, one_chip)
+    if algo == "pagerank":
+        fn = kernels.pagerank_step(N_SLOTS, 0.85, 0.0)
+        args = (v(np.float64), e(np.int32), e(np.int32), e(np.float64),
+                v(np.bool_), v(np.bool_), 1_000_000.0)
+    elif algo == "wcc":
+        fn = kernels.wcc_step(N_SLOTS)
+        args = (v(np.int64), v(np.bool_), e(np.int32), e(np.int32))
+    else:
+        fn = kernels.sssp_step(N_SLOTS, True)
+        args = (v(np.float64), v(np.bool_), e(np.int32), e(np.int32),
+                e(np.float64))
+    _, secs = _compile(fn, *args)
+    assert secs < 120, f"{algo}_step took {secs:.0f}s to compile"
