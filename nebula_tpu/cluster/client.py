@@ -42,6 +42,7 @@ from typing import Dict, List, Optional, Sequence, Union
 from ..core.wire import from_wire
 from ..exec.context import ResultSet
 from ..utils.config import get_config
+from ..utils.stats import stats
 from .rpc import RpcClient, RpcConnError, RpcError, RpcNeverSentError
 
 #: how much longer the client waits than the server's statement budget:
@@ -154,7 +155,6 @@ class GraphClient:
                     self._adopted.add(ep)
                 self.addr = ep
                 if count:
-                    from ..utils.stats import stats
                     stats().inc("coordinator_failovers")
                 return True
             except (RpcError, RpcConnError):
@@ -203,7 +203,6 @@ class GraphClient:
         if self.session_id is None:
             raise RpcError("not authenticated")
         from ..utils.admission import is_overload, parse_retry_after
-        from ..utils.stats import stats
         deadline = time.monotonic() + _statement_timeout()
         lost: set = set()
         while True:
@@ -264,8 +263,13 @@ class GraphClient:
                 from .storage_client import note_peer_latency
                 note_peer_latency(self.addr, time.perf_counter() - t0)
                 if not is_overload(r["error"]):
-                    data = from_wire(r["data"]) \
-                        if r["data"] is not None else None
+                    data = None
+                    if r["data"] is not None:
+                        td = time.perf_counter()
+                        data = from_wire(r["data"])
+                        stats().add_value(
+                            "client_decode_us",
+                            (time.perf_counter() - td) * 1e6)
                     return ResultSet(data=data, space=r["space"],
                                      latency_us=r["latency_us"],
                                      plan_desc=r["plan_desc"],

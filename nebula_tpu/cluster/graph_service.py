@@ -9,6 +9,7 @@ mount, SURVEY §0]).
 """
 from __future__ import annotations
 
+import contextlib
 import re
 import threading
 import time
@@ -16,6 +17,7 @@ from typing import Any, Dict, Optional
 
 from ..core.wire import to_wire
 from ..exec.engine import QueryEngine, Session
+from ..utils import trace
 from ..utils.admission import overload_error
 from ..utils.config import get_config
 from ..utils.stats import stats
@@ -291,6 +293,15 @@ class GraphService:
         return True
 
     def rpc_execute(self, p):
+        # ONE trace per served statement, from entry to return of this
+        # handler: session lookup, parse, plan, execution, encode and
+        # the session update all lie under its root
+        tg = self.engine.statement_trace(p.get("session_id"),
+                                         p.get("stmt", ""))
+        with tg or contextlib.nullcontext():
+            return self._execute(p, tg)
+
+    def _execute(self, p, tg):
         if self._draining:
             # refused BEFORE execution: the client may retry ANY
             # statement (including writes) on the sibling — nothing ran
@@ -312,20 +323,24 @@ class GraphService:
             sess = self.sessions.get(p["session_id"])
         if sess is None:
             raise RpcError("Session invalid or expired")
-        rs = self.engine.execute(sess, p["stmt"])
+        rs = self.engine.execute(sess, p["stmt"], trace_root=tg)
+        # bulk numeric results leave here as typed column blobs
+        # (core/wire.py columnar fast path) — the RPC layer ships
+        # them out-of-band of the JSON, zero-copy
+        data = None
+        if rs.data is not None:
+            with trace.span("graphd:encode"):
+                data = to_wire(rs.data)
         if sess.space:
             try:
                 self.meta.update_session(sess.id, space=sess.space)
-            except Exception:  # noqa: BLE001
-                pass
+            except Exception:  # noqa: BLE001 — the statement has run
+                stats().inc("session_update_failed")
         return {
             "error": rs.error,
             "space": rs.space,
             "latency_us": rs.latency_us,
-            # bulk numeric results leave here as typed column blobs
-            # (core/wire.py columnar fast path) — the RPC layer ships
-            # them out-of-band of the JSON, zero-copy
-            "data": to_wire(rs.data) if rs.data is not None else None,
+            "data": data,
             "plan_desc": rs.plan_desc,
         }
 

@@ -574,8 +574,8 @@ class RaftPart:
             # a caught-up learner became a voter: from here its acks
             # count toward quorum and it may campaign / grant votes
             fail.hit("raft:promote_learner", key=self.group)
-            _trace.record_phase("raft:promote_learner", 0.0,
-                                group=self.group, peers=promoted)
+            _trace.mark("raft:promote_learner",
+                        group=self.group, peers=promoted)
         if self.is_leader():
             self._replicate_all()   # new follower gets snapshot/catch-up
 

@@ -347,8 +347,8 @@ class CircuitBreaker:
             _stats().inc("rpc_breaker_probes")
         # trace coverage (ISSUE 8 satellite): breaker state changes land
         # in the statement's trace tree with the peer labeled
-        _trace.record_phase("rpc:breaker", 0.0, peer=self.peer,
-                            to="half_open")
+        _trace.mark("rpc:breaker", peer=self.peer,
+                    to="half_open")
         return True
 
     def record_success(self):
@@ -361,8 +361,8 @@ class CircuitBreaker:
             self.failures = 0
             self._probing = False
         if reopened:
-            _trace.record_phase("rpc:breaker", 0.0, peer=self.peer,
-                                to="closed")
+            _trace.mark("rpc:breaker", peer=self.peer,
+                        to="closed")
 
     def release_probe(self):
         """Relinquish a half-open probe slot without a verdict: the
@@ -394,8 +394,8 @@ class CircuitBreaker:
                 self.state = "open"
                 self.opened_at = time.monotonic()
         if tripped:
-            _trace.record_phase("rpc:breaker", 0.0, peer=self.peer,
-                                to="open")
+            _trace.mark("rpc:breaker", peer=self.peer,
+                        to="open")
 
 
 _breakers: Dict[str, CircuitBreaker] = {}
@@ -480,10 +480,12 @@ class RpcServer:
                 try:
                     while True:
                         req, _, rid = _recv_frame(sock)
+                        t_read = time.perf_counter()
                         if outer._stopped.is_set():
                             break       # drop the connection, no reply
                         if rid is None:
-                            outer._serve_one(sock, wlock, None, req)
+                            outer._serve_one(sock, wlock, None, req,
+                                             t_read)
                             continue
                         shed = outer._inbox_enter(req)
                         if shed is not None:
@@ -503,7 +505,7 @@ class RpcServer:
                                 max_workers=max(1, workers),
                                 thread_name_prefix="rpc-srv")
                         pool.submit(outer._serve_pooled, sock, wlock,
-                                    rid, req)
+                                    rid, req, t_read)
                 except (RpcConnError, socket.timeout, OSError,
                         json.JSONDecodeError, ValueError):
                     pass
@@ -551,9 +553,9 @@ class RpcServer:
             retry, f"{self.service_role}:rpc_inbox",
             f"server inbox full (inflight={depth}, capacity={cap})")}
 
-    def _serve_pooled(self, sock, wlock, rid, req):
+    def _serve_pooled(self, sock, wlock, rid, req, t_read):
         try:
-            self._serve_one(sock, wlock, rid, req)
+            self._serve_one(sock, wlock, rid, req, t_read)
         finally:
             with self._inbox_mu:
                 self._inbox = max(self._inbox - 1, 0)
@@ -565,8 +567,8 @@ class RpcServer:
                 # teaching shed clients to retry far too early
                 self._inbox_drain.note_done()
 
-    def _serve_one(self, sock, wlock, rid, req):
-        reply = self._dispatch(req)
+    def _serve_one(self, sock, wlock, rid, req, t_read):
+        reply = self._dispatch(req, t_read)
         try:
             # the ack-lost window: the handler HAS run (possibly a
             # committed write) but the reply never reaches the client —
@@ -613,7 +615,11 @@ class RpcServer:
             if name.startswith("rpc_"):
                 self.register(prefix + name[4:], getattr(obj, name))
 
-    def _dispatch(self, req: Any) -> Dict[str, Any]:
+    def _dispatch(self, req: Any, t_read: float) -> Dict[str, Any]:
+        """`t_read`: perf_counter when the frame had been read — the
+        handler span's `inbox_us` is the wait from there to its start
+        (worker-pool queueing), which the caller's `rpc:` span cannot
+        tell from transport."""
         method = req.get("method") if isinstance(req, dict) else None
         if not method:
             return {"ok": False, "error": "malformed request frame"}
@@ -676,8 +682,10 @@ class RpcServer:
                 with _trace.adopt_remote(wire_trace[0], wire_trace[1],
                                          self.service_role) as rg:
                     spans = rg.spans
-                    with _trace.span(f"rpc.server:{method}"), \
-                            use_cost(crec):
+                    with _trace.span(
+                            f"rpc.server:{method}",
+                            inbox_us=int((time.perf_counter() - t_read)
+                                         * 1e6)), use_cost(crec):
                         result = fn(params)
                 return _cost_of({"ok": True, "result": result,
                                  "spans": spans})
@@ -997,9 +1005,9 @@ class RpcClient:
             _stats().inc_labeled("rpc_client_retries", {"op": method})
             # trace coverage (ISSUE 8 satellite): every retry attempt
             # is a leaf in the statement's trace with the peer labeled
-            _trace.record_phase("rpc:retry", 0.0, peer=peer, op=method,
-                                attempt=attempt,
-                                error=type(ex).__name__)
+            _trace.mark("rpc:retry", peer=peer, op=method,
+                        attempt=attempt,
+                        error=type(ex).__name__)
 
         with _trace.span(f"rpc:{method}", peer=f"{self.host}:{self.port}"):
             for attempt in range(self.retries + 1):
@@ -1047,9 +1055,8 @@ class RpcClient:
                         # re-sent anything, so the rpc_client_retries
                         # counter (an internal-re-send measure feeding
                         # retry_amplification) must not move
-                        _trace.record_phase(
-                            "rpc:retry", 0.0, peer=peer, op=method,
-                            attempt=attempt, error="CircuitOpen")
+                        _trace.mark("rpc:retry", peer=peer, op=method,
+                                    attempt=attempt, error="CircuitOpen")
                         deadline_sleep(retry_backoff(attempt))
                     continue
                 sent_any = False
@@ -1145,9 +1152,8 @@ class RpcClient:
                     if attempt < self.retries:
                         _stats().inc_labeled("overload_client_retries",
                                              {"op": method})
-                        _trace.record_phase(
-                            "rpc:retry", 0.0, peer=peer, op=method,
-                            attempt=attempt, error="Overload")
+                        _trace.mark("rpc:retry", peer=peer, op=method,
+                                    attempt=attempt, error="Overload")
                         hint = parse_retry_after(err)
                         # jitter the hint: every client shed in one
                         # saturation burst sees the same depth and the

@@ -664,9 +664,9 @@ class StorageService:
                     raise RpcError(
                         f"part_leader_changed: {part.leader_id or ''}")
         stats().inc_labeled("follower_read_total", {"consistency": lvl})
-        _trace.record_phase("storage:follower_read", 0.0, part=pid,
-                            addr=self.my_addr, consistency=lvl,
-                            applied=part.applied_index())
+        _trace.mark("storage:follower_read", part=pid,
+                    addr=self.my_addr, consistency=lvl,
+                    applied=part.applied_index())
         cc = current_cost()
         if cc is not None:
             cc.add("follower_reads", 1)
@@ -721,8 +721,8 @@ class StorageService:
                 # path hit is a zero-duration leaf in the statement's
                 # trace (shipped back in the reply spans) and a
                 # `dedup_hits` field in the reply cost record
-                _trace.record_phase("storage:dedup_hit", 0.0, part=pid,
-                                    writer=writer, seq=seq)
+                _trace.mark("storage:dedup_hit", part=pid,
+                            writer=writer, seq=seq)
                 cc = current_cost()
                 if cc is not None:
                     cc.add("dedup_hits", 1)

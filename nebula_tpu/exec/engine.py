@@ -8,6 +8,7 @@ registry.
 """
 from __future__ import annotations
 
+import contextlib
 import itertools
 import threading
 import time
@@ -20,13 +21,15 @@ from ..query import ast as A
 from ..query.optimizer import optimize
 from ..query.parser import ParseError, parse
 from ..query.planner import PlannerContext, QueryError, plan_statement
+from ..utils import trace
+from ..utils.config import define_flag as _define_flag
+from ..utils.config import get_config
+from ..utils.stats import stats
 from .context import ExecutionContext, QueryContext, ResultSet
 from .scheduler import ProfileStats, Scheduler
 
 _session_ids = itertools.count(1)
 _query_ids = itertools.count(1)
-
-from ..utils.config import define_flag as _define_flag
 
 _define_flag("plan_cache_size", 128,
              "parsed-plan LRU entries per engine (0 disables); keyed by "
@@ -97,14 +100,12 @@ class PlanCache:
 
     @staticmethod
     def capacity() -> int:
-        from ..utils.config import get_config
         try:
             return int(get_config().get("plan_cache_size"))
         except Exception:  # noqa: BLE001 — config not initialized
             return 0
 
     def get(self, key: Tuple):
-        from ..utils.stats import stats
         with self._lock:
             ent = self._map.get(key)
             if ent is not None:
@@ -117,7 +118,6 @@ class PlanCache:
         cap = self.capacity()
         if cap <= 0:
             return
-        from ..utils.stats import stats
         # a put IS the miss: counting at insert time keeps the miss
         # counter scoped to CACHEABLE statements — bulk INSERT/DDL
         # traffic (looked up, never inserted) must not read as a bad
@@ -166,14 +166,12 @@ class ResultCache:
 
     @staticmethod
     def capacity() -> int:
-        from ..utils.config import get_config
         try:
             return int(get_config().get("result_cache_size"))
         except Exception:  # noqa: BLE001 — config not initialized
             return 0
 
     def get(self, key: Tuple):
-        from ..utils.stats import stats
         with self._lock:
             ent = self._map.get(key)
             if ent is not None:
@@ -186,7 +184,6 @@ class ResultCache:
         cap = self.capacity()
         if cap <= 0:
             return
-        from ..utils.stats import stats
         # a put IS the miss (same scoping rationale as PlanCache.put:
         # only statements that COULD have hit count against the rate)
         stats().inc("result_cache_misses")
@@ -203,7 +200,6 @@ class ResultCache:
         count it (the `result_cache_invalidations` metric; a
         dedup-window-replayed write still acks as ONE statement, so it
         bumps — and counts — exactly once)."""
-        from ..utils.stats import stats
         with self._lock:
             n = len(self._map)
         if n:
@@ -296,7 +292,6 @@ class QueryEngine:
         # reap idle sessions so a long-lived embedded engine doesn't
         # accumulate them (the cluster graphd reaps via metad TTL; the
         # standalone registry uses the same idle-timeout flag)
-        from ..utils.config import get_config
         ttl = float(get_config().get("session_idle_timeout_secs"))
         now = time.time()
         # list() snapshots atomically under the GIL — a comprehension
@@ -384,7 +379,6 @@ class QueryEngine:
         running engine."""
         if self._slow_override is not None:
             return int(self._slow_override)
-        from ..utils.config import get_config
         return int(get_config().get("slow_query_threshold_us"))
 
     def _fingerprint(self, stmt: A.Sentence, text: str,
@@ -423,7 +417,6 @@ class QueryEngine:
         if (PlanCache.capacity() <= 0 and ResultCache.capacity() <= 0) \
                 or session.var_cols:
             return None
-        from ..utils.config import get_config
         tpu_on = self.qctx.tpu_runtime is not None and \
             bool(get_config().get("tpu_enable"))
         epoch = getattr(self.qctx.catalog, "version", 0)
@@ -434,7 +427,6 @@ class QueryEngine:
         table first: `result_cache_strict_epoch` is on AND the read
         asked for leader consistency (weaker levels accepted bounded
         staleness by contract — the heartbeat window is within it)."""
-        from ..utils.config import get_config
         try:
             if not bool(get_config().get("result_cache_strict_epoch")):
                 return False
@@ -443,15 +435,37 @@ class QueryEngine:
         from ..utils.consistency import LEADER, effective_consistency
         return effective_consistency() == LEADER
 
+    def statement_trace(self, session_id, text: str):
+        """The root trace of one statement (named `query:<kind>` once
+        the kind is known), or None with `enable_query_tracing` off.
+        The graph service opens it at the entry of its handler and
+        hands it to `execute(trace_root=...)`, so that ONE trace covers
+        session lookup, parse, plan, execution, encode and the session
+        update; an engine used directly roots it in `execute`."""
+        if not get_config().get("enable_query_tracing"):
+            return None
+        kind = "Statement"      # until the parse (or a cache) names it
+        return trace.start_trace(f"query:{kind}", service="graphd",
+                                 stmt=text[:200], session=session_id)
+
     def execute(self, session: Session, text: str,
-                params: Optional[Dict[str, Any]] = None) -> ResultSet:
+                params: Optional[Dict[str, Any]] = None,
+                trace_root=None) -> ResultSet:
         t0 = time.perf_counter()
+        if trace_root is not None:
+            return self._execute(session, text, t0, trace_root)
+        tg = self.statement_trace(session.id, text)
+        with tg or contextlib.nullcontext():
+            return self._execute(session, text, t0, tg)
+
+    def _execute(self, session: Session, text: str, t0: float,
+                 tg) -> ResultSet:
+        """`tg`: the statement's open root trace, or None."""
         if session.killed:
             rs = ResultSet()
             rs.error = "Session was killed"
             return rs
         session.last_used = time.time()
-        from ..utils.stats import stats
         key = self._cache_key(session, text)
         # result cache first (ISSUE 11): a hit skips parse AND
         # execution — the write epoch in the key guarantees no local
@@ -482,17 +496,27 @@ class QueryEngine:
                           self.cluster_epochs.gen(session.space))
             ent = self.result_cache.get(rkey)
             if ent is not None:
+                if tg is not None:
+                    tg.set_name("query:CachedRead")
+                    tg.set(result_cache="hit")
                 return self._result_cache_hit(session, text, ent, t0)
         if key is not None:
             ent = self.plan_cache.get(key)
             if ent is not None:
                 stmt, plan = ent
+                if tg is not None:
+                    tg.set(plan_cache="hit")
                 return self._execute_parsed(session, stmt, text, t0,
                                             cached_plan=plan,
-                                            result_key=rkey)
+                                            result_key=rkey, tg=tg)
+        if tg is not None:
+            tg.set(plan_cache="miss")
         try:
-            stmt = parse(text)
+            with trace.span("graphd:parse"):
+                stmt = parse(text)
         except ParseError as ex:
+            if tg is not None:
+                tg.set_name("query:Parse")
             stats().inc("num_queries")
             stats().inc("num_query_errors")
             err = f"SyntaxError: {ex}"
@@ -521,18 +545,24 @@ class QueryEngine:
             # after the previous ran, so DDL/USE side effects are visible
             # to later statements; the result is the last statement's
             # (reference semantics for compound execute())
+            # ONE trace for the compound (`query:Seq`): its root covers
+            # the one parse, and each sub-statement's plan and executor
+            # spans hang off it in order
+            if tg is not None:
+                tg.set_name("query:Seq")
             res = ResultSet()
             for sub in stmt.stmts:
                 # memo_fp off: the (text, space) memo key would alias
                 # every sub-statement of the compound to one fingerprint
                 res = self._execute_parsed(session, sub, text,
                                            time.perf_counter(),
-                                           memo_fp=False)
+                                           memo_fp=False, tg=tg,
+                                           name_root=False)
                 if not res.ok:
                     return res
             return res
         return self._execute_parsed(session, stmt, text, t0,
-                                    cache_key=key, result_key=rkey)
+                                    cache_key=key, result_key=rkey, tg=tg)
 
     def _result_cache_hit(self, session: Session, text: str, ent,
                           t0: float) -> ResultSet:
@@ -542,7 +572,6 @@ class QueryEngine:
         /stats and leaves a flight-recorder entry."""
         from ..core.wire import from_wire
         from ..utils.flight import flight_recorder
-        from ..utils.stats import stats
         wire_data, space = ent
         data = from_wire(wire_data) if wire_data is not None else None
         us = int((time.perf_counter() - t0) * 1e6)
@@ -584,38 +613,28 @@ class QueryEngine:
                         text: str, t0: float, cached_plan=None,
                         cache_key: Optional[tuple] = None,
                         result_key: Optional[tuple] = None,
-                        memo_fp: bool = True) -> ResultSet:
-        """Metrics + tracing wrapper: every statement outcome (incl.
-        semantic and execution errors) is visible in /stats; every
-        statement produces one trace in the trace store, queryable via
-        /traces and SHOW TRACES — and a per-operator profile that the
-        flight recorder retains for sampled/slow/failed statements."""
-        from ..utils import trace
-        from ..utils.config import get_config
-        from ..utils.stats import stats
+                        memo_fp: bool = True, tg=None,
+                        name_root: bool = True) -> ResultSet:
+        """Metrics wrapper: every statement outcome (incl. semantic and
+        execution errors) is visible in /stats; the statement's trace
+        (`tg`, opened by `execute` or the graph service) takes its name
+        here, is queryable via /traces and SHOW TRACES, and a
+        per-operator profile goes to the flight recorder for
+        sampled/slow/failed statements."""
         kind = self._stmt_kind(stmt)
+        if tg is not None and name_root:
+            tg.set_name(f"query:{kind}")
         # statement fingerprint (ISSUE 16): computed once here (memoized
         # next to the plan-cache key), stamped onto the live row, the
         # slow log and the flight entry, and aggregated on completion
         space0 = session.space or ""
         fp = self._fingerprint(stmt, text, space0, memo=memo_fp)
-        tg = None
-        if get_config().get("enable_query_tracing"):
-            tg = trace.start_trace(f"query:{kind}", service="graphd",
-                                   stmt=text[:200], session=session.id)
         # always-on observation (ISSUE 8): per-node timings/rows/remote
         # cost are collected for EVERY statement — PROFILE renders them,
         # the flight recorder retains them for the queries that matter
         obs = ProfileStats()
-        if tg is not None:
-            with tg:
-                res = self._execute_inner(session, stmt, text, t0,
-                                          cached_plan, cache_key, obs,
-                                          fp=fp)
-        else:
-            res = self._execute_inner(session, stmt, text, t0,
-                                      cached_plan, cache_key, obs,
-                                      fp=fp)
+        res = self._execute_inner(session, stmt, text, t0, cached_plan,
+                                  cache_key, obs, fp=fp)
         us = int((time.perf_counter() - t0) * 1e6)
         stats().inc("num_queries")
         stats().add_value("query_latency_us", us)
@@ -678,7 +697,6 @@ class QueryEngine:
                        cache_key: Optional[tuple] = None,
                        obs: Optional[ProfileStats] = None,
                        fp: Optional[str] = None) -> ResultSet:
-        from ..utils.config import get_config
         if get_config().get("enable_authorize"):
             from .permissions import check as _perm_check
             msg = _perm_check(stmt, session.user, self.qctx.store.catalog,
@@ -716,22 +734,24 @@ class QueryEngine:
             plan = cached_plan
         else:
             try:
-                pctx = PlannerContext(self.qctx, session.space)
-                pctx.var_cols.update(session.var_cols)
-                from ..query.validator import ValidationError, validate
-                try:
-                    validate(inner, pctx)
-                except ValidationError as ex:
-                    return ResultSet(error=f"SemanticError: {ex}")
-                from ..query.planner import _plan
-                root = _plan(pctx, inner)
-                from ..query.plan import ExecutionPlan
-                plan = ExecutionPlan(root, pctx.space)
-                from ..utils.config import get_config
-                plan = optimize(plan, enable=self.enable_optimizer,
-                                tpu=self.qctx.tpu_runtime is not None
-                                and bool(get_config().get("tpu_enable")),
-                                pctx=pctx)
+                # validate + plan + optimise; absent on a plan-cache hit
+                with trace.span("graphd:plan"):
+                    pctx = PlannerContext(self.qctx, session.space)
+                    pctx.var_cols.update(session.var_cols)
+                    from ..query.validator import ValidationError, validate
+                    try:
+                        validate(inner, pctx)
+                    except ValidationError as ex:
+                        return ResultSet(error=f"SemanticError: {ex}")
+                    from ..query.planner import _plan
+                    root = _plan(pctx, inner)
+                    from ..query.plan import ExecutionPlan
+                    plan = ExecutionPlan(root, pctx.space)
+                    plan = optimize(
+                        plan, enable=self.enable_optimizer,
+                        tpu=self.qctx.tpu_runtime is not None
+                        and bool(get_config().get("tpu_enable")),
+                        pctx=pctx)
             except QueryError as ex:
                 return ResultSet(error=f"SemanticError: {ex}")
             if cache_key is not None and not explain_only \
@@ -809,10 +829,14 @@ class QueryEngine:
         try:
             with _cancel.use_cancel(kill=stmt_ectx.kill_event,
                                     deadline=dl):
-                ticket = _adm.admission().acquire(
-                    qid=qid, session=session.id,
-                    kind=self._stmt_kind(stmt), live=live,
-                    tracker=stmt_ectx.tracker, user=session.user)
+                adm = _adm.admission()
+                if adm.slots() > 0:
+                    # the admission wait; no span when admission is off
+                    with trace.span("graphd:admit"):
+                        ticket = adm.acquire(
+                            qid=qid, session=session.id,
+                            kind=self._stmt_kind(stmt), live=live,
+                            tracker=stmt_ectx.tracker, user=session.user)
                 if ticket is not None and ticket.queue_wait_us:
                     # pseudo-operator: the admission wait reaches the
                     # flight recorder next to the real plan nodes
@@ -826,7 +850,6 @@ class QueryEngine:
             # captures it (classify → "shed") from the E_OVERLOAD error
             return ResultSet(error=str(ex), space=plan.space)
         except _cancel.DeadlineExceeded:
-            from ..utils.stats import stats
             stats().inc("query_deadline_exceeded")
             return ResultSet(
                 error=f"E_QUERY_TIMEOUT: statement exceeded "

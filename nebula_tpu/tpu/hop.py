@@ -44,6 +44,7 @@ the host materializers rely on.
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable, Dict, List, Optional, Sequence
 
 import jax
@@ -53,6 +54,20 @@ import numpy as np
 from .device import shard_map as _shard_map
 
 MAXI = np.iinfo(np.int32).max
+
+
+def _stage(name: str):
+    """Trace the function's operations under `jax.named_scope(name)`:
+    their op_name then carries `hop/<stage>`, a name that stays put
+    when the HLO text of a fusion does not (a profiler trace keys
+    device time by it)."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def scoped(*args, **kw):
+            with jax.named_scope(name):
+                return fn(*args, **kw)
+        return scoped
+    return wrap
 
 
 def _expand_block(indptr, nbr, rank, fbm, EB: int, P: int, pid,
@@ -83,38 +98,43 @@ def _expand_block(indptr, nbr, rank, fbm, EB: int, P: int, pid,
       valid), plus (total, ovf): true expansion size and overflow flag.
     """
     vmax = fbm.shape[0]
-    deg = jnp.where(fbm, indptr[1:] - indptr[:-1], 0).astype(jnp.int32)
-    ends = jnp.cumsum(deg)
-    total = ends[-1]
-    starts = ends - deg                       # (vmax,)
-    has = deg > 0
-    # compact index of each expanding vertex, and its inverse table
-    cidx = jnp.cumsum(has.astype(jnp.int32)) - 1
-    vid_of = jnp.zeros((vmax,), jnp.int32).at[
-        jnp.where(has, cidx, vmax)].set(
-        jnp.arange(vmax, dtype=jnp.int32), mode="drop")
-    # +1 at each expanding vertex's first slot; prefix-sum = compact row
-    bump = jnp.zeros((EB,), jnp.int32).at[
-        jnp.where(has, starts, EB)].add(1, mode="drop")
-    crow = jnp.cumsum(bump) - 1               # (EB,)
-    row = vid_of[jnp.maximum(crow, 0)]
-    j = jnp.arange(EB, dtype=jnp.int32)
-    eidx = indptr[row] + (j - starts[row])
-    ve = j < jnp.minimum(total, EB)
-    eidx = jnp.where(ve, eidx, 0).astype(jnp.int32)
-    dst = jnp.where(ve, nbr[eidx], -1)
-    if hub_dense is None:
-        src_id = row * P + pid
-    else:
-        src_id = jnp.where(
-            row < vmax_local, row * P + pid,
-            hub_dense[jnp.clip(row - vmax_local, 0,
-                               hub_dense.shape[0] - 1)])
-    src = jnp.where(ve, src_id, -1)
-    rk = jnp.where(ve, rank[eidx], 0)
+    with jax.named_scope("hop/expand"):
+        deg = jnp.where(fbm, indptr[1:] - indptr[:-1], 0).astype(jnp.int32)
+        ends = jnp.cumsum(deg)
+        total = ends[-1]
+        starts = ends - deg                       # (vmax,)
+        has = deg > 0
+        # compact index of each expanding vertex, and its inverse table
+        cidx = jnp.cumsum(has.astype(jnp.int32)) - 1
+        vid_of = jnp.zeros((vmax,), jnp.int32).at[
+            jnp.where(has, cidx, vmax)].set(
+            jnp.arange(vmax, dtype=jnp.int32), mode="drop")
+        # +1 at each expanding vertex's first slot; prefix-sum = compact
+        # row
+        bump = jnp.zeros((EB,), jnp.int32).at[
+            jnp.where(has, starts, EB)].add(1, mode="drop")
+        crow = jnp.cumsum(bump) - 1               # (EB,)
+        row = vid_of[jnp.maximum(crow, 0)]
+        j = jnp.arange(EB, dtype=jnp.int32)
+        eidx = indptr[row] + (j - starts[row])
+        ve = j < jnp.minimum(total, EB)
+        eidx = jnp.where(ve, eidx, 0).astype(jnp.int32)
+    with jax.named_scope("hop/gather"):
+        # the neighbour and rank gathers over the hop's EB slots
+        dst = jnp.where(ve, nbr[eidx], -1)
+        if hub_dense is None:
+            src_id = row * P + pid
+        else:
+            src_id = jnp.where(
+                row < vmax_local, row * P + pid,
+                hub_dense[jnp.clip(row - vmax_local, 0,
+                                   hub_dense.shape[0] - 1)])
+        src = jnp.where(ve, src_id, -1)
+        rk = jnp.where(ve, rank[eidx], 0)
     return src, dst, rk, eidx, ve, total, total > EB
 
 
+@_stage("hop/delta_merge")
 def _merge_delta(dl, fbm, src, dst, rk, eidx, ve, total, P: int, pid,
                  emax: int):
     """Merge the device-resident delta plane into one block's expansion
@@ -165,6 +185,7 @@ def _delta_cap(b) -> int:
     return int(b["d_src"].shape[-1]) if "d_src" in b else 0
 
 
+@_stage("hop/mark")
 def _mark(dst, keep, P: int, vmax: int, acc=None):
     """Scatter keep-passing dense dst ids into a (P, vmax) ownership
     bitmap: row d = the candidate set destined for part d.  This is the
@@ -202,6 +223,7 @@ def _unpack_or(recv, vmax: int):
     return bits.reshape(-1)[:vmax].astype(bool)
 
 
+@_stage("hop/exchange")
 def _exchange_marks(marks, P: int, vmax: int):
     """The per-hop frontier exchange: row d of `marks` is part d's
     candidate bitmap; ship it there (ONE all_to_all over ICI, packed)
@@ -211,6 +233,7 @@ def _exchange_marks(marks, P: int, vmax: int):
     return _unpack_or(recv.reshape(P, -1), vmax)
 
 
+@_stage("hop/exchange")
 def _exchange_marks_lanes(marks, P: int, vmax: int):
     """Lane-batched frontier exchange: `marks` is (Ll, P, vmax) — one
     mark matrix per resident query lane.  Still ONE `all_to_all` per hop:
@@ -235,6 +258,7 @@ def a2a_payload_bytes(P: int, vmax: int, lanes: int = 1) -> int:
     return int(lanes) * P * P * W * 4
 
 
+@_stage("hop/compact")
 def _compact_cap(src, dst, rk, eidx, keep, EB: int):
     """Stable-partition the kept edge slots to the FRONT of each capture
     row (cumsum scatter, O(EB)) and return the kept count.
@@ -351,6 +375,8 @@ def build_traverse_fn(mesh, P: int, EB, steps: int,
     """
 
     ebs = _norm_ebs(EB, steps, capture_hops)
+    # a MATCH program captures every hop as a frame; a GO its last hop
+    cap_scope = "match/frame_capture" if capture_hops else "hop/capture"
     hubs_c, hub_owner, hub_local = _hub_consts(hub_dense, P)
 
     def kernel(blocks_data, frontier):
@@ -398,13 +424,16 @@ def build_traverse_fn(mesh, P: int, EB, steps: int,
                     cols = {"_rank": rk, "_src": src, "_dst": dst}
                     for name in pred_cols:
                         if not name.startswith("_"):
-                            cols[name] = _col(name)[eidx]
-                    keep = pred(cols) & ve
+                            with jax.named_scope("hop/pred_gather"):
+                                cols[name] = _col(name)[eidx]
+                    with jax.named_scope("hop/predicate"):
+                        keep = pred(cols) & ve
                 else:
                     keep = ve
                 if capture and (last or capture_hops):
-                    cs, cd, cr, ce, kc = _compact_cap(src, dst, rk, eidx,
-                                                      keep, EBh + dcap)
+                    with jax.named_scope(cap_scope):
+                        cs, cd, cr, ce, kc = _compact_cap(
+                            src, dst, rk, eidx, keep, EBh + dcap)
                     caps["src"].append(cs)
                     caps["dst"].append(cd)
                     caps["rank"].append(cr)
@@ -412,8 +441,9 @@ def build_traverse_fn(mesh, P: int, EB, steps: int,
                     caps["kcount"].append(kc)
                     if last and not capture_hops:
                         for name in yield_cols:
-                            caps.setdefault("prop:" + name, []).append(
-                                _col(name)[ce])
+                            with jax.named_scope("hop/prop_" + name):
+                                caps.setdefault("prop:" + name, []).append(
+                                    _col(name)[ce])
                 if not last:
                     marks = _mark(dst, keep, P, vmax, marks)
             hop_edges.append(edges_this_hop)
@@ -423,12 +453,13 @@ def build_traverse_fn(mesh, P: int, EB, steps: int,
             if last:
                 if capture:
                     if capture_hops:
-                        arr_keys = ("src", "dst", "rank", "eidx")
-                        cap_out = {k: jnp.stack([hc[k] for hc in hop_caps]
-                                                )[None]
-                                   for k in arr_keys}
-                        kcount_out = jnp.stack(
-                            [hc["kcount"] for hc in hop_caps])[None]
+                        with jax.named_scope("match/frame_stack"):
+                            arr_keys = ("src", "dst", "rank", "eidx")
+                            cap_out = {
+                                k: jnp.stack([hc[k] for hc in hop_caps])[None]
+                                for k in arr_keys}
+                            kcount_out = jnp.stack(
+                                [hc["kcount"] for hc in hop_caps])[None]
                     else:
                         cap_out = {k: v[None]
                                    for k, v in hop_caps[-1].items()
@@ -473,6 +504,8 @@ def _build_local_fn(P: int, EB, steps: int,
     vmap over a leading query-lane axis; ISSUE 15)."""
     pids = jnp.arange(P, dtype=jnp.int32)
     ebs = _norm_ebs(EB, steps, capture_hops)
+    # a MATCH program captures every hop as a frame; a GO its last hop
+    cap_scope = "match/frame_capture" if capture_hops else "hop/capture"
     hubs_c, hub_owner, hub_local = _hub_consts(hub_dense, P)
 
     def one_part_expand(block, fbm, pid, want_pred, EBh, vmax_local):
@@ -492,8 +525,10 @@ def _build_local_fn(P: int, EB, steps: int,
                     c = block["props"][name]
                     if "d_src" in block:
                         c = jnp.concatenate([c, block["d_props"][name]])
-                    cols[name] = c[eidx]
-            keep = pred(cols) & ve
+                    with jax.named_scope("hop/pred_gather"):
+                        cols[name] = c[eidx]
+            with jax.named_scope("hop/predicate"):
+                keep = pred(cols) & ve
         else:
             keep = ve
         return src, dst, rk, eidx, ve, keep, total, ovf
@@ -531,10 +566,11 @@ def _build_local_fn(P: int, EB, steps: int,
                 ovf_e = ovf_e | ovf
                 edges = edges + total
                 if capture and (last or capture_hops):
-                    cs, cd, cr, ce, kc = jax.vmap(
-                        lambda s, d, r, e, k: _compact_cap(s, d, r, e, k,
-                                                           EBh + dcap)
-                    )(src, dst, rk, eidx, keep)
+                    with jax.named_scope(cap_scope):
+                        cs, cd, cr, ce, kc = jax.vmap(
+                            lambda s, d, r, e, k: _compact_cap(
+                                s, d, r, e, k, EBh + dcap)
+                        )(src, dst, rk, eidx, keep)
                     caps["src"].append(cs)
                     caps["dst"].append(cd)
                     caps["rank"].append(cr)
@@ -546,8 +582,9 @@ def _build_local_fn(P: int, EB, steps: int,
                             if dcap:
                                 col = jnp.concatenate(
                                     [col, b["d_props"][name]], axis=1)
-                            caps.setdefault("prop:" + name, []).append(
-                                jax.vmap(lambda c, e: c[e])(col, ce))
+                            with jax.named_scope("hop/prop_" + name):
+                                caps.setdefault("prop:" + name, []).append(
+                                    jax.vmap(lambda c, e: c[e])(col, ce))
                 if not last:
                     blk_marks = jax.vmap(
                         lambda d, k: _mark(d, k, P, vmax))(dst, keep)
@@ -562,13 +599,14 @@ def _build_local_fn(P: int, EB, steps: int,
             if last:
                 if capture:
                     if capture_hops:
-                        arr_keys = ("src", "dst", "rank", "eidx")
-                        # (P, steps, nb, EB); kcount (P, steps, nb)
-                        cap_out = {k: jnp.stack([hc[k] for hc in hop_caps],
-                                                axis=1)
-                                   for k in arr_keys}
-                        kcount_out = jnp.stack(
-                            [hc["kcount"] for hc in hop_caps], axis=1)
+                        with jax.named_scope("match/frame_stack"):
+                            arr_keys = ("src", "dst", "rank", "eidx")
+                            # (P, steps, nb, EB); kcount (P, steps, nb)
+                            cap_out = {k: jnp.stack([hc[k] for hc in hop_caps],
+                                                    axis=1)
+                                       for k in arr_keys}
+                            kcount_out = jnp.stack(
+                                [hc["kcount"] for hc in hop_caps], axis=1)
                     else:
                         cap_out = {k: v for k, v in hop_caps[-1].items()
                                    if k != "kcount"}
@@ -683,6 +721,8 @@ def build_traverse_fn_lanes_sharded(mesh, P: int, EB, steps: int,
     axis unsplit.
     """
     ebs = _norm_ebs(EB, steps, capture_hops)
+    # a MATCH program captures every hop as a frame; a GO its last hop
+    cap_scope = "match/frame_capture" if capture_hops else "hop/capture"
     hubs_c, hub_owner, hub_local = _hub_consts(hub_dense, P)
 
     def kernel(blocks_data, frontier):
@@ -741,15 +781,18 @@ def build_traverse_fn_lanes_sharded(mesh, P: int, EB, steps: int,
                     cols = {"_rank": rk, "_src": src, "_dst": dst}
                     for name in pred_cols:
                         if not name.startswith("_"):
-                            cols[name] = _col(name)[eidx]
-                    keep = pred(cols) & ve
+                            with jax.named_scope("hop/pred_gather"):
+                                cols[name] = _col(name)[eidx]
+                    with jax.named_scope("hop/predicate"):
+                        keep = pred(cols) & ve
                 else:
                     keep = ve
                 if capture and (last or capture_hops):
-                    cs, cd, cr, ce, kc = jax.vmap(
-                        lambda s, d, r, e, k: _compact_cap(
-                            s, d, r, e, k,
-                            EBh + dcap))(src, dst, rk, eidx, keep)
+                    with jax.named_scope(cap_scope):
+                        cs, cd, cr, ce, kc = jax.vmap(
+                            lambda s, d, r, e, k: _compact_cap(
+                                s, d, r, e, k,
+                                EBh + dcap))(src, dst, rk, eidx, keep)
                     caps["src"].append(cs)
                     caps["dst"].append(cd)
                     caps["rank"].append(cr)
@@ -757,8 +800,9 @@ def build_traverse_fn_lanes_sharded(mesh, P: int, EB, steps: int,
                     caps["kcount"].append(kc)
                     if last and not capture_hops:
                         for name in yield_cols:
-                            caps.setdefault("prop:" + name, []).append(
-                                _col(name)[ce])
+                            with jax.named_scope("hop/prop_" + name):
+                                caps.setdefault("prop:" + name, []).append(
+                                    _col(name)[ce])
                 if not last:
                     marks_b = jax.vmap(
                         lambda d, k: _mark(d, k, P, vmax))(dst, keep)
@@ -772,14 +816,15 @@ def build_traverse_fn_lanes_sharded(mesh, P: int, EB, steps: int,
             if last:
                 if capture:
                     if capture_hops:
-                        arr_keys = ("src", "dst", "rank", "eidx")
-                        # local (Ll, 1, steps, nb, EB)
-                        cap_out = {k: jnp.stack(
-                            [hc[k] for hc in hop_caps], axis=1)[:, None]
-                            for k in arr_keys}
-                        kcount_out = jnp.stack(
-                            [hc["kcount"] for hc in hop_caps],
-                            axis=1)[:, None]
+                        with jax.named_scope("match/frame_stack"):
+                            arr_keys = ("src", "dst", "rank", "eidx")
+                            # local (Ll, 1, steps, nb, EB)
+                            cap_out = {k: jnp.stack(
+                                [hc[k] for hc in hop_caps], axis=1)[:, None]
+                                for k in arr_keys}
+                            kcount_out = jnp.stack(
+                                [hc["kcount"] for hc in hop_caps],
+                                axis=1)[:, None]
                     else:
                         cap_out = {k: v[:, None]
                                    for k, v in hop_caps[-1].items()

@@ -29,6 +29,8 @@ from ..graphstore.csr import (build_snapshot, decode_prop_column,
 from ..graphstore.delta import (DeltaOverflow, DeltaUnsupported, HostDelta,
                                 pow2 as _delta_pow2)
 from ..graphstore.store import GraphStore
+from ..utils import trace as _t
+from ..utils.stats import stats as _metrics
 from .device import (DeviceSnapshot, TpuUnavailable, make_mesh,
                      mesh_lanes, mesh_parts, note_host_fallback,
                      pin_snapshot, put_delta_blocks)
@@ -563,7 +565,11 @@ class TpuRuntime:
         # the plain epoch check
         if cur is not None and not force and getattr(
                 cur, "space_uid", None) == getattr(sd, "uid", None):
-            if self._served_epoch(cur) == sd.epoch:
+            # the freshness probe every dispatch pays: on a cluster
+            # store `sd.epoch` is a part_stats fan-out, one RPC per part
+            with _t.span("tpu:snapshot_check", space=space):
+                fresh = self._served_epoch(cur) == sd.epoch
+            if fresh:
                 return cur
             if cur.delta is not None and hasattr(store, "delta_records"):
                 # ISSUE 19 fast path: fold the dirty-key log into the
@@ -792,12 +798,10 @@ class TpuRuntime:
         """Fold the delta back into a fresh base CSR: the whole build
         runs OFF the dispatch gate (reads keep flowing against the old
         base + delta); only the buffer swap takes the write side."""
-        from ..utils import trace
         from ..utils.failpoints import FailpointError, fail
         from ..utils.stats import stats
-        t0 = time.perf_counter()
         try:
-            with trace.span("tpu:compaction", space=space):
+            with _t.span("tpu:compaction", space=space):
                 dflag = self._delta_flag()
                 snap = self._build_fresh(store, space, dflag)
                 fail.hit("tpu:compact_swap", key=space)
@@ -819,9 +823,6 @@ class TpuRuntime:
                 stats().inc("tpu_compactions")
                 self._emit_delta_gauges(new)
                 self._emit_hbm_gauges()
-                trace.record_phase("tpu:compaction",
-                                   time.perf_counter() - t0,
-                                   space=space)
         except FailpointError:
             pass                         # KILL test hook: abort cleanly
         except Exception:  # noqa: BLE001 — background thread must not die
@@ -1062,7 +1063,6 @@ class TpuRuntime:
         accounting cannot drift between the two paths."""
         from ..utils.failpoints import fail as _fail
         from ..utils.stats import current_cost
-        from ..utils.stats import stats as _metrics
         from ..utils.workload import current_live, dispatch_table
         tok = dispatch_table().enter(kernel)
         acquired = False
@@ -1070,10 +1070,11 @@ class TpuRuntime:
             # inside the try: a `raise` action must still exit the
             # token, or GET /queries shows a phantom forever-queued
             # dispatch and the depth gauge sticks at 1
-            _fail.hit("tpu:dispatch_gate", key=kernel)
-            self._gate.acquire_read()
-            acquired = True
-            wait_us = dispatch_table().mark_running(tok)
+            with _t.span("device:queue", kernel=kernel):
+                _fail.hit("tpu:dispatch_gate", key=kernel)
+                self._gate.acquire_read()
+                acquired = True
+                wait_us = dispatch_table().mark_running(tok)
             _metrics().observe("tpu_dispatch_queue_us", wait_us,
                                {"kernel": kernel})
             cc = current_cost()
@@ -1112,7 +1113,6 @@ class TpuRuntime:
         in `tpu_dispatch_us{kernel}`, `device_us` and the SHOW QUERIES
         decomposition.  Returns (result, dispatch_us)."""
         from ..utils.stats import current_cost, current_work
-        from ..utils.stats import stats as _metrics
         from ..utils.workload import current_live
         with self._gated_dispatch(kernel):
             t0 = time.perf_counter()
@@ -1193,7 +1193,6 @@ class TpuRuntime:
         sharing is real.  A batched launch consumes ONE
         `tpu_dispatch_queue_cap` slot (the single _gated_dispatch
         below), never K."""
-        from ..utils.stats import stats as _metrics
         from ..utils.stats import use_cost, use_work
         from ..utils.workload import use_live
         if getattr(dev, "retired", False):
@@ -1231,14 +1230,22 @@ class TpuRuntime:
             "lanes": L_real, "rungs": [], "compiles": 0, "retries": 0,
             "put_s": 0.0, "fetch_s": 0.0, "device_s": 0.0,
             "gate_wait_us": 0, "ebs": list(EBs), "hbm_bytes": 0,
-            "shards": self.mesh_size, "exchange_bytes": 0}
+            "shards": self.mesh_size, "exchange_bytes": 0,
+            # the shared launch's device phases as (span name,
+            # perf_counter start, seconds, attrs): _lane_attribution
+            # replays them into EACH lane's trace, so nothing is traced
+            # on the launcher's thread while the launch runs
+            "phases": []}
+        phases = info["phases"]
+        launcher_ctx = _t.current_ctx()
         with use_work(None), use_cost(None), use_live(None), \
-                self._gated_dispatch(kernel) as wait_us:
+                _t.use_ctx(None), self._gated_dispatch(kernel) as wait_us:
             info["gate_wait_us"] = wait_us
             tp = time.perf_counter()
             with self._collective_launch():
                 frontier = seed_fn(seed_pad)
             info["put_s"] = time.perf_counter() - tp
+            phases.append(("device:put", tp, info["put_s"], {}))
             for attempt in range(max(self.max_retries, n_hops + 3)):
                 ebs = tuple(EBs)
                 # lane suffix (not prefix): pin/unpin prune _fns by
@@ -1252,31 +1259,20 @@ class TpuRuntime:
                     fn = self._fns[key] = build_fn(ebs)
                     info["compiles"] += 1
                 t0 = time.perf_counter()
-                from ..utils.config import get_config as _gc
-                prof_dir = _gc().get("tpu_profiler_dir")
-                if prof_dir:
-                    # same xplane tracing contract as the solo path: a
-                    # profiled deployment must capture the SHARED
-                    # launches too — they are the ones worth profiling
-                    self._prof_seq = getattr(self, "_prof_seq", 0) + 1
-                    import os as _os
-                    run_dir = _os.path.join(str(prof_dir),
-                                            f"run{self._prof_seq:06d}")
-                    with jax.profiler.trace(run_dir), \
-                            self._collective_launch():
-                        res = fn(*inputs_fn(ebs), frontier)
-                        jax.block_until_ready(res)
-                else:
-                    with self._collective_launch():
-                        res = fn(*inputs_fn(ebs), frontier)
-                        jax.block_until_ready(res)
+                with self._collective_launch():
+                    res = fn(*inputs_fn(ebs), frontier)
+                    jax.block_until_ready(res)
                 t1 = time.perf_counter()
                 info["rungs"].append((int((t1 - t0) * 1e6), compiled))
                 info["device_s"] = t1 - t0
+                phases.append(("device:dispatch", t0, t1 - t0,
+                               {"eb": list(EBs), "attempt": attempt}))
                 cap_dev = res.pop("cap", None) if isinstance(res, dict) \
                     else None
                 res = jax.device_get(res)
-                info["fetch_s"] += time.perf_counter() - t1
+                tm = time.perf_counter()
+                info["fetch_s"] += tm - t1
+                phases.append(("device:fetch", t1, tm - t1, {}))
                 if res["ovf_expand"].any():
                     # per-hop true expansion max over (lane, part):
                     # jump every overflowed hop straight to its bucket
@@ -1309,13 +1305,19 @@ class TpuRuntime:
                         for k, v in cap_dev.items()
                         if fetch_keys is None or k in fetch_keys}
                     res["cap"]["kcount"] = kc
-                    info["fetch_s"] += time.perf_counter() - tf
+                    tm = time.perf_counter()
+                    info["fetch_s"] += tm - tf
+                    phases.append(("device:fetch", tf, tm - tf, {}))
                 # launch-level metrics/ledger: ONE real launch shared
                 # by L_real statements — the sharing proof
                 _metrics().inc("tpu_kernel_runs")
                 _metrics().inc("tpu_edges_traversed",
                                int(np.asarray(res["hop_edges"]).sum()))
                 _metrics().add_value("tpu_kernel_s", info["device_s"])
+                _metrics().add_value("tpu_put_s", info["put_s"])
+                _metrics().add_value("tpu_fetch_s", info["fetch_s"])
+                _metrics().add_value("tpu_queue_s", wait_us / 1e6)
+                _metrics().inc("tpu_escalation_retries", attempt)
                 for r_us, r_compiled in info["rungs"]:
                     _metrics().observe("tpu_dispatch_us", r_us,
                                        {"kernel": kernel})
@@ -1348,15 +1350,19 @@ class TpuRuntime:
                     dispatch_us=int(info["device_s"] * 1e6),
                     hbm_bytes=hbm, retries=attempt,
                     shards=self.mesh_size, exchange_bytes=xbytes)
-                from ..utils import trace as _t
-                _t.record_phase("tpu:batch", info["device_s"],
-                                lanes=L_real, kernel=kernel,
-                                eb=list(EBs))
                 if xbytes:
                     _metrics().inc("tpu_all_to_all_bytes", xbytes)
-                    _t.record_phase("tpu:shard_exchange", 0.0,
-                                    bytes=xbytes, hops=xhops,
-                                    shards=self.mesh_size, lanes=L)
+                with _t.use_ctx(launcher_ctx):
+                    # the launch itself, under the LAUNCHING member's
+                    # statement: from the seed put to the last fetch
+                    _t.record_phase("tpu:batch", tp,
+                                    time.perf_counter() - tp,
+                                    lanes=L_real, kernel=kernel,
+                                    eb=list(EBs))
+                    if xbytes:
+                        _t.mark("tpu:shard_exchange",
+                                bytes=xbytes, hops=xhops,
+                                shards=self.mesh_size, lanes=L)
                 return res, info
         raise TpuUnavailable(
             "lane-batched bucket escalation did not converge")
@@ -1407,11 +1413,13 @@ class TpuRuntime:
             lv.add("device_us", rung_us)
             lv.add("dispatches", n_rungs)
             lv.add("queue_us", int(stats.queue_s * 1e6))
-        from ..utils import trace as _t
-        _t.record_phase("device:put", stats.put_s)
-        _t.record_phase("device:dispatch", stats.device_s,
-                        eb=list(info["ebs"]), retries=stats.retries)
-        _t.record_phase("device:fetch", stats.fetch_s)
+        # this lane's view of the shared launch: it waited (former +
+        # gate) until the seed put began, then the launch's own phases
+        t_put = info["phases"][0][1]
+        _t.record_phase("device:queue", t_put - stats.queue_s,
+                        stats.queue_s, lanes=info["lanes"])
+        for name, start, dur, attrs in info["phases"]:
+            _t.record_phase(name, start, dur, **attrs)
         return {k: v[lane] for k, v in res["cap"].items()}
 
     def _lanes_builder(self, P: int, steps: int, n_blocks: int, **kw):
@@ -1533,7 +1541,7 @@ class TpuRuntime:
 
         seed_pad, seed_fn = self._seed_frontier_prep(dev, dense, target)
         tp = time.perf_counter()
-        with self._collective_launch():
+        with _t.span("device:put"), self._collective_launch():
             frontier = seed_fn(seed_pad)
         stats.put_s = time.perf_counter() - tp
 
@@ -1544,6 +1552,7 @@ class TpuRuntime:
         from ..utils.stats import current_work
         wc = current_work()
         rungs: List[Tuple[int, bool]] = []   # (dispatch_us, compiled)
+        refetches = 0
         for attempt in range(max(self.max_retries, n_hops + 3)):
             stats.retries = attempt
             ebs = tuple(EBs)
@@ -1561,26 +1570,10 @@ class TpuRuntime:
             if wc is not None:
                 wc.add("device_dispatches")
             t0 = time.perf_counter()
-            from ..utils.config import get_config
-            prof_dir = get_config().get("tpu_profiler_dir")
-            if prof_dir:
-                # device-plane tracing (SURVEY §5): one xplane trace per
-                # kernel run, viewable in TensorBoard/XProf.  Each run
-                # gets its own subdir — jax names dumps by wall-clock
-                # second, so two runs inside one second would otherwise
-                # overwrite each other.
-                self._prof_seq = getattr(self, "_prof_seq", 0) + 1
-                import os as _os
-                run_dir = _os.path.join(str(prof_dir),
-                                        f"run{self._prof_seq:06d}")
-                with jax.profiler.trace(run_dir), \
-                        self._collective_launch():
-                    res = fn(*inputs_fn(ebs), frontier)
-                    jax.block_until_ready(res)
-            else:
-                with self._collective_launch():
-                    res = fn(*inputs_fn(ebs), frontier)
-                    jax.block_until_ready(res)
+            with _t.span("device:dispatch", eb=list(EBs),
+                         attempt=attempt), self._collective_launch():
+                res = fn(*inputs_fn(ebs), frontier)
+                jax.block_until_ready(res)
             t1 = time.perf_counter()
             stats.device_s = t1 - t0
             rungs.append((int((t1 - t0) * 1e6), compiled))
@@ -1599,18 +1592,19 @@ class TpuRuntime:
                 else None
             spec_k = self._kmax.get(key) if cap_dev is not None else None
             spec_cap = None
-            if spec_k is not None:
-                bundle = dict(res)
-                for ck, cv in cap_dev.items():
-                    if fetch_keys is None or ck in fetch_keys:
-                        bundle["cap:" + ck] = cv[..., :spec_k]
-                got = jax.device_get(bundle)
-                res = {k: v for k, v in got.items()
-                       if not k.startswith("cap:")}
-                spec_cap = {k[4:]: v for k, v in got.items()
-                            if k.startswith("cap:")}
-            else:
-                res = jax.device_get(res)
+            with _t.span("device:fetch"):
+                if spec_k is not None:
+                    bundle = dict(res)
+                    for ck, cv in cap_dev.items():
+                        if fetch_keys is None or ck in fetch_keys:
+                            bundle["cap:" + ck] = cv[..., :spec_k]
+                    got = jax.device_get(bundle)
+                    res = {k: v for k, v in got.items()
+                           if not k.startswith("cap:")}
+                    spec_cap = {k[4:]: v for k, v in got.items()
+                                if k.startswith("cap:")}
+                else:
+                    res = jax.device_get(res)
             stats.fetch_s = time.perf_counter() - t1
 
             if res["ovf_expand"].any():
@@ -1660,20 +1654,30 @@ class TpuRuntime:
                         res["cap"] = {k: np.asarray(v[..., :K])
                                       for k, v in spec_cap.items()}
                     else:
-                        res["cap"] = {k: np.asarray(
-                            jax.device_get(v[..., :K]))
-                            for k, v in cap_dev.items()
-                            if fetch_keys is None or k in fetch_keys}
+                        # the capture's own fetch: the second phase of
+                        # a first run, or — after a speculative fetch
+                        # that undershot — a refetch
+                        undershot = spec_cap is not None
+                        refetches += undershot
+                        with _t.span("device:fetch", refetch=undershot):
+                            res["cap"] = {k: np.asarray(
+                                jax.device_get(v[..., :K]))
+                                for k, v in cap_dev.items()
+                                if fetch_keys is None or k in fetch_keys}
                     res["cap"]["kcount"] = kc
                     self._kmax[key] = K
                     while len(self._kmax) > 512:
                         self._kmax.pop(next(iter(self._kmax)))
                     stats.fetch_s += time.perf_counter() - tf
-                from ..utils.stats import stats as _metrics
                 _metrics().inc("tpu_kernel_runs")
                 _metrics().inc("tpu_edges_traversed",
                                stats.edges_traversed())
                 _metrics().add_value("tpu_kernel_s", stats.device_s)
+                _metrics().add_value("tpu_put_s", stats.put_s)
+                _metrics().add_value("tpu_fetch_s", stats.fetch_s)
+                _metrics().add_value("tpu_queue_s", stats.queue_s)
+                _metrics().inc("tpu_escalation_retries", stats.retries)
+                _metrics().inc("tpu_refetches", refetches)
                 if wc is not None:
                     wc.add("edges_traversed", stats.edges_traversed())
                     wc.extend_frontier(stats.frontier_sizes)
@@ -1731,22 +1735,14 @@ class TpuRuntime:
                     dispatch_us=dispatch_us, hbm_bytes=hbm,
                     retries=stats.retries, shards=self.mesh_size,
                     exchange_bytes=stats.exchange_bytes)
-                # device-plane trace phases (ISSUE 1): the runtime
-                # timed them itself — emit as leaf spans of whatever
-                # executor span is driving this kernel
-                from ..utils import trace as _t
-                _t.record_phase("device:put", stats.put_s)
-                _t.record_phase("device:dispatch", stats.device_s,
-                                eb=list(EBs), retries=stats.retries)
-                _t.record_phase("device:fetch", stats.fetch_s)
                 if stats.exchange_bytes:
                     _metrics().inc("tpu_all_to_all_bytes",
                                    stats.exchange_bytes)
                     # the exchange runs inside the fused program — its
                     # span carries payload facts, not a separate timing
-                    _t.record_phase("tpu:shard_exchange", 0.0,
-                                    bytes=stats.exchange_bytes,
-                                    hops=xhops, shards=self.mesh_size)
+                    _t.mark("tpu:shard_exchange",
+                            bytes=stats.exchange_bytes,
+                            hops=xhops, shards=self.mesh_size)
                 return res
         raise TpuUnavailable("bucket escalation did not converge")
 
@@ -1884,14 +1880,16 @@ class TpuRuntime:
             return [], stats
 
         t_mat = time.perf_counter()
-        if yields is not None:
-            rows = self._materialize_yields(store, space, dev, block_keys,
-                                            res["cap"], yields,
-                                            dview=dview)
-        else:
-            rows = self._materialize(store, space, dev, block_keys,
-                                     res["cap"], dview=dview)
+        with _t.span("device:materialise"):
+            if yields is not None:
+                rows = self._materialize_yields(
+                    store, space, dev, block_keys, res["cap"], yields,
+                    dview=dview)
+            else:
+                rows = self._materialize(store, space, dev, block_keys,
+                                         res["cap"], dview=dview)
         stats.mat_s = time.perf_counter() - t_mat
+        _metrics().add_value("tpu_mat_s", stats.mat_s)
         stats.result_edges = len(rows)
         stats.total_s = time.perf_counter() - t_start
         return rows, stats
@@ -1996,9 +1994,11 @@ class TpuRuntime:
                 kernel="hops")
 
         t_mat = time.perf_counter()
-        frames = self._build_frames(store, space, dev, block_keys,
-                                    res["cap"], max_hop, dview=dview)
+        with _t.span("device:materialise"):
+            frames = self._build_frames(store, space, dev, block_keys,
+                                        res["cap"], max_hop, dview=dview)
         stats.mat_s = time.perf_counter() - t_mat
+        _metrics().add_value("tpu_mat_s", stats.mat_s)
         stats.result_edges = sum(f.n for f in frames)
         stats.total_s = time.perf_counter() - t_start
         return frames, stats

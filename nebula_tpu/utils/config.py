@@ -190,9 +190,6 @@ define_flag("enable_query_tracing", True,
             "GET /traces); off = no spans ride the RPC envelope, which "
             "also makes wire-byte work counters deterministic for "
             "regression probes")
-define_flag("tpu_profiler_dir", "",
-            "when set, wrap every device kernel run in a jax.profiler "
-            "trace written under this directory (SURVEY §5 tracing)")
 define_flag("storage_read_capacity_qps", 0,
             "per-storaged read admission rate (reads/s, token bucket; "
             "0 = unlimited).  Reads beyond the rate are shed with the "

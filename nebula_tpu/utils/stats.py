@@ -162,6 +162,18 @@ class StatsManager:
             series = self.labeled.setdefault(name, {})
             series[key] = series.get(key, 0) + delta
 
+    def inc_labeled_many(self, label: str,
+                         by_name: Dict[str, Dict[str, float]]):
+        """ONE locked update of several counters that share a single
+        label: {name: {label value: delta}} (the per-statement phase
+        fold, utils/trace.py)."""
+        with self.lock:
+            for name, deltas in by_name.items():
+                series = self.labeled.setdefault(name, {})
+                for value, delta in deltas.items():
+                    key = ((label, value),)
+                    series[key] = series.get(key, 0) + delta
+
     def gauge(self, name: str, value: float):
         with self.lock:
             self.gauges[name] = value
@@ -205,6 +217,10 @@ class StatsManager:
             for n, v in self.labeled_gauges.items():
                 labeled.setdefault(n, {}).update(v)
             hists = dict(self.histograms)
+        # CPU seconds of this process (user + system, every thread),
+        # read at snapshot time only: a difference of two snapshots
+        # over the seconds between them is the cores kept busy
+        out["process_cpu_s"] = time.process_time()
         for name, s in series.items():
             for k, v in s.snapshot().items():
                 out[f"{name}.{k}"] = v

@@ -23,14 +23,32 @@ Design constraints:
 Span fields: tid, sid, psid (parent span id), name, svc (service
 role), t0 (epoch seconds), dur_us, attrs (flat dict).  Remote spans
 grafted from an RPC reply additionally carry remote=True.
+
+One clock (ISSUE 24): a span's start and end are both readings of
+`time.perf_counter_ns`; `t0` is that start moved to epoch seconds
+through ONE per-process offset, so intervals of one process compare
+exactly.  While a `jax.profiler` session is collecting (and jax is
+imported in the process), the spans of every trace that opens are also
+`jax.profiler.TraceAnnotation`s, so the session shows the program's
+spans on the `/host:CPU` thread lines, on the device plane's clock;
+outside a session a trace pays one flag test (measured: entering an
+annotation on every span cost a GIL-bound cell its share of 5% more
+interpreter work, PERF.md section 6, PR 24).  When a statement's root
+(`query:*`) closes, the self times of
+its spans are folded ONCE into `stmt_phase_us{phase}` /
+`stmt_phase_n{phase}` (`fold_phases`): the closed per-statement time
+budget `/metrics` and the benchmark read.
 """
 from __future__ import annotations
 
 import itertools
 import os
+import sys
 import threading
 import time
 from typing import Any, Dict, List, Optional, Tuple
+
+from .stats import stats
 
 _tls = threading.local()
 _span_seq = itertools.count(1)
@@ -39,18 +57,58 @@ _span_seq = itertools.count(1)
 _PROC = f"{os.getpid():x}"
 
 
+# one monotonic clock for starts and lengths; epoch seconds only in
+# the JSON, through this one offset
+_now_ns = time.perf_counter_ns
+_EPOCH_OFFSET_NS = time.time_ns() - time.perf_counter_ns()
+
+
 def _new_id(kind: str) -> str:
     return f"{kind}{_PROC}-{next(_span_seq)}"
 
 
-class _Ctx:
-    __slots__ = ("tid", "sid", "sink", "service")
+def _epoch_s(ns: int) -> float:
+    """A perf_counter_ns reading as epoch seconds, on whole
+    microseconds (so `round(t0 * 1e6)` gives the start back exactly)."""
+    return ((_EPOCH_OFFSET_NS + ns) // 1000) / 1e6
 
-    def __init__(self, tid: str, sid: str, sink: List[dict], service: str):
+
+_annotation_cls = None
+
+
+def _profiling() -> bool:
+    """True while a jax profiler session is collecting: spans opened
+    under a trace that starts now become `TraceAnnotation`s.  jax is
+    looked up in sys.modules, never imported from here; outside a
+    session this is one flag test per trace."""
+    global _annotation_cls
+    cls = _annotation_cls
+    if cls is None:
+        prof = sys.modules.get("jax.profiler")
+        if prof is None:
+            return False
+        cls = _annotation_cls = prof.TraceAnnotation
+    return cls.is_enabled()
+
+
+def _annotate(name: str):
+    ann = _annotation_cls(name)
+    ann.__enter__()
+    return ann
+
+
+class _Ctx:
+    __slots__ = ("tid", "sid", "sink", "service", "annotate")
+
+    def __init__(self, tid: str, sid: str, sink: List[dict], service: str,
+                 annotate: bool = False):
         self.tid = tid
         self.sid = sid
         self.sink = sink
         self.service = service
+        # spans of this trace are profiler annotations too: decided
+        # once, where the trace (or a remote handler's part of it) opens
+        self.annotate = annotate
 
 
 def _get_ctx() -> Optional[_Ctx]:
@@ -96,13 +154,14 @@ def use_ctx(ctx: Optional[_Ctx]) -> _CtxGuard:
     stomp each other's parenting (sink.append itself is atomic)."""
     if ctx is None:
         return _CtxGuard(None)
-    return _CtxGuard(_Ctx(ctx.tid, ctx.sid, ctx.sink, ctx.service))
+    return _CtxGuard(_Ctx(ctx.tid, ctx.sid, ctx.sink, ctx.service,
+                          ctx.annotate))
 
 
 class _SpanGuard:
     """Open span: on exit, append the finished record to the sink."""
 
-    __slots__ = ("_ctx", "_rec", "_t0", "_prev_sid")
+    __slots__ = ("_ctx", "_rec", "_t0", "_prev_sid", "_ann")
 
     def __init__(self, ctx: Optional[_Ctx], name: str, attrs: Dict[str, Any]):
         self._ctx = ctx
@@ -110,7 +169,7 @@ class _SpanGuard:
             return
         self._rec = {"tid": ctx.tid, "sid": _new_id("s"),
                      "psid": ctx.sid, "name": name, "svc": ctx.service,
-                     "t0": time.time(), "dur_us": 0}
+                     "t0": 0.0, "dur_us": 0}
         if attrs:
             self._rec["attrs"] = attrs
 
@@ -118,18 +177,22 @@ class _SpanGuard:
         ctx = self._ctx
         if ctx is None:
             return None
-        self._t0 = time.perf_counter()
         self._prev_sid = ctx.sid
         ctx.sid = self._rec["sid"]
+        self._ann = _annotate(self._rec["name"]) if ctx.annotate else None
+        self._t0 = _now_ns()
         return self._rec
 
     def __exit__(self, exc_type, exc, tb):
         ctx = self._ctx
         if ctx is None:
             return False
+        t1 = _now_ns()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         ctx.sid = self._prev_sid
-        self._rec["dur_us"] = int(
-            (time.perf_counter() - self._t0) * 1e6)
+        self._rec["t0"] = ((_EPOCH_OFFSET_NS + self._t0) // 1000) / 1e6
+        self._rec["dur_us"] = (t1 - self._t0) // 1000
         if exc is not None:
             self._rec.setdefault("attrs", {})["error"] = \
                 f"{type(exc).__name__}: {exc}"
@@ -142,19 +205,31 @@ def span(name: str, **attrs) -> _SpanGuard:
     return _SpanGuard(_get_ctx(), name, attrs)
 
 
-def record_phase(name: str, dur_s: float, **attrs):
-    """Append an already-measured span (device phases: the runtime times
-    put/dispatch/fetch itself; these become leaf spans of the executor
-    span that drove the kernel)."""
+def _append(name: str, start_ns: int, dur_us: int, attrs: Dict[str, Any]):
     ctx = _get_ctx()
     if ctx is None:
         return
     rec = {"tid": ctx.tid, "sid": _new_id("s"), "psid": ctx.sid,
-           "name": name, "svc": ctx.service, "t0": time.time() - dur_s,
-           "dur_us": int(dur_s * 1e6)}
+           "name": name, "svc": ctx.service, "t0": _epoch_s(start_ns),
+           "dur_us": dur_us}
     if attrs:
         rec["attrs"] = attrs
     ctx.sink.append(rec)
+
+
+def record_phase(name: str, start_s: float, dur_s: float, **attrs):
+    """Append an interval the caller timed itself: `start_s` is its
+    `time.perf_counter()` reading at the start, `dur_s` its length.
+    For phases measured on another thread (a shared batched launch
+    replayed into each lane's trace); code that can should use a
+    `with span(...)` instead, which is also a profiler annotation."""
+    _append(name, int(start_s * 1e9), int(dur_s * 1e6), attrs)
+
+
+def mark(name: str, **attrs):
+    """A zero-length marker span at now (a retry, a breaker transition,
+    a dedup hit): carries facts in its attrs, no time."""
+    _append(name, _now_ns(), 0, attrs)
 
 
 def graft(spans: List[dict]):
@@ -173,14 +248,15 @@ def graft(spans: List[dict]):
 class _TraceGuard:
     """Root context: owns the sink; stores the finished trace."""
 
-    __slots__ = ("_ctx", "_rec", "_t0", "_prev")
+    __slots__ = ("_ctx", "_rec", "_t0", "_prev", "_ann")
 
     def __init__(self, name: str, service: str, attrs: Dict[str, Any]):
         tid = _new_id("t")
         sink: List[dict] = []
         self._ctx = _Ctx(tid, "", sink, service)
+        self._ann = None
         self._rec = {"tid": tid, "sid": _new_id("s"), "psid": "",
-                     "name": name, "svc": service, "t0": time.time(),
+                     "name": name, "svc": service, "t0": 0.0,
                      "dur_us": 0}
         if attrs:
             self._rec["attrs"] = attrs
@@ -189,29 +265,56 @@ class _TraceGuard:
     def trace_id(self) -> str:
         return self._ctx.tid
 
+    def set_name(self, name: str):
+        """Name the root once the statement's kind is known (the root
+        opens before the parse).  Its profiler annotation keeps the
+        name it was entered under and takes this one as metadata."""
+        self._rec["name"] = name
+        if self._ann is not None:
+            self._ann.set_metadata(name=name)
+
+    def set(self, **attrs):
+        self._rec.setdefault("attrs", {}).update(attrs)
+
     def __enter__(self):
         self._prev = getattr(_tls, "ctx", None)
         self._ctx.sid = self._rec["sid"]
         _tls.ctx = self._ctx
-        self._t0 = time.perf_counter()
+        if _profiling():
+            self._ctx.annotate = True
+            self._ann = _annotate(self._rec["name"])
+        self._t0 = _now_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb):
+        t1 = _now_ns()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         _tls.ctx = self._prev
-        self._rec["dur_us"] = int((time.perf_counter() - self._t0) * 1e6)
+        rec, sink = self._rec, self._ctx.sink
+        rec["t0"] = _epoch_s(self._t0)
+        rec["dur_us"] = (t1 - self._t0) // 1000
         if exc is not None:
-            self._rec.setdefault("attrs", {})["error"] = \
+            rec.setdefault("attrs", {})["error"] = \
                 f"{type(exc).__name__}: {exc}"
-        self._ctx.sink.append(self._rec)
-        trace_store().add(self._ctx.tid, self._rec["name"],
-                          list(self._ctx.sink))
+        sink.append(rec)
+        spans = list(sink)
+        if rec["name"].startswith("query:"):
+            # the statement's closed time budget: ONE locked counter
+            # update per statement, no per-span lock or stats() call
+            us, n = fold_phases(spans)
+            stats().inc_labeled_many(
+                "phase", {PHASE_US: us, PHASE_N: n})
+        trace_store().add(self._ctx.tid, rec["name"], spans)
         return False
 
 
 def start_trace(name: str, service: str = "standalone",
                 **attrs) -> _TraceGuard:
-    """Open a new root trace on this thread.  Nested start_trace calls
-    (compound `a; b` statements) each get their own trace."""
+    """Open a new root trace on this thread (a nested call opens a
+    trace of its own).  A root named `query:*` is a statement: its
+    spans' self times are folded into `stmt_phase_us{phase}` when it
+    closes."""
     return _TraceGuard(name, service, attrs)
 
 
@@ -224,7 +327,7 @@ class _RemoteGuard:
     __slots__ = ("_ctx", "_prev")
 
     def __init__(self, tid: str, psid: str, service: str):
-        self._ctx = _Ctx(tid, psid, [], service)
+        self._ctx = _Ctx(tid, psid, [], service, _profiling())
 
     @property
     def spans(self) -> List[dict]:
@@ -315,6 +418,151 @@ def render_tree(entry: dict) -> str:
     for s in sorted(children.get("__orphan__", []), key=lambda x: x["t0"]):
         visit(s, 1)
     return "\n".join(lines)
+
+
+# -- the statement phase ledger ---------------------------------------------
+
+PHASE_US, PHASE_N = "stmt_phase_us", "stmt_phase_n"
+
+#: the FIXED phase vocabulary (at most 16 labels): where a statement's
+#: time went, by the self time of its spans.  `other` is the root's own
+#: self time — what no child span explains.
+PHASES = ("parse", "plan", "admit", "exec", "snapshot_check", "rpc_wait",
+          "remote", "queue", "put", "dispatch", "fetch", "materialise",
+          "encode", "other")
+
+# span name -> phase, by the first prefix that matches; None = a
+# zero-length marker that is not a unit of work.  What no prefix names
+# (`exec:*`, `tpu:*`, and `store:*` / `raft:*` run in-process) is the
+# executors' own Python and row assembly.
+_PHASE_BY_PREFIX = (
+    ("graphd:parse", "parse"), ("graphd:plan", "plan"),
+    ("graphd:admit", "admit"), ("graphd:encode", "encode"),
+    ("tpu:snapshot_check", "snapshot_check"),
+    ("device:queue", "queue"), ("device:put", "put"),
+    ("device:dispatch", "dispatch"), ("device:fetch", "fetch"),
+    ("device:materialise", "materialise"),
+    ("rpc:retry", None), ("rpc:breaker", None),
+    ("storage:dedup_hit", None), ("storage:follower_read", None),
+    ("storage:", "rpc_wait"), ("rpc:", "rpc_wait"), ("meta:", "rpc_wait"),
+    ("query:", "other"))
+_phase_cache: Dict[str, Optional[str]] = {}
+
+
+def phase_of(name: str) -> Optional[str]:
+    ph = next((p for pre, p in _PHASE_BY_PREFIX if name.startswith(pre)),
+              "exec")
+    if len(_phase_cache) < 1024:
+        _phase_cache[name] = ph
+    return ph
+
+
+def self_times(spans: List[dict]) -> Dict[str, float]:
+    """sid -> self time in us, for every LOCAL span of one finished
+    trace: the span's duration minus the union of its children's
+    intervals, each clipped to it (NOT minus their sum: a fan-out's
+    children overlap).  Where siblings overlap, each sibling's subtree
+    is scaled by union / sum of that sibling group, so the wall time a
+    fan-out shares is split in proportion to length and the self times
+    of a trace sum to its root's duration.  Remote spans carry another
+    host's clock and are left out (`fold_phases` places them inside
+    their `rpc:` parent by their length alone); a span whose parent was
+    not recorded hangs off the root.  One pass down the tree: this runs
+    once per statement, under the GIL every session shares."""
+    kids: Dict[str, List[dict]] = {}
+    sids = set()
+    root = None
+    for s in spans:
+        if s.get("remote"):
+            continue
+        sids.add(s["sid"])
+        p = s["psid"]
+        if p:
+            kids.setdefault(p, []).append(s)
+        else:
+            root = s
+    if root is None:
+        return {}
+    for p in [p for p in kids if p not in sids]:
+        kids.setdefault(root["sid"], []).extend(kids.pop(p))
+    out: Dict[str, float] = {}
+    a = round(root["t0"] * 1e6)
+    stack = [(root, a, a + root["dur_us"], 1.0)]
+    while stack:
+        s, a, b, w = stack.pop()
+        covered = 0
+        ch = kids.get(s["sid"])
+        if ch:
+            ivs = []
+            for c in ch:
+                ca = round(c["t0"] * 1e6)
+                cb = min(ca + c["dur_us"], b)
+                ca = min(max(ca, a), b)
+                if cb > ca:
+                    ivs.append((ca, cb, c))
+                else:
+                    out[c["sid"]] = 0.0     # a marker, or clipped away
+            if len(ivs) == 1:
+                ca, cb, c = ivs[0]
+                covered = cb - ca
+                stack.append((c, ca, cb, w))
+            elif ivs:
+                ivs.sort(key=_by_start)
+                total, end = 0, a
+                for ca, cb, c in ivs:
+                    total += cb - ca
+                    if cb > end:
+                        covered += cb - max(ca, end)
+                        end = cb
+                f = w * covered / total
+                for ca, cb, c in ivs:
+                    stack.append((c, ca, cb, f))
+        out[s["sid"]] = (b - a - covered) * w
+    return out
+
+
+def _by_start(iv):
+    return iv[0]
+
+
+def fold_phases(spans: List[dict]
+                ) -> Tuple[Dict[str, int], Dict[str, int]]:
+    """One finished statement trace -> ({phase: self us}, {phase:
+    spans}).  The us sum to the root's duration.  An `rpc:` span's self
+    time is transport and queueing; the share of it that its grafted
+    handler span's LENGTH covers goes to `remote` (another host's clock
+    is not ours, so only the length is used).  `rpc_wait` counts `rpc:`
+    spans only: a `storage:` span wraps one and is not counted again."""
+    selfs = self_times(spans)
+    us: Dict[str, float] = {}
+    n: Dict[str, int] = {}
+    handler: Dict[str, int] = {}
+    rpcs = []
+    for s in spans:
+        if s.get("remote"):
+            if s["psid"] in selfs:
+                handler[s["psid"]] = handler.get(s["psid"], 0) + s["dur_us"]
+            continue
+        name = s["name"]
+        ph = _phase_cache.get(name, "")
+        if ph == "":
+            ph = phase_of(name)
+        if ph is None:
+            continue
+        if ph != "rpc_wait":
+            n[ph] = n.get(ph, 0) + 1
+        elif name.startswith("rpc:"):
+            n[ph] = n.get(ph, 0) + 1
+            rpcs.append(s)
+        us[ph] = us.get(ph, 0.0) + selfs[s["sid"]]
+    for s in rpcs:
+        h = handler.get(s["sid"])
+        if h:
+            part = selfs[s["sid"]] * min(1.0, h / max(s["dur_us"], 1))
+            us["remote"] = us.get("remote", 0.0) + part
+            us["rpc_wait"] -= part
+            n["remote"] = n.get("remote", 0) + 1
+    return {k: round(v) for k, v in us.items()}, n
 
 
 _store = TraceStore()

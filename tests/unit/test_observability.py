@@ -142,31 +142,3 @@ def test_query_metrics_flow():
     after = stats().snapshot()
     assert after["num_queries"] >= before + 2
     assert after["query_latency_us.count"] >= 2
-
-
-def test_tpu_profiler_trace(tmp_path):
-    """tpu_profiler_dir wraps kernel runs in a jax.profiler trace and
-    leaves an xplane dump on disk (SURVEY §5 tracing)."""
-    import os
-
-    from nebula_tpu.exec import QueryEngine
-    from nebula_tpu.tpu.device import make_mesh
-    from nebula_tpu.tpu.runtime import TpuRuntime
-    from nebula_tpu.utils.config import get_config
-
-    get_config().set_dynamic("tpu_profiler_dir", str(tmp_path))
-    try:
-        eng = QueryEngine(tpu_runtime=TpuRuntime(make_mesh()))
-        s = eng.new_session()
-        for q in ["CREATE SPACE pf(partition_num=8, vid_type=INT64)",
-                  "USE pf", "CREATE EDGE e(w int)",
-                  "INSERT EDGE e(w) VALUES 1->2:(1), 2->3:(2), 1->3:(3)",
-                  "GO 2 STEPS FROM 1 OVER e YIELD dst(edge) AS d"]:
-            r = eng.execute(s, q)
-            assert r.error is None, f"{q} -> {r.error}"
-        assert eng.qctx.last_tpu_stats is not None
-        dumped = [os.path.join(dp, f) for dp, _, fs in os.walk(tmp_path)
-                  for f in fs]
-        assert dumped, "profiler trace left no files"
-    finally:
-        get_config().set_dynamic("tpu_profiler_dir", "")

@@ -409,6 +409,14 @@ def _emitted_metric_names():
         (REPO / "nebula_tpu/cluster/storage_service.py").read_text()
     names.update({"storage_pushdown_scanned",
                   "storage_pushdown_shipped"})
+    # the statement phase ledger is one batched update by constant
+    # names (utils/trace.py); process_cpu_s exists in snapshots only
+    from nebula_tpu.utils import trace
+    assert "inc_labeled_many(" in \
+        (REPO / "nebula_tpu/utils/trace.py").read_text()
+    assert 'out["process_cpu_s"]' in \
+        (REPO / "nebula_tpu/utils/stats.py").read_text()
+    names.update({trace.PHASE_US, trace.PHASE_N, "process_cpu_s"})
     return names
 
 
@@ -443,7 +451,7 @@ def _emitted_span_names():
     with dynamic f-string segments (`{node.kind}`) normalized to `*`
     so `exec:{node.kind}` and the catalogue's `exec:*` compare equal."""
     pat = re.compile(
-        r'(?:trace|_trace|_t)\.(?:span|record_phase|start_trace)\(\s*'
+        r'(?:trace|_trace|_t)\.(?:span|record_phase|mark|start_trace)\(\s*'
         r'(f?)["\']([^"\']+)["\']')
     names = set()
     for p in (REPO / "nebula_tpu").rglob("*.py"):

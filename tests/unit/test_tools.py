@@ -233,7 +233,8 @@ def test_metrics_dump_perfetto_export(tmp_path, capsys):
     # a stitched trace with host + device + remote-ish spans
     with trace.start_trace("query:Go", service="graphd", stmt="GO ..."):
         with trace.span("exec:ExpandAll", node=7):
-            trace.record_phase("device:dispatch", 0.003, eb=[256])
+            with trace.span("device:dispatch", eb=[256]):
+                pass
         trace.graft([{"tid": "t1", "sid": "r1", "psid": "x",
                       "name": "store:get_neighbors", "svc": "storaged",
                       "t0": 1.0, "dur_us": 42}])
