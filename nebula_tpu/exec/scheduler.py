@@ -208,6 +208,15 @@ class Scheduler:
                         "fetch_s": round(ts.fetch_s, 6),
                         "mat_s": round(ts.mat_s, 6),
                         "hop_edges": ts.hop_edges,
+                        # share of the edge budgets' chunks the hops'
+                        # by-need loops ran (None: no loop, the budgets
+                        # fit one chunk)
+                        "chunks": {
+                            "run": ts.chunks_run,
+                            "budget": ts.chunks_budget,
+                            "share": round(ts.chunks_run
+                                           / ts.chunks_budget, 4)
+                            if ts.chunks_budget else None},
                         "buckets": {"EB": ts.e_cap},
                         "retries": ts.retries,
                         "compiles": getattr(ts, "compiles", 0),

@@ -36,6 +36,14 @@ EB is a power-of-two bucket chosen by the runtime; every kernel output
 carries overflow flags, and the runtime re-runs with doubled buckets on
 overflow (inputs are never consumed, so the retry is exact).
 
+Work by need (PR 25): EB bounds a hop's SHAPES, not its work.  A gather
+or scatter on the chip costs what its slots cost (20 to 27 ns apiece,
+PERF.md section 5), so every per-slot stage of a hop runs over
+ceil(need / CHUNK) chunks inside one device loop whose trip count is
+the expansion's own size (`_by_need`); only the vmax-sized passes and
+the streaming cumsums run whole.  A hop whose budget fits one chunk
+compiles to the straight-line program.
+
 Frontier representation between hops: (P, vmax) bool, row p = the
 membership bitmap of part p's local ids (dense id = local * P + p).
 Expansion enumerates set bits in ascending local-id order, so captured
@@ -45,7 +53,7 @@ the host materializers rely on.
 from __future__ import annotations
 
 import functools
-from typing import Any, Callable, Dict, List, Optional, Sequence
+from typing import Any, Callable, Dict, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -70,20 +78,72 @@ def _stage(name: str):
     return wrap
 
 
-def _expand_block(indptr, nbr, rank, fbm, EB: int, P: int, pid,
-                  vmax_local: int = 0, hub_dense=None):
-    """Vectorized CSR expansion of one block from one part's frontier
-    bitmap.
+# Slots of one part that one trip of a by-need loop handles (_by_need).
+# A hop whose budget is no larger runs straight-line.  Settled on the
+# chip (PERF.md, PR 25): in the 30 M-edge cell device time is flat from
+# 2^13 to 2^15 (within 1%), 2% more at 2^16, 4% at 2^17, a third at 2^20.
+CHUNK = 1 << 14
 
-    indptr: (vmax+1,) local CSR row pointers; nbr/rank: (E,) edge
-    arrays; fbm: (vmax,) bool frontier membership; pid: this part's id
-    (dense id = local * P + pid).
 
-    With a degree-split snapshot (graphstore.csr.degree_split) the
-    block carries H extra HUB rows after the vmax_local local rows, and
-    fbm arrives EXTENDED to vmax_local+H (hub-active bits appended by
-    the caller); a hub row's source dense id comes from `hub_dense`
-    instead of the local-row arithmetic.
+def _window(x, lo, size: int):
+    """Slots [lo, lo + size) of x's last axis — x itself when that is
+    all of them (the straight-line case slices nothing)."""
+    if size == x.shape[-1]:
+        return x
+    return jax.lax.dynamic_slice_in_dim(x, lo, size, axis=-1)
+
+
+def _put(out, x, lo):
+    """A chunk's values written at slot `lo` of the preallocated `out`
+    — x itself when it already spans all of out."""
+    if x.shape == out.shape:
+        return x
+    return jax.lax.dynamic_update_slice_in_dim(out, x, lo, axis=-1)
+
+
+def _by_need(body, outs, n, width: int, tail: int = 0, chunk: int = CHUNK):
+    """Run a per-slot stage of a hop over the slots the expansion
+    FILLED, not over the hop's whole edge budget.
+
+    body(outs, lo, size) -> outs handles slots [lo, lo + size) of the
+    last axis and writes what it produced into the preallocated `outs`
+    (a tuple of arrays holding the fill values slots never visited
+    keep).  `n` is the traced number of live slots among the first
+    `width`; the body runs on [c*chunk, (c+1)*chunk) for
+    c < ceil(n / chunk) inside one device loop, then once on the
+    `tail` slots that follow `width` (the delta plane's appended rows,
+    always live).  A gather's cost on the chip follows the slots it is
+    asked for, so a hop that filled a fifth of its budget pays a fifth.
+
+    The choice between loop and no loop is the STATIC width: a budget
+    of one chunk or less (or one that chunks do not tile) runs the body
+    once over everything, which is the straight-line program.  Under vmap
+    the loop runs to the largest trip count among the mapped instances.
+
+    Returns (outs, chunks run, chunks budgeted) — (outs, 0, 0) when no
+    loop was emitted."""
+    if width <= chunk or width % chunk:
+        return body(outs, 0, width + tail), 0, 0
+    trips = (jnp.minimum(n, width) + (chunk - 1)) // chunk
+    # inside a shard_map a fresh constant is the same on every shard and
+    # what the body writes is not: the loop carry takes the body's type
+    want = jax.eval_shape(lambda o: body(o, 0, chunk), outs)
+    missing = [tuple((w.vma or frozenset()) - jax.typeof(o).vma)
+               for o, w in zip(outs, want)]
+    outs = tuple(jax.lax.pcast(o, axes, to="varying") if axes else o
+                 for o, axes in zip(outs, missing))
+    outs = jax.lax.fori_loop(
+        0, trips, lambda c, o: body(o, c * chunk, chunk), outs)
+    if tail:
+        outs = body(outs, width, tail)
+    return outs, trips, width // chunk
+
+
+def _expand_plan(indptr, fbm, EB: int):
+    """The cheap half of one block's CSR expansion from one part's
+    frontier bitmap: everything that is vmax-sized or a streaming pass
+    over the EB slots.  Returns (total, ovf, plan) — the true expansion
+    size, the overflow flag, and the tables `_expand_slots` reads.
 
     Slot→source-row assignment is a cumsum-scatter, not a binary
     search: bump +1 at each frontier vertex's first slot, prefix-sum
@@ -91,11 +151,6 @@ def _expand_block(indptr, nbr, rank, fbm, EB: int, P: int, pid,
     id through a scattered lookup table — O(vmax + EB) total, versus
     O(EB log vmax) for searchsorted (the log factor dominated the old
     kernel's per-slot cost on both the VPU and the CPU-emulated mesh).
-
-    Returns per-edge-slot arrays of length EB:
-      src (frontier dense id), dst, rk, eidx (index into the block's
-      edge arrays — the host uses it to decode properties), ve (slot
-      valid), plus (total, ovf): true expansion size and overflow flag.
     """
     vmax = fbm.shape[0]
     with jax.named_scope("hop/expand"):
@@ -114,13 +169,25 @@ def _expand_block(indptr, nbr, rank, fbm, EB: int, P: int, pid,
         bump = jnp.zeros((EB,), jnp.int32).at[
             jnp.where(has, starts, EB)].add(1, mode="drop")
         crow = jnp.cumsum(bump) - 1               # (EB,)
-        row = vid_of[jnp.maximum(crow, 0)]
-        j = jnp.arange(EB, dtype=jnp.int32)
+    return total, total > EB, (vid_of, starts, crow)
+
+
+def _expand_slots(indptr, nbr, rank, plan, total, lo, size: int, EB: int,
+                  P: int, pid, vmax_local: int = 0, hub_dense=None):
+    """The per-slot half: slots [lo, lo + size) of the expansion
+    `_expand_plan` laid out.  Returns arrays of length `size`:
+      src (frontier dense id), dst, rk, eidx (index into the block's
+      edge arrays — the host uses it to decode properties), ve (slot
+      valid)."""
+    vid_of, starts, crow = plan
+    with jax.named_scope("hop/expand"):
+        row = vid_of[jnp.maximum(_window(crow, lo, size), 0)]
+        j = lo + jnp.arange(size, dtype=jnp.int32)
         eidx = indptr[row] + (j - starts[row])
         ve = j < jnp.minimum(total, EB)
         eidx = jnp.where(ve, eidx, 0).astype(jnp.int32)
     with jax.named_scope("hop/gather"):
-        # the neighbour and rank gathers over the hop's EB slots
+        # the neighbour and rank gathers over the slots
         dst = jnp.where(ve, nbr[eidx], -1)
         if hub_dense is None:
             src_id = row * P + pid
@@ -131,7 +198,62 @@ def _expand_block(indptr, nbr, rank, fbm, EB: int, P: int, pid,
                                    hub_dense.shape[0] - 1)])
         src = jnp.where(ve, src_id, -1)
         rk = jnp.where(ve, rank[eidx], 0)
-    return src, dst, rk, eidx, ve, total, total > EB
+    return src, dst, rk, eidx, ve
+
+
+def _expand_block(indptr, nbr, rank, fbm, EB: int, P: int, pid,
+                  vmax_local: int = 0, hub_dense=None):
+    """Vectorized CSR expansion of one block from one part's frontier
+    bitmap, all EB slots at once (the BFS and algo level bodies; the
+    traverse builders run `_expand_slots` by need).
+
+    indptr: (vmax+1,) local CSR row pointers; nbr/rank: (E,) edge
+    arrays; fbm: (vmax,) bool frontier membership; pid: this part's id
+    (dense id = local * P + pid).
+
+    With a degree-split snapshot (graphstore.csr.degree_split) the
+    block carries H extra HUB rows after the vmax_local local rows, and
+    fbm arrives EXTENDED to vmax_local+H (hub-active bits appended by
+    the caller); a hub row's source dense id comes from `hub_dense`
+    instead of the local-row arithmetic.
+
+    Returns the per-edge-slot arrays of `_expand_slots` at length EB,
+    plus (total, ovf): true expansion size and overflow flag.
+    """
+    total, ovf, plan = _expand_plan(indptr, fbm, EB)
+    return _expand_slots(indptr, nbr, rank, plan, total, 0, EB, EB, P,
+                         pid, vmax_local, hub_dense) + (total, ovf)
+
+
+def _drop_tombstoned(tomb, eidx, ve):
+    """The delta merge's per-slot half: a searchsorted membership test
+    drops base slots whose eidx was deleted/overwritten since the pin.
+    tomb: (Tcap,) SORTED int32 base-edge indices (MAXI-padded)."""
+    if not tomb.shape[0]:
+        return ve
+    pos = jnp.clip(jnp.searchsorted(tomb, eidx), 0, tomb.shape[0] - 1)
+    return ve & ~(tomb[pos] == eidx)
+
+
+def _append_delta(dl, fbm, src, dst, rk, eidx, ve, total, P: int, pid,
+                  emax: int):
+    """The delta merge's other half: delta rows whose source vertex is
+    on the frontier are APPENDED to the capture arrays — delta row j
+    takes the virtual edge index emax + j, so downstream prop gathers
+    read from columns extended with the delta prop columns and the host
+    can split captured rows back into base (< emax) and delta halves."""
+    dsrc = dl["d_src"]
+    Dcap = dsrc.shape[0]
+    if Dcap:
+        active = dl["d_valid"] & fbm[jnp.clip(dsrc, 0, fbm.shape[0] - 1)]
+        src = jnp.concatenate([src, jnp.where(active, dsrc * P + pid, -1)])
+        dst = jnp.concatenate([dst, jnp.where(active, dl["d_dst"], -1)])
+        rk = jnp.concatenate([rk, jnp.where(active, dl["d_rank"], 0)])
+        eidx = jnp.concatenate(
+            [eidx, emax + jnp.arange(Dcap, dtype=jnp.int32)])
+        ve = jnp.concatenate([ve, active])
+        total = total + jnp.sum(active, dtype=jnp.int32)
+    return src, dst, rk, eidx, ve, total
 
 
 @_stage("hop/delta_merge")
@@ -148,36 +270,17 @@ def _merge_delta(dl, fbm, src, dst, rk, eidx, ve, total, P: int, pid,
     fbm: (vmax,) bool — this part's frontier bitmap (delta snapshots are
     never degree-split, so no hub extension applies).
 
-    Two halves, in order:
-      1. tombstones: a searchsorted membership test drops base slots
-         whose eidx was deleted/overwritten since the pin;
-      2. inserts: delta rows whose source vertex is on the frontier are
-         APPENDED to the capture arrays — delta row j takes the virtual
-         edge index emax + j, so downstream prop gathers read from
-         columns extended with the delta prop columns and the host can
-         split captured rows back into base (< emax) and delta halves.
+    Two halves, in order: tombstones (`_drop_tombstoned`, per slot),
+    then inserts (`_append_delta`).
 
     The appended slots keep the ascending-eidx tail position, so the
     (part, src)-contiguous prefix invariant of the BASE slots survives;
     the host re-sorts the merged union per part into canonical CSR
     order (runtime._block_columns) before materializing rows.
     """
-    tomb = dl["d_tomb"]
-    if tomb.shape[0]:
-        pos = jnp.clip(jnp.searchsorted(tomb, eidx), 0, tomb.shape[0] - 1)
-        ve = ve & ~(tomb[pos] == eidx)
-    dsrc = dl["d_src"]
-    Dcap = dsrc.shape[0]
-    if Dcap:
-        active = dl["d_valid"] & fbm[jnp.clip(dsrc, 0, fbm.shape[0] - 1)]
-        src = jnp.concatenate([src, jnp.where(active, dsrc * P + pid, -1)])
-        dst = jnp.concatenate([dst, jnp.where(active, dl["d_dst"], -1)])
-        rk = jnp.concatenate([rk, jnp.where(active, dl["d_rank"], 0)])
-        eidx = jnp.concatenate(
-            [eidx, emax + jnp.arange(Dcap, dtype=jnp.int32)])
-        ve = jnp.concatenate([ve, active])
-        total = total + jnp.sum(active, dtype=jnp.int32)
-    return src, dst, rk, eidx, ve, total
+    ve = _drop_tombstoned(dl["d_tomb"], eidx, ve)
+    return _append_delta(dl, fbm, src, dst, rk, eidx, ve, total, P, pid,
+                         emax)
 
 
 def _delta_cap(b) -> int:
@@ -259,24 +362,49 @@ def a2a_payload_bytes(P: int, vmax: int, lanes: int = 1) -> int:
 
 
 @_stage("hop/compact")
-def _compact_cap(src, dst, rk, eidx, keep, EB: int):
+def _compact_cap(src, dst, rk, eidx, keep, n, EB: int, tail: int = 0,
+                 chunk: int = CHUNK):
     """Stable-partition the kept edge slots to the FRONT of each capture
-    row (cumsum scatter, O(EB)) and return the kept count.
+    row (cumsum scatter, O(slots)) and return the kept count.
 
     Why: capture arrays are EB-padded and EB is sized for the worst hop
     (millions of slots); fetching them wholesale ships mostly padding
     (~2 GB/query at north-star shape).  With kept entries compacted to a
     prefix the host fetches only [:kmax] slices (runtime._escalate).
     The scatter is order-preserving, so the (part, src)-contiguous
-    ascending-eidx invariant the host materializers rely on survives."""
-    pos = jnp.where(keep, jnp.cumsum(keep, dtype=jnp.int32) - 1,
-                    EB).astype(jnp.int32)
+    ascending-eidx invariant the host materializers rely on survives.
 
-    def put(a, fill):
-        return jnp.full((EB,), fill, a.dtype).at[pos].set(a, mode="drop")
+    The arrays carry the builder's leading axes before the EB + tail
+    slots.  The cumsum is a streaming pass and stays whole; the four
+    scatters run by need (only the first `n` slots and the tail can
+    hold a kept entry) into FLAT outputs, every row at its own offset:
+    a scatter on the chip works on a flat operand, and a loop that
+    carried the rows as rows would re-lay all of them out on every
+    trip.
 
-    return (put(src, -1), put(jnp.where(keep, dst, -1), -1), put(rk, 0),
-            put(eidx, 0), jnp.sum(keep, dtype=jnp.int32))
+    Returns (src, dst, rank, eidx, kcount, chunks run, chunks budgeted).
+    """
+    W = EB + tail
+    rows = int(np.prod(keep.shape[:-1], dtype=np.int64))
+    row0 = (jnp.arange(rows, dtype=jnp.int32) * W).reshape(
+        keep.shape[:-1] + (1,))
+    pos = jnp.where(keep,
+                    jnp.cumsum(keep, axis=-1, dtype=jnp.int32) - 1 + row0,
+                    rows * W).astype(jnp.int32)
+    vals = (src, jnp.where(keep, dst, -1), rk, eidx)
+
+    def scatter(outs, lo, size):
+        at = _window(pos, lo, size).reshape(-1)
+        return tuple(o.at[at].set(_window(v, lo, size).reshape(-1),
+                                  mode="drop")
+                     for o, v in zip(outs, vals))
+
+    init = tuple(jnp.full((rows * W,), fill, v.dtype)
+                 for v, fill in zip(vals, (-1, -1, 0, 0)))
+    outs, run, budget = _by_need(scatter, init, n, EB, tail, chunk)
+    cs, cd, cr, ce = (o.reshape(keep.shape) for o in outs)
+    return (cs, cd, cr, ce, jnp.sum(keep, axis=-1, dtype=jnp.int32),
+            run, budget)
 
 
 def _norm_ebs(EB, steps: int, capture_hops: bool):
@@ -333,6 +461,195 @@ def _extend_fbm_local(fbm, hub_owner, hub_local, P: int):
         [fbm, jnp.broadcast_to(bits, (P, bits.shape[0]))], axis=1)
 
 
+_CAP_KEYS = ("src", "dst", "rank", "eidx", "kcount")
+
+
+def _traverse(over, nlead: int, blocks, fbm, pid, extend, exchange, *,
+              P: int, ebs, pred, pred_cols, capture: bool,
+              capture_hops: bool, yield_cols, hubs_c, chunk: int):
+    """The N-hop program, written once for every builder.
+
+    Every array carries the builder's `nlead` leading axes (none inside
+    one shard, the part axis on one chip, the lane axis inside one
+    shard of the lanes x shards grid) before its own; `over(f)` maps a
+    per-part function f(block_leaves, pid, *arrays) over them, `extend`
+    appends the hub bits to a frontier bitmap and `exchange` turns a
+    hop's mark matrices into the next frontier.  `blocks` holds each
+    block's leaves as `over` expects them.
+
+    Per hop and block: lay the expansion out (`_expand_plan`, cheap),
+    then run the per-slot stages by need (`_by_need`) — the expansion's
+    gathers with the delta plane's tombstone test and the predicate's
+    column gathers in one loop, the capture's compaction scatters in a
+    second, the yielded property gathers in a third.
+
+    Returns the result dict of `build_traverse_fn` without its shard
+    axis."""
+    steps = len(ebs)
+    vmax = fbm.shape[-1]
+    # a MATCH program captures every hop as a frame; a GO its last hop
+    cap_scope = "match/frame_capture" if capture_hops else "hop/capture"
+    gcols = [c for c in pred_cols if not c.startswith("_")] \
+        if pred is not None else []
+    hop_edges, frontier_sizes = [], []     # popcount entering each hop
+    chunks_run, chunks_budget = [], []
+    ovf_e = None
+    hop_caps = []
+
+    for hop, EB in enumerate(ebs):
+        frontier_sizes.append(jnp.sum(fbm, axis=-1, dtype=jnp.int32))
+        last = hop == steps - 1
+        marks = None
+        edges = run = budget = 0
+        caps = {k: [] for k in _CAP_KEYS}
+        efbm = fbm if hubs_c is None else extend(fbm)
+        want_pred = pred is not None and (last or capture_hops)
+        want_cap = capture and (last or capture_hops)
+        hcols = gcols if want_pred else []     # columns gathered this hop
+        for b in blocks:
+            dcap = _delta_cap(b)
+            emax = b["nbr"].shape[-1]
+            total, ovf, plan = over(
+                lambda blk, pd, f: _expand_plan(blk["indptr"], f, EB))(
+                b, pid, efbm)
+            # the live slots of the fullest part (or lane): the trip
+            # count of every by-need loop of this block
+            n = jnp.minimum(jnp.max(total), EB)
+
+            def expand(outs, lo, size):
+                def part(blk, pd, pl, tot):
+                    s, d, r, e, v = _expand_slots(
+                        blk["indptr"], blk["nbr"], blk["rank"], pl, tot,
+                        lo, size, EB, P, pd, vmax, hubs_c)
+                    if dcap:
+                        with jax.named_scope("hop/delta_merge"):
+                            v = _drop_tombstoned(blk["d_tomb"], e, v)
+                    with jax.named_scope("hop/pred_gather"):
+                        g = tuple(blk["props"][c][e] for c in hcols)
+                    return (s, d, r, e, v) + g
+                vals = over(part)(b, pid, plan, total)
+                return tuple(_put(o, v, lo) for o, v in zip(outs, vals))
+
+            lead = total.shape
+            fills = [(-1, jnp.int32), (-1, jnp.int32),
+                     (0, b["rank"].dtype), (0, jnp.int32), (False, bool)]
+            fills += [(0, b["props"][c].dtype) for c in hcols]
+            outs, r, bd = _by_need(
+                expand, tuple(jnp.full(lead + (EB,), f, dt)
+                              for f, dt in fills), n, EB, chunk=chunk)
+            run, budget = run + r, budget + bd
+            src, dst, rk, eidx, ve = outs[:5]
+            pcols = dict(zip(hcols, outs[5:]))
+            if dcap:
+                # delta snapshots are never hub-extended, so efbm here
+                # is the plain (vmax,) membership row
+                with jax.named_scope("hop/delta_merge"):
+                    src, dst, rk, eidx, ve, total = over(
+                        lambda blk, pd, f, *a: _append_delta(
+                            blk, f, *a, P, pd, emax))(
+                        b, pid, efbm, src, dst, rk, eidx, ve, total)
+                # a delta row's eidx is its own index past emax: its
+                # predicate columns are the delta columns themselves
+                pcols = {c: jnp.concatenate(
+                    [v, jnp.broadcast_to(b["d_props"][c], v.shape[:-1]
+                                         + b["d_props"][c].shape[-1:])],
+                    axis=-1) for c, v in pcols.items()}
+            ovf_e = ovf if ovf_e is None else ovf_e | ovf
+            edges = edges + total
+
+            if want_pred:
+                with jax.named_scope("hop/predicate"):
+                    keep = pred({"_rank": rk, "_src": src, "_dst": dst,
+                                 **pcols}) & ve
+            else:
+                keep = ve
+            if want_cap:
+                if want_pred or dcap:
+                    with jax.named_scope(cap_scope):
+                        cs, cd, cr, ce, kc, r, bd = _compact_cap(
+                            src, dst, rk, eidx, keep, n, EB, dcap, chunk)
+                    run, budget = run + r, budget + bd
+                else:
+                    # nothing filtered a slot out: the expansion's live
+                    # slots already are the prefix, fills and all
+                    cs, cd, cr, ce = src, dst, rk, eidx
+                    kc = jnp.minimum(total, EB)
+                for k, v in zip(_CAP_KEYS, (cs, cd, cr, ce, kc)):
+                    caps[k].append(v)
+                if last and not capture_hops and yield_cols:
+                    ycols = {c: b["props"][c] if not dcap else
+                             jnp.concatenate(
+                                 [b["props"][c], b["d_props"][c]], axis=-1)
+                             for c in yield_cols}
+
+                    def props(outs, lo, size):
+                        e = _window(ce, lo, size)
+                        got = []
+                        for c, o in zip(yield_cols, outs):
+                            with jax.named_scope("hop/prop_" + c):
+                                got.append(_put(o, over(
+                                    lambda col, _p, i: col[i])(
+                                    ycols[c], pid, e), lo))
+                        return tuple(got)
+
+                    # kept entries sit in a prefix: the live range is
+                    # the fullest part's kept count
+                    got, r, bd = _by_need(
+                        props, tuple(jnp.zeros(ce.shape, ycols[c].dtype)
+                                     for c in yield_cols),
+                        jnp.max(kc), EB, dcap, chunk)
+                    run, budget = run + r, budget + bd
+                    for c, g in zip(yield_cols, got):
+                        caps.setdefault("prop:" + c, []).append(g)
+            if not last:
+                blk_marks = over(
+                    lambda _b, _p, d, k: _mark(d, k, P, vmax))(
+                    None, None, dst, keep)
+                marks = blk_marks if marks is None else marks | blk_marks
+        hop_edges.append(edges)
+        zero = jnp.zeros_like(edges)
+        chunks_run.append(zero + run)
+        chunks_budget.append(zero + budget)
+        if want_cap:
+            # arrays lead + (nb, EB); kcount lead + (nb,)
+            hop_caps.append({k: jnp.stack(v, axis=nlead)
+                             for k, v in caps.items()})
+        # the post-final frontier is not needed for GO; report empty
+        fbm = jnp.zeros_like(fbm) if last else exchange(marks)
+
+    res = {
+        "frontier": fbm,
+        "fcount": jnp.sum(fbm, axis=-1, dtype=jnp.int32),
+        # pre-filter expansion size per hop: lead + (steps,)
+        "hop_edges": jnp.stack(hop_edges, axis=nlead),
+        # deterministic work counter (ISSUE 1): per-hop frontier size,
+        # this part's members only — host sums over parts
+        "frontier_sizes": jnp.stack(frontier_sizes, axis=nlead),
+        "ovf_expand": ovf_e,
+        # by-need engagement: loop trips run and budgeted per hop, one
+        # chunk being CHUNK slots of one part (0 where no loop ran)
+        "chunks_run": jnp.stack(chunks_run, axis=nlead),
+        "chunks_budget": jnp.stack(chunks_budget, axis=nlead),
+    }
+    if capture:
+        if capture_hops:
+            with jax.named_scope("match/frame_stack"):
+                # lead + (steps, nb, EB); kcount lead + (steps, nb)
+                cap = {k: jnp.stack([hc[k] for hc in hop_caps], axis=nlead)
+                       for k in _CAP_KEYS}
+        else:
+            cap = dict(hop_caps[-1])
+        res["kcount"] = cap.pop("kcount")   # small: fetched with the meta
+        res["cap"] = cap
+    return res
+
+
+def _part_view(blocks_data):
+    """One shard's blocks without their shard axis (length 1 inside a
+    shard_map over 'part')."""
+    return [jax.tree.map(lambda x: x[0], b) for b in blocks_data]
+
+
 def build_traverse_fn(mesh, P: int, EB, steps: int,
                       n_blocks: int,
                       pred: Optional[Callable[[Dict[str, Any]], Any]] = None,
@@ -340,7 +657,7 @@ def build_traverse_fn(mesh, P: int, EB, steps: int,
                       capture: bool = True,
                       capture_hops: bool = False,
                       yield_cols: Sequence[str] = (),
-                      hub_dense=None):
+                      hub_dense=None, chunk: int = CHUNK):
     """Compile the N-step traversal program for one bucket configuration.
     EB: per-block edge budget — an int (uniform) or a per-hop sequence.
 
@@ -351,6 +668,9 @@ def build_traverse_fn(mesh, P: int, EB, steps: int,
     host-side gather (GO capture mode only; x64 is enabled, so device
     gathers are bit-exact with the host decode).
 
+    chunk: the by-need loops' chunk (`_by_need`); the module constant
+    everywhere but in tests.
+
     blocks_data (runtime arg): tuple of n_blocks dicts with keys
       indptr (P, vmax+1), nbr (P, E), rank (P, E), props {name: (P, E)}
     where props holds the columns the predicate needs PLUS yield_cols
@@ -360,11 +680,14 @@ def build_traverse_fn(mesh, P: int, EB, steps: int,
       frontier (P, vmax) bool, fcount (P,): next frontier after the LAST
         hop (mid-hop frontiers never leave the device)
       hop_edges (P, steps): pre-filter expansion size per hop per part
+      chunks_run, chunks_budget (P, steps): by-need loop trips run and
+        budgeted per hop (0 where the hop's budget fits one chunk)
       ovf_expand (P,) bool: some hop's expansion exceeded EB
       cap (if capture): dict of (P, n_blocks, EB) arrays
         src, dst, rank, eidx, prop:<name> per yield_col — the final
         hop's edge set (kept entries compacted to a prefix;
-        kcount (P, n_blocks) gives the counts)
+        kcount (P, n_blocks) gives the counts; what a prop array holds
+        past its kept count is unspecified)
 
     capture_hops=True is the MATCH mode (SURVEY §2 row 23 Traverse):
     the predicate is applied at EVERY hop (a MATCH edge pattern's filter
@@ -373,116 +696,20 @@ def build_traverse_fn(mesh, P: int, EB, steps: int,
     a leading hop axis, (P, steps, n_blocks, EB).  The host assembles
     trail-semantics paths from the layered frames (runtime.py).
     """
-
     ebs = _norm_ebs(EB, steps, capture_hops)
-    # a MATCH program captures every hop as a frame; a GO its last hop
-    cap_scope = "match/frame_capture" if capture_hops else "hop/capture"
     hubs_c, hub_owner, hub_local = _hub_consts(hub_dense, P)
 
     def kernel(blocks_data, frontier):
-        fbm = frontier[0]                      # (vmax,) bool
-        vmax = fbm.shape[0]
         pid = jax.lax.axis_index("part").astype(jnp.int32)
-        hop_edges: List[Any] = []
-        frontier_sizes: List[Any] = []         # popcount entering each hop
-        ovf_e = jnp.zeros((), bool)
-        cap_out = None
-        hop_caps: List[Dict[str, Any]] = []
-
-        for hop in range(steps):
-            frontier_sizes.append(jnp.sum(fbm, dtype=jnp.int32))
-            last = hop == steps - 1
-            EBh = ebs[hop]
-            marks = None
-            edges_this_hop = jnp.zeros((), jnp.int32)
-            caps = {"src": [], "dst": [], "rank": [], "eidx": [],
-                    "kcount": []}
-            efbm = fbm if hubs_c is None else _extend_fbm_sharded(
-                fbm, pid, hub_owner, hub_local)
-            for bi in range(n_blocks):
-                b = blocks_data[bi]
-                src, dst, rk, eidx, ve, total, ovf = _expand_block(
-                    b["indptr"][0], b["nbr"][0], b["rank"][0], efbm, EBh,
-                    P, pid, vmax_local=vmax, hub_dense=hubs_c)
-                ovf_e = ovf_e | ovf
-                dcap = _delta_cap(b)
-                if dcap:
-                    dl = {k: b[k][0] for k in
-                          ("d_src", "d_dst", "d_rank", "d_valid", "d_tomb")}
-                    src, dst, rk, eidx, ve, total = _merge_delta(
-                        dl, fbm, src, dst, rk, eidx, ve, total, P, pid,
-                        b["nbr"].shape[-1])
-                edges_this_hop = edges_this_hop + total
-
-                def _col(name):
-                    c = b["props"][name][0]
-                    if dcap:
-                        c = jnp.concatenate([c, b["d_props"][name][0]])
-                    return c
-
-                if pred is not None and (last or capture_hops):
-                    cols = {"_rank": rk, "_src": src, "_dst": dst}
-                    for name in pred_cols:
-                        if not name.startswith("_"):
-                            with jax.named_scope("hop/pred_gather"):
-                                cols[name] = _col(name)[eidx]
-                    with jax.named_scope("hop/predicate"):
-                        keep = pred(cols) & ve
-                else:
-                    keep = ve
-                if capture and (last or capture_hops):
-                    with jax.named_scope(cap_scope):
-                        cs, cd, cr, ce, kc = _compact_cap(
-                            src, dst, rk, eidx, keep, EBh + dcap)
-                    caps["src"].append(cs)
-                    caps["dst"].append(cd)
-                    caps["rank"].append(cr)
-                    caps["eidx"].append(ce)
-                    caps["kcount"].append(kc)
-                    if last and not capture_hops:
-                        for name in yield_cols:
-                            with jax.named_scope("hop/prop_" + name):
-                                caps.setdefault("prop:" + name, []).append(
-                                    _col(name)[ce])
-                if not last:
-                    marks = _mark(dst, keep, P, vmax, marks)
-            hop_edges.append(edges_this_hop)
-            if capture and (last or capture_hops):
-                hop_caps.append({k: jnp.stack(v) for k, v in caps.items()})
-
-            if last:
-                if capture:
-                    if capture_hops:
-                        with jax.named_scope("match/frame_stack"):
-                            arr_keys = ("src", "dst", "rank", "eidx")
-                            cap_out = {
-                                k: jnp.stack([hc[k] for hc in hop_caps])[None]
-                                for k in arr_keys}
-                            kcount_out = jnp.stack(
-                                [hc["kcount"] for hc in hop_caps])[None]
-                    else:
-                        cap_out = {k: v[None]
-                                   for k, v in hop_caps[-1].items()
-                                   if k != "kcount"}
-                        kcount_out = hop_caps[-1]["kcount"][None]
-                # the post-final frontier is not needed for GO; report empty
-                fbm = jnp.zeros((vmax,), bool)
-            else:
-                fbm = _exchange_marks(marks, P, vmax)
-
-        res = {
-            "frontier": fbm[None],
-            "fcount": jnp.sum(fbm, dtype=jnp.int32)[None],
-            "hop_edges": jnp.stack(hop_edges)[None],
-            # deterministic work counter (ISSUE 1): per-hop frontier
-            # size, this shard's members only — host sums over parts
-            "frontier_sizes": jnp.stack(frontier_sizes)[None],
-            "ovf_expand": ovf_e[None],
-        }
-        if capture:
-            res["cap"] = cap_out
-            res["kcount"] = kcount_out   # small: fetched with the meta
-        return res
+        vmax = frontier.shape[-1]
+        res = _traverse(
+            lambda f: f, 0, _part_view(blocks_data), frontier[0], pid,
+            lambda f: _extend_fbm_sharded(f, pid, hub_owner, hub_local),
+            lambda marks: _exchange_marks(marks, P, vmax),
+            P=P, ebs=ebs, pred=pred, pred_cols=pred_cols, capture=capture,
+            capture_hops=capture_hops, yield_cols=yield_cols,
+            hubs_c=hubs_c, chunk=chunk)
+        return jax.tree.map(lambda x: x[None], res)
 
     from jax.sharding import PartitionSpec
     spec = PartitionSpec("part")
@@ -498,136 +725,26 @@ def _build_local_fn(P: int, EB, steps: int,
                     capture: bool = True,
                     capture_hops: bool = False,
                     yield_cols: Sequence[str] = (),
-                    hub_dense=None):
+                    hub_dense=None, chunk: int = CHUNK):
     """The UNJITTED single-chip traversal program — shared by
     build_traverse_fn_local (jit) and build_traverse_fn_lanes (jit of a
-    vmap over a leading query-lane axis; ISSUE 15)."""
+    vmap over a leading query-lane axis; ISSUE 15).  Every leaf of a
+    block (indptr/nbr/rank/props AND the d_* delta plane) carries a
+    leading part axis, and the per-part functions are vmapped over it;
+    the frontier exchange is an OR over the source parts' marks
+    (marks[s, d] = part s's candidate bitmap for part d)."""
     pids = jnp.arange(P, dtype=jnp.int32)
     ebs = _norm_ebs(EB, steps, capture_hops)
-    # a MATCH program captures every hop as a frame; a GO its last hop
-    cap_scope = "match/frame_capture" if capture_hops else "hop/capture"
     hubs_c, hub_owner, hub_local = _hub_consts(hub_dense, P)
 
-    def one_part_expand(block, fbm, pid, want_pred, EBh, vmax_local):
-        src, dst, rk, eidx, ve, total, ovf = _expand_block(
-            block["indptr"], block["nbr"], block["rank"], fbm, EBh, P,
-            pid, vmax_local=vmax_local, hub_dense=hubs_c)
-        if "d_src" in block:
-            # delta snapshots are never hub-extended, so fbm here is the
-            # plain (vmax,) membership row
-            src, dst, rk, eidx, ve, total = _merge_delta(
-                block, fbm, src, dst, rk, eidx, ve, total, P, pid,
-                block["nbr"].shape[-1])
-        if want_pred:
-            cols = {"_rank": rk, "_src": src, "_dst": dst}
-            for name in pred_cols:
-                if not name.startswith("_"):
-                    c = block["props"][name]
-                    if "d_src" in block:
-                        c = jnp.concatenate([c, block["d_props"][name]])
-                    with jax.named_scope("hop/pred_gather"):
-                        cols[name] = c[eidx]
-            with jax.named_scope("hop/predicate"):
-                keep = pred(cols) & ve
-        else:
-            keep = ve
-        return src, dst, rk, eidx, ve, keep, total, ovf
-
     def fn(blocks_data, frontier):
-        fbm = frontier                     # (P, vmax) bool
-        vmax = fbm.shape[1]
-        hop_edges = []
-        frontier_sizes = []                # popcount entering each hop
-        ovf_e = jnp.zeros((P,), bool)
-        cap_out = None
-        hop_caps = []
-
-        for hop in range(steps):
-            frontier_sizes.append(jnp.sum(fbm, axis=1, dtype=jnp.int32))
-            last = hop == steps - 1
-            EBh = ebs[hop]
-            marks = None                   # (P_src, P_dst, vmax) bool
-            edges = jnp.zeros((P,), jnp.int32)
-            caps = {"src": [], "dst": [], "rank": [], "eidx": [],
-                    "kcount": []}
-            efbm = fbm if hubs_c is None else _extend_fbm_local(
-                fbm, hub_owner, hub_local, P)
-            for bi in range(n_blocks):
-                b = blocks_data[bi]
-                want_pred = pred is not None and (last or capture_hops)
-                dcap = _delta_cap(b)
-                # the whole block dict is the vmap operand: every leaf
-                # (indptr/nbr/rank/props AND the d_* delta plane) carries
-                # a leading part axis
-                src, dst, rk, eidx, ve, keep, total, ovf = jax.vmap(
-                    lambda blk, f, pd: one_part_expand(
-                        blk, f, pd, want_pred, EBh, vmax)
-                )(b, efbm, pids)
-                ovf_e = ovf_e | ovf
-                edges = edges + total
-                if capture and (last or capture_hops):
-                    with jax.named_scope(cap_scope):
-                        cs, cd, cr, ce, kc = jax.vmap(
-                            lambda s, d, r, e, k: _compact_cap(
-                                s, d, r, e, k, EBh + dcap)
-                        )(src, dst, rk, eidx, keep)
-                    caps["src"].append(cs)
-                    caps["dst"].append(cd)
-                    caps["rank"].append(cr)
-                    caps["eidx"].append(ce)
-                    caps["kcount"].append(kc)
-                    if last and not capture_hops:
-                        for name in yield_cols:
-                            col = b["props"][name]
-                            if dcap:
-                                col = jnp.concatenate(
-                                    [col, b["d_props"][name]], axis=1)
-                            with jax.named_scope("hop/prop_" + name):
-                                caps.setdefault("prop:" + name, []).append(
-                                    jax.vmap(lambda c, e: c[e])(col, ce))
-                if not last:
-                    blk_marks = jax.vmap(
-                        lambda d, k: _mark(d, k, P, vmax))(dst, keep)
-                    marks = blk_marks if marks is None \
-                        else marks | blk_marks
-            hop_edges.append(edges)
-            if capture and (last or capture_hops):
-                # arrays (P, nb, EB); kcount (P, nb)
-                hop_caps.append({k: jnp.stack(v, axis=1)
-                                 for k, v in caps.items()})
-
-            if last:
-                if capture:
-                    if capture_hops:
-                        with jax.named_scope("match/frame_stack"):
-                            arr_keys = ("src", "dst", "rank", "eidx")
-                            # (P, steps, nb, EB); kcount (P, steps, nb)
-                            cap_out = {k: jnp.stack([hc[k] for hc in hop_caps],
-                                                    axis=1)
-                                       for k in arr_keys}
-                            kcount_out = jnp.stack(
-                                [hc["kcount"] for hc in hop_caps], axis=1)
-                    else:
-                        cap_out = {k: v for k, v in hop_caps[-1].items()
-                                   if k != "kcount"}
-                        kcount_out = hop_caps[-1]["kcount"]
-                fbm = jnp.zeros((P, vmax), bool)
-            else:
-                # marks[s, d] = part s's candidate bitmap for part d;
-                # OR over sources = the exchange + merge in one reduce
-                fbm = marks.any(axis=0)
-
-        res = {
-            "frontier": fbm,
-            "fcount": jnp.sum(fbm, axis=1, dtype=jnp.int32),
-            "hop_edges": jnp.stack(hop_edges, axis=1),      # (P, steps)
-            "frontier_sizes": jnp.stack(frontier_sizes, axis=1),
-            "ovf_expand": ovf_e,
-        }
-        if capture:
-            res["cap"] = cap_out
-            res["kcount"] = kcount_out   # small: fetched with the meta
-        return res
+        return _traverse(
+            jax.vmap, 1, blocks_data, frontier, pids,
+            lambda f: _extend_fbm_local(f, hub_owner, hub_local, P),
+            lambda marks: marks.any(axis=0),
+            P=P, ebs=ebs, pred=pred, pred_cols=pred_cols, capture=capture,
+            capture_hops=capture_hops, yield_cols=yield_cols,
+            hubs_c=hubs_c, chunk=chunk)
 
     return fn
 
@@ -639,7 +756,7 @@ def build_traverse_fn_local(P: int, EB, steps: int,
                             capture: bool = True,
                             capture_hops: bool = False,
                             yield_cols: Sequence[str] = (),
-                            hub_dense=None):
+                            hub_dense=None, chunk: int = CHUNK):
     """Single-chip variant: all P partitions resident on one device, the
     per-part kernel vmapped over the part axis, and the frontier exchange
     an OR-reduce over the mark matrices (the degenerate all_to_all).
@@ -651,7 +768,7 @@ def build_traverse_fn_local(P: int, EB, steps: int,
     return jax.jit(_build_local_fn(
         P, EB, steps, n_blocks, pred=pred, pred_cols=pred_cols,
         capture=capture, capture_hops=capture_hops,
-        yield_cols=yield_cols, hub_dense=hub_dense))
+        yield_cols=yield_cols, hub_dense=hub_dense, chunk=chunk))
 
 
 def build_traverse_fn_lanes(P: int, EB, steps: int,
@@ -661,7 +778,7 @@ def build_traverse_fn_lanes(P: int, EB, steps: int,
                             capture: bool = True,
                             capture_hops: bool = False,
                             yield_cols: Sequence[str] = (),
-                            hub_dense=None):
+                            hub_dense=None, chunk: int = CHUNK):
     """Query-lane-batched single-chip program (ISSUE 15 tentpole).
 
     The same traversal program with a leading QUERY-ID LANE axis vmapped
@@ -684,7 +801,7 @@ def build_traverse_fn_lanes(P: int, EB, steps: int,
     fn = _build_local_fn(
         P, EB, steps, n_blocks, pred=pred, pred_cols=pred_cols,
         capture=capture, capture_hops=capture_hops,
-        yield_cols=yield_cols, hub_dense=hub_dense)
+        yield_cols=yield_cols, hub_dense=hub_dense, chunk=chunk)
     return jax.jit(jax.vmap(fn, in_axes=(None, 0)))
 
 
@@ -695,7 +812,7 @@ def build_traverse_fn_lanes_sharded(mesh, P: int, EB, steps: int,
                                     capture: bool = True,
                                     capture_hops: bool = False,
                                     yield_cols: Sequence[str] = (),
-                                    hub_dense=None):
+                                    hub_dense=None, chunk: int = CHUNK):
     """The lanes × shards launch grid: ONE shard_map program over the
     2-axis ("lane", "part") mesh that fuses PR 12's query-id lane axis
     with the partition axis.
@@ -721,130 +838,28 @@ def build_traverse_fn_lanes_sharded(mesh, P: int, EB, steps: int,
     axis unsplit.
     """
     ebs = _norm_ebs(EB, steps, capture_hops)
-    # a MATCH program captures every hop as a frame; a GO its last hop
-    cap_scope = "match/frame_capture" if capture_hops else "hop/capture"
     hubs_c, hub_owner, hub_local = _hub_consts(hub_dense, P)
 
+    def over_lanes(f):
+        # the CSR block and the part id are this shard's; everything
+        # else carries the lane axis (delta-row activity, too, depends
+        # on THIS lane's frontier bitmap)
+        return lambda blk, pd, *xs: jax.vmap(
+            lambda *ys: f(blk, pd, *ys))(*xs)
+
     def kernel(blocks_data, frontier):
-        fbm = frontier[:, 0]                   # (Ll, vmax) bool
-        Ll = fbm.shape[0]
-        vmax = fbm.shape[1]
         pid = jax.lax.axis_index("part").astype(jnp.int32)
-        hop_edges: List[Any] = []
-        frontier_sizes: List[Any] = []
-        ovf_e = jnp.zeros((Ll,), bool)
-        cap_out = None
-        hop_caps: List[Dict[str, Any]] = []
-
-        for hop in range(steps):
-            frontier_sizes.append(jnp.sum(fbm, axis=1, dtype=jnp.int32))
-            last = hop == steps - 1
-            EBh = ebs[hop]
-            marks = None                       # (Ll, P, vmax) bool
-            edges_this_hop = jnp.zeros((Ll,), jnp.int32)
-            caps = {"src": [], "dst": [], "rank": [], "eidx": [],
-                    "kcount": []}
-            efbm = fbm if hubs_c is None else _extend_fbm_sharded_lanes(
-                fbm, pid, hub_owner, hub_local)
-            for bi in range(n_blocks):
-                b = blocks_data[bi]
-                dcap = _delta_cap(b)
-                dl = ({k: b[k][0] for k in
-                       ("d_src", "d_dst", "d_rank", "d_valid", "d_tomb")}
-                      if dcap else None)
-                emax = b["nbr"].shape[-1]
-
-                def lane_expand(f):
-                    out = _expand_block(
-                        b["indptr"][0], b["nbr"][0], b["rank"][0], f, EBh,
-                        P, pid, vmax_local=vmax, hub_dense=hubs_c)
-                    s, d, r, e, v, t, o = out
-                    if dl is not None:
-                        # per-lane merge: delta-row activity depends on
-                        # THIS lane's frontier bitmap
-                        s, d, r, e, v, t = _merge_delta(
-                            dl, f, s, d, r, e, v, t, P, pid, emax)
-                    return s, d, r, e, v, t, o
-
-                src, dst, rk, eidx, ve, total, ovf = jax.vmap(
-                    lane_expand)(efbm)
-                ovf_e = ovf_e | ovf
-                edges_this_hop = edges_this_hop + total
-
-                def _col(name):
-                    c = b["props"][name][0]
-                    if dcap:
-                        c = jnp.concatenate([c, b["d_props"][name][0]])
-                    return c
-
-                if pred is not None and (last or capture_hops):
-                    cols = {"_rank": rk, "_src": src, "_dst": dst}
-                    for name in pred_cols:
-                        if not name.startswith("_"):
-                            with jax.named_scope("hop/pred_gather"):
-                                cols[name] = _col(name)[eidx]
-                    with jax.named_scope("hop/predicate"):
-                        keep = pred(cols) & ve
-                else:
-                    keep = ve
-                if capture and (last or capture_hops):
-                    with jax.named_scope(cap_scope):
-                        cs, cd, cr, ce, kc = jax.vmap(
-                            lambda s, d, r, e, k: _compact_cap(
-                                s, d, r, e, k,
-                                EBh + dcap))(src, dst, rk, eidx, keep)
-                    caps["src"].append(cs)
-                    caps["dst"].append(cd)
-                    caps["rank"].append(cr)
-                    caps["eidx"].append(ce)
-                    caps["kcount"].append(kc)
-                    if last and not capture_hops:
-                        for name in yield_cols:
-                            with jax.named_scope("hop/prop_" + name):
-                                caps.setdefault("prop:" + name, []).append(
-                                    _col(name)[ce])
-                if not last:
-                    marks_b = jax.vmap(
-                        lambda d, k: _mark(d, k, P, vmax))(dst, keep)
-                    marks = marks_b if marks is None else marks | marks_b
-            hop_edges.append(edges_this_hop)
-            if capture and (last or capture_hops):
-                # arrays (Ll, nb, EB); kcount (Ll, nb)
-                hop_caps.append({k: jnp.stack(v, axis=1)
-                                 for k, v in caps.items()})
-
-            if last:
-                if capture:
-                    if capture_hops:
-                        with jax.named_scope("match/frame_stack"):
-                            arr_keys = ("src", "dst", "rank", "eidx")
-                            # local (Ll, 1, steps, nb, EB)
-                            cap_out = {k: jnp.stack(
-                                [hc[k] for hc in hop_caps], axis=1)[:, None]
-                                for k in arr_keys}
-                            kcount_out = jnp.stack(
-                                [hc["kcount"] for hc in hop_caps],
-                                axis=1)[:, None]
-                    else:
-                        cap_out = {k: v[:, None]
-                                   for k, v in hop_caps[-1].items()
-                                   if k != "kcount"}
-                        kcount_out = hop_caps[-1]["kcount"][:, None]
-                fbm = jnp.zeros((Ll, vmax), bool)
-            else:
-                fbm = _exchange_marks_lanes(marks, P, vmax)
-
-        res = {
-            "frontier": fbm[:, None],                       # (Ll, 1, vmax)
-            "fcount": jnp.sum(fbm, axis=1, dtype=jnp.int32)[:, None],
-            "hop_edges": jnp.stack(hop_edges, axis=1)[:, None],
-            "frontier_sizes": jnp.stack(frontier_sizes, axis=1)[:, None],
-            "ovf_expand": ovf_e[:, None],
-        }
-        if capture:
-            res["cap"] = cap_out
-            res["kcount"] = kcount_out
-        return res
+        vmax = frontier.shape[-1]
+        res = _traverse(
+            over_lanes, 1, _part_view(blocks_data), frontier[:, 0], pid,
+            lambda f: _extend_fbm_sharded_lanes(
+                f, pid, hub_owner, hub_local),
+            lambda marks: _exchange_marks_lanes(marks, P, vmax),
+            P=P, ebs=ebs, pred=pred, pred_cols=pred_cols, capture=capture,
+            capture_hops=capture_hops, yield_cols=yield_cols,
+            hubs_c=hubs_c, chunk=chunk)
+        # local (Ll, 1, ...): the shard axis follows the lane axis
+        return jax.tree.map(lambda x: x[:, None], res)
 
     from jax.sharding import PartitionSpec
     csr_spec = PartitionSpec("part")

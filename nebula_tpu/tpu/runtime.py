@@ -216,7 +216,8 @@ class TraverseStats:
                  "e_cap", "retries", "device_s", "steps",
                  "pin_s", "put_s", "fetch_s", "mat_s", "total_s",
                  "compiles", "hbm_bytes", "segments", "queue_s",
-                 "shards", "exchange_bytes")
+                 "shards", "exchange_bytes", "chunks_run",
+                 "chunks_budget")
 
     def __init__(self):
         self.hop_edges: List[int] = []
@@ -248,6 +249,12 @@ class TraverseStats:
         # single-chip local mode — there is no exchange)
         self.shards = 1
         self.exchange_bytes = 0
+        # by-need engagement (PR 25, hop.py _by_need): loop trips the
+        # hops' per-slot stages ran and the trips their edge budgets
+        # hold, summed over hops and parts; both 0 when every hop's
+        # budget fits one chunk (straight-line program)
+        self.chunks_run = 0
+        self.chunks_budget = 0
 
     def edges_traversed(self) -> int:
         return int(sum(self.hop_edges))
@@ -1313,6 +1320,10 @@ class TpuRuntime:
                 _metrics().inc("tpu_kernel_runs")
                 _metrics().inc("tpu_edges_traversed",
                                int(np.asarray(res["hop_edges"]).sum()))
+                _metrics().inc("tpu_hop_chunks_run",
+                               int(res["chunks_run"].sum()))
+                _metrics().inc("tpu_hop_chunks_budget",
+                               int(res["chunks_budget"].sum()))
                 _metrics().add_value("tpu_kernel_s", info["device_s"])
                 _metrics().add_value("tpu_put_s", info["put_s"])
                 _metrics().add_value("tpu_fetch_s", info["fetch_s"])
@@ -1382,6 +1393,8 @@ class TpuRuntime:
             stats.frontier_sizes = [
                 int(x) for x in
                 np.asarray(res["frontier_sizes"])[lane].sum(axis=0)]
+        stats.chunks_run = int(res["chunks_run"][lane].sum())
+        stats.chunks_budget = int(res["chunks_budget"][lane].sum())
         stats.retries = info["retries"]
         stats.compiles = info["compiles"]
         stats.device_s = info["device_s"]
@@ -1642,6 +1655,9 @@ class TpuRuntime:
                     stats.frontier_sizes = [
                         int(x) for x in
                         np.asarray(res["frontier_sizes"]).sum(axis=0)]
+                if "chunks_run" in res:
+                    stats.chunks_run = int(res["chunks_run"].sum())
+                    stats.chunks_budget = int(res["chunks_budget"].sum())
                 if cap_dev is not None:
                     tf = time.perf_counter()
                     kc = np.asarray(res["kcount"])
@@ -1672,6 +1688,9 @@ class TpuRuntime:
                 _metrics().inc("tpu_kernel_runs")
                 _metrics().inc("tpu_edges_traversed",
                                stats.edges_traversed())
+                _metrics().inc("tpu_hop_chunks_run", stats.chunks_run)
+                _metrics().inc("tpu_hop_chunks_budget",
+                               stats.chunks_budget)
                 _metrics().add_value("tpu_kernel_s", stats.device_s)
                 _metrics().add_value("tpu_put_s", stats.put_s)
                 _metrics().add_value("tpu_fetch_s", stats.fetch_s)

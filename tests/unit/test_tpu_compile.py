@@ -64,7 +64,8 @@ def _block(P, vmax, E, sharding, props=("w",), rev=False):
     b = {"indptr": _struct((P, vmax + 1), np.int32, sharding),
          "nbr": _struct((P, E), np.int32, sharding),
          "rank": _struct((P, E), np.int32, sharding),
-         "props": {n: _struct((P, E), np.int64, sharding) for n in props}}
+         "props": {n: _struct((P, E), np.float64 if n == "f" else np.int64,
+                              sharding) for n in props}}
     if rev:
         b.update(rev_indptr=b["indptr"], rev_nbr=b["nbr"],
                  rev_rank=b["rank"], rev_props={})
@@ -90,6 +91,26 @@ def test_go_three_steps_capture_compiles(one_chip):
                                  yield_cols=("w",))
     _compile(fn, (_block(P8, VMAX8, E8, one_chip),),
              _struct((P8, VMAX8), np.bool_, one_chip))
+
+
+def test_proxy_cell_go3_by_need_loops_compile(one_chip):
+    """`snb-sf100-proxy.go3`'s program (benchmarks/configs): budgets
+    (2048, 2^20, 2^22) a part, YIELD dst, w, f — the last two hops run
+    their gathers in by-need loops (hop.py `_by_need`; the int64 `w` and
+    the float64 `f` ride the loop carry as 32-bit pairs on a TPU).  A
+    loop the TPU compiler rejects, or takes minutes over, fails here
+    and not first on the chip."""
+    from nebula_tpu.tpu.hop import CHUNK, build_traverse_fn_local
+    ebs = (1 << 11, 1 << 20, 1 << 22)
+    assert ebs[0] <= CHUNK < ebs[1], "the cell no longer exercises the loop"
+    fn = build_traverse_fn_local(P8, ebs, 3, n_blocks=1, capture=True,
+                                 yield_cols=("f", "w"))
+    compiled, secs = _compile(
+        fn, (_block(P8, VMAX8, E8, one_chip, props=("f", "w")),),
+        _struct((P8, VMAX8), np.bool_, one_chip))
+    assert secs < 120, f"the proxy cell's program took {secs:.0f}s to compile"
+    # the second hop's expansion, the last hop's, its property gathers
+    assert compiled.as_text().count(" while(") >= 3
 
 
 def test_match_var_len_capture_hops_compiles(one_chip):
