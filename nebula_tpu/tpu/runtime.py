@@ -1104,12 +1104,23 @@ class TpuRuntime:
         concurrent collective programs on overlapping devices
         interleave their all_to_all rendezvous and deadlock.  A no-op
         in local mode — the vmapped single-chip programs have no
-        collectives and dispatch concurrently as before."""
+        collectives and dispatch concurrently as before.  What a launch
+        waited for the mutex is one observation of
+        `tpu_collective_wait_s` (emitted after the release: nothing is
+        added to the extent the mutex covers) and, inside a statement's
+        trace, a `device:launch_wait` span."""
         if self.local_mode:
             yield
             return
-        with self._launch_mutex:
+        t0 = time.perf_counter()
+        with _t.span("device:launch_wait"):
+            self._launch_mutex.acquire()
+        wait_s = time.perf_counter() - t0
+        try:
             yield
+        finally:
+            self._launch_mutex.release()
+            _metrics().add_value("tpu_collective_wait_s", wait_s)
 
     def algo_dispatch(self, kernel: str, fn, *args):
         """One gated single-shot device dispatch for the algo plane

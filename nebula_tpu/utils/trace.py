@@ -439,7 +439,8 @@ _PHASE_BY_PREFIX = (
     ("graphd:parse", "parse"), ("graphd:plan", "plan"),
     ("graphd:admit", "admit"), ("graphd:encode", "encode"),
     ("tpu:snapshot_check", "snapshot_check"),
-    ("device:queue", "queue"), ("device:put", "put"),
+    ("device:queue", "queue"), ("device:launch_wait", "queue"),
+    ("device:put", "put"),
     ("device:dispatch", "dispatch"), ("device:fetch", "fetch"),
     ("device:materialise", "materialise"),
     ("rpc:retry", None), ("rpc:breaker", None),

@@ -137,6 +137,34 @@ def test_sharded_go_compiles_with_all_to_all(topo):
     assert "all-to-all" in compiled.as_text()
 
 
+def test_mesh_cell_go3_compiles_for_the_four_chip_host(topo):
+    """`snb-sf300-proxy.go3-4chip`'s program (benchmarks/configs): 6 M
+    persons over 4 parts of 50,331,648 padded slots, budgets (2048, 2^16,
+    2^21) a part, YIELD dst, w, f, one partition per chip of the 2x2
+    host.  Each chip is handed a quarter of the blocks the statement
+    reads (1.2 GB of the 3.2 GB it holds) and two frontier exchanges
+    stay in the program: a traverse skips the last hop's."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from nebula_tpu.tpu.hop import a2a_payload_bytes, build_traverse_fn
+    vmax, width = 1_500_000, 50_331_648
+    mesh = Mesh(np.asarray(topo.devices[:P4]), ("part",))
+    part = NamedSharding(mesh, PartitionSpec("part"))
+    fn = build_traverse_fn(mesh, P4, (1 << 11, 1 << 16, 1 << 21), 3,
+                           n_blocks=1, capture=True, yield_cols=("f", "w"))
+    compiled, secs = _compile(
+        fn, (_block(P4, vmax, width, part, props=("f", "w")),),
+        _struct((P4, vmax), np.bool_, part))
+    assert secs < 120, f"the mesh cell's program took {secs:.0f}s to compile"
+    text = compiled.as_text()
+    assert text.count(" all-to-all(") == 2 and " all-reduce(" not in text
+    # each chip sends a row of ceil(vmax / 32) words to each of the four
+    assert f"u32[{P4},1,{-(-vmax // 32)}]" in text
+    assert a2a_payload_bytes(P4, vmax) == P4 * P4 * -(-vmax // 32) * 4
+    args = compiled.memory_analysis().argument_size_in_bytes
+    assert 1.1e9 < args < 1.3e9, args
+
+
 def test_bfs_compiles(one_chip):
     """FIND SHORTEST PATH's direction-optimizing single-chip BFS."""
     from nebula_tpu.tpu.bfs import build_bfs_fn_local
