@@ -34,6 +34,13 @@ from .context import ExecutionContext, QueryContext
 from .executors import run_node
 
 
+def _of_budget(run: int, budget: int) -> dict:
+    """A device-plane engagement counter pair as PROFILE shows it
+    (share None where the budget did not move: nothing engaged)."""
+    return {"run": run, "budget": budget,
+            "share": round(run / budget, 4) if budget else None}
+
+
 class ProfileStats:
     """Per-plan-node execution stats.  Safe under the parallel schedule:
     each node runs exactly once, so concurrent record() calls write
@@ -211,12 +218,13 @@ class Scheduler:
                         # share of the edge budgets' chunks the hops'
                         # by-need loops ran (None: no loop, the budgets
                         # fit one chunk)
-                        "chunks": {
-                            "run": ts.chunks_run,
-                            "budget": ts.chunks_budget,
-                            "share": round(ts.chunks_run
-                                           / ts.chunks_budget, 4)
-                            if ts.chunks_budget else None},
+                        "chunks": _of_budget(ts.chunks_run,
+                                             ts.chunks_budget),
+                        # scatter updates the hops' expansion plans
+                        # issued, of what plans over every local vertex
+                        # issue (None: the bitmaps are narrow, those
+                        # plans were compiled)
+                        "plan": _of_budget(ts.plan_run, ts.plan_budget),
                         "buckets": {"EB": ts.e_cap},
                         "retries": ts.retries,
                         "compiles": getattr(ts, "compiles", 0),

@@ -40,9 +40,15 @@ Work by need (PR 25): EB bounds a hop's SHAPES, not its work.  A gather
 or scatter on the chip costs what its slots cost (20 to 27 ns apiece,
 PERF.md section 5), so every per-slot stage of a hop runs over
 ceil(need / CHUNK) chunks inside one device loop whose trip count is
-the expansion's own size (`_by_need`); only the vmax-sized passes and
-the streaming cumsums run whole.  A hop whose budget fits one chunk
-compiles to the straight-line program.
+the expansion's own size (`_by_need`).  A hop whose budget fits one chunk
+compiles to the straight-line program.  Since PR 29 the expansion PLAN
+follows need too (`_expand_plan`): over a bitmap wider than PLAN_CHUNK
+its scatters are sized by the words of the bitmap that hold an
+expanding vertex (one update a word to list them, PLAN_BLOCK updates a
+listed word, by need), not by the part's local vertices; what still
+runs over the whole bitmap or the whole budget is streaming (`where`,
+cumsum, running maximum, reduce, pad, reshape, the bit pack and
+unpack).
 
 Frontier representation between hops: (P, vmax) bool, row p = the
 membership bitmap of part p's local ids (dense id = local * P + p).
@@ -139,11 +145,37 @@ def _by_need(body, outs, n, width: int, tail: int = 0, chunk: int = CHUNK):
     return outs, trips, width // chunk
 
 
-def _expand_plan(indptr, fbm, EB: int):
-    """The cheap half of one block's CSR expansion from one part's
-    frontier bitmap: everything that is vmax-sized or a streaming pass
-    over the EB slots.  Returns (total, ovf, plan) — the true expansion
-    size, the overflow flag, and the tables `_expand_slots` reads.
+# Lane updates one trip of the member plan's loop issues for one part
+# (_plan_members), and the bitmap width at or under which a hop compiles
+# the whole-bitmap plan instead (_expand_plan): a bitmap no wider than
+# one trip has nothing for the loop to skip.  Settled on the chip
+# (PERF.md, PR 29): at 1,500,000 local vertices and budgets up to 2^18 a
+# 3-hop program is flat from 2^10 to 2^14 (31.80 to 31.93 ms); with every
+# word of 8 x 125,000 vertices listed and a 2^22 budget the plan alone
+# takes 12.0 ms at 2^14 and 20.7 at 2^12 against the whole-bitmap plan's
+# 18.4 (XLA sorts a trip's indices before a scatter into an operand that
+# large, about 0.4 ms a trip), so the largest trip that was flat.
+PLAN_CHUNK = 1 << 14
+# Local ids that share one entry of the member plan's word list: a word
+# that holds a member costs PLAN_BLOCK lane updates, a list entry one.
+# 31.8 ms at 32, 33.6 at 16, 31.4 at 64 (the same program; PR 29).
+PLAN_BLOCK = 32
+
+
+def _frontier_starts(indptr, fbm):
+    """(deg, starts, total) of a frontier bitmap over its CSR rows, along
+    the last axis: each vertex's expansion size (0 off the frontier), its
+    first slot, and the expansion's size."""
+    deg = jnp.where(fbm, indptr[..., 1:] - indptr[..., :-1],
+                    0).astype(jnp.int32)
+    ends = jnp.cumsum(deg, axis=-1)
+    return deg, ends - deg, ends[..., -1]
+
+
+def _plan_whole(indptr, fbm, EB: int):
+    """One part's expansion plan by two scatters over EVERY local
+    vertex, whatever the frontier holds: the plan of a bitmap no wider
+    than PLAN_CHUNK (`_expand_plan`).
 
     Slot→source-row assignment is a cumsum-scatter, not a binary
     search: bump +1 at each frontier vertex's first slot, prefix-sum
@@ -154,10 +186,7 @@ def _expand_plan(indptr, fbm, EB: int):
     """
     vmax = fbm.shape[0]
     with jax.named_scope("hop/expand"):
-        deg = jnp.where(fbm, indptr[1:] - indptr[:-1], 0).astype(jnp.int32)
-        ends = jnp.cumsum(deg)
-        total = ends[-1]
-        starts = ends - deg                       # (vmax,)
+        deg, starts, total = _frontier_starts(indptr, fbm)
         has = deg > 0
         # compact index of each expanding vertex, and its inverse table
         cidx = jnp.cumsum(has.astype(jnp.int32)) - 1
@@ -172,6 +201,109 @@ def _expand_plan(indptr, fbm, EB: int):
     return total, total > EB, (vid_of, starts, crow)
 
 
+@_stage("hop/expand")
+def _plan_members(indptr, fbm, EB: int, chunk: int):
+    """The expansion plan laid out from the frontier's expanding
+    MEMBERS: no gather or scatter here has one index per local vertex.
+
+    A member is a frontier vertex with an edge whose first slot lies
+    below EB (a later one owns no slot of this hop; `total > EB` is the
+    overflow the ladder retries).  Local ids are taken PLAN_BLOCK to a
+    word.  Two levels: one scatter with an update per WORD lists the
+    words that hold a member, ascending; a by-need loop over that list
+    (`_by_need`, `chunk` lane updates a trip and part) fetches each
+    listed word's lanes as one row and writes every member's local id
+    + 1 at its first slot.  A running maximum over the EB slots then IS
+    the slot→source-row table (members ascend with their first slots),
+    so there is neither a compact-row table nor a per-slot gather
+    through one.
+
+    Arrays carry the builder's leading axes (`indptr` may lack them: a
+    shard's CSR under its lanes); the scatters run on flat operands,
+    every row at its own offset, as `_compact_cap`'s do.  Whole-width
+    passes that stay are streaming: the `where`s, the cumsums, a
+    reduce, the pad and reshape.
+
+    Returns (total, ovf, plan, updates issued, updates the whole-bitmap
+    plan issues) — the counts per part.
+    """
+    B = PLAN_BLOCK
+    vmax = fbm.shape[-1]
+    lead = fbm.shape[:-1]
+    rows = int(np.prod(lead, dtype=np.int64))
+    W = -(-vmax // B)                         # words a part
+    CW = max(chunk // B, 1)                   # list entries a trip
+    LW = -(-min(W, EB) // CW) * CW            # the list, in whole trips
+    deg, starts, total = _frontier_starts(indptr, fbm)
+    # a member's first slot; EB (dropped by every scatter) elsewhere
+    at = jnp.where((deg > 0) & (starts < EB), starts, EB)
+    at = jnp.pad(at, [(0, 0)] * len(lead) + [(0, W * B - vmax)],
+                 constant_values=EB).reshape(lead + (W, B))
+    full = jnp.any(at < EB, axis=-1)          # words holding a member
+    widx = jnp.cumsum(full, axis=-1, dtype=jnp.int32) - 1
+    nwords = widx[..., -1] + 1                # lead
+    row0 = jnp.arange(rows, dtype=jnp.int32).reshape(lead + (1,))
+    words = jnp.zeros((rows * LW,), jnp.int32).at[
+        jnp.where(full, widx + row0 * LW, rows * LW).reshape(-1)].set(
+        jnp.broadcast_to(jnp.arange(W, dtype=jnp.int32),
+                         full.shape).reshape(-1),
+        mode="drop").reshape(lead + (LW,))
+    lanes = at.reshape(rows * W, B)
+    lane = jnp.arange(B, dtype=jnp.int32)
+
+    def mark_firsts(outs, lo, size):
+        w = _window(words, lo, size)          # lead + (size,)
+        live = lo + jnp.arange(size, dtype=jnp.int32) < nwords[..., None]
+        slot = lanes[(w + row0 * W).reshape(-1)].reshape(
+            lead + (size, B))
+        slot = jnp.where(live[..., None] & (slot < EB),
+                         slot + row0[..., None] * EB, rows * EB)
+        vid1 = w[..., None] * B + lane + 1
+        return (outs[0].at[slot.reshape(-1)].set(vid1.reshape(-1),
+                                                 mode="drop"),)
+
+    (first,), trips, looped = _by_need(
+        mark_firsts, (jnp.zeros((rows * EB,), jnp.int32),),
+        jnp.max(nwords), LW, chunk=CW)
+    row = jnp.maximum(jax.lax.cummax(first.reshape(lead + (EB,)),
+                                     axis=len(lead)) - 1, 0)
+    run = W + (trips if looped else 1) * CW * B
+    return total, total > EB, (None, starts, row), run, 2 * vmax
+
+
+def _expand_plan(over, blk, pid, fbm, EB: int, chunk: int):
+    """Lay one block's CSR expansion out from the frontier bitmap(s):
+    everything that is a streaming pass over the bitmap or the EB
+    slots, and the scatters that turn members into slot→source-row
+    tables.  `over`, `blk` and `pid` are `_traverse`'s.
+
+    The plan's cost follows the frontier, not the part: on the chip a
+    scatter costs 6 to 7 ns an update, dropped or not, and the
+    whole-bitmap plan's two scatters a hop were 56.5 of a 73.3 ms
+    program at 1,500,000 local vertices a part whatever the frontier
+    held (PERF.md section 6, PR 28).  The choice is the STATIC bitmap
+    width: at or under `chunk` the whole-bitmap plan (`_plan_whole`,
+    the program PR 28 ran), over it the member plan (`_plan_members`),
+    whose trip count is the traced number of words that hold a member.
+    A frontier that fills every word runs every trip and issues HALF
+    the whole-bitmap plan's updates (one scatter, not two) plus one
+    per word, so there is no break-even to fall back at, hence no
+    second threshold.  Chip readings of the plan alone (PERF.md
+    section 6, PR 29; whole-bitmap → member): one part of 1,500,000
+    vertices, EB 2^18, 20.28 ms → 1.05 (empty frontier) to 2.32 (1% of
+    the vertices); 8 parts of 125,000, EB 2^22, 16.4 → 2.9 (empty),
+    18.4 → 12.0 (every vertex).
+
+    Returns (total, ovf, plan, run, budget) — the true expansion size,
+    the overflow flag, the tables `_expand_slots` reads, and the
+    scatter updates issued and what the whole-bitmap plan issues, per
+    part (0, 0 where that plan was compiled)."""
+    if fbm.shape[-1] <= chunk:
+        return over(lambda b, _p, f: _plan_whole(b["indptr"], f, EB))(
+            blk, pid, fbm) + (0, 0)
+    return _plan_members(blk["indptr"], fbm, EB, chunk)
+
+
 def _expand_slots(indptr, nbr, rank, plan, total, lo, size: int, EB: int,
                   P: int, pid, vmax_local: int = 0, hub_dense=None):
     """The per-slot half: slots [lo, lo + size) of the expansion
@@ -181,7 +313,9 @@ def _expand_slots(indptr, nbr, rank, plan, total, lo, size: int, EB: int,
       valid)."""
     vid_of, starts, crow = plan
     with jax.named_scope("hop/expand"):
-        row = vid_of[jnp.maximum(_window(crow, lo, size), 0)]
+        row = jnp.maximum(_window(crow, lo, size), 0)
+        if vid_of is not None:      # the whole-bitmap plan's compact rows
+            row = vid_of[row]
         j = lo + jnp.arange(size, dtype=jnp.int32)
         eidx = indptr[row] + (j - starts[row])
         ve = j < jnp.minimum(total, EB)
@@ -220,7 +354,8 @@ def _expand_block(indptr, nbr, rank, fbm, EB: int, P: int, pid,
     Returns the per-edge-slot arrays of `_expand_slots` at length EB,
     plus (total, ovf): true expansion size and overflow flag.
     """
-    total, ovf, plan = _expand_plan(indptr, fbm, EB)
+    total, ovf, plan, _, _ = _expand_plan(
+        lambda f: f, {"indptr": indptr}, pid, fbm, EB, PLAN_CHUNK)
     return _expand_slots(indptr, nbr, rank, plan, total, 0, EB, EB, P,
                          pid, vmax_local, hub_dense) + (total, ovf)
 
@@ -466,7 +601,8 @@ _CAP_KEYS = ("src", "dst", "rank", "eidx", "kcount")
 
 def _traverse(over, nlead: int, blocks, fbm, pid, extend, exchange, *,
               P: int, ebs, pred, pred_cols, capture: bool,
-              capture_hops: bool, yield_cols, hubs_c, chunk: int):
+              capture_hops: bool, yield_cols, hubs_c, chunk: int,
+              plan_chunk: int):
     """The N-hop program, written once for every builder.
 
     Every array carries the builder's `nlead` leading axes (none inside
@@ -477,7 +613,8 @@ def _traverse(over, nlead: int, blocks, fbm, pid, extend, exchange, *,
     hop's mark matrices into the next frontier.  `blocks` holds each
     block's leaves as `over` expects them.
 
-    Per hop and block: lay the expansion out (`_expand_plan`, cheap),
+    Per hop and block: lay the expansion out (`_expand_plan`, from the
+    frontier's members where the bitmap is wider than `plan_chunk`),
     then run the per-slot stages by need (`_by_need`) — the expansion's
     gathers with the delta plane's tombstone test and the predicate's
     column gathers in one loop, the capture's compaction scatters in a
@@ -493,6 +630,7 @@ def _traverse(over, nlead: int, blocks, fbm, pid, extend, exchange, *,
         if pred is not None else []
     hop_edges, frontier_sizes = [], []     # popcount entering each hop
     chunks_run, chunks_budget = [], []
+    plan_run, plan_budget = [], []
     ovf_e = None
     hop_caps = []
 
@@ -500,7 +638,7 @@ def _traverse(over, nlead: int, blocks, fbm, pid, extend, exchange, *,
         frontier_sizes.append(jnp.sum(fbm, axis=-1, dtype=jnp.int32))
         last = hop == steps - 1
         marks = None
-        edges = run = budget = 0
+        edges = run = budget = prun = pbudget = 0
         caps = {k: [] for k in _CAP_KEYS}
         efbm = fbm if hubs_c is None else extend(fbm)
         want_pred = pred is not None and (last or capture_hops)
@@ -509,9 +647,9 @@ def _traverse(over, nlead: int, blocks, fbm, pid, extend, exchange, *,
         for b in blocks:
             dcap = _delta_cap(b)
             emax = b["nbr"].shape[-1]
-            total, ovf, plan = over(
-                lambda blk, pd, f: _expand_plan(blk["indptr"], f, EB))(
-                b, pid, efbm)
+            total, ovf, plan, r, bd = _expand_plan(
+                over, b, pid, efbm, EB, plan_chunk)
+            prun, pbudget = prun + r, pbudget + bd
             # the live slots of the fullest part (or lane): the trip
             # count of every by-need loop of this block
             n = jnp.minimum(jnp.max(total), EB)
@@ -610,6 +748,8 @@ def _traverse(over, nlead: int, blocks, fbm, pid, extend, exchange, *,
         zero = jnp.zeros_like(edges)
         chunks_run.append(zero + run)
         chunks_budget.append(zero + budget)
+        plan_run.append(zero + prun)
+        plan_budget.append(zero + pbudget)
         if want_cap:
             # arrays lead + (nb, EB); kcount lead + (nb,)
             hop_caps.append({k: jnp.stack(v, axis=nlead)
@@ -630,6 +770,10 @@ def _traverse(over, nlead: int, blocks, fbm, pid, extend, exchange, *,
         # chunk being CHUNK slots of one part (0 where no loop ran)
         "chunks_run": jnp.stack(chunks_run, axis=nlead),
         "chunks_budget": jnp.stack(chunks_budget, axis=nlead),
+        # member-plan engagement: scatter updates the hops' plans issued
+        # and what whole-bitmap plans issue (0 where those were compiled)
+        "plan_run": jnp.stack(plan_run, axis=nlead),
+        "plan_budget": jnp.stack(plan_budget, axis=nlead),
     }
     if capture:
         if capture_hops:
@@ -657,7 +801,8 @@ def build_traverse_fn(mesh, P: int, EB, steps: int,
                       capture: bool = True,
                       capture_hops: bool = False,
                       yield_cols: Sequence[str] = (),
-                      hub_dense=None, chunk: int = CHUNK):
+                      hub_dense=None, chunk: int = CHUNK,
+                      plan_chunk: int = PLAN_CHUNK):
     """Compile the N-step traversal program for one bucket configuration.
     EB: per-block edge budget — an int (uniform) or a per-hop sequence.
 
@@ -668,8 +813,9 @@ def build_traverse_fn(mesh, P: int, EB, steps: int,
     host-side gather (GO capture mode only; x64 is enabled, so device
     gathers are bit-exact with the host decode).
 
-    chunk: the by-need loops' chunk (`_by_need`); the module constant
-    everywhere but in tests.
+    chunk: the by-need loops' chunk (`_by_need`); plan_chunk: the
+    member plan's trip and the bitmap width over which it is compiled
+    (`_expand_plan`); the module constants everywhere but in tests.
 
     blocks_data (runtime arg): tuple of n_blocks dicts with keys
       indptr (P, vmax+1), nbr (P, E), rank (P, E), props {name: (P, E)}
@@ -682,6 +828,9 @@ def build_traverse_fn(mesh, P: int, EB, steps: int,
       hop_edges (P, steps): pre-filter expansion size per hop per part
       chunks_run, chunks_budget (P, steps): by-need loop trips run and
         budgeted per hop (0 where the hop's budget fits one chunk)
+      plan_run, plan_budget (P, steps): scatter updates the hop's
+        expansion plans issued, and what whole-bitmap plans issue (0
+        where the bitmap is no wider than plan_chunk)
       ovf_expand (P,) bool: some hop's expansion exceeded EB
       cap (if capture): dict of (P, n_blocks, EB) arrays
         src, dst, rank, eidx, prop:<name> per yield_col — the final
@@ -708,7 +857,7 @@ def build_traverse_fn(mesh, P: int, EB, steps: int,
             lambda marks: _exchange_marks(marks, P, vmax),
             P=P, ebs=ebs, pred=pred, pred_cols=pred_cols, capture=capture,
             capture_hops=capture_hops, yield_cols=yield_cols,
-            hubs_c=hubs_c, chunk=chunk)
+            hubs_c=hubs_c, chunk=chunk, plan_chunk=plan_chunk)
         return jax.tree.map(lambda x: x[None], res)
 
     from jax.sharding import PartitionSpec
@@ -725,7 +874,8 @@ def _build_local_fn(P: int, EB, steps: int,
                     capture: bool = True,
                     capture_hops: bool = False,
                     yield_cols: Sequence[str] = (),
-                    hub_dense=None, chunk: int = CHUNK):
+                    hub_dense=None, chunk: int = CHUNK,
+                    plan_chunk: int = PLAN_CHUNK):
     """The UNJITTED single-chip traversal program — shared by
     build_traverse_fn_local (jit) and build_traverse_fn_lanes (jit of a
     vmap over a leading query-lane axis; ISSUE 15).  Every leaf of a
@@ -744,7 +894,7 @@ def _build_local_fn(P: int, EB, steps: int,
             lambda marks: marks.any(axis=0),
             P=P, ebs=ebs, pred=pred, pred_cols=pred_cols, capture=capture,
             capture_hops=capture_hops, yield_cols=yield_cols,
-            hubs_c=hubs_c, chunk=chunk)
+            hubs_c=hubs_c, chunk=chunk, plan_chunk=plan_chunk)
 
     return fn
 
@@ -756,7 +906,8 @@ def build_traverse_fn_local(P: int, EB, steps: int,
                             capture: bool = True,
                             capture_hops: bool = False,
                             yield_cols: Sequence[str] = (),
-                            hub_dense=None, chunk: int = CHUNK):
+                            hub_dense=None, chunk: int = CHUNK,
+                            plan_chunk: int = PLAN_CHUNK):
     """Single-chip variant: all P partitions resident on one device, the
     per-part kernel vmapped over the part axis, and the frontier exchange
     an OR-reduce over the mark matrices (the degenerate all_to_all).
@@ -768,7 +919,8 @@ def build_traverse_fn_local(P: int, EB, steps: int,
     return jax.jit(_build_local_fn(
         P, EB, steps, n_blocks, pred=pred, pred_cols=pred_cols,
         capture=capture, capture_hops=capture_hops,
-        yield_cols=yield_cols, hub_dense=hub_dense, chunk=chunk))
+        yield_cols=yield_cols, hub_dense=hub_dense, chunk=chunk,
+        plan_chunk=plan_chunk))
 
 
 def build_traverse_fn_lanes(P: int, EB, steps: int,
@@ -778,7 +930,8 @@ def build_traverse_fn_lanes(P: int, EB, steps: int,
                             capture: bool = True,
                             capture_hops: bool = False,
                             yield_cols: Sequence[str] = (),
-                            hub_dense=None, chunk: int = CHUNK):
+                            hub_dense=None, chunk: int = CHUNK,
+                            plan_chunk: int = PLAN_CHUNK):
     """Query-lane-batched single-chip program (ISSUE 15 tentpole).
 
     The same traversal program with a leading QUERY-ID LANE axis vmapped
@@ -801,7 +954,8 @@ def build_traverse_fn_lanes(P: int, EB, steps: int,
     fn = _build_local_fn(
         P, EB, steps, n_blocks, pred=pred, pred_cols=pred_cols,
         capture=capture, capture_hops=capture_hops,
-        yield_cols=yield_cols, hub_dense=hub_dense, chunk=chunk)
+        yield_cols=yield_cols, hub_dense=hub_dense, chunk=chunk,
+        plan_chunk=plan_chunk)
     return jax.jit(jax.vmap(fn, in_axes=(None, 0)))
 
 
@@ -812,7 +966,8 @@ def build_traverse_fn_lanes_sharded(mesh, P: int, EB, steps: int,
                                     capture: bool = True,
                                     capture_hops: bool = False,
                                     yield_cols: Sequence[str] = (),
-                                    hub_dense=None, chunk: int = CHUNK):
+                                    hub_dense=None, chunk: int = CHUNK,
+                                    plan_chunk: int = PLAN_CHUNK):
     """The lanes × shards launch grid: ONE shard_map program over the
     2-axis ("lane", "part") mesh that fuses PR 12's query-id lane axis
     with the partition axis.
@@ -857,7 +1012,7 @@ def build_traverse_fn_lanes_sharded(mesh, P: int, EB, steps: int,
             lambda marks: _exchange_marks_lanes(marks, P, vmax),
             P=P, ebs=ebs, pred=pred, pred_cols=pred_cols, capture=capture,
             capture_hops=capture_hops, yield_cols=yield_cols,
-            hubs_c=hubs_c, chunk=chunk)
+            hubs_c=hubs_c, chunk=chunk, plan_chunk=plan_chunk)
         # local (Ll, 1, ...): the shard axis follows the lane axis
         return jax.tree.map(lambda x: x[:, None], res)
 

@@ -1158,8 +1158,8 @@ class _Runner:
         s.f_cap = st.f_cap          # bucket shapes: report the last chain's
         s.e_cap = st.e_cap
         s.compiles += getattr(st, "compiles", 0)
-        s.chunks_run += st.chunks_run
-        s.chunks_budget += st.chunks_budget
+        for k in ("chunks_run", "chunks_budget", "plan_run", "plan_budget"):
+            setattr(s, k, getattr(s, k) + getattr(st, k))
         s.hbm_bytes = max(s.hbm_bytes, getattr(st, "hbm_bytes", 0))
         for ph in ("pin_s", "put_s", "fetch_s", "mat_s", "device_s",
                    "total_s", "queue_s"):

@@ -211,13 +211,19 @@ class _DispatchGate:
             return bool(self._writer or self._writers_waiting)
 
 
+# Result keys of a traverse program (hop.py `_traverse`) that say how far
+# its by-need loops and member plans engaged, lead + (steps,); summed
+# they are the TraverseStats fields of the same names.
+_ENGAGEMENT = ("chunks_run", "chunks_budget", "plan_run", "plan_budget")
+
+
 class TraverseStats:
     __slots__ = ("hop_edges", "frontier_sizes", "result_edges", "f_cap",
                  "e_cap", "retries", "device_s", "steps",
                  "pin_s", "put_s", "fetch_s", "mat_s", "total_s",
                  "compiles", "hbm_bytes", "segments", "queue_s",
                  "shards", "exchange_bytes", "chunks_run",
-                 "chunks_budget")
+                 "chunks_budget", "plan_run", "plan_budget")
 
     def __init__(self):
         self.hop_edges: List[int] = []
@@ -255,6 +261,13 @@ class TraverseStats:
         # budget fits one chunk (straight-line program)
         self.chunks_run = 0
         self.chunks_budget = 0
+        # member-plan engagement (PR 29, hop.py _expand_plan): scatter
+        # updates the hops' expansion plans issued and what plans over
+        # every local vertex issue, summed over hops, blocks and parts;
+        # both 0 when every bitmap is narrow enough for the whole-bitmap
+        # plan
+        self.plan_run = 0
+        self.plan_budget = 0
 
     def edges_traversed(self) -> int:
         return int(sum(self.hop_edges))
@@ -1335,6 +1348,10 @@ class TpuRuntime:
                                int(res["chunks_run"].sum()))
                 _metrics().inc("tpu_hop_chunks_budget",
                                int(res["chunks_budget"].sum()))
+                _metrics().inc("tpu_hop_plan_run",
+                               int(res["plan_run"].sum()))
+                _metrics().inc("tpu_hop_plan_budget",
+                               int(res["plan_budget"].sum()))
                 _metrics().add_value("tpu_kernel_s", info["device_s"])
                 _metrics().add_value("tpu_put_s", info["put_s"])
                 _metrics().add_value("tpu_fetch_s", info["fetch_s"])
@@ -1404,8 +1421,8 @@ class TpuRuntime:
             stats.frontier_sizes = [
                 int(x) for x in
                 np.asarray(res["frontier_sizes"])[lane].sum(axis=0)]
-        stats.chunks_run = int(res["chunks_run"][lane].sum())
-        stats.chunks_budget = int(res["chunks_budget"][lane].sum())
+        for k in _ENGAGEMENT:
+            setattr(stats, k, int(res[k][lane].sum()))
         stats.retries = info["retries"]
         stats.compiles = info["compiles"]
         stats.device_s = info["device_s"]
@@ -1667,8 +1684,8 @@ class TpuRuntime:
                         int(x) for x in
                         np.asarray(res["frontier_sizes"]).sum(axis=0)]
                 if "chunks_run" in res:
-                    stats.chunks_run = int(res["chunks_run"].sum())
-                    stats.chunks_budget = int(res["chunks_budget"].sum())
+                    for k in _ENGAGEMENT:
+                        setattr(stats, k, int(res[k].sum()))
                 if cap_dev is not None:
                     tf = time.perf_counter()
                     kc = np.asarray(res["kcount"])
@@ -1702,6 +1719,8 @@ class TpuRuntime:
                 _metrics().inc("tpu_hop_chunks_run", stats.chunks_run)
                 _metrics().inc("tpu_hop_chunks_budget",
                                stats.chunks_budget)
+                _metrics().inc("tpu_hop_plan_run", stats.plan_run)
+                _metrics().inc("tpu_hop_plan_budget", stats.plan_budget)
                 _metrics().add_value("tpu_kernel_s", stats.device_s)
                 _metrics().add_value("tpu_put_s", stats.put_s)
                 _metrics().add_value("tpu_fetch_s", stats.fetch_s)
