@@ -11,9 +11,9 @@ device program (kernel family + shape bucket + predicate/yield program
 reach the dispatch boundary together, they enroll in a forming GROUP;
 after a bounded `batch_wait_us` window (or as soon as the group fills
 to `batch_max_lanes`) ONE member launches a single lane-batched kernel
-(`hop.build_traverse_fn_lanes` on a single chip, or the lanes × shards
-`hop.build_traverse_fn_lanes_sharded` grid program on a multi-device
-mesh — PR 17) for everyone, and each member de-muxes its own lane back
+(`hop.build_traverse_fn(..., lanes=True)`: the vmapped program on a
+single chip, the lanes × shards grid program on a multi-device mesh —
+PR 17) for everyone, and each member de-muxes its own lane back
 out through the per-statement attribution machinery (rows, WorkCounters,
 cost sinks, flight entries stay exactly per-statement — the PR 7
 concurrent-attribution contract).
@@ -54,7 +54,7 @@ Design points:
 
 Metrics: `tpu_batches_formed`, `tpu_batch_lanes`,
 `tpu_batch_form_wait_us`; span `tpu:batch` (emitted by the runtime's
-lane escalation); failpoint `tpu:batch_form` at the enrollment boundary
+escalation driver); failpoint `tpu:batch_form` at the enrollment boundary
 (`raise` = this statement dispatches solo, `delay` = held forming).
 Docs: docs/PERFORMANCE.md §10, docs/OBSERVABILITY.md catalogues,
 docs/ROBUSTNESS.md failpoint table.
@@ -332,8 +332,8 @@ class BatchFormer:
     def _launch(self, key, g: _Group, launch, kernel: str):
         """Run the shared launch for every non-withdrawn member.  The
         claiming member executes on its own thread; per-statement TLS
-        attribution is suppressed inside (the runtime's lane
-        escalation), and each member attributes its own lane at
+        attribution is suppressed inside (the runtime's
+        `_try_batched`), and each member attributes its own lane at
         de-mux."""
         with self._mu:
             if self._groups.get(key) is g:

@@ -436,53 +436,44 @@ def _mark(dst, keep, P: int, vmax: int, acc=None):
 
 
 def _pack_bits(m):
-    """(P, vmax) bool → (P, W) uint32 words (W = ceil(vmax/32)): the
-    mark matrix is bit-packed BEFORE the inter-chip exchange, cutting
+    """(..., P, vmax) bool → (..., P, W) uint32 words (W = ceil(vmax/32)):
+    the mark matrix is bit-packed BEFORE the inter-chip exchange, cutting
     the all_to_all payload 8× vs bool (at SF300 scale: ~35 MB/chip/hop
     instead of ~280 MB).  Packing is a shift-weighted sum over disjoint
     bits (sum of distinct powers of two == OR — no overflow)."""
-    P, vmax = m.shape
+    vmax = m.shape[-1]
     W = -(-vmax // 32)
     pad = W * 32 - vmax
-    mb = jnp.pad(m, ((0, 0), (0, pad)))
-    bits = mb.reshape(P, W, 32).astype(jnp.uint32)
+    mb = jnp.pad(m, ((0, 0),) * (m.ndim - 1) + ((0, pad),))
+    bits = mb.reshape(m.shape[:-1] + (W, 32)).astype(jnp.uint32)
     weights = jnp.left_shift(jnp.uint32(1),
                              jnp.arange(32, dtype=jnp.uint32))
     return jnp.sum(bits * weights, axis=-1, dtype=jnp.uint32)
 
 
 def _unpack_or(recv, vmax: int):
-    """(P, W) received words → (vmax,) bool: OR the P rows on PACKED
-    words, then unpack once."""
-    ored = recv[0]
-    for i in range(1, recv.shape[0]):
-        ored = ored | recv[i]
-    bits = (ored[:, None] >> jnp.arange(32, dtype=jnp.uint32)) & 1
-    return bits.reshape(-1)[:vmax].astype(bool)
+    """(..., P, W) received words → (..., vmax) bool: OR the P rows on
+    PACKED words, then unpack once."""
+    ored = recv[..., 0, :]
+    for i in range(1, recv.shape[-2]):
+        ored = ored | recv[..., i, :]
+    bits = (ored[..., None] >> jnp.arange(32, dtype=jnp.uint32)) & 1
+    return bits.reshape(ored.shape[:-1] + (-1,))[..., :vmax].astype(bool)
 
 
 @_stage("hop/exchange")
 def _exchange_marks(marks, P: int, vmax: int):
     """The per-hop frontier exchange: row d of `marks` is part d's
     candidate bitmap; ship it there (ONE all_to_all over ICI, packed)
-    and OR what this part received."""
+    and OR what this part received.  `marks` is (P, vmax), or
+    (Ll, P, vmax) with one mark matrix per resident query lane: still
+    ONE `all_to_all` per hop, split and concatenated over the part axis
+    of a payload that carries the lanes x parts grid, so L compatible
+    queries share the ICI transfer.  Returns (vmax,) or (Ll, vmax)."""
     packed = _pack_bits(marks)
-    recv = jax.lax.all_to_all(packed, "part", 0, 0, tiled=False)
-    return _unpack_or(recv.reshape(P, -1), vmax)
-
-
-@_stage("hop/exchange")
-def _exchange_marks_lanes(marks, P: int, vmax: int):
-    """Lane-batched frontier exchange: `marks` is (Ll, P, vmax) — one
-    mark matrix per resident query lane.  Still ONE `all_to_all` per hop:
-    the packed payload carries the lanes × parts grid in a single
-    (Ll, P, W) tensor split/concatenated over the part axis (axis 1), so
-    L compatible queries share the ICI transfer instead of paying one
-    collective each.  Returns (Ll, vmax) bool — this part's next
-    frontier per lane."""
-    packed = jax.vmap(_pack_bits)(marks)              # (Ll, P, W)
-    recv = jax.lax.all_to_all(packed, "part", 1, 1, tiled=False)
-    return jax.vmap(lambda r: _unpack_or(r, vmax))(recv)
+    ax = packed.ndim - 2
+    recv = jax.lax.all_to_all(packed, "part", ax, ax, tiled=False)
+    return _unpack_or(recv.reshape(marks.shape[:-1] + (-1,)), vmax)
 
 
 def a2a_payload_bytes(P: int, vmax: int, lanes: int = 1) -> int:
@@ -505,7 +496,7 @@ def _compact_cap(src, dst, rk, eidx, keep, n, EB: int, tail: int = 0,
     Why: capture arrays are EB-padded and EB is sized for the worst hop
     (millions of slots); fetching them wholesale ships mostly padding
     (~2 GB/query at north-star shape).  With kept entries compacted to a
-    prefix the host fetches only [:kmax] slices (runtime._escalate).
+    prefix the host fetches only [:kmax] slices (runtime._fetch).
     The scatter is order-preserving, so the (part, src)-contiguous
     ascending-eidx invariant the host materializers rely on survives.
 
@@ -569,22 +560,14 @@ def _extend_fbm_sharded(fbm, pid, hub_owner, hub_local):
     """Append hub-active bits to one shard's expansion bitmap: each
     hub's frontier bit lives in its OWNER's shard — OR the per-part
     contributions over the mesh so every part expands its chunk of
-    each active hub."""
+    each active hub.  fbm is (vmax,), or (Ll, vmax) under the lanes x
+    shards grid: the psum sits outside any vmap (the lane axis is a
+    leading data axis of its operand), ONE collective for all resident
+    lanes."""
     mine = hub_owner == pid
-    vals = jnp.where(mine, fbm[hub_local], False)
+    vals = jnp.where(mine, fbm[..., hub_local], False)
     bits = jax.lax.psum(vals.astype(jnp.int32), "part") > 0
-    return jnp.concatenate([fbm, bits])
-
-
-def _extend_fbm_sharded_lanes(fbm, pid, hub_owner, hub_local):
-    """Lane-batched hub extension: fbm is (Ll, vmax) — gather each
-    lane's owned hub bits and psum over the part axis in ONE collective
-    for all resident lanes (the collective sits OUTSIDE any vmap: the
-    lane axis is just a leading data axis of the psum operand)."""
-    mine = hub_owner == pid                               # (H,)
-    vals = jnp.where(mine[None, :], fbm[:, hub_local], False)
-    bits = jax.lax.psum(vals.astype(jnp.int32), "part") > 0
-    return jnp.concatenate([fbm, bits], axis=1)           # (Ll, vmax+H)
+    return jnp.concatenate([fbm, bits], axis=-1)
 
 
 def _extend_fbm_local(fbm, hub_owner, hub_local, P: int):
@@ -603,9 +586,10 @@ def _traverse(over, nlead: int, blocks, fbm, pid, extend, exchange, *,
               P: int, ebs, pred, pred_cols, capture: bool,
               capture_hops: bool, yield_cols, hubs_c, chunk: int,
               plan_chunk: int):
-    """The N-hop program, written once for every builder.
+    """The N-hop program, written once for every layout of
+    `build_traverse_fn`.
 
-    Every array carries the builder's `nlead` leading axes (none inside
+    Every array carries the layout's `nlead` leading axes (none inside
     one shard, the part axis on one chip, the lane axis inside one
     shard of the lanes x shards grid) before its own; `over(f)` maps a
     per-part function f(block_leaves, pid, *arrays) over them, `extend`
@@ -794,8 +778,8 @@ def _part_view(blocks_data):
     return [jax.tree.map(lambda x: x[0], b) for b in blocks_data]
 
 
-def build_traverse_fn(mesh, P: int, EB, steps: int,
-                      n_blocks: int,
+def build_traverse_fn(mesh, P: int, EB, steps: int, n_blocks: int, *,
+                      lanes: bool = False,
                       pred: Optional[Callable[[Dict[str, Any]], Any]] = None,
                       pred_cols: Sequence[str] = (),
                       capture: bool = True,
@@ -805,6 +789,31 @@ def build_traverse_fn(mesh, P: int, EB, steps: int,
                       plan_chunk: int = PLAN_CHUNK):
     """Compile the N-step traversal program for one bucket configuration.
     EB: per-block edge budget — an int (uniform) or a per-hop sequence.
+
+    mesh: the ('part',) or ('lane', 'part') mesh whose part axis holds
+    one partition a device — ONE `shard_map` program, the frontier
+    exchanged by a bit-packed `all_to_all` between hops — or None for
+    one chip: all P partitions resident on the device, the per-part
+    kernel vmapped over the part axis and the exchange an OR-reduce
+    over the mark matrices (marks[s, d] = part s's candidate bitmap for
+    part d; the degenerate all_to_all, no ICI).  The two have identical
+    semantics.
+
+    lanes: the query-lane-batched program (ISSUE 15): the frontier and
+    every result leaf gain a LEADING lane axis, L compatible statements
+    (same kernel family, shape bucket, predicate/yield program) share
+    one device put, dispatch and fetch, and the runtime de-muxes lane l
+    back to its statement by slicing `[l]`.  Lanes are independent
+    computations (no cross-lane reduction anywhere), so each lane's
+    captured edge set is bit-identical to the same statement's solo
+    dispatch at the same edge budget; padding lanes (all-false
+    frontier) expand zero edges.  On one chip the CSR blocks are closed
+    over once and broadcast across lanes (`in_axes=(None, 0)`).  On a
+    mesh they stay mesh-resident (device (l, p) reads partition p's
+    adjacency out of its own HBM), the frontier is sharded over BOTH
+    axes and a hop's exchange is still ONE `all_to_all` carrying the
+    lanes x parts grid; a legacy 1-D ('part',) mesh has no lane axis,
+    so every device holds all lanes.
 
     yield_cols: edge-prop names the caller's YIELD list reads — their
     values are gathered ON DEVICE from the pinned prop columns at the
@@ -820,210 +829,79 @@ def build_traverse_fn(mesh, P: int, EB, steps: int,
     blocks_data (runtime arg): tuple of n_blocks dicts with keys
       indptr (P, vmax+1), nbr (P, E), rank (P, E), props {name: (P, E)}
     where props holds the columns the predicate needs PLUS yield_cols
-    (any other result prop decodes on host via the captured eidx).
+    (any other result prop decodes on host via the captured eidx); the
+    delta plane's d_* leaves carry the part axis too.
 
-    Returns jitted fn(blocks_data, frontier) -> dict with:
-      frontier (P, vmax) bool, fcount (P,): next frontier after the LAST
-        hop (mid-hop frontiers never leave the device)
-      hop_edges (P, steps): pre-filter expansion size per hop per part
-      chunks_run, chunks_budget (P, steps): by-need loop trips run and
-        budgeted per hop (0 where the hop's budget fits one chunk)
-      plan_run, plan_budget (P, steps): scatter updates the hop's
+    Returns jitted fn(blocks_data, frontier) -> dict with (lead = (P,),
+    or (L, P) with lanes):
+      frontier lead + (vmax,) bool, fcount lead: next frontier after
+        the LAST hop (mid-hop frontiers never leave the device)
+      hop_edges lead + (steps,): pre-filter expansion size per hop
+      chunks_run, chunks_budget lead + (steps,): by-need loop trips run
+        and budgeted per hop (0 where the hop's budget fits one chunk)
+      plan_run, plan_budget lead + (steps,): scatter updates the hop's
         expansion plans issued, and what whole-bitmap plans issue (0
         where the bitmap is no wider than plan_chunk)
-      ovf_expand (P,) bool: some hop's expansion exceeded EB
-      cap (if capture): dict of (P, n_blocks, EB) arrays
+      ovf_expand lead, bool: some hop's expansion exceeded EB
+      cap (if capture): dict of lead + (n_blocks, EB) arrays
         src, dst, rank, eidx, prop:<name> per yield_col — the final
         hop's edge set (kept entries compacted to a prefix;
-        kcount (P, n_blocks) gives the counts; what a prop array holds
-        past its kept count is unspecified)
+        kcount lead + (n_blocks,) gives the counts; what a prop array
+        holds past its kept count is unspecified)
 
     capture_hops=True is the MATCH mode (SURVEY §2 row 23 Traverse):
     the predicate is applied at EVERY hop (a MATCH edge pattern's filter
     is uniform over a variable-length expansion, unlike GO's final-step
     WHERE) and the edge frame of every hop is captured — cap arrays gain
-    a leading hop axis, (P, steps, n_blocks, EB).  The host assembles
+    a hop axis, lead + (steps, n_blocks, EB).  The host assembles
     trail-semantics paths from the layered frames (runtime.py).
     """
     ebs = _norm_ebs(EB, steps, capture_hops)
     hubs_c, hub_owner, hub_local = _hub_consts(hub_dense, P)
+    kw = dict(P=P, ebs=ebs, pred=pred, pred_cols=pred_cols,
+              capture=capture, capture_hops=capture_hops,
+              yield_cols=yield_cols, hubs_c=hubs_c, chunk=chunk,
+              plan_chunk=plan_chunk)
 
-    def kernel(blocks_data, frontier):
-        pid = jax.lax.axis_index("part").astype(jnp.int32)
-        vmax = frontier.shape[-1]
-        res = _traverse(
-            lambda f: f, 0, _part_view(blocks_data), frontier[0], pid,
-            lambda f: _extend_fbm_sharded(f, pid, hub_owner, hub_local),
-            lambda marks: _exchange_marks(marks, P, vmax),
-            P=P, ebs=ebs, pred=pred, pred_cols=pred_cols, capture=capture,
-            capture_hops=capture_hops, yield_cols=yield_cols,
-            hubs_c=hubs_c, chunk=chunk, plan_chunk=plan_chunk)
-        return jax.tree.map(lambda x: x[None], res)
+    if mesh is None:
+        pids = jnp.arange(P, dtype=jnp.int32)
 
-    from jax.sharding import PartitionSpec
-    spec = PartitionSpec("part")
-    smapped = _shard_map(kernel, mesh=mesh,
-                         in_specs=(spec, spec), out_specs=spec)
-    return jax.jit(smapped)
+        def fn(blocks_data, frontier):
+            return _traverse(
+                jax.vmap, 1, blocks_data, frontier, pids,
+                lambda f: _extend_fbm_local(f, hub_owner, hub_local, P),
+                lambda marks: marks.any(axis=0), **kw)
 
-
-def _build_local_fn(P: int, EB, steps: int,
-                    n_blocks: int,
-                    pred: Optional[Callable[[Dict[str, Any]], Any]] = None,
-                    pred_cols: Sequence[str] = (),
-                    capture: bool = True,
-                    capture_hops: bool = False,
-                    yield_cols: Sequence[str] = (),
-                    hub_dense=None, chunk: int = CHUNK,
-                    plan_chunk: int = PLAN_CHUNK):
-    """The UNJITTED single-chip traversal program — shared by
-    build_traverse_fn_local (jit) and build_traverse_fn_lanes (jit of a
-    vmap over a leading query-lane axis; ISSUE 15).  Every leaf of a
-    block (indptr/nbr/rank/props AND the d_* delta plane) carries a
-    leading part axis, and the per-part functions are vmapped over it;
-    the frontier exchange is an OR over the source parts' marks
-    (marks[s, d] = part s's candidate bitmap for part d)."""
-    pids = jnp.arange(P, dtype=jnp.int32)
-    ebs = _norm_ebs(EB, steps, capture_hops)
-    hubs_c, hub_owner, hub_local = _hub_consts(hub_dense, P)
-
-    def fn(blocks_data, frontier):
-        return _traverse(
-            jax.vmap, 1, blocks_data, frontier, pids,
-            lambda f: _extend_fbm_local(f, hub_owner, hub_local, P),
-            lambda marks: marks.any(axis=0),
-            P=P, ebs=ebs, pred=pred, pred_cols=pred_cols, capture=capture,
-            capture_hops=capture_hops, yield_cols=yield_cols,
-            hubs_c=hubs_c, chunk=chunk, plan_chunk=plan_chunk)
-
-    return fn
-
-
-def build_traverse_fn_local(P: int, EB, steps: int,
-                            n_blocks: int,
-                            pred: Optional[Callable[[Dict[str, Any]], Any]] = None,
-                            pred_cols: Sequence[str] = (),
-                            capture: bool = True,
-                            capture_hops: bool = False,
-                            yield_cols: Sequence[str] = (),
-                            hub_dense=None, chunk: int = CHUNK,
-                            plan_chunk: int = PLAN_CHUNK):
-    """Single-chip variant: all P partitions resident on one device, the
-    per-part kernel vmapped over the part axis, and the frontier exchange
-    an OR-reduce over the mark matrices (the degenerate all_to_all).
-    This is the program that runs on one real chip (the bench config) —
-    identical semantics to the sharded build, no ICI.  capture_hops
-    follows the sharded contract (MATCH mode: per-hop pred + per-hop
-    frames, cap arrays (P, steps, n_blocks, EB)).
-    """
-    return jax.jit(_build_local_fn(
-        P, EB, steps, n_blocks, pred=pred, pred_cols=pred_cols,
-        capture=capture, capture_hops=capture_hops,
-        yield_cols=yield_cols, hub_dense=hub_dense, chunk=chunk,
-        plan_chunk=plan_chunk))
-
-
-def build_traverse_fn_lanes(P: int, EB, steps: int,
-                            n_blocks: int,
-                            pred: Optional[Callable[[Dict[str, Any]], Any]] = None,
-                            pred_cols: Sequence[str] = (),
-                            capture: bool = True,
-                            capture_hops: bool = False,
-                            yield_cols: Sequence[str] = (),
-                            hub_dense=None, chunk: int = CHUNK,
-                            plan_chunk: int = PLAN_CHUNK):
-    """Query-lane-batched single-chip program (ISSUE 15 tentpole).
-
-    The same traversal program with a leading QUERY-ID LANE axis vmapped
-    over the frontier: L compatible statements (same kernel family, same
-    shape bucket, same predicate/yield program) share ONE device put,
-    ONE dispatch and ONE fetch — the CSR blocks are closed over once and
-    broadcast across lanes (`in_axes=(None, 0)`), so the marginal cost
-    of a lane is its own expansion work, not a full kernel launch.
-
-    Inputs/outputs match the local builder's contract with a leading L
-    axis added: frontier (L, P, vmax) bool; every result leaf —
-    hop_edges, frontier_sizes, ovf_expand, kcount and the cap arrays —
-    gains the lane axis, and the runtime de-muxes lane l back to its
-    statement by slicing `[l]`.  Lanes are INDEPENDENT computations
-    (no cross-lane reduction anywhere), so each lane's captured edge
-    set is bit-identical to the same statement's solo dispatch at the
-    same edge budget; padding lanes (all-false frontier) expand zero
-    edges and only cost their share of the dense kernel shape.
-    """
-    fn = _build_local_fn(
-        P, EB, steps, n_blocks, pred=pred, pred_cols=pred_cols,
-        capture=capture, capture_hops=capture_hops,
-        yield_cols=yield_cols, hub_dense=hub_dense, chunk=chunk,
-        plan_chunk=plan_chunk)
-    return jax.jit(jax.vmap(fn, in_axes=(None, 0)))
-
-
-def build_traverse_fn_lanes_sharded(mesh, P: int, EB, steps: int,
-                                    n_blocks: int,
-                                    pred: Optional[Callable[[Dict[str, Any]], Any]] = None,
-                                    pred_cols: Sequence[str] = (),
-                                    capture: bool = True,
-                                    capture_hops: bool = False,
-                                    yield_cols: Sequence[str] = (),
-                                    hub_dense=None, chunk: int = CHUNK,
-                                    plan_chunk: int = PLAN_CHUNK):
-    """The lanes × shards launch grid: ONE shard_map program over the
-    2-axis ("lane", "part") mesh that fuses PR 12's query-id lane axis
-    with the partition axis.
-
-    Unlike `build_traverse_fn_lanes` (single chip: CSR broadcast to every
-    lane via `in_axes=(None, 0)`), the CSR blocks here are MESH-RESIDENT:
-    their in_specs name the part axis, so device (l, p) reads partition
-    p's adjacency out of its own HBM and never sees the other P-1 shards.
-    The frontier is (L, P, vmax) sharded over BOTH axes — each device
-    owns L/lanes query lanes of its partition's bitmap — and the per-hop
-    bit-packed exchange is ONE `all_to_all` whose payload carries the
-    full lanes × parts grid (`_exchange_marks_lanes`).
-
-    The global result contract is IDENTICAL to `build_traverse_fn_lanes`:
-    every leaf carries leading (L, P) axes (hop_edges (L, P, steps),
-    cap arrays (L, P, nb, EB) / (L, P, steps, nb, EB), ...), so the
-    runtime's `_escalate_lanes` / `_lane_attribution` de-mux paths work
-    unchanged on either program.
-
-    Degrade semantics: a (1, 1) mesh never reaches this builder (the
-    runtime's local mode uses the vmap program), and a (1, P) mesh runs
-    it with every lane resident on the part row — same program, lane
-    axis unsplit.
-    """
-    ebs = _norm_ebs(EB, steps, capture_hops)
-    hubs_c, hub_owner, hub_local = _hub_consts(hub_dense, P)
-
-    def over_lanes(f):
-        # the CSR block and the part id are this shard's; everything
-        # else carries the lane axis (delta-row activity, too, depends
-        # on THIS lane's frontier bitmap)
-        return lambda blk, pd, *xs: jax.vmap(
-            lambda *ys: f(blk, pd, *ys))(*xs)
-
-    def kernel(blocks_data, frontier):
-        pid = jax.lax.axis_index("part").astype(jnp.int32)
-        vmax = frontier.shape[-1]
-        res = _traverse(
-            over_lanes, 1, _part_view(blocks_data), frontier[:, 0], pid,
-            lambda f: _extend_fbm_sharded_lanes(
-                f, pid, hub_owner, hub_local),
-            lambda marks: _exchange_marks_lanes(marks, P, vmax),
-            P=P, ebs=ebs, pred=pred, pred_cols=pred_cols, capture=capture,
-            capture_hops=capture_hops, yield_cols=yield_cols,
-            hubs_c=hubs_c, chunk=chunk, plan_chunk=plan_chunk)
-        # local (Ll, 1, ...): the shard axis follows the lane axis
-        return jax.tree.map(lambda x: x[:, None], res)
+        return jax.jit(jax.vmap(fn, in_axes=(None, 0)) if lanes else fn)
 
     from jax.sharding import PartitionSpec
     csr_spec = PartitionSpec("part")
-    # legacy 1-D ('part',) meshes carry no lane axis: the global lane
-    # dimension stays unsharded (every device holds all lanes) and the
-    # same kernel runs with Ll == L
-    lane_ax = "lane" if "lane" in mesh.axis_names else None
-    lane_spec = PartitionSpec(lane_ax, "part")
+    if lanes:
+        # the CSR block and the part id are this shard's; everything
+        # else carries the lane axis (delta-row activity, too, depends
+        # on THIS lane's frontier bitmap)
+        def over(f):
+            return lambda blk, pd, *xs: jax.vmap(
+                lambda *ys: f(blk, pd, *ys))(*xs)
+        lane_ax = "lane" if "lane" in mesh.axis_names else None
+        fr_spec = PartitionSpec(lane_ax, "part")
+        # local (Ll, 1, ...): the shard axis follows the lane axis
+        nlead, shard = 1, (slice(None), 0)
+    else:
+        def over(f):
+            return f
+        fr_spec = csr_spec
+        nlead, shard = 0, (0,)
+
+    def kernel(blocks_data, frontier):
+        pid = jax.lax.axis_index("part").astype(jnp.int32)
+        vmax = frontier.shape[-1]
+        res = _traverse(
+            over, nlead, _part_view(blocks_data), frontier[shard], pid,
+            lambda f: _extend_fbm_sharded(f, pid, hub_owner, hub_local),
+            lambda marks: _exchange_marks(marks, P, vmax), **kw)
+        return jax.tree.map(lambda x: jnp.expand_dims(x, nlead), res)
+
     smapped = _shard_map(kernel, mesh=mesh,
-                         in_specs=(csr_spec, lane_spec),
-                         out_specs=lane_spec)
+                         in_specs=(csr_spec, fr_spec), out_specs=fr_spec)
     return jax.jit(smapped)

@@ -13,9 +13,10 @@ a predicate needs them.
 """
 from __future__ import annotations
 
+import functools
 import threading
 import time
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -36,9 +37,7 @@ from .device import (DeviceSnapshot, TpuUnavailable, make_mesh,
                      pin_snapshot, put_delta_blocks)
 from .exprjit import (CannotCompile, compile_predicate, eval_yield_column,
                       eval_yield_column_np)
-from .hop import (a2a_payload_bytes, build_traverse_fn,
-                  build_traverse_fn_lanes, build_traverse_fn_lanes_sharded,
-                  build_traverse_fn_local)
+from .hop import a2a_payload_bytes, build_traverse_fn
 
 
 def _pow2(n: int) -> int:
@@ -960,19 +959,6 @@ class TpuRuntime:
 
     # -- traversal --------------------------------------------------------
 
-    @staticmethod
-    def _seed_sorted(dense_ids: Sequence[int], P: int,
-                     vmax: int) -> List[int]:
-        """Normalized seed list with the range check both preps share.
-        The old host-side numpy build crashed loudly on an id from a
-        stale/foreign snapshot; JAX scatter would DROP it."""
-        d = sorted(set(int(x) for x in dense_ids if x >= 0))
-        if d and d[-1] >= P * vmax:
-            raise ValueError(
-                f"dense seed id {d[-1]} out of range for snapshot "
-                f"(P={P}, vmax={vmax})")
-        return d
-
     def _seed_builder(self, target, P: int, vmax: int, lanes: bool):
         """The jitted seed-bitmap scatter builder, cached and bounded —
         ONE copy of the build closure, sharding resolution and eviction
@@ -1010,40 +996,58 @@ class TpuRuntime:
         return key, fn
 
     def _seed_frontier_prep(self, dev: DeviceSnapshot,
-                            dense_ids: Sequence[int], target):
-        """Prep for the on-device seed-bitmap build: pad the dense-id
-        list to a pow2 bucket and return (pad, jitted builder) with the
-        builder already COMPILED for this shape — first-bucket XLA
-        trace/compile must not be charged to put_s (it would report a
-        one-off compile as steady-state transfer cost).
+                            lane_dense: Sequence[Sequence[int]],
+                            lanes: bool):
+        """Prep for the on-device seed-bitmap build: pad each launch
+        lane's dense-id list to one pow2 bucket and return (pad, jitted
+        builder) with the builder already COMPILED for this shape —
+        first-bucket XLA trace/compile must not be charged to put_s (it
+        would report a one-off compile as steady-state transfer cost).
 
         The builder scatter-ors the ids into a (P, vmax) bool bitmap on
         device (dense = local * P + p), so the per-query host→device
         transfer shrinks from the graph-sized zeros bitmap (8 MB at
-        north-star scale) to the seed ids."""
+        north-star scale) to the seed ids.  A solo launch pads its one
+        list to (cap,).  A lane-shaped launch pads to one (L, cap) block
+        that the vmapped scatter builds into a (L, P, vmax) frontier
+        stack: L is pow2-padded so the compile count stays logarithmic
+        in batch size, and padding lanes (all -1) scatter nothing and
+        expand nothing."""
         P, vmax = dev.num_parts, dev.vmax
-        d = self._seed_sorted(dense_ids, P, vmax)
-        cap = _pow2(max(len(d), 1))
-        pad = np.full(cap, -1, np.int64)
-        if d:
-            pad[:len(d)] = d
-        key, fn = self._seed_builder(target, P, vmax, lanes=False)
-        wk = (key, cap)
+        ds = [sorted({int(x) for x in d if x >= 0}) for d in lane_dense]
+        top = max((d[-1] for d in ds if d), default=-1)
+        if top >= P * vmax:
+            # an id from a stale/foreign snapshot: the old host-side
+            # numpy build crashed loudly, a JAX scatter would DROP it
+            raise ValueError(
+                f"dense seed id {top} out of range for snapshot "
+                f"(P={P}, vmax={vmax})")
+        shape: Tuple[int, ...] = (_pow2(max([len(d) for d in ds] + [1])),)
+        # lanes × shards grid: the frontier stack is sharded over BOTH
+        # mesh axes — each device owns its lane rows of its partition's
+        # bitmap.  On a legacy 1-D ('part',) mesh the lane dimension
+        # stays unsharded (replicated lanes).
+        spec = PartitionSpec("part")
+        if lanes:
+            # on a (lanes, parts) mesh the global lane axis must divide
+            # evenly over the lane-axis rows: pad to Lm × pow2 lanes
+            # (Lm=1 in local mode reduces to the plain pow2 bucket)
+            Lm = max(self.mesh_lanes, 1)
+            shape = (Lm * _pow2(max(-(-len(ds) // Lm), 1)),) + shape
+            spec = PartitionSpec(
+                "lane" if "lane" in self.mesh.axis_names else None, "part")
+        pad = np.full(shape, -1, np.int64)
+        for row, d in zip(pad if lanes else pad[None], ds):
+            row[:len(d)] = d
+        target = (self.mesh.devices.reshape(-1)[0] if self.local_mode
+                  else NamedSharding(self.mesh, spec))
+        key, fn = self._seed_builder(target, P, vmax, lanes)
+        wk = (key,) + shape
         if wk not in self._seed_warm:
             with self._collective_launch():
                 jax.block_until_ready(fn(pad))   # compile outside timer
             self._seed_warm.add(wk)
         return pad, fn
-
-    def _blocks_for(self, dev: DeviceSnapshot, etypes: Sequence[str],
-                    direction: str):
-        keys = []
-        for et in etypes:
-            if direction in ("out", "both"):
-                keys.append((et, "out"))
-            if direction in ("in", "both"):
-                keys.append((et, "in"))
-        return keys
 
     def _escalate(self, dev: DeviceSnapshot, dense: Sequence[int],
                   key_fn, build_fn, inputs_fn, stats: "TraverseStats",
@@ -1051,24 +1055,17 @@ class TpuRuntime:
                   min_eb: Optional[int] = None,
                   fetch_keys: Optional[set] = None,
                   kernel: str = "traverse"):
-        """Dispatch-queue wrapper around _escalate_locked (ISSUE 9).
-
-        Every device program passes through here: the dispatch
-        registers in the live DispatchTable (queued → running → done,
-        feeding the tpu_dispatch_queue_depth gauge and the stall
-        watchdog), waits on the READ side of the dispatch-vs-repin
-        gate, and the wait lands in `tpu_dispatch_queue_us{kernel}`,
-        the statement's cost sink (`queue_us`) and its live-registry
-        row — the wait-vs-run decomposition the admission-control work
-        (ROADMAP item 2) will be specified against.  The failpoint
-        site `tpu:dispatch_gate` stalls a dispatch while it is still
-        QUEUED (stall-watchdog and queue-accounting tests)."""
+        """A solo statement's launch: the one whose single lane is this
+        statement's seeds and whose program is the solo program, under
+        the dispatch gate (`_gated_dispatch`, ISSUE 9), charged to the
+        statement on its own thread."""
         with self._gated_dispatch(kernel) as wait_us:
-            stats.queue_s = wait_us / 1e6
-            return self._escalate_locked(
-                dev, dense, key_fn, build_fn, inputs_fn, stats,
+            res, info = self._escalate_locked(
+                dev, [dense], key_fn, build_fn, inputs_fn, wait_us,
                 n_hops=n_hops, uniform=uniform, min_eb=min_eb,
                 fetch_keys=fetch_keys, kernel=kernel)
+            self._attribute(info, res, None, stats)
+            return res
 
     @contextmanager
     def _gated_dispatch(self, kernel: str):
@@ -1165,333 +1162,25 @@ class TpuRuntime:
                 wc.add("device_dispatches")
             return res, us
 
-    # -- multi-lane batched dispatch (ISSUE 15 tentpole) -----------------
-
-    def _seed_frontier_prep_lanes(self, dev: DeviceSnapshot,
-                                  lane_dense: Sequence[Sequence[int]],
-                                  target):
-        """Lane-batched variant of _seed_frontier_prep: every lane's
-        dense seed ids padded to one (L, cap) block, built into a
-        (L, P, vmax) bool frontier stack by the vmapped on-device
-        scatter (same builder closure — _seed_builder).  L is
-        pow2-padded so the compile count stays logarithmic in batch
-        size; padding lanes (all -1) scatter nothing and expand
-        nothing."""
-        P, vmax = dev.num_parts, dev.vmax
-        lanes = [self._seed_sorted(dense_ids, P, vmax)
-                 for dense_ids in lane_dense]
-        cap = _pow2(max((len(d) for d in lanes), default=1) or 1)
-        # on a (lanes, parts) mesh the global lane axis must divide
-        # evenly over the lane-axis rows: pad to Lm × pow2 lanes (Lm=1
-        # in local mode reduces to the plain pow2 bucket)
-        Lm = max(self.mesh_lanes, 1)
-        L = Lm * _pow2(max(-(-len(lanes) // Lm), 1))
-        pad = np.full((L, cap), -1, np.int64)
-        for i, d in enumerate(lanes):
-            if d:
-                pad[i, :len(d)] = d
-        key, fn = self._seed_builder(target, P, vmax, lanes=True)
-        wk = (key, L, cap)
-        if wk not in self._seed_warm:
-            with self._collective_launch():
-                jax.block_until_ready(fn(pad))   # compile outside timer
-            self._seed_warm.add(wk)
-        return pad, fn, L
-
-    def _escalate_lanes(self, dev: DeviceSnapshot,
-                        lane_dense: Sequence[Sequence[int]],
-                        key_fn, build_fn, inputs_fn,
-                        n_hops: int = 1, uniform: bool = False,
-                        fetch_keys: Optional[set] = None,
-                        kernel: str = "traverse"):
-        """The lane-batched escalation driver: ONE gated dispatch, ONE
-        put, ONE fetch for every lane of a formed batch (the launcher
-        member runs this on its own thread; batch.py fans the result
-        out).  Returns (res, info): res carries lane-major arrays —
-        hop_edges/frontier_sizes (L, P, steps), cap arrays with a
-        leading L — and info the launch-level facts each lane's
-        de-mux attribution needs (rungs, budgets, phase timings, gate
-        wait).
-
-        Per-statement TLS attribution (work/cost/live) is SUPPRESSED
-        here — the lane-aware de-mux (_lane_attribution) charges each
-        statement its own lane on its own thread, so rows,
-        WorkCounters, cost sinks and flight entries stay exactly
-        per-statement (the PR 7 concurrent-attribution contract).
-        Launch-level truth still lands where it belongs: the kernel
-        ledger, tpu_kernel_runs and the dispatch-table slot record ONE
-        real launch, which is precisely how the ledger proves the
-        sharing is real.  A batched launch consumes ONE
-        `tpu_dispatch_queue_cap` slot (the single _gated_dispatch
-        below), never K."""
-        from ..utils.stats import use_cost, use_work
-        from ..utils.workload import use_live
-        if getattr(dev, "retired", False):
-            raise TpuUnavailable(
-                "device snapshot retired by a concurrent re-pin")
-        base = self.init_eb
-        EBs = [base] * n_hops
-        L_real = len(lane_dense)
-        # mesh identity in the bucket key: a 1-shard and an 8-shard run
-        # of the same program have different overflow profiles (per-part
-        # expansion vs whole-graph expansion)
-        bkey = (key_fn(()) + ("lanes", self._mesh_key()),
-                _pow2(max(L_real, 1)))
-        prev = self._buckets.get(bkey)
-        if prev is not None:
-            pe = prev[-1]
-            pe = [pe] * n_hops if isinstance(pe, int) else list(pe)
-            if len(pe) == n_hops:
-                EBs = [max(a, int(b)) for a, b in zip(EBs, pe)]
-        if uniform:
-            EBs = [max(EBs)] * n_hops
-        if self.local_mode:
-            target = self.mesh.devices.reshape(-1)[0]
-        else:
-            # lanes × shards grid: the frontier stack is sharded over
-            # BOTH mesh axes — each device owns its lane rows of its
-            # partition's bitmap.  On a legacy 1-D ('part',) mesh the
-            # lane dimension stays unsharded (replicated lanes).
-            lane_ax = "lane" if "lane" in self.mesh.axis_names else None
-            target = NamedSharding(self.mesh,
-                                   PartitionSpec(lane_ax, "part"))
-        seed_pad, seed_fn, L = self._seed_frontier_prep_lanes(
-            dev, lane_dense, target)
-        info: Dict[str, Any] = {
-            "lanes": L_real, "rungs": [], "compiles": 0, "retries": 0,
-            "put_s": 0.0, "fetch_s": 0.0, "device_s": 0.0,
-            "gate_wait_us": 0, "ebs": list(EBs), "hbm_bytes": 0,
-            "shards": self.mesh_size, "exchange_bytes": 0,
-            # the shared launch's device phases as (span name,
-            # perf_counter start, seconds, attrs): _lane_attribution
-            # replays them into EACH lane's trace, so nothing is traced
-            # on the launcher's thread while the launch runs
-            "phases": []}
-        phases = info["phases"]
-        launcher_ctx = _t.current_ctx()
-        with use_work(None), use_cost(None), use_live(None), \
-                _t.use_ctx(None), self._gated_dispatch(kernel) as wait_us:
-            info["gate_wait_us"] = wait_us
-            tp = time.perf_counter()
-            with self._collective_launch():
-                frontier = seed_fn(seed_pad)
-            info["put_s"] = time.perf_counter() - tp
-            phases.append(("device:put", tp, info["put_s"], {}))
-            for attempt in range(max(self.max_retries, n_hops + 3)):
-                ebs = tuple(EBs)
-                # lane suffix (not prefix): pin/unpin prune _fns by
-                # key[0]==space / key[1]==epoch — lane programs must
-                # age out with their snapshot like solo programs do;
-                # the mesh key separates per-grid compilations
-                key = key_fn(ebs) + ("lanes", L, self._mesh_key())
-                fn = self._fns.get(key)
-                compiled = fn is None
-                if compiled:
-                    fn = self._fns[key] = build_fn(ebs)
-                    info["compiles"] += 1
-                t0 = time.perf_counter()
-                with self._collective_launch():
-                    res = fn(*inputs_fn(ebs), frontier)
-                    jax.block_until_ready(res)
-                t1 = time.perf_counter()
-                info["rungs"].append((int((t1 - t0) * 1e6), compiled))
-                info["device_s"] = t1 - t0
-                phases.append(("device:dispatch", t0, t1 - t0,
-                               {"eb": list(EBs), "attempt": attempt}))
-                cap_dev = res.pop("cap", None) if isinstance(res, dict) \
-                    else None
-                res = jax.device_get(res)
-                tm = time.perf_counter()
-                info["fetch_s"] += tm - t1
-                phases.append(("device:fetch", t1, tm - t1, {}))
-                if res["ovf_expand"].any():
-                    # per-hop true expansion max over (lane, part):
-                    # jump every overflowed hop straight to its bucket
-                    need = np.asarray(res["hop_edges"]).max(axis=(0, 1))
-                    EBs = [e if need[h] <= e else
-                           min(max(2 * e, _pow2(int(need[h]))),
-                               self.max_cap)
-                           for h, e in enumerate(EBs)]
-                    if uniform:
-                        EBs = [max(EBs)] * n_hops
-                    continue
-                info["ebs"] = list(EBs)
-                info["retries"] = attempt
-                if self._buckets.get(bkey) != (0, ebs):
-                    self._buckets[bkey] = (0, ebs)
-                    while len(self._buckets) > 512:
-                        self._buckets.pop(next(iter(self._buckets)))
-                    self._save_buckets()
-                if cap_dev is not None:
-                    tf = time.perf_counter()
-                    kc = np.asarray(res["kcount"])
-                    kmax = int(kc.max()) if kc.size else 0
-                    # bound by the ACTUAL capture width, not max(EBs):
-                    # a live delta plane widens capture to EB + Dcap,
-                    # so kept counts can legitimately exceed EB
-                    capw = next(iter(cap_dev.values())).shape[-1]
-                    K = min(int(capw), _pow2(max(kmax, 1)))
-                    res["cap"] = {k: np.asarray(
-                        jax.device_get(v[..., :K]))
-                        for k, v in cap_dev.items()
-                        if fetch_keys is None or k in fetch_keys}
-                    res["cap"]["kcount"] = kc
-                    tm = time.perf_counter()
-                    info["fetch_s"] += tm - tf
-                    phases.append(("device:fetch", tf, tm - tf, {}))
-                # launch-level metrics/ledger: ONE real launch shared
-                # by L_real statements — the sharing proof
-                _metrics().inc("tpu_kernel_runs")
-                _metrics().inc("tpu_edges_traversed",
-                               int(np.asarray(res["hop_edges"]).sum()))
-                _metrics().inc("tpu_hop_chunks_run",
-                               int(res["chunks_run"].sum()))
-                _metrics().inc("tpu_hop_chunks_budget",
-                               int(res["chunks_budget"].sum()))
-                _metrics().inc("tpu_hop_plan_run",
-                               int(res["plan_run"].sum()))
-                _metrics().inc("tpu_hop_plan_budget",
-                               int(res["plan_budget"].sum()))
-                _metrics().add_value("tpu_kernel_s", info["device_s"])
-                _metrics().add_value("tpu_put_s", info["put_s"])
-                _metrics().add_value("tpu_fetch_s", info["fetch_s"])
-                _metrics().add_value("tpu_queue_s", wait_us / 1e6)
-                _metrics().inc("tpu_escalation_retries", attempt)
-                for r_us, r_compiled in info["rungs"]:
-                    _metrics().observe("tpu_dispatch_us", r_us,
-                                       {"kernel": kernel})
-                    if r_compiled:
-                        _metrics().inc_labeled("tpu_kernel_compiles",
-                                               {"kernel": kernel})
-                    else:
-                        _metrics().inc_labeled("tpu_kernel_cache_hits",
-                                               {"kernel": kernel})
-                hbm = self.hbm_bytes()
-                info["hbm_bytes"] = hbm
-                self._hbm_high_water = max(
-                    getattr(self, "_hbm_high_water", 0), hbm)
-                _metrics().gauge("tpu_hbm_high_water_bytes",
-                                 float(self._hbm_high_water))
-                # lanes × shards exchange accounting (PR 17): the
-                # shared launch's single per-hop all_to_all carries the
-                # whole L-lane payload
-                xhops = n_hops if kernel == "bfs" else max(n_hops - 1, 0)
-                xbytes = (0 if self.local_mode else
-                          xhops * a2a_payload_bytes(
-                              self.mesh_size, dev.vmax, lanes=L))
-                info["shards"] = self.mesh_size
-                info["exchange_bytes"] = xbytes
-                _metrics().gauge("tpu_shards", float(self.mesh_size))
-                from ..utils.flight import kernel_ledger
-                kernel_ledger().record(
-                    kernel=kernel, shape=[L] + list(EBs), steps=n_hops,
-                    compiled=bool(info["compiles"]),
-                    dispatch_us=int(info["device_s"] * 1e6),
-                    hbm_bytes=hbm, retries=attempt,
-                    shards=self.mesh_size, exchange_bytes=xbytes)
-                if xbytes:
-                    _metrics().inc("tpu_all_to_all_bytes", xbytes)
-                with _t.use_ctx(launcher_ctx):
-                    # the launch itself, under the LAUNCHING member's
-                    # statement: from the seed put to the last fetch
-                    _t.record_phase("tpu:batch", tp,
-                                    time.perf_counter() - tp,
-                                    lanes=L_real, kernel=kernel,
-                                    eb=list(EBs))
-                    if xbytes:
-                        _t.mark("tpu:shard_exchange",
-                                bytes=xbytes, hops=xhops,
-                                shards=self.mesh_size, lanes=L)
-                return res, info
-        raise TpuUnavailable(
-            "lane-batched bucket escalation did not converge")
-
-    def _lane_attribution(self, tk, stats: "TraverseStats"):
-        """De-mux one lane of a shared launch: fill this statement's
-        TraverseStats and charge ITS thread-local work/cost/live sinks
-        with its own lane's deterministic counts (edges, frontier
-        sizes) plus the shared launch's timings — exactly what a solo
-        dispatch of the same statement would have recorded.  Returns
-        the lane's slice of the capture arrays (the lane-aware epilogue
-        of the gated dispatch)."""
-        info, res, lane = tk.info, tk.res, tk.lane
-        he = np.asarray(res["hop_edges"])[lane]          # (P, steps)
-        stats.hop_edges = [int(x) for x in he.sum(axis=0)]
-        if "frontier_sizes" in res:
-            stats.frontier_sizes = [
-                int(x) for x in
-                np.asarray(res["frontier_sizes"])[lane].sum(axis=0)]
-        for k in _ENGAGEMENT:
-            setattr(stats, k, int(res[k][lane].sum()))
-        stats.retries = info["retries"]
-        stats.compiles = info["compiles"]
-        stats.device_s = info["device_s"]
-        stats.put_s = info["put_s"]
-        stats.fetch_s = info["fetch_s"]
-        stats.queue_s = (info["gate_wait_us"] + tk.form_wait_us) / 1e6
-        stats.f_cap, stats.e_cap = 0, list(info["ebs"])
-        stats.hbm_bytes = info["hbm_bytes"]
-        stats.shards = info.get("shards", 1)
-        stats.exchange_bytes = info.get("exchange_bytes", 0)
-        n_rungs = len(info["rungs"])
-        rung_us = sum(r for r, _ in info["rungs"])
-        from ..utils.stats import current_cost, current_work
-        from ..utils.workload import current_live
-        wc = current_work()
-        if wc is not None:
-            wc.add("device_dispatches", n_rungs)
-            wc.add("edges_traversed", stats.edges_traversed())
-            wc.extend_frontier(stats.frontier_sizes)
-        cc = current_cost()
-        if cc is not None:
-            cc.add("device_us", rung_us)
-            cc.add("device_dispatches", n_rungs)
-            cc.add("queue_us", int(stats.queue_s * 1e6))
-            if info["compiles"]:
-                cc.add("device_compiles", info["compiles"])
-        lv = current_live()
-        if lv is not None:
-            lv.add("device_us", rung_us)
-            lv.add("dispatches", n_rungs)
-            lv.add("queue_us", int(stats.queue_s * 1e6))
-        # this lane's view of the shared launch: it waited (former +
-        # gate) until the seed put began, then the launch's own phases
-        t_put = info["phases"][0][1]
-        _t.record_phase("device:queue", t_put - stats.queue_s,
-                        stats.queue_s, lanes=info["lanes"])
-        for name, start, dur, attrs in info["phases"]:
-            _t.record_phase(name, start, dur, **attrs)
-        return {k: v[lane] for k, v in res["cap"].items()}
-
-    def _lanes_builder(self, P: int, steps: int, n_blocks: int, **kw):
-        """Grid-aware lanes program factory: the single-chip vmap
-        program in local mode, the lanes × shards shard_map program on
-        a multi-device mesh (CSR blocks mesh-resident, ONE all_to_all
-        per hop carrying every lane)."""
-        def build_lanes(ebs):
-            if self.local_mode:
-                return build_traverse_fn_lanes(
-                    P, ebs, steps, n_blocks, **kw)
-            return build_traverse_fn_lanes_sharded(
-                self.mesh, P, ebs, steps, n_blocks, **kw)
-        return build_lanes
+    # -- the escalation driver: solo and lane-batched launches -----------
 
     def _try_batched(self, dense: Sequence[int], dev: DeviceSnapshot,
-                     key_fn, build_lanes, inputs_fn, n_hops: int,
+                     key_fn, build_fn, inputs_fn, n_hops: int,
                      uniform: bool, fetch_keys: Optional[set],
                      kernel: str, stats: "TraverseStats",
                      delta_epoch: Optional[int] = None):
-        """Submit this dispatch to the batch former; returns the
-        statement's solo-shaped {"cap": ...} after a shared launch, or
-        None when the dispatch should run solo (batching off, no
+        """Submit this dispatch to the batch former (ISSUE 15); returns
+        the statement's solo-shaped {"cap": ...} after a shared launch,
+        or None when the dispatch should run solo (batching off, no
         concurrent company, a mesh the snapshot is not sharded for, or
         the `tpu:batch_form` failpoint rejected enrollment).
+        `build_fn(ebs)` builds the LANES program.
 
-        Sharded meshes batch too (PR 17): the lanes builder the caller
-        hands us is grid-aware (lanes × shards shard_map when
-        local_mode is off), and the compatibility key carries the mesh
-        shape + epoch so a re-pin to a different shard count can never
-        merge lanes compiled for different launch grids."""
+        Sharded meshes batch too (PR 17): the lanes program is the
+        lanes × shards shard_map when local_mode is off, and the
+        compatibility key carries the mesh shape + epoch so a re-pin to
+        a different shard count can never merge lanes compiled for
+        different launch grids."""
         if not self.local_mode and dev.num_parts != self.mesh_size:
             return None
         from ..utils.failpoints import FailpointError
@@ -1511,10 +1200,25 @@ class TpuRuntime:
                     if delta_epoch is not None else None)
 
         def launch(lane_dense):
-            return self._escalate_lanes(
-                dev, lane_dense, key_fn=key_fn, build_fn=build_lanes,
-                inputs_fn=inputs_fn, n_hops=n_hops, uniform=uniform,
-                fetch_keys=fetch_keys, kernel=kernel)
+            # ONE gated dispatch, ONE put, ONE fetch for every lane of
+            # the formed batch, on the launcher member's thread; it
+            # consumes ONE `tpu_dispatch_queue_cap` slot, never K.
+            # Per-statement TLS attribution (work/cost/live/trace) is
+            # SUPPRESSED while it runs: each member charges its own
+            # lane on its own thread (_attribute), so rows,
+            # WorkCounters, cost sinks and flight entries stay exactly
+            # per-statement (the PR 7 concurrent-attribution contract)
+            from ..utils.stats import use_cost, use_work
+            from ..utils.workload import use_live
+            ctx = _t.current_ctx()
+            with use_work(None), use_cost(None), use_live(None), \
+                    _t.use_ctx(None), \
+                    self._gated_dispatch(kernel) as wait_us:
+                return self._escalate_locked(
+                    dev, lane_dense, key_fn, build_fn, inputs_fn,
+                    wait_us, n_hops=n_hops, uniform=uniform,
+                    fetch_keys=fetch_keys, kernel=kernel, lanes=True,
+                    launcher_ctx=ctx)
 
         try:
             tk = former.submit(base_key, dense, launch, kernel=kernel,
@@ -1523,18 +1227,44 @@ class TpuRuntime:
             return None          # batch forming rejected → solo dispatch
         if tk is None:
             return None
-        return {"cap": self._lane_attribution(tk, stats)}
+        self._attribute(tk.info, tk.res, tk.lane, stats, tk.form_wait_us)
+        return {"cap": {k: v[tk.lane] for k, v in tk.res["cap"].items()}}
 
-    def _escalate_locked(self, dev: DeviceSnapshot, dense: Sequence[int],
-                         key_fn, build_fn, inputs_fn,
-                         stats: "TraverseStats",
+    @staticmethod
+    @contextmanager
+    def _phase(phases: list, name: str, **attrs):
+        """One device phase of a launch: a span LIVE where a trace is
+        active (a solo statement's: the benchmark's trace reduction
+        labels idle gaps by the spans open at a gap's midpoint and the
+        phase ledger folds them) and a (name, perf_counter start,
+        seconds, attrs) record in the launch's phase list, which a
+        shared launch's members replay into their own traces
+        (_attribute): nothing is traced on the launcher's thread while
+        the launch runs."""
+        t0 = time.perf_counter()
+        with _t.span(name, **attrs):
+            yield
+        phases.append((name, t0, time.perf_counter() - t0, attrs))
+
+    def _escalate_locked(self, dev: DeviceSnapshot,
+                         lane_dense: Sequence[Sequence[int]],
+                         key_fn, build_fn, inputs_fn, wait_us: int,
                          n_hops: int = 1, uniform: bool = False,
                          min_eb: Optional[int] = None,
                          fetch_keys: Optional[set] = None,
-                         kernel: str = "traverse"):
-        """Shared power-of-two bucket escalation driver for all device
-        programs (traverse, bfs): seed bitmap layout, jit cache, one
-        batched fetch, overflow-driven retry (SURVEY §7 hard-part #1).
+                         kernel: str = "traverse", lanes: bool = False,
+                         launcher_ctx=None):
+        """The power-of-two bucket escalation driver of every device
+        program (traverse, hops, bfs), solo or lane-batched: seed
+        bitmap put, jit cache, overflow-driven retry (SURVEY §7
+        hard-part #1), fetch, launch-level accounting.  Runs inside the
+        caller's `_gated_dispatch`, whose wait is `wait_us`.
+
+        A launch is a list of lanes, each a statement's dense seed ids.
+        A solo statement is the launch of one lane that runs the solo
+        program; with `lanes` the program carries a leading lane axis
+        and every result leaf is lane-major (hop_edges (L, P, steps),
+        cap arrays with a leading L).
 
         key_fn(ebs) → jit-cache key; build_fn(ebs) → jitted program
         fn(*inputs, frontier); inputs_fn(ebs) → tuple of extra inputs;
@@ -1548,7 +1278,14 @@ class TpuRuntime:
         hop's padding.  `uniform=True` keeps all hops at one size
         (capture_hops stacks frames along a hop axis; BFS compiles one
         per-level body).
-        """
+
+        Returns (res, info): the fetched result and the launch's facts
+        (rungs, budgets, phase timings, gate wait) that `_attribute`
+        charges to each lane's statement.  Launch-level truth lands
+        here, once per converged launch: the kernel ledger,
+        tpu_kernel_runs and the dispatch-table slot record ONE real
+        launch however many statements share it, which is precisely how
+        the ledger proves the sharing is real."""
         if getattr(dev, "retired", False):
             # a concurrent re-pin donated this snapshot's buffers while
             # we were queued at the gate; the caller re-pins / falls back
@@ -1561,10 +1298,17 @@ class TpuRuntime:
             # never climb the recompile ladder
             base = min(max(base, min_eb), self.max_cap)
         EBs = [base] * n_hops
-        # cache key includes the seed-count bucket: one supernode query
-        # must not permanently inflate every later small query of the
-        # same program to supernode-sized padded kernels
-        bkey = (key_fn(()), _pow2(max(len(set(dense)), 1)))
+        # cache key includes the seed-count (or lane-count) bucket: one
+        # supernode query must not permanently inflate every later small
+        # query of the same program to supernode-sized padded kernels.
+        # A lane launch's also names the mesh: a 1-shard and an 8-shard
+        # run of the same program have different overflow profiles
+        # (per-part expansion vs whole-graph expansion)
+        if lanes:
+            bkey = (key_fn(()) + ("lanes", self._mesh_key()),
+                    _pow2(max(len(lane_dense), 1)))
+        else:
+            bkey = (key_fn(()), _pow2(max(len(set(lane_dense[0])), 1)))
         prev = self._buckets.get(bkey)
         if prev is not None:
             # value kept as (0, ebs) for cache-file compat (slot 0 was
@@ -1575,16 +1319,17 @@ class TpuRuntime:
                 EBs = [max(a, int(b)) for a, b in zip(EBs, pe)]
         if uniform:
             EBs = [max(EBs)] * n_hops
-        if self.local_mode:
-            target = self.mesh.devices.reshape(-1)[0]
-        else:
-            target = NamedSharding(self.mesh, PartitionSpec("part"))
 
-        seed_pad, seed_fn = self._seed_frontier_prep(dev, dense, target)
-        tp = time.perf_counter()
-        with _t.span("device:put"), self._collective_launch():
+        seed_pad, seed_fn = self._seed_frontier_prep(dev, lane_dense, lanes)
+        L = seed_pad.shape[0] if lanes else 1
+        info: Dict[str, Any] = {
+            "lanes": len(lane_dense), "rungs": [], "compiles": 0,
+            "refetches": 0, "gate_wait_us": wait_us, "phases": []}
+        phases, rungs = info["phases"], info["rungs"]
+        with self._phase(phases, "device:put"), \
+                self._collective_launch():
             frontier = seed_fn(seed_pad)
-        stats.put_s = time.perf_counter() - tp
+        info["put_s"] = phases[-1][2]
 
         # a post-overflow hop's reported count is a LOWER bound (its
         # frontier was truncated), so in the worst case each attempt
@@ -1592,208 +1337,362 @@ class TpuRuntime:
         # scale with the hop count
         from ..utils.stats import current_work
         wc = current_work()
-        rungs: List[Tuple[int, bool]] = []   # (dispatch_us, compiled)
-        refetches = 0
         for attempt in range(max(self.max_retries, n_hops + 3)):
-            stats.retries = attempt
             ebs = tuple(EBs)
             key = key_fn(ebs)
+            if lanes:
+                # lane suffix (not prefix): pin/unpin prune _fns by
+                # key[0]==space / key[1]==epoch — lane programs must
+                # age out with their snapshot like solo programs do;
+                # the mesh key separates per-grid compilations
+                key += ("lanes", L, self._mesh_key())
             fn = self._fns.get(key)
             compiled = fn is None
             if compiled:
                 fn = self._fns[key] = build_fn(ebs)
-                stats.compiles += 1
+                info["compiles"] += 1
             # per-rung bookkeeping stays PLAIN-PYTHON here (ints and a
             # list append on locals): the dispatch neighborhood is
             # timing-sensitive under concurrent serve-while-repin (a
             # latent jaxlib CPU race); all metric/ledger emission for
-            # the rungs happens once after convergence below
+            # the rungs happens once after convergence below.  A solo
+            # statement's work counters see every rung as it is
+            # dispatched, also of a ladder that then fails to converge
+            # (a shared launch's are suppressed: _attribute)
             if wc is not None:
                 wc.add("device_dispatches")
-            t0 = time.perf_counter()
-            with _t.span("device:dispatch", eb=list(EBs),
-                         attempt=attempt), self._collective_launch():
+            with self._phase(phases, "device:dispatch", eb=list(EBs),
+                             attempt=attempt), \
+                    self._collective_launch():
                 res = fn(*inputs_fn(ebs), frontier)
                 jax.block_until_ready(res)
-            t1 = time.perf_counter()
-            stats.device_s = t1 - t0
-            rungs.append((int((t1 - t0) * 1e6), compiled))
-            # two-phase fetch: capture arrays stay on device while the
-            # small meta (counters/overflow flags) comes back first; the
-            # EB-padded capture rows are then fetched as [:kmax] slices —
-            # kept entries are device-compacted to a prefix (hop.py
-            # _compact_cap), so the transfer is kept-sized, not
-            # bucket-sized (~2 GB → MBs on the north-star config).
-            # SPECULATIVE single-phase: once this program shape has run
-            # in-process, the previous kept-size bounds the slice and
-            # both phases collapse into ONE device_get — one fewer
-            # device round trip per query.  An undershoot (kept grew
-            # past the speculation) falls back to the exact refetch.
-            cap_dev = res.pop("cap", None) if isinstance(res, dict) \
-                else None
-            spec_k = self._kmax.get(key) if cap_dev is not None else None
-            spec_cap = None
-            with _t.span("device:fetch"):
-                if spec_k is not None:
-                    bundle = dict(res)
-                    for ck, cv in cap_dev.items():
-                        if fetch_keys is None or ck in fetch_keys:
-                            bundle["cap:" + ck] = cv[..., :spec_k]
-                    got = jax.device_get(bundle)
-                    res = {k: v for k, v in got.items()
-                           if not k.startswith("cap:")}
-                    spec_cap = {k[4:]: v for k, v in got.items()
-                                if k.startswith("cap:")}
-                else:
-                    res = jax.device_get(res)
-            stats.fetch_s = time.perf_counter() - t1
+            info["device_s"] = phases[-1][2]
+            rungs.append((int(info["device_s"] * 1e6), compiled))
+            # rebinding `res` releases the rung's device buffers, after
+            # the fetch has timed itself; a failed rung's capture is so
+            # dropped BEFORE the larger rung runs: holding both nearly
+            # doubles peak HBM and can fail the retry
+            res, info["refetches"], info["fetch_s"] = self._fetch(
+                res, key, fetch_keys, phases)
+            if not res["ovf_expand"].any():
+                break
+            # hop_edges reports the true per-part pre-filter expansion
+            # size PER HOP, so jump each overflowed hop STRAIGHT to its
+            # needed bucket — blind doubling needs ~20 rounds for a
+            # 1-seed BFS over a 30M-edge graph and times out the retry
+            # budget.  (A pre-overflow hop's count is exact; a
+            # post-overflow hop's is a lower bound from the truncated
+            # frontier — the loop converges.)  The need is the maximum
+            # over every leading axis: parts, and lanes before them
+            he = np.asarray(res["hop_edges"])
+            need = he.reshape(-1, he.shape[-1]).max(axis=0)
+            EBs = [e if need[h] <= e else
+                   min(max(2 * e, _pow2(int(need[h]))), self.max_cap)
+                   for h, e in enumerate(EBs)]
+            if uniform:
+                EBs = [max(EBs)] * n_hops
+        else:
+            raise TpuUnavailable("bucket escalation did not converge")
 
-            if res["ovf_expand"].any():
-                # hop_edges reports the true per-part pre-filter
-                # expansion size PER HOP, so jump each overflowed hop
-                # STRAIGHT to its needed bucket — blind doubling needs
-                # ~20 rounds for a 1-seed BFS over a 30M-edge graph and
-                # times out the retry budget.  (A pre-overflow hop's
-                # count is exact; a post-overflow hop's is a lower bound
-                # from the truncated frontier — the loop converges.)
-                # Drop the failed rung's device capture buffers BEFORE
-                # the larger rung runs — holding both nearly doubles
-                # peak HBM and can fail a retry that would converge.
-                need = np.asarray(res["hop_edges"]).max(axis=0)
-                EBs = [e if need[h] <= e else
-                       min(max(2 * e, _pow2(int(need[h]))), self.max_cap)
-                       for h, e in enumerate(EBs)]
-                if uniform:
-                    EBs = [max(EBs)] * n_hops
-                cap_dev = None
+        info["retries"], info["ebs"] = attempt, list(EBs)
+        if self._buckets.get(bkey) != (0, ebs):
+            self._buckets[bkey] = (0, ebs)
+            # bound by evicting oldest entries — a wholesale clear()
+            # would also wipe the persistent cache file on the next
+            # save, re-exposing every converged query shape to the
+            # recompile ladder
+            while len(self._buckets) > 512:
+                self._buckets.pop(next(iter(self._buckets)))
+            self._save_buckets()
+        m = _metrics()
+        m.inc("tpu_kernel_runs")
+        m.inc("tpu_edges_traversed", int(np.asarray(res["hop_edges"]).sum()))
+        if "chunks_run" in res:
+            for k in _ENGAGEMENT:
+                m.inc(f"tpu_hop_{k}", int(res[k].sum()))
+        m.add_value("tpu_kernel_s", info["device_s"])
+        m.add_value("tpu_put_s", info["put_s"])
+        m.add_value("tpu_fetch_s", info["fetch_s"])
+        m.add_value("tpu_queue_s", wait_us / 1e6)
+        m.inc("tpu_escalation_retries", attempt)
+        m.inc("tpu_refetches", info["refetches"])
+        # device kernel ledger (ISSUE 8 tentpole): per-RUNG dispatch µs
+        # and compile-vs-cache dispositions were accumulated as plain
+        # locals in the loop (every escalation rung is a real dispatch —
+        # counting only the converged run would skew the ratios under
+        # retries); emit them to histograms/counters HERE, outside the
+        # timing-sensitive dispatch neighborhood
+        for r_us, r_compiled in rungs:
+            m.observe("tpu_dispatch_us", r_us, {"kernel": kernel})
+            if r_compiled:
+                m.inc_labeled("tpu_kernel_compiles", {"kernel": kernel})
             else:
-                stats.f_cap, stats.e_cap = 0, list(EBs)
-                if self._buckets.get(bkey) != (0, ebs):
-                    self._buckets[bkey] = (0, ebs)
-                    # bound by evicting oldest entries — a wholesale
-                    # clear() would also wipe the persistent cache file
-                    # on the next save, re-exposing every converged
-                    # query shape to the recompile ladder
-                    while len(self._buckets) > 512:
-                        self._buckets.pop(next(iter(self._buckets)))
-                    self._save_buckets()
-                stats.hop_edges = [int(x)
-                                   for x in res["hop_edges"].sum(axis=0)]
-                if "frontier_sizes" in res:
-                    stats.frontier_sizes = [
-                        int(x) for x in
-                        np.asarray(res["frontier_sizes"]).sum(axis=0)]
-                if "chunks_run" in res:
-                    for k in _ENGAGEMENT:
-                        setattr(stats, k, int(res[k].sum()))
-                if cap_dev is not None:
-                    tf = time.perf_counter()
-                    kc = np.asarray(res["kcount"])
-                    kmax = int(kc.max()) if kc.size else 0
-                    # actual capture width, not max(EBs): a live delta
-                    # plane widens capture to EB + Dcap per hop
-                    capw = next(iter(cap_dev.values())).shape[-1]
-                    K = min(int(capw), _pow2(max(kmax, 1)))
-                    if spec_cap is not None and spec_k >= K:
-                        res["cap"] = {k: np.asarray(v[..., :K])
-                                      for k, v in spec_cap.items()}
-                    else:
-                        # the capture's own fetch: the second phase of
-                        # a first run, or — after a speculative fetch
-                        # that undershot — a refetch
-                        undershot = spec_cap is not None
-                        refetches += undershot
-                        with _t.span("device:fetch", refetch=undershot):
-                            res["cap"] = {k: np.asarray(
-                                jax.device_get(v[..., :K]))
-                                for k, v in cap_dev.items()
-                                if fetch_keys is None or k in fetch_keys}
-                    res["cap"]["kcount"] = kc
-                    self._kmax[key] = K
-                    while len(self._kmax) > 512:
-                        self._kmax.pop(next(iter(self._kmax)))
-                    stats.fetch_s += time.perf_counter() - tf
-                _metrics().inc("tpu_kernel_runs")
-                _metrics().inc("tpu_edges_traversed",
-                               stats.edges_traversed())
-                _metrics().inc("tpu_hop_chunks_run", stats.chunks_run)
-                _metrics().inc("tpu_hop_chunks_budget",
-                               stats.chunks_budget)
-                _metrics().inc("tpu_hop_plan_run", stats.plan_run)
-                _metrics().inc("tpu_hop_plan_budget", stats.plan_budget)
-                _metrics().add_value("tpu_kernel_s", stats.device_s)
-                _metrics().add_value("tpu_put_s", stats.put_s)
-                _metrics().add_value("tpu_fetch_s", stats.fetch_s)
-                _metrics().add_value("tpu_queue_s", stats.queue_s)
-                _metrics().inc("tpu_escalation_retries", stats.retries)
-                _metrics().inc("tpu_refetches", refetches)
-                if wc is not None:
-                    wc.add("edges_traversed", stats.edges_traversed())
-                    wc.extend_frontier(stats.frontier_sizes)
-                # device kernel ledger (ISSUE 8 tentpole): per-RUNG
-                # dispatch µs and compile-vs-cache dispositions were
-                # accumulated as plain locals in the loop (every
-                # escalation rung is a real dispatch — counting only
-                # the converged run would skew the ratios under
-                # retries); emit them to histograms/counters/cost HERE,
-                # outside the timing-sensitive dispatch neighborhood
-                from ..utils.stats import current_cost as _cc
-                cc = _cc()
-                for r_us, r_compiled in rungs:
-                    _metrics().observe("tpu_dispatch_us", r_us,
-                                       {"kernel": kernel})
-                    if r_compiled:
-                        _metrics().inc_labeled("tpu_kernel_compiles",
-                                               {"kernel": kernel})
-                    else:
-                        _metrics().inc_labeled("tpu_kernel_cache_hits",
-                                               {"kernel": kernel})
-                if cc is not None:
-                    cc.add("device_us", sum(r for r, _ in rungs))
-                    cc.add("device_dispatches", len(rungs))
-                    if stats.compiles:
-                        cc.add("device_compiles", stats.compiles)
-                # live workload row (ISSUE 9): SHOW QUERIES reports the
-                # statement's device time while it is still running
-                from ..utils.workload import current_live as _cl
-                lv = _cl()
-                if lv is not None:
-                    lv.add("device_us", sum(r for r, _ in rungs))
-                    lv.add("dispatches", len(rungs))
-                dispatch_us = int(stats.device_s * 1e6)
-                hbm = self.hbm_bytes()
-                stats.hbm_bytes = hbm
-                self._hbm_high_water = max(
-                    getattr(self, "_hbm_high_water", 0), hbm)
-                _metrics().gauge("tpu_hbm_high_water_bytes",
-                                 float(self._hbm_high_water))
-                # per-shard dispatch/exchange facts (PR 17): the
-                # bit-packed frontier all_to_all payload this converged
-                # run moved over ICI — BFS exchanges every level, the
-                # traverse kernels skip the final hop's exchange
-                stats.shards = self.mesh_size
-                xhops = n_hops if kernel == "bfs" else max(n_hops - 1, 0)
-                stats.exchange_bytes = (
-                    0 if self.local_mode else
-                    xhops * a2a_payload_bytes(self.mesh_size, dev.vmax))
-                _metrics().gauge("tpu_shards", float(self.mesh_size))
-                from ..utils.flight import kernel_ledger
-                kernel_ledger().record(
-                    kernel=kernel, shape=list(EBs), steps=n_hops,
-                    compiled=bool(stats.compiles),
-                    dispatch_us=dispatch_us, hbm_bytes=hbm,
-                    retries=stats.retries, shards=self.mesh_size,
-                    exchange_bytes=stats.exchange_bytes)
-                if stats.exchange_bytes:
-                    _metrics().inc("tpu_all_to_all_bytes",
-                                   stats.exchange_bytes)
-                    # the exchange runs inside the fused program — its
-                    # span carries payload facts, not a separate timing
-                    _t.mark("tpu:shard_exchange",
-                            bytes=stats.exchange_bytes,
-                            hops=xhops, shards=self.mesh_size)
-                return res
-        raise TpuUnavailable("bucket escalation did not converge")
+                m.inc_labeled("tpu_kernel_cache_hits", {"kernel": kernel})
+        hbm = info["hbm_bytes"] = self.hbm_bytes()
+        self._hbm_high_water = max(
+            getattr(self, "_hbm_high_water", 0), hbm)
+        m.gauge("tpu_hbm_high_water_bytes", float(self._hbm_high_water))
+        # per-shard dispatch/exchange facts (PR 17): the bit-packed
+        # frontier all_to_all payload this converged run moved over ICI
+        # — BFS exchanges every level, the traverse kernels skip the
+        # final hop's exchange; a shared launch's single per-hop
+        # all_to_all carries the whole L-lane payload
+        xhops = n_hops if kernel == "bfs" else max(n_hops - 1, 0)
+        xbytes = xhops * a2a_payload_bytes(self.mesh_size, dev.vmax, lanes=L)
+        info["shards"], info["exchange_bytes"] = self.mesh_size, xbytes
+        m.gauge("tpu_shards", float(self.mesh_size))
+        from ..utils.flight import kernel_ledger
+        kernel_ledger().record(
+            kernel=kernel, shape=([L] if lanes else []) + list(EBs),
+            steps=n_hops, compiled=bool(info["compiles"]),
+            dispatch_us=int(info["device_s"] * 1e6), hbm_bytes=hbm,
+            retries=attempt, shards=self.mesh_size, exchange_bytes=xbytes)
+        if xbytes:
+            m.inc("tpu_all_to_all_bytes", xbytes)
+        # a shared launch is traced under the LAUNCHING member's
+        # statement, whose context the launch suppressed: the launch
+        # itself, from the seed put to the last fetch
+        with _t.use_ctx(launcher_ctx) if lanes else nullcontext():
+            if lanes:
+                tp = phases[0][1]
+                _t.record_phase("tpu:batch", tp, time.perf_counter() - tp,
+                                lanes=len(lane_dense), kernel=kernel,
+                                eb=list(EBs))
+            if xbytes:
+                # the exchange runs inside the fused program — its span
+                # carries payload facts, not a separate timing
+                _t.mark("tpu:shard_exchange", bytes=xbytes, hops=xhops,
+                        shards=self.mesh_size,
+                        **({"lanes": L} if lanes else {}))
+        return res, info
+
+    def _fetch(self, res, key, fetch_keys: Optional[set], phases):
+        """Bring one rung's result to the host: (res, refetches,
+        seconds).  It times itself, as its last statement: the caller
+        holds the device result and this frame the slices taken of it
+        until then, because releasing device buffers waits its turn
+        (tens of ms under eight sessions) and is no part of the fetch.
+
+        Two-phase: capture arrays stay on device while the small meta
+        (counters/overflow flags) comes back first; the EB-padded
+        capture rows are then fetched as [:K] slices — kept entries are
+        device-compacted to a prefix (hop.py _compact_cap), so the
+        transfer is kept-sized, not bucket-sized (~2 GB → MBs on the
+        north-star config).  SPECULATIVE single-phase: once this program
+        shape (`key`) has run in-process, the previous kept-size bounds
+        the slice and both phases collapse into ONE device_get — one
+        fewer device round trip per query.  An undershoot (kept grew
+        past the speculation) falls back to the exact refetch and is
+        the one refetch counted.  An overflowed rung returns meta alone."""
+        t0 = time.perf_counter()
+        cap_dev = res.get("cap") if isinstance(res, dict) else None
+        spec_k = spec_cap = None
+        if cap_dev is not None:
+            res = {k: v for k, v in res.items() if k != "cap"}
+            # bound by the ACTUAL capture width, not max(EBs): a live
+            # delta plane widens capture to EB + Dcap per hop, so kept
+            # counts can legitimately exceed EB
+            capw = next(iter(cap_dev.values())).shape[-1]
+            cap_dev = {k: v for k, v in cap_dev.items()
+                       if fetch_keys is None or k in fetch_keys}
+            spec_k = self._kmax.get(key)
+        with self._phase(phases, "device:fetch"):
+            if spec_k is not None:
+                spec_dev = {k: v[..., :spec_k] for k, v in cap_dev.items()}
+                res, spec_cap = jax.device_get((res, spec_dev))
+            else:
+                res = jax.device_get(res)
+        if cap_dev is None or res["ovf_expand"].any():
+            return res, 0, time.perf_counter() - t0
+        kc = np.asarray(res["kcount"])
+        K = min(int(capw), _pow2(max(int(kc.max()) if kc.size else 0, 1)))
+        undershot = spec_cap is not None and spec_k < K
+        if spec_cap is not None and not undershot:
+            res["cap"] = {k: np.asarray(v[..., :K])
+                          for k, v in spec_cap.items()}
+        else:
+            # the capture's own fetch: the second phase of a first run,
+            # or — after a speculative fetch that undershot — a refetch
+            with self._phase(phases, "device:fetch", refetch=undershot):
+                res["cap"] = {k: np.asarray(jax.device_get(v[..., :K]))
+                              for k, v in cap_dev.items()}
+        res["cap"]["kcount"] = kc
+        self._kmax[key] = K
+        while len(self._kmax) > 512:
+            self._kmax.pop(next(iter(self._kmax)))
+        return res, int(undershot), time.perf_counter() - t0
+
+    @staticmethod
+    def _attribute(info, res, lane: Optional[int],
+                   stats: "TraverseStats", form_wait_us: int = 0):
+        """Charge one statement its launch, on the statement's own
+        thread: fill its TraverseStats and its thread-local
+        work/cost/live sinks with its own deterministic counts (edges,
+        frontier sizes) plus the launch's timings.  `lane` is None for
+        a solo launch; a member of a shared launch names its lane of
+        the lane-major arrays and is charged exactly what a solo
+        dispatch of the same statement would have recorded."""
+        def mine(k):
+            a = np.asarray(res[k])
+            return a if lane is None else a[lane]
+        stats.hop_edges = [int(x) for x in mine("hop_edges").sum(axis=0)]
+        if "frontier_sizes" in res:
+            stats.frontier_sizes = [
+                int(x) for x in mine("frontier_sizes").sum(axis=0)]
+        if "chunks_run" in res:
+            for k in _ENGAGEMENT:
+                setattr(stats, k, int(mine(k).sum()))
+        stats.retries = info["retries"]
+        stats.compiles = info["compiles"]
+        stats.device_s = info["device_s"]
+        stats.put_s = info["put_s"]
+        stats.fetch_s = info["fetch_s"]
+        stats.queue_s = (info["gate_wait_us"] + form_wait_us) / 1e6
+        stats.f_cap, stats.e_cap = 0, list(info["ebs"])
+        stats.hbm_bytes = info["hbm_bytes"]
+        stats.shards = info["shards"]
+        stats.exchange_bytes = info["exchange_bytes"]
+        n_rungs = len(info["rungs"])
+        rung_us = sum(r for r, _ in info["rungs"])
+        from ..utils.stats import current_cost, current_work
+        from ..utils.workload import current_live
+        wc, cc, lv = current_work(), current_cost(), current_live()
+        if wc is not None:
+            wc.add("edges_traversed", stats.edges_traversed())
+            wc.extend_frontier(stats.frontier_sizes)
+        if cc is not None:
+            cc.add("device_us", rung_us)
+            cc.add("device_dispatches", n_rungs)
+            if info["compiles"]:
+                cc.add("device_compiles", info["compiles"])
+        # live workload row (ISSUE 9): SHOW QUERIES reports the
+        # statement's device time while it is still running
+        if lv is not None:
+            lv.add("device_us", rung_us)
+            lv.add("dispatches", n_rungs)
+        if lane is None:
+            return
+        # what the launch's suppression kept from this statement's
+        # sinks: the rungs the driver counts as they are dispatched, the
+        # queue wait `_gated_dispatch` charges, the live spans
+        queue_us = int(stats.queue_s * 1e6)
+        if wc is not None:
+            wc.add("device_dispatches", n_rungs)
+        if cc is not None:
+            cc.add("queue_us", queue_us)
+        if lv is not None:
+            lv.add("queue_us", queue_us)
+        # this lane's view of the shared launch: it waited (former +
+        # gate) until the seed put began, then the launch's own phases
+        t_put = info["phases"][0][1]
+        _t.record_phase("device:queue", t_put - stats.queue_s,
+                        stats.queue_s, lanes=info["lanes"])
+        for name, start, dur, attrs in info["phases"]:
+            _t.record_phase(name, start, dur, **attrs)
+
+    def _statement(self, store: GraphStore, space: str,
+                   vids: Sequence[Any], etypes: Sequence[str],
+                   direction: str, steps: int,
+                   edge_filter: Optional[E.Expr]):
+        """What every device statement starts with: the pinned
+        snapshot, its stats, the blocks it reads, the compiled edge
+        predicate as (pred, pred_cols, pred_key) and the dense seed
+        ids.  Raises CannotCompile if the filter does not vectorize."""
+        t_start = time.perf_counter()
+        dev = self.pin(store, space)
+        sd = store.space(space)
+        stats = TraverseStats()
+        stats.steps = steps
+        stats.pin_s = time.perf_counter() - t_start
+        block_keys = [(et, d) for et in etypes for d in ("out", "in")
+                      if direction in (d, "both")]
+        pred: Tuple[Any, List[str], Optional[str]] = (None, [], None)
+        if edge_filter is not None:
+            # single-etype constraint is enforced by the optimizer rule
+            bl = dev.blocks[block_keys[0]]
+            pred = compile_predicate(
+                edge_filter, bl.prop_types, dev.pool,
+                vid_to_dense=sd.dense_id) + (E.to_text(edge_filter),)
+        dense = [d for d in (sd.dense_id(v) for v in vids) if d >= 0]
+        return t_start, dev, stats, block_keys, pred, dense
+
+    def _block_leaves(self, dev: DeviceSnapshot, block_keys, prop_names):
+        """The kernel leaves of the blocks a statement reads, the props
+        among them those the program gathers, under ONE consistent delta
+        view (`_grab_delta`): (view, a dict per block)."""
+        dview, dextras = self._grab_delta(dev, block_keys, prop_names)
+        return dview, [
+            {"indptr": dev.blocks[bk].indptr, "nbr": dev.blocks[bk].nbr,
+             "rank": dev.blocks[bk].rank,
+             "props": {n: dev.blocks[bk].props[n] for n in prop_names},
+             **(dextras[i] or {})}
+            for i, bk in enumerate(block_keys)]
+
+    def _run_traverse(self, space: str, dev: DeviceSnapshot,
+                      dense: Sequence[int], block_keys, pred,
+                      stats: "TraverseStats", steps: int, kernel: str,
+                      capture: bool = True, yield_cols: tuple = (),
+                      fetch_keys: Optional[set] = None):
+        """Assemble and dispatch one traverse program (kernel
+        "traverse": GO, the last hop captured; "hops": MATCH, every hop
+        a frame at one uniform budget): the blocks' leaves with ONE
+        consistent delta view, the program's jit key, then a shared
+        launch if the batch former finds company, else a solo one.
+        Returns (res, dview)."""
+        pred_fn, pred_cols, pred_key = pred
+        hops = kernel == "hops"
+        prop_names = {n for n in pred_cols if not n.startswith("_")}
+        dview, blocks = self._block_leaves(dev, block_keys,
+                                           prop_names | set(yield_cols))
+        blocks_data = tuple(blocks)
+        if fetch_keys is not None and dview is not None:
+            # delta rows interleave with base rows in canonical CSR
+            # order at materialize time — the host re-sort needs every
+            # identity column regardless of what the yields read
+            fetch_keys |= {"src", "dst", "rank", "eidx"}
+        hub_dense = getattr(dev.host, "hub_dense", None)
+        hub_n = 0 if hub_dense is None else len(hub_dense)
+
+        def build(ebs, lanes=False):
+            return build_traverse_fn(
+                None if self.local_mode else self.mesh, dev.num_parts,
+                ebs, steps, len(block_keys), lanes=lanes, pred=pred_fn,
+                pred_cols=pred_cols, capture=capture, capture_hops=hops,
+                yield_cols=yield_cols, hub_dense=hub_dense)
+
+        def key_fn(ebs):
+            if hops:
+                return (space, dev.epoch, "hops", tuple(block_keys),
+                        steps, ebs, pred_key, tuple(pred_cols), hub_n,
+                        self._delta_sig(dev))
+            return (space, dev.epoch, tuple(block_keys), steps, ebs,
+                    pred_key, capture, tuple(pred_cols), yield_cols,
+                    hub_n, self._delta_sig(dev))
+
+        launch = dict(key_fn=key_fn, inputs_fn=lambda ebs: (blocks_data,),
+                      n_hops=steps, uniform=hops, fetch_keys=fetch_keys,
+                      kernel=kernel, stats=stats)
+        # multi-lane batched dispatch (ISSUE 15): concurrent compatible
+        # statements share ONE launch; None falls through to the solo
+        # path (batching off / no company / capture-less program)
+        res = None
+        if capture:
+            res = self._try_batched(
+                dense, dev, build_fn=functools.partial(build, lanes=True),
+                delta_epoch=dview[0] if dview is not None else None,
+                **launch)
+        if res is None:
+            res = self._escalate(dev, dense, build_fn=build, **launch)
+        return res, dview
+
+    @contextmanager
+    def _materialising(self, stats: "TraverseStats"):
+        """Row or frame assembly on the host, timed into mat_s."""
+        t_mat = time.perf_counter()
+        with _t.span("device:materialise"):
+            yield
+        stats.mat_s = time.perf_counter() - t_mat
+        _metrics().add_value("tpu_mat_s", stats.mat_s)
 
     def traverse(self, store: GraphStore, space: str, vids: Sequence[Any],
                  etypes: Sequence[str], direction: str, steps: int,
@@ -1812,31 +1711,11 @@ class TpuRuntime:
         boundary (the E2E fast path).  Raises CannotCompile if the
         filter does not vectorize (caller falls back to the host path).
         """
-        t_start = time.perf_counter()
-        dev = self.pin(store, space)
-        sd = store.space(space)
-        stats = TraverseStats()
-        stats.steps = steps
-        stats.pin_s = time.perf_counter() - t_start
-
-        block_keys = self._blocks_for(dev, etypes, direction)
-        pred = None
-        pred_cols: List[str] = []
-        pred_key = None
-        if edge_filter is not None:
-            # single-etype constraint is enforced by the optimizer rule
-            bl = dev.blocks[block_keys[0]]
-            pred, pred_cols = compile_predicate(
-                edge_filter, bl.prop_types, dev.pool,
-                vid_to_dense=sd.dense_id)
-            pred_key = E.to_text(edge_filter) if hasattr(E, "to_text") else repr(edge_filter)
-
-        dense = [sd.dense_id(v) for v in vids]
-        dense = [d for d in dense if d >= 0]
+        t_start, dev, stats, block_keys, pred, dense = self._statement(
+            store, space, vids, etypes, direction, steps, edge_filter)
         if not dense:
             return [], stats
 
-        P = dev.num_parts
         # edge props the yields read and EVERY block carries are
         # gathered on device at the compacted final-hop slots (the
         # fused-Project leg: the fetch then ships exactly the result
@@ -1856,15 +1735,6 @@ class TpuRuntime:
             # on host via eidx as before
             if len(yield_cols) > 4:
                 yield_cols = yield_cols[:4]
-        prop_names = {n for n in pred_cols if not n.startswith("_")}
-        prop_names |= set(yield_cols)
-        dview, dextras = self._grab_delta(dev, block_keys, prop_names)
-        blocks_data = tuple(
-            {"indptr": dev.blocks[bk].indptr, "nbr": dev.blocks[bk].nbr,
-             "rank": dev.blocks[bk].rank,
-             "props": {n: dev.blocks[bk].props[n] for n in prop_names},
-             **(dextras[i] or {})}
-            for i, bk in enumerate(block_keys))
 
         # fetch only the capture arrays the yields actually read (each
         # is a kept-sized column — src+rank+eidx are most of the result
@@ -1876,60 +1746,15 @@ class TpuRuntime:
             # reverse blocks serve src(edge) from the dst array and vice
             # versa (physical-edge orientation) — need both
             fetch_keys |= {"src", "dst"}
-        if fetch_keys is not None and dview is not None:
-            # delta rows interleave with base rows in canonical CSR
-            # order at materialize time — the host re-sort needs every
-            # identity column regardless of what the yields read
-            fetch_keys |= {"src", "dst", "rank", "eidx"}
 
-        hub_dense = getattr(dev.host, "hub_dense", None)
-        hub_n = 0 if hub_dense is None else len(hub_dense)
-
-        def build(ebs):
-            if self.local_mode:
-                return build_traverse_fn_local(
-                    P, ebs, steps, len(block_keys), pred=pred,
-                    pred_cols=pred_cols, capture=capture,
-                    yield_cols=yield_cols, hub_dense=hub_dense)
-            return build_traverse_fn(
-                self.mesh, P, ebs, steps, len(block_keys),
-                pred=pred, pred_cols=pred_cols, capture=capture,
-                yield_cols=yield_cols, hub_dense=hub_dense)
-
-        def key_fn(ebs):
-            return (space, dev.epoch, tuple(block_keys), steps, ebs,
-                    pred_key, capture, tuple(pred_cols), yield_cols,
-                    hub_n, self._delta_sig(dev))
-
-        # multi-lane batched dispatch (ISSUE 15): concurrent compatible
-        # statements share ONE launch; None falls through to the solo
-        # path (batching off / no company / capture-less program)
-        res = None
-        if capture:
-            res = self._try_batched(
-                dense, dev, key_fn,
-                build_lanes=self._lanes_builder(
-                    P, steps, len(block_keys), pred=pred,
-                    pred_cols=pred_cols, capture=True,
-                    yield_cols=yield_cols, hub_dense=hub_dense),
-                inputs_fn=lambda ebs: (blocks_data,),
-                n_hops=steps, uniform=False, fetch_keys=fetch_keys,
-                kernel="traverse", stats=stats,
-                delta_epoch=dview[0] if dview is not None else None)
-        if res is None:
-            res = self._escalate(
-                dev, dense,
-                key_fn=key_fn,
-                build_fn=build,
-                inputs_fn=lambda ebs: (blocks_data,),
-                stats=stats, n_hops=steps, fetch_keys=fetch_keys,
-                kernel="traverse")
+        res, dview = self._run_traverse(
+            space, dev, dense, block_keys, pred, stats, steps, "traverse",
+            capture=capture, yield_cols=yield_cols, fetch_keys=fetch_keys)
         if not capture:
             stats.total_s = time.perf_counter() - t_start
             return [], stats
 
-        t_mat = time.perf_counter()
-        with _t.span("device:materialise"):
+        with self._materialising(stats):
             if yields is not None:
                 rows = self._materialize_yields(
                     store, space, dev, block_keys, res["cap"], yields,
@@ -1937,8 +1762,6 @@ class TpuRuntime:
             else:
                 rows = self._materialize(store, space, dev, block_keys,
                                          res["cap"], dview=dview)
-        stats.mat_s = time.perf_counter() - t_mat
-        _metrics().add_value("tpu_mat_s", stats.mat_s)
         stats.result_edges = len(rows)
         stats.total_s = time.perf_counter() - t_start
         return rows, stats
@@ -1968,86 +1791,15 @@ class TpuRuntime:
         may retry with edge_filter=None and re-check rows on host —
         frames are then a superset pruned during assembly).
         """
-        t_start = time.perf_counter()
-        dev = self.pin(store, space)
-        sd = store.space(space)
-        stats = TraverseStats()
-        stats.steps = max_hop
-        stats.pin_s = time.perf_counter() - t_start
-
-        block_keys = self._blocks_for(dev, etypes, direction)
-        pred = None
-        pred_cols: List[str] = []
-        pred_key = None
-        if edge_filter is not None:
-            bl = dev.blocks[block_keys[0]]
-            pred, pred_cols = compile_predicate(
-                edge_filter, bl.prop_types, dev.pool,
-                vid_to_dense=sd.dense_id)
-            pred_key = E.to_text(edge_filter) if hasattr(E, "to_text") \
-                else repr(edge_filter)
-
-        dense = [sd.dense_id(v) for v in vids]
-        dense = [d for d in dense if d >= 0]
+        t_start, dev, stats, block_keys, pred, dense = self._statement(
+            store, space, vids, etypes, direction, max_hop, edge_filter)
         if not dense:
             return [HopFrame.empty() for _ in range(max_hop)], stats
-
-        P = dev.num_parts
-        prop_names = {n for n in pred_cols if not n.startswith("_")}
-        dview, dextras = self._grab_delta(dev, block_keys, prop_names)
-        blocks_data = tuple(
-            {"indptr": dev.blocks[bk].indptr, "nbr": dev.blocks[bk].nbr,
-             "rank": dev.blocks[bk].rank,
-             "props": {n: dev.blocks[bk].props[n] for n in prop_names},
-             **(dextras[i] or {})}
-            for i, bk in enumerate(block_keys))
-
-        hub_dense = getattr(dev.host, "hub_dense", None)
-        hub_n = 0 if hub_dense is None else len(hub_dense)
-
-        def build(ebs):
-            if self.local_mode:
-                return build_traverse_fn_local(
-                    P, ebs, max_hop, len(block_keys), pred=pred,
-                    pred_cols=pred_cols, capture=True, capture_hops=True,
-                    hub_dense=hub_dense)
-            return build_traverse_fn(
-                self.mesh, P, ebs, max_hop, len(block_keys),
-                pred=pred, pred_cols=pred_cols, capture=True,
-                capture_hops=True, hub_dense=hub_dense)
-
-        def key_fn(ebs):
-            return (space, dev.epoch, "hops", tuple(block_keys),
-                    max_hop, ebs, pred_key, tuple(pred_cols), hub_n,
-                    self._delta_sig(dev))
-
-        # multi-lane batched dispatch (ISSUE 15): concurrent MATCH
-        # expansions of the same program share ONE launch
-        res = self._try_batched(
-            dense, dev, key_fn,
-            build_lanes=self._lanes_builder(
-                P, max_hop, len(block_keys), pred=pred,
-                pred_cols=pred_cols, capture=True, capture_hops=True,
-                hub_dense=hub_dense),
-            inputs_fn=lambda ebs: (blocks_data,),
-            n_hops=max_hop, uniform=True, fetch_keys=None,
-            kernel="hops", stats=stats,
-            delta_epoch=dview[0] if dview is not None else None)
-        if res is None:
-            res = self._escalate(
-                dev, dense,
-                key_fn=key_fn,
-                build_fn=build,
-                inputs_fn=lambda ebs: (blocks_data,),
-                stats=stats, n_hops=max_hop, uniform=True,
-                kernel="hops")
-
-        t_mat = time.perf_counter()
-        with _t.span("device:materialise"):
+        res, dview = self._run_traverse(
+            space, dev, dense, block_keys, pred, stats, max_hop, "hops")
+        with self._materialising(stats):
             frames = self._build_frames(store, space, dev, block_keys,
                                         res["cap"], max_hop, dview=dview)
-        stats.mat_s = time.perf_counter() - t_mat
-        _metrics().add_value("tpu_mat_s", stats.mat_s)
         stats.result_edges = sum(f.n for f in frames)
         stats.total_s = time.perf_counter() - t_start
         return frames, stats
@@ -2181,23 +1933,9 @@ class TpuRuntime:
         oracle's filtered expansion.
         """
         from .bfs import build_bfs_fn, build_bfs_fn_local
-        dev = self.pin(store, space)
-        sd = store.space(space)
-        stats = TraverseStats()
-        stats.steps = max_steps
-
-        block_keys = self._blocks_for(dev, etypes, direction)
-        pred = None
-        pred_cols: List[str] = []
-        pred_key = None
-        if edge_filter is not None:
-            bl = dev.blocks[block_keys[0]]
-            pred, pred_cols = compile_predicate(
-                edge_filter, bl.prop_types, dev.pool,
-                vid_to_dense=sd.dense_id)
-            pred_key = E.to_text(edge_filter)
-        dense = [sd.dense_id(v) for v in srcs]
-        dense = [d for d in dense if d >= 0]
+        _, dev, stats, block_keys, (pred, pred_cols, pred_key), dense = \
+            self._statement(store, space, srcs, etypes, direction,
+                            max_steps, edge_filter)
         if not dense:
             return np.full((dev.num_parts, dev.vmax), -1, np.int32), stats
 
@@ -2218,35 +1956,15 @@ class TpuRuntime:
         have_rev = (self.local_mode and dev.delta is None
                     and len(rev_keys) == len(block_keys)
                     and all(rk in dev.blocks for rk in rev_keys))
-        pnames = [n for n in pred_cols if not n.startswith("_")]
-        dview, dextras = self._grab_delta(dev, block_keys, set(pnames))
-
-        def _bd(bk):
-            out = {"indptr": dev.blocks[bk].indptr,
-                   "nbr": dev.blocks[bk].nbr,
-                   "rank": dev.blocks[bk].rank}
-            if pred is not None:
-                out["props"] = {n: dev.blocks[bk].props[n] for n in pnames}
-            return out
-
-        blocks_data = []
-        for i, bk in enumerate(block_keys):
-            d = _bd(bk)
-            if dextras[i] is not None:
-                d.update(dextras[i])
-            if have_rev:
-                rb = dev.blocks[rev_keys[i]]
-                d["rev_indptr"] = rb.indptr
-                d["rev_nbr"] = rb.nbr
-                d["rev_rank"] = rb.rank
-                if pred is not None:
-                    d["rev_props"] = {n: rb.props[n] for n in pnames}
-                else:
-                    d["rev_props"] = {}
-            if pred is None:
-                d.setdefault("props", {})
-            blocks_data.append(d)
-        blocks_data = tuple(blocks_data)
+        pnames = {n for n in pred_cols if not n.startswith("_")}
+        _, blocks = self._block_leaves(dev, block_keys, pnames)
+        if have_rev:
+            for d, rk in zip(blocks, rev_keys):
+                rb = dev.blocks[rk]
+                d.update(rev_indptr=rb.indptr, rev_nbr=rb.nbr,
+                         rev_rank=rb.rank,
+                         rev_props={n: rb.props[n] for n in pnames})
+        blocks_data = tuple(blocks)
 
         n_phantom = int(P * dev.vmax
                         - np.asarray(dev.num_vertices).sum())

@@ -195,6 +195,177 @@ def test_batch_form_failpoint_raise_dispatches_solo(rt, clean, company):
         s0.get("tpu_batches_formed", 0)
 
 
+# -- the one driver: a shared launch fetches as a solo one does -------------
+
+
+def _go_traces(seen):
+    """The `query:Go` traces recorded since `seen` (a set of trace ids,
+    updated in place), newest first."""
+    from nebula_tpu.utils import trace
+    new = [trace.trace_store().get(t["tid"])
+           for t in trace.trace_store().list(limit=256)
+           if t["name"] == "query:Go" and t["tid"] not in seen]
+    seen.update(e["tid"] for e in new)
+    return new
+
+
+def test_warm_lane_launch_fetches_once_and_counts_an_undershoot(
+        clean, company):
+    """A lane-batched launch takes the speculative single-phase fetch
+    with it: the second launch of a warm shape brings meta and capture
+    back in ONE `device:fetch` phase per lane and counts no
+    `tpu_refetches`; a speculation that undershoots falls back to the
+    exact refetch and counts one; rows equal the solo run's every
+    time."""
+    rt = TpuRuntime(make_mesh(1))       # no kept size known yet
+    eng = device_engine(rt)
+    seeds = [1, 2, 3, 5]
+    stmts = {sd: GO_TMPL.format(seed=sd) for sd in seeds}
+    truth = {}
+    for sd in seeds:
+        out = {}
+        _run_stmt(eng, stmts[sd], out, sd, [])
+        truth[sd] = sorted(map(repr, out[sd][0].data.rows))
+    get_config().set_dynamic_many({"batch_max_lanes": 8,
+                                   "batch_wait_us": 300_000})
+    seen = set()
+    _go_traces(seen)
+
+    def launch():
+        s0 = stats().snapshot()
+        out = _concurrent(eng, stmts)
+        s1 = stats().snapshot()
+        assert s1.get("tpu_batches_formed", 0) \
+            - s0.get("tpu_batches_formed", 0) == 1
+        for sd in seeds:
+            assert sorted(map(repr, out[sd][0].data.rows)) == truth[sd]
+        fetches = [[s.get("attrs", {}) for s in e["spans"]
+                    if s["name"] == "device:fetch"] for e in _go_traces(seen)]
+        assert len(fetches) == len(seeds)
+        return fetches, (s1.get("tpu_refetches", 0)
+                         - s0.get("tpu_refetches", 0))
+
+    def lane_keys():
+        return [k for k in rt._kmax if "lanes" in k]
+    assert not lane_keys()
+    cold, refetched = launch()
+    # a first run knows no kept size: meta, then the capture's own fetch
+    assert all(f == [{}, {"refetch": False}] for f in cold), cold
+    assert refetched == 0 and len(lane_keys()) == 1
+    warm, refetched = launch()
+    assert all(f == [{}] for f in warm), warm
+    assert refetched == 0
+    rt._kmax[lane_keys()[0]] = 1        # a speculation no lane fits in
+    under, refetched = launch()
+    assert all(f == [{}, {"refetch": True}] for f in under), under
+    assert refetched == 1
+    assert rt._kmax[lane_keys()[0]] > 1
+
+
+def test_solo_statement_keeps_live_device_spans(clean, monkeypatch):
+    """A solo statement's `device:put`, `device:dispatch` and
+    `device:fetch` are LIVE spans, open while the work they name runs
+    (the benchmark's trace reduction labels an idle gap by the spans
+    open at its midpoint), in that order with `device:queue` closed
+    before them: not phases replayed after the launch, as a shared
+    launch's members get them."""
+    import jax
+
+    from nebula_tpu.tpu import runtime
+    from nebula_tpu.utils import trace
+    open_around = {}
+
+    def probed(what, fn):
+        def run(*a):
+            ctx = trace.current_ctx()
+            open_around[what] = ctx.sid if ctx is not None else None
+            return fn(*a)
+        return run
+    real_build, real_seed = runtime.build_traverse_fn, TpuRuntime._seed_builder
+    monkeypatch.setattr(runtime, "build_traverse_fn",
+                        lambda *a, **kw: probed("device:dispatch",
+                                                real_build(*a, **kw)))
+
+    def seed_builder(self, *a, **kw):
+        key, fn = real_seed(self, *a, **kw)
+        return key, probed("device:put", fn)
+    monkeypatch.setattr(TpuRuntime, "_seed_builder", seed_builder)
+    eng = device_engine(TpuRuntime(make_mesh(1)))
+    out = {}
+    _run_stmt(eng, GO_TMPL.format(seed=1), out, "cold", [])  # warms the put
+    monkeypatch.setattr(jax, "device_get",
+                        probed("device:fetch", jax.device_get))
+    seen = set()
+    _go_traces(seen)
+    open_around.clear()
+    _run_stmt(eng, GO_TMPL.format(seed=1), out, "warm", [])
+    assert out["warm"][0].error is None, out["warm"][0].error
+    (entry,) = _go_traces(seen)
+    by_sid = {s["sid"]: s for s in entry["spans"]}
+    for name, sid in open_around.items():
+        assert sid in by_sid and by_sid[sid]["name"] == name, \
+            (name, by_sid.get(sid))
+    assert set(open_around) == {"device:put", "device:dispatch",
+                                "device:fetch"}
+    order = ["device:queue", "device:put", "device:dispatch", "device:fetch"]
+    dev = sorted((s for s in entry["spans"] if s["name"] in order),
+                 key=lambda s: s["t0"])
+    assert [s["name"] for s in dev] == order
+    assert round(dev[0]["t0"] * 1e6) + dev[0]["dur_us"] \
+        <= round(dev[1]["t0"] * 1e6) + 1
+
+
+def test_fetch_times_itself(clean, monkeypatch):
+    """`tpu_fetch_s` and a statement's `fetch_s` are the seconds `_fetch`
+    measured itself, two-phase or speculative: the release of the
+    rung's device buffers, which waits its turn under concurrent
+    sessions (eight of them read 2.4 times the fetch on the chip when it
+    was timed around the routine), comes after the routine's timer."""
+    seen = []
+    real = TpuRuntime._fetch
+
+    def fetch(self, res, *a):
+        got = real(self, res, *a)
+        # the caller still holds the device result it handed in
+        assert "cap" in res and got[0] is not res
+        seen.append(got[2])
+        return got
+    monkeypatch.setattr(TpuRuntime, "_fetch", fetch)
+    st = batched_store()
+    rt = TpuRuntime(make_mesh(1))
+    s0 = stats().snapshot()
+    took = []
+    for _ in range(2):                  # two-phase, then speculative
+        rows, ts = rt.traverse(st, "bt", [1, 2], ["E"], "out", 2)
+        assert rows and ts.retries == 0
+        took.append(ts.fetch_s)
+    assert took == seen and all(t > 0 for t in took)
+    s1 = stats().snapshot()
+    assert s1["tpu_fetch_s.sum"] - s0.get("tpu_fetch_s.sum", 0) \
+        == pytest.approx(sum(seen))
+
+
+def test_bucket_jit_and_kept_size_keys_keep_their_form(clean):
+    """`.tpu_buckets.json` is read across processes and versions: a
+    bucket saved under the solo key form, `(key_fn(()), pow2(seeds)) ->
+    (0, ebs)`, starts the ladder AT those budgets (no retry), and the
+    program and its kept size are cached under `key_fn(ebs)`."""
+    st = batched_store()
+    rt = TpuRuntime(make_mesh(1))
+    epoch = rt.pin(st, "bt").epoch
+
+    def key(ebs):
+        return ("bt", epoch, (("E", "out"),), 2, ebs, None, True, (), (),
+                0, None)
+    ebs = (4096, 8192)
+    assert rt.init_eb < ebs[0]
+    rt._buckets[(key(()), 2)] = (0, ebs)
+    rows, ts = rt.traverse(st, "bt", [1, 2], ["E"], "out", 2)
+    assert rows and ts.retries == 0 and ts.e_cap == list(ebs)
+    assert list(rt._fns) == [key(ebs)] and list(rt._kmax) == [key(ebs)]
+    assert rt._buckets[(key(()), 2)] == (0, ebs)
+
+
 # -- PR 8 shed interaction: one dispatch-queue slot per batch ---------------
 
 

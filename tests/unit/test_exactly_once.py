@@ -98,8 +98,16 @@ def test_duplicate_dbatch_apply_skips(cluster, clean_faults):
     # random, and the FETCH below reads through the leader — a side-
     # applied write on a lagged follower would be invisible to it
     sid = cluster.storageds[0].meta.catalog.get_space("eo").space_id
-    ss = next(s for s in cluster.storageds
-              if (sid, pid) in s.parts and s.parts[(sid, pid)].is_leader())
+    # between two leaders (an election on a busy machine) nobody leads:
+    # wait for one
+    deadline = time.monotonic() + 20
+    while True:
+        ss = next((s for s in cluster.storageds if (sid, pid) in s.parts
+                   and s.parts[(sid, pid)].is_leader()), None)
+        if ss is not None:
+            break
+        assert time.monotonic() < deadline, "no storaged leads the part"
+        time.sleep(0.02)
     ss._apply_dbatch("eo", pid, "wdup", 1,
                      [["upd_vertex", 1, "Person", {"age": 77}]])
     before = stats().snapshot().get("storage_write_dedup_apply_skips", 0)

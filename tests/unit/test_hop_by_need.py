@@ -33,8 +33,12 @@ DEGS = [0, 1, C - 1, C, C + 1, EB, EB + 5]
 IDENT = ("src", "dst", "rank", "eidx")
 META = ("frontier", "fcount", "hop_edges", "frontier_sizes", "ovf_expand",
         "kcount")
-BUILDERS = ("build_traverse_fn", "build_traverse_fn_local",
-            "build_traverse_fn_lanes", "build_traverse_fn_lanes_sharded")
+# the four layouts of the one entry `hop.build_traverse_fn(mesh or None,
+# ..., lanes=)`, as (on a mesh, lane-batched)
+LAYOUTS = [pytest.param((True, False), id="mesh"),
+           pytest.param((False, False), id="one-chip"),
+           pytest.param((False, True), id="one-chip-lanes"),
+           pytest.param((True, True), id="mesh-lanes")]
 
 
 def _block(seed=5):
@@ -100,9 +104,9 @@ def test_one_hop_of_exact_size(total, with_pred):
     eb = EB
     while True:
         got = _same(
-            hop.build_traverse_fn_local(P, eb, 1, 1, chunk=C, **kw)(
+            hop.build_traverse_fn(None, P, eb, 1, 1, chunk=C, **kw)(
                 blocks, frontier),
-            hop.build_traverse_fn_local(P, eb, 1, 1, chunk=STRAIGHT, **kw)(
+            hop.build_traverse_fn(None, P, eb, 1, 1, chunk=STRAIGHT, **kw)(
                 blocks, frontier), (total, eb))
         assert got["hop_edges"][0, 0] == total
         need = -(-min(total, eb) // C)             # chunks the hop fills
@@ -131,9 +135,9 @@ def test_hops_of_different_budgets_feed_each_other():
     frontier[:, 1:5] = True
     kw = dict(pred=_w_over_50, pred_cols=("w", "_rank"), yield_cols=("w",))
     got = _same(
-        hop.build_traverse_fn_local(P, ebs, 3, 2, chunk=C, **kw)(
+        hop.build_traverse_fn(None, P, ebs, 3, 2, chunk=C, **kw)(
             blocks, frontier),
-        hop.build_traverse_fn_local(P, ebs, 3, 2, chunk=STRAIGHT, **kw)(
+        hop.build_traverse_fn(None, P, ebs, 3, 2, chunk=STRAIGHT, **kw)(
             blocks, frontier))
     assert (got["chunks_budget"][:, 0] == 0).all()
     assert (got["chunks_budget"][:, 1] == 2 * 4).all()       # no capture
@@ -148,9 +152,11 @@ def test_lanes_vmap_runs_to_the_fullest_lane():
     frontier = np.stack([_frontier(t) for t in totals])
     kw = dict(yield_cols=("f", "w"))
     got = _same(
-        hop.build_traverse_fn_lanes(P, EB, 1, 1, chunk=C, **kw)(
+        hop.build_traverse_fn(None, P, EB, 1, 1, lanes=True, chunk=C,
+                              **kw)(
             blocks, frontier),
-        hop.build_traverse_fn_lanes(P, EB, 1, 1, chunk=STRAIGHT, **kw)(
+        hop.build_traverse_fn(None, P, EB, 1, 1, lanes=True,
+                              chunk=STRAIGHT, **kw)(
             blocks, frontier))
     assert list(got["hop_edges"][:, 0, 0]) == totals
     assert list(got["chunks_run"][:, 0, 0]) == [0, 2, 4, 8]
@@ -183,11 +189,10 @@ def test_lanes_by_shards_grid():
     frontier = np.stack([_frontier(t) for t in (1, EB, C - 1, 0)])
     kw = dict(pred=_w_over_50, pred_cols=("w",), yield_cols=("w",))
     _same(
-        hop.build_traverse_fn_lanes_sharded(mesh, P, EB, 2, 1, chunk=C,
-                                            **kw)(blocks, frontier),
-        hop.build_traverse_fn_lanes_sharded(mesh, P, EB, 2, 1,
-                                            chunk=STRAIGHT, **kw)(
-            blocks, frontier))
+        hop.build_traverse_fn(mesh, P, EB, 2, 1, lanes=True, chunk=C,
+                              **kw)(blocks, frontier),
+        hop.build_traverse_fn(mesh, P, EB, 2, 1, lanes=True,
+                              chunk=STRAIGHT, **kw)(blocks, frontier))
 
 
 # -- the runtime's own inputs: delta plane, hubs, frames, the ladder ------
@@ -216,8 +221,8 @@ def paired(monkeypatch):
                 return got
             return fn
         return both
-    for name in BUILDERS:
-        monkeypatch.setattr(runtime, name, pair(getattr(hop, name)))
+    monkeypatch.setattr(runtime, "build_traverse_fn",
+                        pair(hop.build_traverse_fn))
     return seen
 
 
@@ -313,9 +318,9 @@ def test_chunk_counters_move_only_when_a_loop_ran(monkeypatch):
     assert rows and moved() == before
     assert (ts.chunks_run, ts.chunks_budget) == (0, 0)
 
-    small = hop.build_traverse_fn_local
+    small = hop.build_traverse_fn
     monkeypatch.setattr(
-        runtime, "build_traverse_fn_local",
+        runtime, "build_traverse_fn",
         lambda *a, **kw: small(*a, chunk=64, **kw))
     rt2 = TpuRuntime(make_mesh(1))
     rows2, ts2 = rt2.traverse(st, "g", vids, ["knows"], "out", 2)

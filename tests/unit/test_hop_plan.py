@@ -7,9 +7,9 @@ kept count — while `plan_run` / `plan_budget` say what it issued.
 
 The blocks are the runtime's own, captured from a pinned store (plain,
 degree-split with hub rows, delta plane live); the frontiers are made
-here.  The threshold is small through the builders' `plan_chunk`
+here.  The threshold is small through the builder's `plan_chunk`
 argument (the module constant is 2^14 ids); the whole-bitmap program is
-the same builder with a `plan_chunk` no bitmap exceeds.
+the same layout with a `plan_chunk` no bitmap exceeds.
 """
 import functools
 
@@ -26,7 +26,7 @@ from nebula_tpu.tpu import TpuRuntime, make_mesh, runtime    # noqa: E402
 from nebula_tpu.tpu import hop                               # noqa: E402
 
 from test_delta import store_p                               # noqa: E402
-from test_hop_by_need import BUILDERS, GO_Q, IDENT, META, _rows  # noqa: E402
+from test_hop_by_need import GO_Q, IDENT, LAYOUTS, META, _rows  # noqa: E402
 from test_tpu import _hubby_store                            # noqa: E402
 
 PC = 64                 # two words of 32 ids a trip
@@ -44,18 +44,19 @@ def _plane(name):
     """The kernel inputs of one pinned store, as the runtime hands them
     to a traverse program: (blocks on the host, builder keywords, P)."""
     seen = []
-    real = hop.build_traverse_fn_local
+    real = hop.build_traverse_fn
 
     def capturing(*a, **kw):
         fn = real(*a, **kw)
 
         def run(blocks, frontier):
-            seen.append((kw, jax.device_get(blocks)))
+            seen.append(({k: v for k, v in kw.items() if k != "lanes"},
+                         jax.device_get(blocks)))
             return fn(blocks, frontier)
         return run
     cfg = get_config()
     cfg.set_dynamic_many(FLAGS.get(name, {}))
-    runtime.build_traverse_fn_local = capturing
+    runtime.build_traverse_fn = capturing
     try:
         st = _hubby_store(n=600) if name == "hubs" else store_p(2, n=600)
         rt = TpuRuntime(make_mesh(1))
@@ -72,7 +73,7 @@ def _plane(name):
             _rows(eng, GO_Q)
             assert stats().snapshot().get("tpu_pins", 0) == pins
     finally:
-        runtime.build_traverse_fn_local = real
+        runtime.build_traverse_fn = real
         with cfg.lock:
             for k in FLAGS.get(name, {}):
                 cfg.dynamic_layer.pop(k, None)
@@ -114,17 +115,18 @@ def _frontier(case, blocks, kw, P, seed=0):
 
 
 @functools.lru_cache(maxsize=None)
-def _program(builder, plane, plan_chunk, ebs=EBS):
+def _program(layout, plane, plan_chunk, ebs=EBS):
     blocks, kw, P = _plane(plane)
-    args = (P, ebs, len(ebs), len(blocks))
-    if builder in ("build_traverse_fn", "build_traverse_fn_lanes_sharded"):
-        lanes = 2 if builder.endswith("sharded") and 2 * P <= 8 else 1
-        devs = np.asarray(jax.devices()[:lanes * P])
+    meshed, lanes = layout
+    mesh = None
+    if meshed:
+        rows = 2 if lanes and 2 * P <= 8 else 1
+        devs = np.asarray(jax.devices()[:rows * P])
         from jax.sharding import Mesh
-        mesh = (Mesh(devs.reshape(lanes, P), ("lane", "part"))
-                if builder.endswith("sharded") else Mesh(devs, ("part",)))
-        args = (mesh,) + args
-    return getattr(hop, builder)(*args, plan_chunk=plan_chunk, **kw)
+        mesh = (Mesh(devs.reshape(rows, P), ("lane", "part"))
+                if lanes else Mesh(devs, ("part",)))
+    return hop.build_traverse_fn(mesh, P, ebs, len(ebs), len(blocks),
+                                 lanes=lanes, plan_chunk=plan_chunk, **kw)
 
 
 def _same(got, want, tag):
@@ -148,47 +150,47 @@ def _same(got, want, tag):
     return got
 
 
-def _inputs(builder, case, plane):
+def _inputs(layout, case, plane):
     blocks, kw, P = _plane(plane)
     f = _frontier(case, blocks, kw, P)
-    if "lanes" in builder:      # the case beside a sparse lane
+    if layout[1]:               # the case beside a sparse lane
         f = np.stack([f, _frontier("sparse", blocks, kw, P, seed=1)])
     return blocks, f
 
 
 @pytest.mark.parametrize("plane", PLANES)
 @pytest.mark.parametrize("case", FRONTIERS)
-@pytest.mark.parametrize("builder", BUILDERS)
+@pytest.mark.parametrize("layout", LAYOUTS)
 def test_member_plan_returns_what_the_whole_bitmap_plan_returns(
-        builder, case, plane):
-    blocks, f = _inputs(builder, case, plane)
-    got = _same(_program(builder, plane, PC)(blocks, f),
-                _program(builder, plane, WHOLE)(blocks, f),
-                (builder, case, plane))
+        layout, case, plane):
+    blocks, f = _inputs(layout, case, plane)
+    got = _same(_program(layout, plane, PC)(blocks, f),
+                _program(layout, plane, WHOLE)(blocks, f),
+                (layout, case, plane))
     width = blocks[0]["indptr"].shape[1] - 1      # hub rows and all
     assert (got["plan_budget"] == 2 * width * len(blocks)).all()
     assert (got["plan_run"] > 0).all()
     assert (got["plan_run"] < got["plan_budget"]).all()
     if case != "empty":
         assert got["hop_edges"].sum() > 0
-    if case == "every" and "lanes" not in builder:
+    if case == "every" and not layout[1]:
         assert got["frontier_sizes"][..., 0].sum() == f.sum()
 
 
 @pytest.mark.parametrize("plane", PLANES)
-@pytest.mark.parametrize("builder", BUILDERS)
+@pytest.mark.parametrize("layout", LAYOUTS)
 def test_more_expanding_vertices_than_slots_flags_the_overflow(
-        builder, plane):
+        layout, plane):
     """EB = 16 under a frontier of every vertex: more members than
     slots.  Only those whose first slot lies below EB are listed; the
     flag is raised, the rows are the whole-bitmap program's and no
     index leaves its table."""
-    blocks, f = _inputs(builder, "every", plane)
+    blocks, f = _inputs(layout, "every", plane)
     ebs = (16, 16)
-    got = _same(_program(builder, plane, PC, ebs)(blocks, f),
-                _program(builder, plane, WHOLE, ebs)(blocks, f),
-                (builder, plane))
-    lane0 = (0,) if "lanes" in builder else ()    # the every-vertex lane
+    got = _same(_program(layout, plane, PC, ebs)(blocks, f),
+                _program(layout, plane, WHOLE, ebs)(blocks, f),
+                (layout, plane))
+    lane0 = (0,) if layout[1] else ()             # the every-vertex lane
     assert got["ovf_expand"][lane0].all()
     assert (got["hop_edges"][lane0][..., 0] > 16).all()
     emax = blocks[0]["nbr"].shape[-1] + (
@@ -217,16 +219,15 @@ def _host_plan_updates(indptr, f, EB, plan_chunk, together):
 
 
 @pytest.mark.parametrize("case", FRONTIERS)
-@pytest.mark.parametrize("builder", ["build_traverse_fn",
-                                     "build_traverse_fn_local"])
-def test_plan_counters_count_the_updates_issued(builder, case):
+@pytest.mark.parametrize("layout", LAYOUTS[:2])
+def test_plan_counters_count_the_updates_issued(layout, case):
     """One chip runs the parts in one loop, to the fullest part's trip
     count; a shard runs its own."""
     blocks, kw, P = _plane("plain")
     f = _frontier(case, blocks, kw, P)
-    got = jax.device_get(_program(builder, "plain", PC)(blocks, f))
+    got = jax.device_get(_program(layout, "plain", PC)(blocks, f))
     want = _host_plan_updates(blocks[0]["indptr"], f, EBS[0], PC,
-                              together=builder.endswith("local"))
+                              together=not layout[0])
     assert got["plan_run"][:, 0].tolist() == want.tolist()
     assert (got["plan_budget"] == 2 * f.shape[1]).all()
     if case == "every":     # every word listed: a scatter's worth, plus the list
@@ -241,7 +242,7 @@ def test_a_narrow_bitmap_compiles_the_whole_bitmap_plan():
     blocks, kw, P = _plane("plain")
     f = _frontier("sparse", blocks, kw, P)
     assert f.shape[1] <= hop.PLAN_CHUNK
-    fn = hop.build_traverse_fn_local(P, EBS, 2, len(blocks), **kw)
+    fn = hop.build_traverse_fn(None, P, EBS, 2, len(blocks), **kw)
     got = jax.device_get(fn(blocks, f))
     assert not got["plan_run"].any() and not got["plan_budget"].any()
     assert got["plan_run"].shape == got["chunks_run"].shape == (P, 2)
@@ -275,11 +276,10 @@ def small_plans(monkeypatch):
     """Every traverse program the runtime builds lays its plans out
     from the members (plan_chunk 32: one word a trip), and
     `_expand_block` with them."""
-    for name in BUILDERS:
-        real = getattr(hop, name)
-        monkeypatch.setattr(
-            runtime, name,
-            lambda *a, _real=real, **kw: _real(*a, plan_chunk=32, **kw))
+    real = hop.build_traverse_fn
+    monkeypatch.setattr(
+        runtime, "build_traverse_fn",
+        lambda *a, **kw: real(*a, plan_chunk=32, **kw))
     monkeypatch.setattr(hop, "PLAN_CHUNK", 32)
 
 
@@ -313,9 +313,9 @@ def test_plan_counters_move_only_over_a_wide_bitmap(monkeypatch):
     assert rows and moved() == before
     assert (ts.plan_run, ts.plan_budget) == (0, 0)
 
-    small = hop.build_traverse_fn_local
+    small = hop.build_traverse_fn
     monkeypatch.setattr(
-        runtime, "build_traverse_fn_local",
+        runtime, "build_traverse_fn",
         lambda *a, **kw: small(*a, plan_chunk=64, **kw))
     rows2, ts2 = TpuRuntime(make_mesh(1)).traverse(
         st, "g", vids, ["knows"], "out", 2)

@@ -121,10 +121,45 @@ def wait_leader(parts, timeout=20.0):
     raise AssertionError("no unique leader elected")
 
 
-def wait_applied(apps, want, timeout=20.0, exclude=()):
+def commit(parts, data, retried, timeout=60.0):
+    """Commit `data` through whichever live member leads NOW, as a real
+    client does, and return that leader: these groups elect within 50
+    to 120 ms, so on a busy machine the leadership can move between a
+    look at `is_leader()` and the propose, and a follower refuses at
+    once (propose contract: None -> retry).  A try waits 20 s, so a
+    slow commit is never retried, only a refusal or a deposal; `data`
+    then joins `retried`, because the first try's entry may still commit
+    under the next leader and only such an entry may be applied twice."""
+    dl = time.monotonic() + timeout
+    tries = 0
+    while True:
+        ld = next((p for p in parts if p.alive and p.is_leader()), None)
+        if ld is not None:
+            tries += 1
+            if tries > 1:
+                retried.add(data)
+            if ld.propose(data, timeout=20):
+                return ld
+        assert time.monotonic() < dl, f"{data!r} never committed"
+        time.sleep(0.02)
+
+
+def applied(app, retried=()):
+    """What `app` applied, in order.  An entry in `retried` (`commit`)
+    is read once however often it was applied; every other entry counts
+    each time, so a run without a retry is held to exactly-once."""
+    seen, out = set(), []
+    for d in app.data():
+        if not (d in retried and d in seen):
+            out.append(d)
+        seen.add(d)
+    return out
+
+
+def wait_applied(apps, want, timeout=20.0, exclude=(), retried=()):
     dl = time.monotonic() + timeout
     while time.monotonic() < dl:
-        if all(a.data() == want for i, a in enumerate(apps)
+        if all(applied(a, retried) == want for i, a in enumerate(apps)
                if i not in exclude):
             return
         time.sleep(0.01)
@@ -160,27 +195,25 @@ def test_single_node_group(tmp_path):
 
 def test_leader_failover_and_catchup(tmp_path):
     tr, parts, apps = make_cluster(tmp_path)
+    retried = set()
     try:
-        leader = wait_leader(parts)
-        # generous timeout: on a starved 2-core VM under full-suite
-        # load a commit can exceed the 5s default while still in
-        # flight — timing out would retry and double-apply
-        assert leader.propose(b"a", timeout=20)
-        wait_applied(apps, [b"a"])
+        # against the current leader with a retry: under full-suite
+        # load the leadership moves between electing and proposing
+        leader = commit(parts, b"a", retried)
+        wait_applied(apps, [b"a"], retried=retried)
         # kill the leader; a new one takes over and accepts writes
         dead = parts.index(leader)
         leader.alive = False
         rest = [p for p in parts if p is not leader]
-        new_leader = wait_leader(rest)
-        assert new_leader.propose(b"b", timeout=20)
-        wait_applied(apps, [b"a", b"b"], exclude=(dead,))
+        new_leader = commit(rest, b"b", retried)
+        wait_applied(apps, [b"a", b"b"], exclude=(dead,), retried=retried)
         # old leader rejoins as follower and catches up
         parts[dead].state = "follower"
         parts[dead].alive = True
         parts[dead]._thread = threading.Thread(
             target=parts[dead]._run, daemon=True)
         parts[dead]._thread.start()
-        wait_applied(apps, [b"a", b"b"])
+        wait_applied(apps, [b"a", b"b"], retried=retried)
         assert not parts[dead].is_leader() or parts[dead].current_term >= \
             new_leader.current_term
     finally:
@@ -189,6 +222,7 @@ def test_leader_failover_and_catchup(tmp_path):
 
 def test_partition_minority_cannot_commit(tmp_path):
     tr, parts, apps = make_cluster(tmp_path)
+    retried = set()
     try:
         leader = wait_leader(parts)
         others = [p for p in parts if p is not leader]
@@ -199,29 +233,24 @@ def test_partition_minority_cannot_commit(tmp_path):
         # Retry-against-current-leader like a real client: the first
         # majority-side leader can be deposed by a concurrent election
         # before the propose lands (propose contract: None -> retry).
-        deadline = time.time() + 15
-        while True:
-            new_leader = wait_leader(others)
-            if new_leader.propose(b"kept"):
-                break
-            assert time.time() < deadline, "majority never committed"
+        commit(others, b"kept", retried)
         tr.heal()
-        wait_applied(apps, [b"kept"])
+        wait_applied(apps, [b"kept"], retried=retried)
         # the isolated leader's uncommitted entry must be discarded
-        assert apps[parts.index(leader)].data() == [b"kept"]
+        assert applied(apps[parts.index(leader)], retried) == [b"kept"]
     finally:
         stop_all(parts)
 
 
 def test_restart_replays_from_wal(tmp_path):
     tr, parts, apps = make_cluster(tmp_path)
+    retried = set()
     try:
-        leader = wait_leader(parts)
-        for i in range(5):
-            # starved-VM tolerance: see test_leader_failover_and_catchup
-            assert leader.propose(f"v{i}".encode(), timeout=20)
         want = [f"v{i}".encode() for i in range(5)]
-        wait_applied(apps, want)
+        for d in want:
+            # starved-VM tolerance: see test_leader_failover_and_catchup
+            commit(parts, d, retried)
+        wait_applied(apps, want, retried=retried)
     finally:
         stop_all(parts)
     # restart node 0 from its WAL dir with a fresh state machine
@@ -233,7 +262,7 @@ def test_restart_replays_from_wal(tmp_path):
     try:
         wait_leader([p0])
         assert p0.propose(b"after")
-        assert app.data() == [f"v{i}".encode() for i in range(5)] + [b"after"]
+        assert applied(app, retried) == want + [b"after"]
     finally:
         p0.stop()
 

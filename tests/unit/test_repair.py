@@ -73,13 +73,50 @@ def _wait_leader(parts, timeout=20.0):
     raise AssertionError("no unique leader elected")
 
 
-def _wait_data(app, want, timeout=20.0):
+def _applied(app, retried=()):
+    """What `app` applied, in order.  An entry in `retried` (`_commit`)
+    is read once however often it was applied; every other entry counts
+    each time, so a run without a retry is held to exactly-once."""
+    seen, out = set(), []
+    for d in app.data():
+        if not (d in retried and d in seen):
+            out.append(d)
+        seen.add(d)
+    return out
+
+
+def _wait_data(app, want, retried=(), timeout=20.0):
     dl = time.monotonic() + timeout
     while time.monotonic() < dl:
-        if app.data() == want:
+        if _applied(app, retried) == want:
             return
         time.sleep(0.01)
     raise AssertionError(f"want {want}, got {app.data()}")
+
+
+def _commit(members, data, retried, timeout=60.0):
+    """Commit `data` through whichever member leads NOW, as a real
+    client does, and return that leader.  These groups elect within 50
+    to 120 ms, so on a busy machine (six xdist workers) a heartbeat
+    thread that is starved that long moves the leadership between a
+    look at `is_leader()` and the propose, and a follower refuses at
+    once: waiting on the clock failed, retrying on the state does not.
+    A try waits 20 s, so only a refusal or a deposal makes a second
+    one; `data` then joins `retried`, because the first try's entry may
+    still commit under the next leader (raft is at-least-once to a
+    client that retries) and only such an entry may be applied twice."""
+    dl = time.monotonic() + timeout
+    tries = 0
+    while True:
+        ld = next((p for p in members if p.alive and p.is_leader()), None)
+        if ld is not None:
+            tries += 1
+            if tries > 1:
+                retried.add(data)
+            if ld.propose(data, timeout=20):
+                return ld
+        assert time.monotonic() < dl, f"{data!r} never committed"
+        time.sleep(0.02)
 
 
 def test_learner_replicates_but_never_counts_toward_quorum(tmp_path):
@@ -88,17 +125,17 @@ def test_learner_replicates_but_never_counts_toward_quorum(tmp_path):
     substitute for a voter (quorum stays 2-of-2 voters)."""
     tr, parts, apps = _mixed_group(tmp_path, n_voters=2, n_learners=1)
     v0, v1, lrn = parts
+    retried = set()
     try:
-        leader = _wait_leader([v0, v1])
-        assert leader.propose(b"a", timeout=20)
+        leader = _commit([v0, v1], b"a", retried)
         # the learner received and applied the entry (replication works)
-        _wait_data(apps[2], [b"a"])
+        _wait_data(apps[2], [b"a"], retried)
         # kill the OTHER voter: voter quorum is gone; the live learner
         # must not let the leader commit
         other = v1 if leader is v0 else v0
         other.alive = False
         assert leader.propose(b"b", timeout=0.6) is None
-        assert b"b" not in apps[0].data() + apps[1].data()
+        assert not any(b"b" in a.data() for a in apps)
     finally:
         for p in parts:
             p.stop()
@@ -134,26 +171,19 @@ def test_learner_promote_then_counts_and_votes(tmp_path):
     quorum and commits flow again."""
     tr, parts, apps = _mixed_group(tmp_path, n_voters=2, n_learners=1)
     v0, v1, lrn = parts
+    retried = set()
     try:
-        leader = _wait_leader([v0, v1])
-        assert leader.propose(b"a", timeout=20)
-        _wait_data(apps[2], [b"a"])     # caught up
+        leader = _commit([v0, v1], b"a", retried)
+        _wait_data(apps[2], [b"a"], retried)    # caught up
         fail.reset()
         for p in parts:
             p.update_peers(["v0", "v1", "l0"], [])
         other = v1 if leader is v0 else v0
         other.alive = False
-        # retry against the current leader like a real client: the
-        # config change may race a heartbeat round
-        dl = time.monotonic() + 15
-        while True:
-            live = [p for p in (v0, v1, lrn) if p.alive]
-            ld = next((p for p in live if p.is_leader()), None)
-            if ld is not None and ld.propose(b"b", timeout=2):
-                break
-            assert time.monotonic() < dl, "promoted group never committed"
-            time.sleep(0.05)
-        _wait_data(apps[2], [b"a", b"b"])
+        # against the current leader, whoever it is: the config change
+        # may race a heartbeat round
+        _commit([v0, v1, lrn], b"b", retried)
+        _wait_data(apps[2], [b"a", b"b"], retried)
     finally:
         for p in parts:
             p.stop()
@@ -165,12 +195,12 @@ def test_learner_snapshot_install_catchup(tmp_path):
     tr, parts, apps = _mixed_group(tmp_path, n_voters=2, n_learners=0,
                                    snapshot=True, snapshot_threshold=10)
     v0, v1 = parts
+    retried = set()
     try:
-        leader = _wait_leader(parts)
         want = []
         for i in range(25):             # > snapshot_threshold
             d = f"e{i}".encode()
-            assert leader.propose(d, timeout=20)
+            leader = _commit(parts, d, retried)
             want.append(d)
         dl = time.monotonic() + 10
         while leader.snap_index == 0 and time.monotonic() < dl:
@@ -194,7 +224,7 @@ def test_learner_snapshot_install_catchup(tmp_path):
             p.update_peers(["v0", "v1"], ["l0"])
         dl = time.monotonic() + 15
         while time.monotonic() < dl:
-            got = app.data()
+            got = _applied(app, retried)
             if got and got == want[-len(got):] and \
                     lrn.applied_index() >= leader.applied_index():
                 break
@@ -268,9 +298,13 @@ def test_membership_change_resumes_after_each_phase_kill(tmp_path):
         sid = c.storageds[0].meta.catalog.get_space("mv").space_id
         for pid, (src, dst) in moved.items():
             ss_src = c.storageds[addrs.index(src)]
-            deadline = time.monotonic() + 10
+            # the reconcile that drops a part pops it under the parts
+            # lock and stops and clears it after (storage_service.py
+            # reconcile_parts), maybe on another thread: wait for both
+            deadline = time.monotonic() + 30
             while time.monotonic() < deadline:
-                if (sid, pid) not in ss_src.parts:
+                if (sid, pid) not in ss_src.parts and not \
+                        ss_src.store.space("mv").parts[pid].vertices:
                     break
                 ss_src.reconcile_parts()
                 time.sleep(0.1)
@@ -301,6 +335,7 @@ def test_fresh_meta_leader_reports_unknown_not_dead(tmp_path):
     one full heartbeat interval of leadership has elapsed — and the
     supervisor must not create any repair plan inside that window."""
     from nebula_tpu.cluster.launcher import LocalCluster
+    from nebula_tpu.cluster.rpc import RpcError
     c = LocalCluster(n_meta=3, n_storage=2, n_graph=1,
                      data_dir=str(tmp_path))
     get_config().set_dynamic_many({"heartbeat_interval_secs": 3.0,
@@ -319,30 +354,44 @@ def test_fresh_meta_leader_reports_unknown_not_dead(tmp_path):
             mc.stop_heartbeat()
         old = c.meta_leader_index()
         assert old >= 0
+        # the grace is one heartbeat interval of leadership, read live:
+        # a minute of it, so that however long a busy machine takes to
+        # elect the successor and replay its log, the window is still
+        # open when the hosts are judged (a 3 s grace under a 2 s wait
+        # failed under six xdist workers); it is closed below by
+        # shortening the interval, not by waiting it out
+        get_config().set_dynamic("heartbeat_interval_secs", 60.0)
         c.stop_metad(old)
-        deadline = time.monotonic() + 15
-        new_leader = None
-        while time.monotonic() < deadline:
-            idx = c.meta_leader_index()
-            if idx >= 0 and idx != old:
-                new_leader = c.metads[idx]
-                break
-            time.sleep(0.02)
-        assert new_leader is not None, "no successor elected"
+
+        def ask(method):
+            """The successor's answer, waited for: on a busy machine the
+            leadership can move once more between a look at it and a
+            call, and a deposed metad answers `not leader`."""
+            deadline = time.monotonic() + 30
+            while True:
+                idx = c.meta_leader_index()
+                if idx >= 0 and idx != old:
+                    try:
+                        return getattr(c.metads[idx], method)({})
+                    except RpcError:
+                        pass
+                assert time.monotonic() < deadline, "no successor elected"
+                time.sleep(0.02)
+
+        def storage_hosts():
+            return [h for h in ask("rpc_list_hosts")
+                    if h["role"] == "storage"]
         # the new leader may still be applying its log backlog; the
         # part-map hosts must surface (as UNKNOWN) within the grace
-        deadline = time.monotonic() + 2.0
-        storage = []
-        while time.monotonic() < deadline:
-            storage = [h for h in new_leader.rpc_list_hosts({})
-                       if h["role"] == "storage"]
-            if len(storage) == 2:
-                break
+        deadline = time.monotonic() + 30
+        storage = storage_hosts()
+        while len(storage) != 2 and time.monotonic() < deadline:
             time.sleep(0.02)
+            storage = storage_hosts()
         assert len(storage) == 2, storage
         assert all(h["status"] == "UNKNOWN" for h in storage), storage
-        assert all(not h["alive"] for h in storage), hosts
-        assert new_leader.rpc_list_repairs({}) == []
+        assert all(not h["alive"] for h in storage), storage
+        assert ask("rpc_list_repairs") == []
         # SHOW HOSTS renders the same verdict through the client
         rs = client.execute("SHOW HOSTS STORAGE")
         assert rs.error is None, rs.error
@@ -350,13 +399,13 @@ def test_fresh_meta_leader_reports_unknown_not_dead(tmp_path):
             rs.data.rows
         # after the grace (one heartbeat interval) + expiry with still
         # no heartbeats, the verdict hardens to OFFLINE
-        deadline = time.monotonic() + 10
-        while time.monotonic() < deadline:
-            hosts = [h for h in new_leader.rpc_list_hosts({})
-                     if h["role"] == "storage"]
-            if all(h["status"] == "OFFLINE" for h in hosts):
-                break
+        get_config().set_dynamic("heartbeat_interval_secs", 0.2)
+        deadline = time.monotonic() + 30
+        hosts = storage_hosts()
+        while not all(h["status"] == "OFFLINE" for h in hosts) \
+                and time.monotonic() < deadline:
             time.sleep(0.1)
+            hosts = storage_hosts()
         assert all(h["status"] == "OFFLINE" for h in hosts), hosts
     finally:
         get_config().set_dynamic_many({"heartbeat_interval_secs": 1.0,

@@ -409,6 +409,12 @@ def _emitted_metric_names():
         (REPO / "nebula_tpu/cluster/storage_service.py").read_text()
     names.update({"storage_pushdown_scanned",
                   "storage_pushdown_shipped"})
+    # the hop programs' engagement counters are one loop over the
+    # programs' result keys (tpu/runtime.py `_ENGAGEMENT`)
+    from nebula_tpu.tpu.runtime import _ENGAGEMENT
+    assert 'm.inc(f"tpu_hop_{k}"' in \
+        (REPO / "nebula_tpu/tpu/runtime.py").read_text()
+    names.update(f"tpu_hop_{k}" for k in _ENGAGEMENT)
     # the statement phase ledger is one batched update by constant
     # names (utils/trace.py); process_cpu_s exists in snapshots only
     from nebula_tpu.utils import trace
@@ -449,10 +455,12 @@ def test_metric_catalogue_lint():
 def _emitted_span_names():
     """Every span / phase / root-trace name the source tree emits,
     with dynamic f-string segments (`{node.kind}`) normalized to `*`
-    so `exec:{node.kind}` and the catalogue's `exec:*` compare equal."""
+    so `exec:{node.kind}` and the catalogue's `exec:*` compare equal.
+    A device launch opens its phases' spans by name through
+    `TpuRuntime._phase(phases, name)`."""
     pat = re.compile(
-        r'(?:trace|_trace|_t)\.(?:span|record_phase|mark|start_trace)\(\s*'
-        r'(f?)["\']([^"\']+)["\']')
+        r'(?:(?:trace|_trace|_t)\.(?:span|record_phase|mark|start_trace)\('
+        r'|\._phase\(phases,)\s*(f?)["\']([^"\']+)["\']')
     names = set()
     for p in (REPO / "nebula_tpu").rglob("*.py"):
         for isf, name in pat.findall(p.read_text()):

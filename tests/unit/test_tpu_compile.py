@@ -82,15 +82,34 @@ def _compile(fn, *args):
     return compiled, time.perf_counter() - t0
 
 
-def test_go_three_steps_capture_compiles(one_chip):
+@pytest.mark.parametrize("meshed,lanes", [
+    (False, False), (True, False), (False, True), (True, True)],
+    ids=["one-chip", "mesh", "one-chip-lanes", "mesh-lanes"])
+def test_go_three_steps_capture_compiles(topo, one_chip, meshed, lanes):
     """The north-star statement: 3-step GO, final hop captured with an
-    int64 prop gathered on device (YIELD dst(edge), KNOWS.w)."""
-    from nebula_tpu.tpu.hop import build_traverse_fn_local
-    fn = build_traverse_fn_local(P8, (1 << 12, 1 << 17, 1 << 22), 3,
-                                 n_blocks=1, capture=True,
-                                 yield_cols=("w",))
-    _compile(fn, (_block(P8, VMAX8, E8, one_chip),),
-             _struct((P8, VMAX8), np.bool_, one_chip))
+    int64 prop gathered on device (YIELD dst(edge), KNOWS.w), in each
+    layout of the one builder entry: all eight parts on one chip, or one
+    partition per chip of the 2x2 host, whose frontier exchange must
+    still be an all-to-all in the compiled program (ONE a hop also
+    under four query lanes); solo, or four lanes to a launch."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from nebula_tpu.tpu.hop import build_traverse_fn
+    L = (4,) if lanes else ()
+    if meshed:
+        mesh = Mesh(np.asarray(topo.devices[:P4]), ("part",))
+        part = NamedSharding(mesh, PartitionSpec("part"))
+        fr = NamedSharding(mesh, PartitionSpec(None, "part") if lanes
+                           else PartitionSpec("part"))
+        P, vmax, E, ebs = P4, VMAX4, E4, (1 << 12, 1 << 17, 1 << 21)
+    else:
+        mesh, part, fr = None, one_chip, one_chip
+        P, vmax, E, ebs = P8, VMAX8, E8, (1 << 12, 1 << 17, 1 << 22)
+    fn = build_traverse_fn(mesh, P, ebs, 3, n_blocks=1, lanes=lanes,
+                           capture=True, yield_cols=("w",))
+    compiled, _ = _compile(fn, (_block(P, vmax, E, part),),
+                           _struct(L + (P, vmax), np.bool_, fr))
+    assert (compiled.as_text().count(" all-to-all(") == 2) == meshed
 
 
 def test_proxy_cell_go3_by_need_loops_compile(one_chip):
@@ -100,11 +119,11 @@ def test_proxy_cell_go3_by_need_loops_compile(one_chip):
     the float64 `f` ride the loop carry as 32-bit pairs on a TPU).  A
     loop the TPU compiler rejects, or takes minutes over, fails here
     and not first on the chip."""
-    from nebula_tpu.tpu.hop import CHUNK, build_traverse_fn_local
+    from nebula_tpu.tpu.hop import CHUNK, build_traverse_fn
     ebs = (1 << 11, 1 << 20, 1 << 22)
     assert ebs[0] <= CHUNK < ebs[1], "the cell no longer exercises the loop"
-    fn = build_traverse_fn_local(P8, ebs, 3, n_blocks=1, capture=True,
-                                 yield_cols=("f", "w"))
+    fn = build_traverse_fn(None, P8, ebs, 3, n_blocks=1, capture=True,
+                           yield_cols=("f", "w"))
     compiled, secs = _compile(
         fn, (_block(P8, VMAX8, E8, one_chip, props=("f", "w")),),
         _struct((P8, VMAX8), np.bool_, one_chip))
@@ -115,26 +134,11 @@ def test_proxy_cell_go3_by_need_loops_compile(one_chip):
 
 def test_match_var_len_capture_hops_compiles(one_chip):
     """MATCH *1..4: four hops, every hop's frame captured."""
-    from nebula_tpu.tpu.hop import build_traverse_fn_local
-    fn = build_traverse_fn_local(P8, 1 << 20, 4, n_blocks=1,
-                                 capture=True, capture_hops=True)
+    from nebula_tpu.tpu.hop import build_traverse_fn
+    fn = build_traverse_fn(None, P8, 1 << 20, 4, n_blocks=1,
+                           capture=True, capture_hops=True)
     _compile(fn, (_block(P8, VMAX8, E8, one_chip, props=()),),
              _struct((P8, VMAX8), np.bool_, one_chip))
-
-
-def test_sharded_go_compiles_with_all_to_all(topo):
-    """One partition per chip on the 2x2 host: the frontier exchange
-    must still be an all-to-all in the compiled program."""
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec
-
-    from nebula_tpu.tpu.hop import build_traverse_fn
-    mesh = Mesh(np.asarray(topo.devices[:P4]), ("part",))
-    part = NamedSharding(mesh, PartitionSpec("part"))
-    fn = build_traverse_fn(mesh, P4, (1 << 12, 1 << 17, 1 << 21), 3,
-                           n_blocks=1, capture=True, yield_cols=("w",))
-    compiled, _ = _compile(fn, (_block(P4, VMAX4, E4, part),),
-                           _struct((P4, VMAX4), np.bool_, part))
-    assert "all-to-all" in compiled.as_text()
 
 
 def test_mesh_cell_go3_compiles_for_the_four_chip_host(topo):
