@@ -141,8 +141,19 @@ def _cat_parts(parts, dtype=None):
     return parts[0].copy()
 
 
-def _cat_prefix(arr, bi, pids, kc, dtype=None):
-    return _cat_parts([arr[p, bi, :kc[p]] for p in pids], dtype)
+def _whole(pieces) -> np.ndarray:
+    """A fetched capture row (its pieces in slot order) as one array."""
+    return pieces[0] if len(pieces) == 1 else np.concatenate(pieces)
+
+
+def _cat_rows(rows, perms=None, dtype=None):
+    """The fetched rows of one capture column, each its pieces in slot
+    order (`_fetch`), as one owned array; `perms` re-orders each row
+    first (the delta plane's canonical CSR order)."""
+    if perms is None:
+        return _cat_parts([a for pieces in rows for a in pieces], dtype)
+    return _cat_parts([_whole(pieces)[pm]
+                       for pieces, pm in zip(rows, perms)], dtype)
 
 
 class _DispatchGate:
@@ -215,6 +226,216 @@ class _DispatchGate:
 # they are the TraverseStats fields of the same names.
 _ENGAGEMENT = ("chunks_run", "chunks_budget", "plan_run", "plan_budget")
 
+# The result leaves some caller reads, the only ones `_fetch` brings to
+# the host besides the capture: the ladder's counts and flags, the kept
+# counts, the work counters, BFS's depths.  The post-final `frontier`
+# bitmap and its `fcount` stay on the device and die with the rung
+# (vmax bools a part: more bytes than a mean four-chip statement's rows).
+_FETCHED = ("hop_edges", "ovf_expand", "kcount", "frontier_sizes",
+            "dist") + _ENGAGEMENT
+
+# How a capture leaves the device (`_fetch`).  A ROW is one index of a
+# capture array's lead + (nb,) axes with its own kept count; kept
+# entries are a prefix of their row (hop.py `_compact_cap`), so only
+# prefixes are shipped, cut by programs that depend on the capture's
+# shape and the columns read alone and are compiled when the traverse
+# program first runs for those columns (`TpuRuntime._warm_fetch`),
+# never when a kept size is first met.
+#
+# W <= SLICE_MAX (the served statements' 8,192 and 65,536 slots): ONE
+# slice of every row, `v[..., :k]`, k a power of two from SLICE_MIN up,
+# speculated from the program's last run.
+#
+# Wider: every row apart, in flat pieces cut on the device that holds
+# it, so the bytes follow each row's own count (not the fullest row's,
+# rounded up, for all) and a 64-bit column comes as 1-D arrays, which
+# the one-chip host moves at 3 GB/s where it moves the slices' [8, k]
+# at 0.2.  What the chips charge (PERF.md section 6, PR 31, has the
+# tables): a piece 0.6 ms on the four-chip host (its launch and a
+# transfer a column) to 3 ms on one chip (a 64-bit operand of 268 MB is
+# split into its halves whole before the slice) however small it is, a
+# byte 0.3 to 0.5 ns, and a second round trip waits behind whatever
+# another session has on the chips.  Hence: a row comes in ONE piece of
+# the smallest of PIECES that holds it unless a second piece saves
+# PIECE_WORTH slots; and a row that last kept at most SPEC_ROWS comes
+# speculatively, one piece of at most SPEC_SLOTS, with the meta (the
+# median statement of the four-chip cell then makes one round trip),
+# while a longer row is not guessed at (a wrong guess would cost more
+# than the round trip, a hundredth of its transfer).
+SLICE_MIN, SLICE_MAX = 1 << 7, 1 << 16
+PIECES = tuple(1 << i for i in range(11, 22))
+PIECE_WORTH = 1 << 16
+SPEC_SLOTS, SPEC_ROWS = 1 << 15, 1 << 17
+
+
+@functools.partial(jax.jit, static_argnames="k")
+def _head(cap, k: int):
+    return {n: v[..., :k] for n, v in cap.items()}
+
+
+@functools.partial(jax.jit, static_argnames="size")
+def _piece(cap, at, size: int):
+    """`size` slots of one row of each of these capture columns, flat,
+    from `at` = the row's index and the first slot, both traced: one
+    executable a capture shape, column set and size.  A start past
+    W - size is clamped to it (`lax.dynamic_slice`)."""
+    return {n: jax.lax.dynamic_slice(
+        v, [at[i] for i in range(v.ndim)],
+        (1,) * (v.ndim - 1) + (size,)).reshape(size)
+        for n, v in cap.items()}
+
+
+def _nbytes(tree) -> int:
+    return sum(a.nbytes for a in jax.tree.leaves(tree))
+
+
+def _taker(cap_dev, want=None):
+    """The way this capture's rows leave the device, by its width; of
+    its columns the host takes those in `want` (all of them if None)."""
+    wide = next(iter(cap_dev.values())).shape[-1] > SLICE_MAX
+    return (_Pieces if wide else _Heads)(cap_dev, want)
+
+
+class _Heads:
+    """The kept prefixes of a capture at most SLICE_MAX slots wide, as
+    one slice of every row.  `speculate(counts)` and `ask(counts)`
+    return the device arrays that cover rows of these kept counts (the
+    last run's, this run's), or None where there is nothing to ask for
+    beyond what was asked before; `got` takes them once on the host;
+    `rows` is the fetched capture: per column an object array over the
+    rows, of each row's pieces in slot order, trimmed to its kept
+    count.  The programs run over the wanted columns together (one
+    launch, not one a column), so they are compiled for a program's
+    key AND the columns its statement reads (`TpuRuntime._warm_fetch`)."""
+
+    def __init__(self, cap_dev, want=None):
+        self.dev = cap_dev
+        self.want = [n for n in cap_dev if want is None or n in want]
+        self.W = next(iter(cap_dev.values())).shape[-1]
+        self.k = 0
+        self.host: Dict[str, np.ndarray] = {}
+        self.nbytes = 0
+
+    def item_bytes(self) -> int:
+        return sum(self.dev[n].dtype.itemsize for n in self.want)
+
+    def _k(self, n: int) -> int:
+        return min(self.W, max(SLICE_MIN, _pow2(n)))
+
+    def warm(self):
+        cols = {n: self.dev[n] for n in self.want}
+        for k in sorted({self._k(1 << i)
+                         for i in range(self.W.bit_length() + 1)}):
+            _head(cols, k)
+
+    def ask(self, counts):
+        k = self._k(int(np.max(counts, initial=0)))
+        if k <= self.k:
+            return None
+        self.k = k
+        return _head({n: self.dev[n] for n in self.want}, k)
+
+    speculate = ask
+
+    def got(self, host):
+        self.host = host
+        self.nbytes += _nbytes(host)
+
+    def rows(self, kc):
+        cap = {}
+        for n, a in self.host.items():
+            col = cap[n] = np.empty(kc.shape, object)
+            for idx in np.ndindex(kc.shape):
+                col[idx] = [a[idx][:kc[idx]]]
+        return cap
+
+
+class _Pieces(_Heads):
+    """The same of a wider capture, every row in pieces cut on the
+    device that holds it: a sharded column is read shard by shard
+    (`addressable_shards`), so no slice crosses chips and `device_get`
+    assembles nothing.  `ask` cuts only what lies past the pieces
+    already asked for: an undershot speculation fetches a tail, never
+    the prefix again."""
+
+    def __init__(self, cap_dev, want=None):
+        super().__init__(cap_dev, want)
+        # where its rows lie in the whole -> a shard's wanted columns
+        self.shards: Dict[Tuple, Dict[str, Any]] = {}
+        for n in self.want:
+            for s in cap_dev[n].addressable_shards:
+                if s.replica_id == 0:
+                    base = tuple(sl.start or 0 for sl in s.index[:-1])
+                    self.shards.setdefault(base, {})[n] = s.data
+        self.sizes = [c for c in PIECES if c <= self.W]
+        self.have: Dict[Tuple, int] = {}    # row -> slots asked for
+        # of each piece asked for: its row, and that it holds the row's
+        # slots [slot, slot + c) from its own `skip` on
+        self.asked: List[Tuple] = []
+        self.host: List[Dict[str, np.ndarray]] = []
+
+    def _size(self, n: int) -> int:
+        """The smallest piece that holds n slots (the largest if none)."""
+        return next((c for c in self.sizes if c >= n), self.sizes[-1])
+
+    def _cut(self, out, cols, idx, row, slot, c):
+        start = min(slot, self.W - c)
+        out.append(_piece(cols, np.asarray(idx + (start,), np.int32), c))
+        self.asked.append((row, slot, slot - start, c))
+        self.have[row] = slot + c
+
+    def _rows(self):
+        for base, cols in self.shards.items():
+            lead = next(iter(cols.values())).shape[:-1]
+            for idx in np.ndindex(lead):
+                yield cols, idx, tuple(b + i for b, i in zip(base, idx))
+
+    def warm(self):
+        at = np.zeros(next(iter(self.dev.values())).ndim, np.int32)
+        for cols in self.shards.values():
+            for c in self.sizes:
+                _piece(cols, at, c)
+
+    def speculate(self, counts):
+        counts = np.broadcast_to(counts, next(
+            iter(self.dev.values())).shape[:-1])
+        out = []
+        for cols, idx, row in self._rows():
+            if 0 < counts[row] <= SPEC_ROWS:
+                self._cut(out, cols, idx, row, 0,
+                          self._size(min(int(counts[row]), SPEC_SLOTS)))
+        return out or None
+
+    def ask(self, counts):
+        out = []
+        for cols, idx, row in self._rows():
+            slot, kept = self.have.get(row, 0), int(counts[row])
+            while slot < kept:
+                c = self._size(kept - slot)
+                half = c // 2
+                if half in self.sizes and kept - slot > half and \
+                        half - self._size(kept - slot - half) >= PIECE_WORTH:
+                    c = half
+                self._cut(out, cols, idx, row, slot, c)
+                slot += c
+        return out or None
+
+    def got(self, host):
+        self.host.extend(host)
+        self.nbytes += _nbytes(host)
+
+    def rows(self, kc):
+        cap = {n: np.empty(kc.shape, object) for n in self.want}
+        for col in cap.values():
+            for row in np.ndindex(kc.shape):
+                col[row] = []
+        for (row, slot, skip, c), piece in zip(self.asked, self.host):
+            end = skip + min(c, int(kc[row]) - slot)
+            if end > skip:
+                for n, col in cap.items():
+                    col[row].append(piece[n][skip:end])
+        return cap
+
 
 class TraverseStats:
     __slots__ = ("hop_edges", "frontier_sizes", "result_edges", "f_cap",
@@ -222,7 +443,8 @@ class TraverseStats:
                  "pin_s", "put_s", "fetch_s", "mat_s", "total_s",
                  "compiles", "hbm_bytes", "segments", "queue_s",
                  "shards", "exchange_bytes", "chunks_run",
-                 "chunks_budget", "plan_run", "plan_budget")
+                 "chunks_budget", "plan_run", "plan_budget",
+                 "fetch_bytes", "fetch_bytes_kept")
 
     def __init__(self):
         self.hop_edges: List[int] = []
@@ -267,6 +489,10 @@ class TraverseStats:
         # plan
         self.plan_run = 0
         self.plan_budget = 0
+        # bytes the launch's fetches brought to the host, and those of
+        # them that are kept capture entries (`_fetch`)
+        self.fetch_bytes = 0
+        self.fetch_bytes_kept = 0
 
     def edges_traversed(self) -> int:
         return int(sum(self.hop_edges))
@@ -441,6 +667,9 @@ class TpuRuntime:
         # speculative single-phase result fetch (one device round trip
         # instead of two for repeat query shapes); in-memory only
         self._kmax: Dict[Tuple, int] = {}
+        # (program key, columns fetched) whose fetch programs are
+        # compiled (`_warm_fetch`); pruned with _kmax
+        self._fetch_warm: set = set()
         # seed-bitmap builder programs (bounded separately from _fns:
         # space-keyed pruning does not reach these target/vmax keys) and
         # the (key, pad bucket) pairs already compiled — the warm call
@@ -509,6 +738,7 @@ class TpuRuntime:
             self.snapshots.clear()
             self._fns.clear()
             self._kmax.clear()
+            self._fetch_warm.clear()
             self._seed_fns.clear()
             self._seed_warm.clear()
             self.mesh = mesh
@@ -921,6 +1151,8 @@ class TpuRuntime:
                          if k[0] != space}
             self._kmax = {k: v for k, v in self._kmax.items()
                           if k[0] != space}
+            self._fetch_warm = {w for w in self._fetch_warm
+                                if w[0][0] != space}
             self._buckets = {k: v for k, v in self._buckets.items()
                              if k[0][0] != space}
         finally:
@@ -1324,7 +1556,8 @@ class TpuRuntime:
         L = seed_pad.shape[0] if lanes else 1
         info: Dict[str, Any] = {
             "lanes": len(lane_dense), "rungs": [], "compiles": 0,
-            "refetches": 0, "gate_wait_us": wait_us, "phases": []}
+            "refetches": 0, "gate_wait_us": wait_us, "phases": [],
+            "fetch_bytes": 0, "fetch_bytes_kept": 0}
         phases, rungs = info["phases"], info["rungs"]
         with self._phase(phases, "device:put"), \
                 self._collective_launch():
@@ -1368,12 +1601,13 @@ class TpuRuntime:
                 jax.block_until_ready(res)
             info["device_s"] = phases[-1][2]
             rungs.append((int(info["device_s"] * 1e6), compiled))
+            if "cap" in res:
+                self._warm_fetch(res["cap"], key, fetch_keys)
             # rebinding `res` releases the rung's device buffers, after
             # the fetch has timed itself; a failed rung's capture is so
             # dropped BEFORE the larger rung runs: holding both nearly
             # doubles peak HBM and can fail the retry
-            res, info["refetches"], info["fetch_s"] = self._fetch(
-                res, key, fetch_keys, phases)
+            res = self._fetch(res, key, fetch_keys, info)
             if not res["ovf_expand"].any():
                 break
             # hop_edges reports the true per-part pre-filter expansion
@@ -1416,6 +1650,11 @@ class TpuRuntime:
         m.add_value("tpu_queue_s", wait_us / 1e6)
         m.inc("tpu_escalation_retries", attempt)
         m.inc("tpu_refetches", info["refetches"])
+        # every byte the launch's fetches brought to the host (meta,
+        # overflowed rungs and discarded speculation included) and those
+        # of them that are kept capture entries
+        m.inc("tpu_fetch_bytes", info["fetch_bytes"])
+        m.inc("tpu_fetch_bytes_kept", info["fetch_bytes_kept"])
         # device kernel ledger (ISSUE 8 tentpole): per-RUNG dispatch µs
         # and compile-vs-cache dispositions were accumulated as plain
         # locals in the loop (every escalation rung is a real dispatch —
@@ -1466,61 +1705,74 @@ class TpuRuntime:
                         **({"lanes": L} if lanes else {}))
         return res, info
 
-    def _fetch(self, res, key, fetch_keys: Optional[set], phases):
-        """Bring one rung's result to the host: (res, refetches,
-        seconds).  It times itself, as its last statement: the caller
+    def _warm_fetch(self, cap_dev, key, fetch_keys: Optional[set]):
+        """Compile the fetch programs of this capture (every slice or
+        piece size its width admits, on each device that holds a shard)
+        when its program first runs for these columns, outside every
+        timed phase: no statement meets one for the first time through
+        the size of what it kept."""
+        wk = (key, None if fetch_keys is None else frozenset(fetch_keys))
+        if wk not in self._fetch_warm:
+            _taker(cap_dev, fetch_keys).warm()
+            if len(self._fetch_warm) > 4096:
+                self._fetch_warm.clear()
+            self._fetch_warm.add(wk)
+
+    def _fetch(self, res, key, fetch_keys: Optional[set], info):
+        """Bring one rung's result to the host and return it; the
+        launch's `info` takes its phases, undershoots, seconds and
+        bytes.  It times itself, as its last statement: the caller
         holds the device result and this frame the slices taken of it
         until then, because releasing device buffers waits its turn
         (tens of ms under eight sessions) and is no part of the fetch.
 
-        Two-phase: capture arrays stay on device while the small meta
-        (counters/overflow flags) comes back first; the EB-padded
-        capture rows are then fetched as [:K] slices — kept entries are
-        device-compacted to a prefix (hop.py _compact_cap), so the
-        transfer is kept-sized, not bucket-sized (~2 GB → MBs on the
-        north-star config).  SPECULATIVE single-phase: once this program
-        shape (`key`) has run in-process, the previous kept-size bounds
-        the slice and both phases collapse into ONE device_get — one
-        fewer device round trip per query.  An undershoot (kept grew
-        past the speculation) falls back to the exact refetch and is
-        the one refetch counted.  An overflowed rung returns meta alone."""
+        What comes: the leaves a caller reads (`_FETCHED`) and, of the
+        capture columns the yields read, each row's kept prefix
+        (`_Heads`, `_Pieces`): the transfer follows the rows kept, not
+        the edge budget nor the fullest row.  Two-phase on a program's
+        first run, and on every run of a wide capture: the small meta
+        first, then the prefixes its kept counts name.  SPECULATIVE
+        single-phase for the slices after it: what the last run of this
+        program (`key`) kept bounds the slice, and both phases collapse
+        into ONE device_get.  An undershoot (kept grew past the
+        speculation) falls back to the exact refetch and is the one
+        refetch counted; an overshoot ships at most what the last run
+        needed.  An overflowed rung returns meta alone, a speculative
+        slice dropped."""
         t0 = time.perf_counter()
-        cap_dev = res.get("cap") if isinstance(res, dict) else None
-        spec_k = spec_cap = None
-        if cap_dev is not None:
-            res = {k: v for k, v in res.items() if k != "cap"}
-            # bound by the ACTUAL capture width, not max(EBs): a live
-            # delta plane widens capture to EB + Dcap per hop, so kept
-            # counts can legitimately exceed EB
-            capw = next(iter(cap_dev.values())).shape[-1]
-            cap_dev = {k: v for k, v in cap_dev.items()
-                       if fetch_keys is None or k in fetch_keys}
-            spec_k = self._kmax.get(key)
+        phases = info["phases"]
+        meta = {k: res[k] for k in _FETCHED if k in res}
+        take = spec = None
+        if "cap" in res:
+            take = _taker(res["cap"], fetch_keys)
+            spec = self._kmax.get(key)
         with self._phase(phases, "device:fetch"):
-            if spec_k is not None:
-                spec_dev = {k: v[..., :spec_k] for k, v in cap_dev.items()}
-                res, spec_cap = jax.device_get((res, spec_dev))
-            else:
-                res = jax.device_get(res)
-        if cap_dev is None or res["ovf_expand"].any():
-            return res, 0, time.perf_counter() - t0
-        kc = np.asarray(res["kcount"])
-        K = min(int(capw), _pow2(max(int(kc.max()) if kc.size else 0, 1)))
-        undershot = spec_cap is not None and spec_k < K
-        if spec_cap is not None and not undershot:
-            res["cap"] = {k: np.asarray(v[..., :K])
-                          for k, v in spec_cap.items()}
-        else:
-            # the capture's own fetch: the second phase of a first run,
-            # or — after a speculative fetch that undershot — a refetch
-            with self._phase(phases, "device:fetch", refetch=undershot):
-                res["cap"] = {k: np.asarray(jax.device_get(v[..., :K]))
-                              for k, v in cap_dev.items()}
-        res["cap"]["kcount"] = kc
-        self._kmax[key] = K
-        while len(self._kmax) > 512:
-            self._kmax.pop(next(iter(self._kmax)))
-        return res, int(undershot), time.perf_counter() - t0
+            first = None if spec is None else take.speculate(spec)
+            host, got = jax.device_get((meta, first))
+            if first is not None:
+                take.got(got)
+        info["fetch_bytes"] += _nbytes(host)
+        info["refetches"] = 0
+        if take is not None and not host["ovf_expand"].any():
+            kc = host["kcount"]
+            more = take.ask(kc)
+            if more is not None:
+                # the capture's own fetch: the second phase where
+                # nothing was speculated, else a refetch
+                info["refetches"] = int(first is not None)
+                with self._phase(phases, "device:fetch",
+                                 refetch=first is not None):
+                    take.got(jax.device_get(more))
+            host["cap"] = take.rows(kc)
+            host["cap"]["kcount"] = kc
+            info["fetch_bytes_kept"] += int(kc.sum()) * take.item_bytes()
+            self._kmax[key] = kc
+            while len(self._kmax) > 512:
+                self._kmax.pop(next(iter(self._kmax)))
+        if take is not None:
+            info["fetch_bytes"] += take.nbytes
+        info["fetch_s"] = time.perf_counter() - t0
+        return host
 
     @staticmethod
     def _attribute(info, res, lane: Optional[int],
@@ -1547,6 +1799,8 @@ class TpuRuntime:
         stats.device_s = info["device_s"]
         stats.put_s = info["put_s"]
         stats.fetch_s = info["fetch_s"]
+        stats.fetch_bytes = info["fetch_bytes"]
+        stats.fetch_bytes_kept = info["fetch_bytes_kept"]
         stats.queue_s = (info["gate_wait_us"] + form_wait_us) / 1e6
         stats.f_cap, stats.e_cap = 0, list(info["ebs"])
         stats.hbm_bytes = info["hbm_bytes"]
@@ -1878,14 +2132,12 @@ class TpuRuntime:
                         and dview[1].get((et, dirn)) is not None:
                     perms = self._delta_perms(
                         cap["src"][:, h], cap["dst"][:, h],
-                        cap["rank"][:, h], bi, pids, kc, P,
+                        cap["rank"][:, h], bi, pids, P,
                         d2v_arr, d2v_id)
 
                 def catp(name, dtype=None):
-                    parts = [cap[name][p, h, bi, :kc[p]] for p in pids]
-                    if perms is not None:
-                        parts = [a[pm] for a, pm in zip(parts, perms)]
-                    return _cat_parts(parts, dtype)
+                    return _cat_rows([cap[name][p, h, bi] for p in pids],
+                                     perms, dtype)
 
                 ss = catp("src", np.int64)
                 dd = catp("dst", np.int64)
@@ -2005,7 +2257,7 @@ class TpuRuntime:
     # -- host materialization --------------------------------------------
 
     @staticmethod
-    def _delta_perms(cap_src, cap_dst, cap_rank, bi, pids, kc, P,
+    def _delta_perms(cap_src, cap_dst, cap_rank, bi, pids, P,
                      d2v_arr, d2v_id):
         """Per-part permutations restoring canonical CSR slot order over
         the merged base+delta capture: within a part, base rows sit in
@@ -2017,10 +2269,9 @@ class TpuRuntime:
         unique per live edge, so the sort is deterministic."""
         perms = []
         for p in pids:
-            k = int(kc[p])
-            s_ = np.asarray(cap_src[p, bi, :k]).astype(np.int64)
-            d_ = np.asarray(cap_dst[p, bi, :k]).astype(np.int64)
-            r_ = np.asarray(cap_rank[p, bi, :k])
+            s_ = _whole(cap_src[p, bi]).astype(np.int64)
+            d_ = _whole(cap_dst[p, bi]).astype(np.int64)
+            r_ = _whole(cap_rank[p, bi])
             if d2v_id:
                 dk = d_
             else:
@@ -2068,14 +2319,12 @@ class TpuRuntime:
             perms = None
             if de is not None:
                 perms = self._delta_perms(
-                    cap["src"], cap["dst"], cap["rank"], bi, pids, kc,
-                    P, d2v_arr, d2v_id)
+                    cap["src"], cap["dst"], cap["rank"], bi, pids, P,
+                    d2v_arr, d2v_id)
 
             def catp(name, dtype=None):
-                parts = [cap[name][p, bi, :kc[p]] for p in pids]
-                if perms is not None:
-                    parts = [a[pm] for a, pm in zip(parts, perms)]
-                return _cat_parts(parts, dtype)
+                return _cat_rows([cap[name][p, bi] for p in pids],
+                                 perms, dtype)
 
             # arrays the caller's yields never read were not fetched
             # (fetch_keys) — and are not decoded here either
@@ -2092,7 +2341,7 @@ class TpuRuntime:
                     raw = catp("prop:" + n)
                 elif "eidx" in cap:
                     if ee_parts is None:
-                        ee_parts = [cap["eidx"][p, bi, :kc[p]]
+                        ee_parts = [_whole(cap["eidx"][p, bi])
                                     for p in pids]
                         if perms is not None:
                             ee_parts = [a[pm] for a, pm in

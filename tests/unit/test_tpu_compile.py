@@ -132,6 +132,28 @@ def test_proxy_cell_go3_by_need_loops_compile(one_chip):
     assert compiled.as_text().count(" while(") >= 3
 
 
+@pytest.mark.parametrize("shape", [(P8, 1, 1 << 22), (1, 1, 1 << 19)],
+                         ids=["proxy", "mesh-shard"])
+def test_fetch_pieces_compile(one_chip, shape):
+    """The fetch programs of a wide capture (runtime.py `_piece`: of
+    every column ONE dynamic_slice with the row and the first slot
+    traced) at the proxy cells' shapes, every size of the ladder that
+    fits: the one-chip cell's (8, 1, 2^22) columns and one shard of the
+    four-chip cell's."""
+    from nebula_tpu.tpu import runtime
+    cap = {n: _struct(shape, dt, one_chip) for n, dt in [
+        ("src", np.int32), ("dst", np.int32), ("rank", np.int32),
+        ("eidx", np.int32), ("prop:w", np.int64), ("prop:f", np.float64)]}
+    at = _struct((len(shape),), np.int32, one_chip)
+    for size in (c for c in runtime.PIECES if c <= shape[-1]):
+        compiled, secs = _compile(runtime._piece, cap, at, size)
+        assert secs < 20
+        # a 64-bit column is split into its halves WHOLE before the
+        # slice: the fixed cost a piece of a 268 MB operand read on the
+        # chip, and why pieces are few
+        assert compiled.as_text().count("X64SplitLow") == 2
+
+
 def test_match_var_len_capture_hops_compiles(one_chip):
     """MATCH *1..4: four hops, every hop's frame captured."""
     from nebula_tpu.tpu.hop import build_traverse_fn
