@@ -30,53 +30,96 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ..tpu.hop import _expand_block, _mark, _merge_delta
+from ..tpu.hop import (_delta_cap, _delta_live, _drop_live_tombstones,
+                       _expand_block, _live_rows, _mark, _mark_rows)
 
 __all__ = ["expand_part", "top_down_step", "bottom_up_step",
-           "sharded_level_step"]
+           "sharded_level_step", "delta_live"]
+
+
+def _keep(block, src, dst, rk, eidx, ve, pred, pred_cols,
+          swap_ends: bool = False):
+    """The compiled edge predicate over one part's expanded slots,
+    folded into the valid mask.  `swap_ends` is the bottom-up contract:
+    $^/$$ are TRAVERSAL source/destination, and bottom-up expands the
+    REVERSE adjacency, so the expansion source is the traversal
+    DESTINATION (the newly reached vertex) and the neighbor is the
+    frontier side — the endpoint columns the predicate sees are
+    swapped."""
+    if pred is None:
+        return ve
+    ps, pd = (dst, src) if swap_ends else (src, dst)
+    cols = {"_rank": rk, "_src": ps, "_dst": pd}
+    for name in pred_cols:
+        if not name.startswith("_"):
+            cols[name] = block["props"][name][eidx]
+    return pred(cols) & ve
 
 
 def expand_part(block, fbm, pid, EB: int, P: int, vmax: int,
                 pred=None, pred_cols=(), hub_dense=None,
                 swap_ends: bool = False):
-    """Expand ONE part's frontier bitmap through ONE block and apply
-    the compiled edge predicate.
-
-    `swap_ends` is the bottom-up contract: $^/$$ are TRAVERSAL
-    source/destination, and bottom-up expands the REVERSE adjacency,
-    so the expansion source is the traversal DESTINATION (the newly
-    reached vertex) and the neighbor is the frontier side — the
-    endpoint columns the predicate sees are swapped.
+    """Expand ONE part's frontier bitmap through ONE block's BASE CSR
+    and apply the compiled edge predicate (`_keep`).  The delta plane
+    is the level bodies' to merge (`_block_marks`): bottom-up never
+    sees it — a level goes top-down while the plane holds anything (the
+    reverse adjacency has no delta).
 
     Returns (src, dst, keep, total, ovf) per the _expand_block slot
     contract with the predicate folded into `keep`."""
     src, dst, rk, eidx, ve, total, ovf = _expand_block(
         block["indptr"], block["nbr"], block["rank"], fbm, EB, P,
         pid, vmax_local=vmax, hub_dense=hub_dense)
-    dcap = 0
-    if not swap_ends and "d_src" in block:
-        # ISSUE 19: merge the device-resident delta plane (tombstone
-        # base slots, append live delta edges) before the predicate so
-        # fresh writes flow through the same filter.  Bottom-up never
-        # takes this path — the runtime disables direction-optimizing
-        # while a delta is live (the reverse adjacency has no delta).
-        dcap = block["d_src"].shape[-1]
-        src, dst, rk, eidx, ve, total = _merge_delta(
-            block, fbm, src, dst, rk, eidx, ve, total, P, pid,
-            block["nbr"].shape[-1])
-    if pred is not None:
-        ps, pd = (dst, src) if swap_ends else (src, dst)
-        cols = {"_rank": rk, "_src": ps, "_dst": pd}
-        for name in pred_cols:
-            if not name.startswith("_"):
-                c = block["props"][name]
-                if dcap:
-                    c = jnp.concatenate([c, block["d_props"][name]])
-                cols[name] = c[eidx]
-        keep = pred(cols) & ve
+    return src, dst, _keep(block, src, dst, rk, eidx, ve, pred,
+                           pred_cols, swap_ends), total, ovf
+
+
+def delta_live(blocks_data):
+    """Whether any block's armed delta plane holds a row or a
+    tombstone (a traced scalar; False where no plane is armed)."""
+    live = jnp.zeros((), bool)
+    for b in blocks_data:
+        if _delta_cap(b):
+            tomb, rows = _delta_live(b)
+            live = live | tomb | rows
+    return live
+
+
+def _block_marks(over, b, efbm, pid, EB: int, P: int, vmax: int,
+                 pred, pred_cols, hub_dense, acc=None):
+    """One block's top-down level: expand the base CSR over all EB
+    slots, merge an armed delta plane BY WHAT IT HOLDS (ISSUE 19; the
+    stages of hop.py's `_traverse`: tombstoned base slots dropped, the
+    plane's rows marked beside the base's, each behind the plane's live
+    count, so that an empty plane costs a level what no plane costs),
+    apply the predicate, mark the destinations.  `over` maps a per-part
+    function over the layout's leading axes as in `_traverse`; `acc` is
+    a mark matrix to mark into.
+
+    -> (marks, edges, ovf)"""
+    dcap = _delta_cap(b)
+    src, dst, rk, eidx, ve, total, ovf = over(
+        lambda blk, pd, f: _expand_block(
+            blk["indptr"], blk["nbr"], blk["rank"], f, EB, P, pd,
+            vmax_local=vmax, hub_dense=hub_dense))(b, pid, efbm)
+    if dcap:
+        has_tomb, has_rows = _delta_live(b)
+        ve = _drop_live_tombstones(over, b, pid, eidx, ve, has_tomb)
+    keep = over(lambda blk, _p, *a: _keep(blk, *a, pred, pred_cols))(
+        b, pid, src, dst, rk, eidx, ve)
+    if acc is None:
+        marks = over(lambda _b, _p, d, k: _mark(d, k, P, vmax))(
+            None, None, dst, keep)
     else:
-        keep = ve
-    return src, dst, keep, total, ovf
+        marks = over(lambda _b, _p, m, d, k: _mark(d, k, P, vmax, m))(
+            None, None, acc, dst, keep)
+    if dcap:
+        _s, tdst, _r, tkeep, tact = _live_rows(
+            over, b, pid, efbm, P, has_rows, rk.dtype, pred,
+            [c for c in pred_cols if not c.startswith("_")])
+        marks = _mark_rows(over, marks, tdst, tkeep, P, vmax, has_rows)
+        total = total + jnp.sum(tact, axis=-1, dtype=jnp.int32)
+    return marks, total, ovf
 
 
 def top_down_step(blocks_data, efbm, EB: int, P: int, vmax: int, pids,
@@ -90,19 +133,14 @@ def top_down_step(blocks_data, efbm, EB: int, P: int, vmax: int, pids,
     marks = None
     edges = jnp.zeros((P,), jnp.int32)
     ovf = jnp.zeros((P,), bool)
-    for bi in range(len(blocks_data)):
-        b = blocks_data[bi]
+    for b in blocks_data:
         # vmap the whole block dict: every leaf (indptr/nbr/rank/props
         # and the d_* delta plane when present) has a leading part axis
-        _s, dst, keep, total, ov = jax.vmap(
-            lambda blk, f, pd: expand_part(
-                blk, f, pd, EB, P, vmax,
-                pred=pred, pred_cols=pred_cols, hub_dense=hub_dense)
-        )(b, efbm, pids)
+        blk_marks, total, ov = _block_marks(
+            jax.vmap, b, efbm, pids, EB, P, vmax, pred, pred_cols,
+            hub_dense)
         ovf = ovf | ov
         edges = edges + total
-        blk_marks = jax.vmap(
-            lambda d, k: _mark(d, k, P, vmax))(dst, keep)
         marks = blk_marks if marks is None else marks | blk_marks
     return marks.any(axis=0), edges, ovf
 
@@ -165,10 +203,9 @@ def sharded_level_step(blocks_data, efbm, EB: int, P: int, pid,
                 blk[k] = b[k][0]
             blk["d_props"] = {n: v[0]
                               for n, v in b.get("d_props", {}).items()}
-        _s, dst, keep, total, ov = expand_part(
-            blk, efbm, pid, EB, P, vmax,
-            pred=pred, pred_cols=pred_cols, hub_dense=hub_dense)
+        marks, total, ov = _block_marks(
+            lambda f: f, blk, efbm, pid, EB, P, vmax, pred, pred_cols,
+            hub_dense, acc=marks)
         ovf = ovf | ov
         edges = edges + total
-        marks = _mark(dst, keep, P, vmax, marks)
     return marks, edges, ovf

@@ -24,6 +24,7 @@ from ..graphstore.schema import (SchemaError, apply_defaults,
                                   fill_row)
 from ..graphstore.store import stable_vid_hash
 from ..utils import consistency as _consistency
+from ..utils import trace as _trace
 from ..utils.failpoints import fail
 from .meta_client import MetaClient
 from .storage_client import StorageClient, StorageError
@@ -265,6 +266,15 @@ class DistributedStore:
                                 "cat_ver": self.meta.version,
                                 "token": self._token()})
         self._note_applied(space, pid, r)
+        self._note_acked()
+
+    @staticmethod
+    def _note_acked():
+        """A write request of the statement is acknowledged, every
+        part's reply in (through raft and the WAL): a marker in its
+        trace, from which the root books `write_ack_s` (graphd's entry
+        to the statement's last acknowledgement) when it closes."""
+        _trace.mark("storage:write_acked")      # trace.WRITE_ACKED
 
     def _write_many(self, space: str, by_part: Dict[int, List[tuple]]):
         """One rpc_write per part — each part's command list becomes ONE
@@ -284,6 +294,7 @@ class DistributedStore:
                  for pid, cmds in by_part.items()},
                 "storage.write"):
             self._note_applied(space, pid, r)
+        self._note_acked()
 
     def insert_vertex(self, space: str, vid: Any, tag: str,
                       props: Dict[str, Any],
@@ -806,11 +817,13 @@ class DistributedStore:
                 return self._sd
 
         from ..utils.config import get_config
-        dflag = int(get_config().get("tpu_delta_max_edges") or 0)
+        # the delta plane is armed unless the flag is an explicit 0
+        # (TpuRuntime._delta_flag): keep rows for vertices it adds
+        dflag = int(get_config().get("tpu_delta_max_edges"))
         snap = build_snapshot(
             _Shim(self.meta.catalog, sd), space,
             vmax_extra=(int(get_config().get("tpu_delta_vmax_slack"))
-                        if dflag > 0 else 0))
+                        if dflag != 0 else 0))
         # the space view serves dense-id lookups from this export (the
         # device data plane's vid dictionary); part_counts ride along so
         # the delta reader can mint dense ids for post-export vids

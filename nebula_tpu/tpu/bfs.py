@@ -32,8 +32,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ..algo.frontier import (bottom_up_step, sharded_level_step,
-                             top_down_step)
+from ..algo.frontier import (bottom_up_step, delta_live,
+                             sharded_level_step, top_down_step)
 from .hop import (_exchange_marks, _extend_fbm_local,
                   _extend_fbm_sharded, _hub_consts, _norm_ebs,
                   a2a_payload_bytes)
@@ -133,6 +133,7 @@ def build_bfs_fn_local(P: int, EB, max_steps: int,
 
     def fn(blocks_data, frontier):
         fbm = frontier                          # (P, vmax) bool seeds
+        armed = any("d_src" in b for b in blocks_data)
         dist = jnp.where(fbm, 0, -1).astype(jnp.int32)   # (P, vmax)
         ovf_e = jnp.zeros((P,), bool)
         hop_edges = []
@@ -157,6 +158,11 @@ def build_bfs_fn_local(P: int, EB, max_steps: int,
                 # smaller partitions sit forever in `unvis`; subtract
                 # them so skewed layouts don't suppress the switch.
                 use_bu = fbm.sum() * 8 > unvis.sum() - n_phantom
+                if armed:
+                    # the reverse adjacency has no delta: a level goes
+                    # top-down while the plane holds anything, and an
+                    # armed plane that holds nothing changes no level
+                    use_bu = use_bu & ~delta_live(blocks_data)
                 cand, edges, ovf = jax.lax.cond(
                     use_bu,
                     lambda args: bottom_up(blocks_data, args[0], args[1],

@@ -231,7 +231,8 @@ class DeviceDelta:
     host: Any
     # bk → {"d_src","d_dst","d_rank","d_valid","d_tomb": device arrays,
     #        "d_props": {name: device array},
-    #        "np": the numpy block_arrays dict these were put from}
+    #        "np": the numpy block_arrays dict these were put from,
+    #        "rows": live delta rows per part when these were put}
     blocks: Dict[Tuple[str, str], Dict[str, Any]] = field(
         default_factory=dict)
     applied_epoch: int = 0            # store epoch the delta covers
@@ -250,7 +251,7 @@ class DeviceDelta:
             for k, v in arrs.items():
                 if k == "d_props":
                     yield from v.values()
-                elif k != "np":
+                elif k not in ("np", "rows"):
                     yield v
 
 
@@ -382,7 +383,8 @@ def put_delta_blocks(dev: DeviceSnapshot, host_delta,
     moved = 0
     for bk in keys:
         arrs = host_delta.block_arrays(bk)
-        placed: Dict[str, Any] = {"np": arrs}
+        placed: Dict[str, Any] = {
+            "np": arrs, "rows": [len(per) for per in host_delta.ins[bk]]}
         for k, v in arrs.items():
             if k == "d_props":
                 placed[k] = {n: put(a) for n, a in v.items()}

@@ -107,7 +107,32 @@ def _put(out, x, lo):
     return jax.lax.dynamic_update_slice_in_dim(out, x, lo, axis=-1)
 
 
-def _by_need(body, outs, n, width: int, tail: int = 0, chunk: int = CHUNK):
+def _when(live, on, off, *ops):
+    """`lax.cond(live, on, off, *ops)` for a stage that runs only where
+    the delta plane holds something: `live` is a traced scalar taken
+    OUTSIDE every vmap (under one a `cond` becomes a `select` and both
+    sides run).  Inside a shard_map what a branch computes from the
+    shard's data varies over the mesh and a constant does not, and the
+    two branches must agree: each result is cast to the union (a
+    shard's `live` varies there; elsewhere the plain `cond` does)."""
+    if not jax.typeof(live).vma:
+        return jax.lax.cond(live, on, off, *ops)
+    want = [jax.eval_shape(f, *ops) for f in (on, off)]
+    vma = jax.tree.map(lambda a, b: (a.vma or frozenset()) | (b.vma or frozenset()),
+                       *want)
+
+    def cast(f):
+        def run(*a):
+            return jax.tree.map(
+                lambda o, v: jax.lax.pcast(
+                    o, tuple(v - jax.typeof(o).vma), to="varying")
+                if v - jax.typeof(o).vma else o, f(*a), vma)
+        return run
+    return jax.lax.cond(live, cast(on), cast(off), *ops)
+
+
+def _by_need(body, outs, n, width: int, tail: int = 0, chunk: int = CHUNK,
+             tail_live=None):
     """Run a per-slot stage of a hop over the slots the expansion
     FILLED, not over the hop's whole edge budget.
 
@@ -116,20 +141,28 @@ def _by_need(body, outs, n, width: int, tail: int = 0, chunk: int = CHUNK):
     (a tuple of arrays holding the fill values slots never visited
     keep).  `n` is the traced number of live slots among the first
     `width`; the body runs on [c*chunk, (c+1)*chunk) for
-    c < ceil(n / chunk) inside one device loop, then once on the
-    `tail` slots that follow `width` (the delta plane's appended rows,
-    always live).  A gather's cost on the chip follows the slots it is
-    asked for, so a hop that filled a fifth of its budget pays a fifth.
+    c < ceil(n / chunk) inside one device loop, then on the `tail`
+    slots that follow `width` (the delta plane's appended rows) where
+    the traced scalar `tail_live` says they hold something: an armed
+    plane with nothing in it runs no trip for them.  A gather's cost on
+    the chip follows the slots it is asked for, so a hop that filled a
+    fifth of its budget pays a fifth.
 
     The choice between loop and no loop is the STATIC width: a budget
     of one chunk or less (or one that chunks do not tile) runs the body
-    once over everything, which is the straight-line program.  Under vmap
+    once over the budget, which is the straight-line program.  Under vmap
     the loop runs to the largest trip count among the mapped instances.
 
     Returns (outs, chunks run, chunks budgeted) — (outs, 0, 0) when no
     loop was emitted."""
+    def with_tail(o):
+        if not tail:
+            return o
+        return _when(tail_live, lambda x: body(x, width, tail),
+                     lambda x: x, o)
+
     if width <= chunk or width % chunk:
-        return body(outs, 0, width + tail), 0, 0
+        return with_tail(body(outs, 0, width)), 0, 0
     trips = (jnp.minimum(n, width) + (chunk - 1)) // chunk
     # inside a shard_map a fresh constant is the same on every shard and
     # what the body writes is not: the loop carry takes the body's type
@@ -140,9 +173,7 @@ def _by_need(body, outs, n, width: int, tail: int = 0, chunk: int = CHUNK):
                  for o, axes in zip(outs, missing))
     outs = jax.lax.fori_loop(
         0, trips, lambda c, o: body(o, c * chunk, chunk), outs)
-    if tail:
-        outs = body(outs, width, tail)
-    return outs, trips, width // chunk
+    return with_tail(outs), trips, width // chunk
 
 
 # Lane updates one trip of the member plan's loop issues for one part
@@ -370,52 +401,93 @@ def _drop_tombstoned(tomb, eidx, ve):
     return ve & ~(tomb[pos] == eidx)
 
 
-def _append_delta(dl, fbm, src, dst, rk, eidx, ve, total, P: int, pid,
-                  emax: int):
-    """The delta merge's other half: delta rows whose source vertex is
-    on the frontier are APPENDED to the capture arrays — delta row j
-    takes the virtual edge index emax + j, so downstream prop gathers
-    read from columns extended with the delta prop columns and the host
-    can split captured rows back into base (< emax) and delta halves."""
+def _delta_rows(dl, fbm, P: int, pid):
+    """The delta merge's other half, one part's: the delta rows whose
+    source vertex is on the frontier, as (src, dst, rank, active) over
+    the Dcap slots (-1, -1, 0 where a slot is not active).  Delta row j
+    takes the virtual edge index emax + j, so the host can split
+    captured rows back into base (< emax) and delta halves."""
     dsrc = dl["d_src"]
-    Dcap = dsrc.shape[0]
-    if Dcap:
-        active = dl["d_valid"] & fbm[jnp.clip(dsrc, 0, fbm.shape[0] - 1)]
-        src = jnp.concatenate([src, jnp.where(active, dsrc * P + pid, -1)])
-        dst = jnp.concatenate([dst, jnp.where(active, dl["d_dst"], -1)])
-        rk = jnp.concatenate([rk, jnp.where(active, dl["d_rank"], 0)])
-        eidx = jnp.concatenate(
-            [eidx, emax + jnp.arange(Dcap, dtype=jnp.int32)])
-        ve = jnp.concatenate([ve, active])
-        total = total + jnp.sum(active, dtype=jnp.int32)
-    return src, dst, rk, eidx, ve, total
+    active = dl["d_valid"] & fbm[jnp.clip(dsrc, 0, fbm.shape[0] - 1)]
+    return (jnp.where(active, dsrc * P + pid, -1),
+            jnp.where(active, dl["d_dst"], -1),
+            jnp.where(active, dl["d_rank"], 0), active)
 
 
-@_stage("hop/delta_merge")
-def _merge_delta(dl, fbm, src, dst, rk, eidx, ve, total, P: int, pid,
-                 emax: int):
-    """Merge the device-resident delta plane into one block's expansion
-    (ISSUE 19).
+def _live_rows(over, b, pid, fbm, P: int, has_rows, rank_dtype,
+               pred=None, pcols=()):
+    """The plane's rows out of a frontier, kept APART from the EB base
+    slots and only where the plane holds any (`has_rows`, a traced
+    scalar): (src, dst, rank, kept, active), each lead + (Dcap,), all
+    off where it holds none.  `pred` sees a delta row's own columns
+    (`d_props`) as its predicate columns.  Delta snapshots are never
+    hub-extended, so `fbm` is the plain membership row."""
+    dcap = _delta_cap(b)
+    lead = fbm.shape[:-1]
 
-    dl: dict with the block's delta leaves for THIS part —
-      d_src (Dcap,) int32 LOCAL source index, d_dst (Dcap,) dense dst,
-      d_rank (Dcap,), d_valid (Dcap,) bool slot-live,
-      d_tomb (Tcap,) SORTED int32 base-edge indices masked out
-      (MAXI-padded).
-    fbm: (vmax,) bool — this part's frontier bitmap (delta snapshots are
-    never degree-split, so no hub extension applies).
+    def rows(f):
+        s, d, r, act = over(
+            lambda blk, pd, fb: _delta_rows(blk, fb, P, pd))(b, pid, f)
+        k = act
+        if pred is not None:
+            k = pred({"_rank": r, "_src": s, "_dst": d, **{
+                c: jnp.broadcast_to(b["d_props"][c], s.shape)
+                for c in pcols}}) & act
+        return s, d, r.astype(rank_dtype), k, act
 
-    Two halves, in order: tombstones (`_drop_tombstoned`, per slot),
-    then inserts (`_append_delta`).
+    def no_rows(f):
+        z = jnp.zeros(lead + (dcap,), jnp.int32)
+        off = jnp.zeros(lead + (dcap,), bool)
+        return z - 1, z - 1, z.astype(rank_dtype), off, off
 
-    The appended slots keep the ascending-eidx tail position, so the
-    (part, src)-contiguous prefix invariant of the BASE slots survives;
-    the host re-sorts the merged union per part into canonical CSR
-    order (runtime._block_columns) before materializing rows.
-    """
-    ve = _drop_tombstoned(dl["d_tomb"], eidx, ve)
-    return _append_delta(dl, fbm, src, dst, rk, eidx, ve, total, P, pid,
-                         emax)
+    with jax.named_scope("hop/delta_merge"):
+        return _when(has_rows, rows, no_rows, fbm)
+
+
+def _drop_live_tombstones(over, b, pid, eidx, ve, has_tomb):
+    """`_drop_tombstoned` over every part, only where the plane holds a
+    tombstone (`has_tomb`, a traced scalar)."""
+    with jax.named_scope("hop/delta_merge"):
+        return _when(
+            has_tomb,
+            over(lambda t, _p, e, v: _drop_tombstoned(t, e, v)),
+            lambda _t, _p, e, v: v, b["d_tomb"], pid, eidx, ve)
+
+
+def _mark_rows(over, marks, dst, keep, P: int, vmax: int, has_rows):
+    """The plane's rows marked into a block's mark matrices, only where
+    it holds any."""
+    return _when(
+        has_rows,
+        over(lambda _b, _p, m, d, k: _mark(d, k, P, vmax, m)),
+        lambda _b, _p, m, d, k: m, None, None, marks, dst, keep)
+
+
+def _delta_live(b):
+    """(tombstones live, rows live) of one block's delta plane, as
+    traced scalars over every part (and shard-resident lane) the program
+    holds: what each merge stage's `_when` follows, so that an armed
+    plane with nothing in it runs none of them.  A part's rows fill its
+    buffer from slot 0 and its tombstones sort before their MAXI
+    padding (`HostDelta.block_arrays`), so slot 0 tells."""
+    return (jnp.any(b["d_tomb"][..., 0] != MAXI),
+            jnp.any(b["d_valid"][..., 0]))
+
+
+def _gather_merged(over, b, pid, c: str, e, emax: int, has_rows):
+    """Column `c` of a block at the (virtual) edge indices `e`: the base
+    column's values, and where the plane holds rows the delta column's
+    at the indices from `emax` on.  Two gathers, each from its own
+    column: the base column is never copied to be extended."""
+    take = over(lambda col, _p, i: col[i])
+    got = take(b["props"][c], pid, jnp.minimum(e, emax - 1))
+    dcap = b["d_props"][c].shape[-1]
+    return _when(
+        has_rows,
+        lambda g, i: jnp.where(
+            i >= emax,
+            take(b["d_props"][c], pid, jnp.clip(i - emax, 0, dcap - 1)), g),
+        lambda g, i: g, got, e)
 
 
 def _delta_cap(b) -> int:
@@ -489,7 +561,7 @@ def a2a_payload_bytes(P: int, vmax: int, lanes: int = 1) -> int:
 
 @_stage("hop/compact")
 def _compact_cap(src, dst, rk, eidx, keep, n, EB: int, tail: int = 0,
-                 chunk: int = CHUNK):
+                 chunk: int = CHUNK, tail_live=None):
     """Stable-partition the kept edge slots to the FRONT of each capture
     row (cumsum scatter, O(slots)) and return the kept count.
 
@@ -502,8 +574,8 @@ def _compact_cap(src, dst, rk, eidx, keep, n, EB: int, tail: int = 0,
 
     The arrays carry the builder's leading axes before the EB + tail
     slots.  The cumsum is a streaming pass and stays whole; the four
-    scatters run by need (only the first `n` slots and the tail can
-    hold a kept entry) into FLAT outputs, every row at its own offset:
+    scatters run by need (only the first `n` slots and, where
+    `tail_live`, the tail can hold a kept entry) into FLAT outputs, every row at its own offset:
     a scatter on the chip works on a flat operand, and a loop that
     carried the rows as rows would re-lay all of them out on every
     trip.
@@ -527,7 +599,8 @@ def _compact_cap(src, dst, rk, eidx, keep, n, EB: int, tail: int = 0,
 
     init = tuple(jnp.full((rows * W,), fill, v.dtype)
                  for v, fill in zip(vals, (-1, -1, 0, 0)))
-    outs, run, budget = _by_need(scatter, init, n, EB, tail, chunk)
+    outs, run, budget = _by_need(scatter, init, n, EB, tail, chunk,
+                                 tail_live)
     cs, cd, cr, ce = (o.reshape(keep.shape) for o in outs)
     return (cs, cd, cr, ce, jnp.sum(keep, axis=-1, dtype=jnp.int32),
             run, budget)
@@ -631,6 +704,9 @@ def _traverse(over, nlead: int, blocks, fbm, pid, extend, exchange, *,
         for b in blocks:
             dcap = _delta_cap(b)
             emax = b["nbr"].shape[-1]
+            # an armed delta plane costs this block what it HOLDS: every
+            # stage it adds sits behind one of these two traced scalars
+            has_tomb, has_rows = _delta_live(b) if dcap else (None, None)
             total, ovf, plan, r, bd = _expand_plan(
                 over, b, pid, efbm, EB, plan_chunk)
             prun, pbudget = prun + r, pbudget + bd
@@ -643,13 +719,14 @@ def _traverse(over, nlead: int, blocks, fbm, pid, extend, exchange, *,
                     s, d, r, e, v = _expand_slots(
                         blk["indptr"], blk["nbr"], blk["rank"], pl, tot,
                         lo, size, EB, P, pd, vmax, hubs_c)
-                    if dcap:
-                        with jax.named_scope("hop/delta_merge"):
-                            v = _drop_tombstoned(blk["d_tomb"], e, v)
                     with jax.named_scope("hop/pred_gather"):
                         g = tuple(blk["props"][c][e] for c in hcols)
                     return (s, d, r, e, v) + g
                 vals = over(part)(b, pid, plan, total)
+                if dcap:
+                    v = _drop_live_tombstones(over, b, pid, *vals[3:5],
+                                              has_tomb)
+                    vals = vals[:4] + (v,) + vals[5:]
                 return tuple(_put(o, v, lo) for o, v in zip(outs, vals))
 
             lead = total.shape
@@ -663,19 +740,11 @@ def _traverse(over, nlead: int, blocks, fbm, pid, extend, exchange, *,
             src, dst, rk, eidx, ve = outs[:5]
             pcols = dict(zip(hcols, outs[5:]))
             if dcap:
-                # delta snapshots are never hub-extended, so efbm here
-                # is the plain (vmax,) membership row
-                with jax.named_scope("hop/delta_merge"):
-                    src, dst, rk, eidx, ve, total = over(
-                        lambda blk, pd, f, *a: _append_delta(
-                            blk, f, *a, P, pd, emax))(
-                        b, pid, efbm, src, dst, rk, eidx, ve, total)
-                # a delta row's eidx is its own index past emax: its
-                # predicate columns are the delta columns themselves
-                pcols = {c: jnp.concatenate(
-                    [v, jnp.broadcast_to(b["d_props"][c], v.shape[:-1]
-                                         + b["d_props"][c].shape[-1:])],
-                    axis=-1) for c, v in pcols.items()}
+                tsrc, tdst, trk, tkeep, tact = _live_rows(
+                    over, b, pid, efbm, P, has_rows, rk.dtype,
+                    pred if want_pred else None, hcols)
+                base_total = total
+                total = total + jnp.sum(tact, axis=-1, dtype=jnp.int32)
             ovf_e = ovf if ovf_e is None else ovf_e | ovf
             edges = edges + total
 
@@ -686,7 +755,40 @@ def _traverse(over, nlead: int, blocks, fbm, pid, extend, exchange, *,
             else:
                 keep = ve
             if want_cap:
-                if want_pred or dcap:
+                if dcap:
+                    def wide(x, t):
+                        return jnp.concatenate([x, t], axis=-1)
+
+                    def compact(*a):
+                        cs, cd, cr, ce, kc, r, bd = _compact_cap(
+                            *a, n, EB, dcap, chunk, has_rows)
+                        return (cs, cd, cr, ce, kc,
+                                jnp.asarray(r, jnp.int32),
+                                jnp.asarray(bd, jnp.int32))
+
+                    teidx = jnp.broadcast_to(
+                        emax + jnp.arange(dcap, dtype=jnp.int32),
+                        lead + (dcap,))
+                    wides = (wide(src, tsrc), wide(dst, tdst),
+                             wide(rk, trk), wide(eidx, teidx),
+                             wide(keep, tkeep))
+                    with jax.named_scope(cap_scope):
+                        if want_pred:
+                            got = compact(*wides)
+                        else:
+                            # nothing tombstoned and no row appended:
+                            # the live slots already are the prefix,
+                            # as in a program without the plane
+                            z = jnp.zeros((), jnp.int32)
+                            got = _when(
+                                has_tomb | has_rows, compact,
+                                lambda s, d, r, e, k: (
+                                    s, d, r, e,
+                                    jnp.minimum(base_total, EB), z, z),
+                                *wides)
+                    cs, cd, cr, ce, kc, r, bd = got
+                    run, budget = run + r, budget + bd
+                elif want_pred:
                     with jax.named_scope(cap_scope):
                         cs, cd, cr, ce, kc, r, bd = _compact_cap(
                             src, dst, rk, eidx, keep, n, EB, dcap, chunk)
@@ -699,27 +801,28 @@ def _traverse(over, nlead: int, blocks, fbm, pid, extend, exchange, *,
                 for k, v in zip(_CAP_KEYS, (cs, cd, cr, ce, kc)):
                     caps[k].append(v)
                 if last and not capture_hops and yield_cols:
-                    ycols = {c: b["props"][c] if not dcap else
-                             jnp.concatenate(
-                                 [b["props"][c], b["d_props"][c]], axis=-1)
-                             for c in yield_cols}
-
                     def props(outs, lo, size):
                         e = _window(ce, lo, size)
                         got = []
                         for c, o in zip(yield_cols, outs):
                             with jax.named_scope("hop/prop_" + c):
-                                got.append(_put(o, over(
-                                    lambda col, _p, i: col[i])(
-                                    ycols[c], pid, e), lo))
+                                if dcap:
+                                    g = _gather_merged(over, b, pid, c, e,
+                                                       emax, has_rows)
+                                else:
+                                    g = over(lambda col, _p, i: col[i])(
+                                        b["props"][c], pid, e)
+                                got.append(_put(o, g, lo))
                         return tuple(got)
 
                     # kept entries sit in a prefix: the live range is
-                    # the fullest part's kept count
+                    # the fullest part's kept count, which reaches the
+                    # tail only where it passes the budget
+                    kmax = jnp.max(kc)
                     got, r, bd = _by_need(
-                        props, tuple(jnp.zeros(ce.shape, ycols[c].dtype)
+                        props, tuple(jnp.zeros(ce.shape, b["props"][c].dtype)
                                      for c in yield_cols),
-                        jnp.max(kc), EB, dcap, chunk)
+                        kmax, EB, dcap, chunk, kmax > EB if dcap else None)
                     run, budget = run + r, budget + bd
                     for c, g in zip(yield_cols, got):
                         caps.setdefault("prop:" + c, []).append(g)
@@ -727,6 +830,9 @@ def _traverse(over, nlead: int, blocks, fbm, pid, extend, exchange, *,
                 blk_marks = over(
                     lambda _b, _p, d, k: _mark(d, k, P, vmax))(
                     None, None, dst, keep)
+                if dcap:
+                    blk_marks = _mark_rows(over, blk_marks, tdst, tkeep,
+                                           P, vmax, has_rows)
                 marks = blk_marks if marks is None else marks | blk_marks
         hop_edges.append(edges)
         zero = jnp.zeros_like(edges)

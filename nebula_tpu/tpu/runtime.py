@@ -152,8 +152,41 @@ def _cat_rows(rows, perms=None, dtype=None):
     first (the delta plane's canonical CSR order)."""
     if perms is None:
         return _cat_parts([a for pieces in rows for a in pieces], dtype)
-    return _cat_parts([_whole(pieces)[pm]
+    return _cat_parts([_whole(pieces) if pm is None else _whole(pieces)[pm]
                        for pieces, pm in zip(rows, perms)], dtype)
+
+
+def _merged_gather(col, de, name: str, p, e):
+    """Column `col` (P, Emax) of a block at part(s) `p` and captured
+    edge indices `e`; with a live delta entry `de`, the entries from
+    Emax on are the view's numpy mirror's (a delta row carries the
+    virtual eidx Emax + slot).  Two gathers: the base column is never
+    copied to be extended."""
+    emax = col.shape[1]
+    if np.ndim(p) == 0:
+        col = col[p]            # one part: a row view, then a 1-D take
+        late = None if de is None else e >= emax
+        if late is None or not late.any():
+            return col[e]
+        got = col[np.minimum(e, emax - 1)]
+        got[late] = de["np"]["d_props"][name][p, e[late] - emax]
+        return got
+    late = None if de is None else e >= emax
+    if late is None or not late.any():
+        return col[p, e]
+    got = col[p, np.minimum(e, emax - 1)]
+    got[late] = de["np"]["d_props"][name][p[late], e[late] - emax]
+    return got
+
+
+def _delta_rows_of(dview, bk):
+    """The delta view's entry for block `bk` where the plane HOLDS rows
+    of it, else None: what the host steps of a live view follow (the
+    identity columns in the fetch, the per-part re-sort, the mirror
+    decode).  Tombstones alone need none of them: a dropped base row
+    leaves the others in their order."""
+    e = None if dview is None else dview[1].get(bk)
+    return e if e is not None and any(e["rows"]) else None
 
 
 class _DispatchGate:
@@ -242,9 +275,12 @@ _FETCHED = ("hop_edges", "ovf_expand", "kcount", "frontier_sizes",
 # program first runs for those columns (`TpuRuntime._warm_fetch`),
 # never when a kept size is first met.
 #
-# W <= SLICE_MAX (the served statements' 8,192 and 65,536 slots): ONE
-# slice of every row, `v[..., :k]`, k a power of two from SLICE_MIN up,
-# speculated from the program's last run.
+# W < 2 * SLICE_MAX, i.e. a hop budget of at most SLICE_MAX (the served
+# statements' 8,192 and 65,536 slots; budgets are powers of two, and an
+# armed delta plane's tail widens a capture by less than its budget, so
+# the plane never moves a capture to the other taker): ONE slice of
+# every row, `v[..., :k]`, k a power of two from SLICE_MIN up (the
+# whole width last), speculated from the program's last run.
 #
 # Wider: every row apart, in flat pieces cut on the device that holds
 # it, so the bytes follow each row's own count (not the fullest row's,
@@ -292,13 +328,14 @@ def _nbytes(tree) -> int:
 def _taker(cap_dev, want=None):
     """The way this capture's rows leave the device, by its width; of
     its columns the host takes those in `want` (all of them if None)."""
-    wide = next(iter(cap_dev.values())).shape[-1] > SLICE_MAX
+    wide = next(iter(cap_dev.values())).shape[-1] >= 2 * SLICE_MAX
     return (_Pieces if wide else _Heads)(cap_dev, want)
 
 
 class _Heads:
-    """The kept prefixes of a capture at most SLICE_MAX slots wide, as
-    one slice of every row.  `speculate(counts)` and `ask(counts)`
+    """The kept prefixes of a capture whose hop budget is at most
+    SLICE_MAX slots (narrower than twice that with a delta plane's
+    tail), as one slice of every row.  `speculate(counts)` and `ask(counts)`
     return the device arrays that cover rows of these kept counts (the
     last run's, this run's), or None where there is nothing to ask for
     beyond what was asked before; `got` takes them once on the host;
@@ -787,13 +824,51 @@ class TpuRuntime:
 
     @staticmethod
     def _delta_flag() -> int:
-        """Per-(block, part) delta edge capacity; 0 = delta plane off
-        (byte-identical to the pre-delta runtime)."""
+        """`tpu_delta_max_edges`: negative (the default) = the delta
+        plane is armed wherever the store feeds one, its per-(block,
+        part) capacity worked out at pin time (`_delta_capacity`); a
+        positive value fixes that capacity; 0 = delta plane off (every
+        epoch bump re-pins; byte-identical to the pre-delta runtime)."""
         from ..utils.config import get_config
         try:
             return int(get_config().get("tpu_delta_max_edges"))
         except Exception:  # noqa: BLE001 — config missing in odd embeds
             return 0
+
+    # -- the delta plane's capacity, from what a pin already sees -----
+    # A (block, part) delta buffer holds 1/DELTA_EDGE_SHARE of the
+    # part's padded edge slots (a plane past a few percent of its base
+    # is a compaction's to fold in, and the merge's sorts and re-puts
+    # grow with it), rounded up to a power of two (the buffers' width is
+    # in every program's key).  Never under DELTA_MIN_EDGES: a serving
+    # window's writes (some hundreds at the INSERT path's rate, all in
+    # one part at worst) must stay under the compaction watermark
+    # (0.75) whatever the graph's size.  And all delta buffers of a
+    # device together take at most 1/DELTA_HBM_SHARE of the HBM headroom
+    # `_check_hbm_budget` finds under `tpu_hbm_limit_bytes`, halving until
+    # they do: the plane never costs a graph its pin.
+    DELTA_EDGE_SHARE = 64
+    DELTA_MIN_EDGES = 1 << 10
+    DELTA_HBM_SHARE = 64
+
+    def _delta_capacity(self, snap, headroom: Optional[int]) -> int:
+        """Per-(block, part) delta capacity in edges for `snap` (the
+        rule above), `headroom` the free HBM bytes a device has under
+        its limit once the snapshot is pinned (None = no limit set)."""
+        width = max((b.nbr.shape[1] for b in snap.blocks.values()),
+                    default=0)
+        cap = max(_delta_pow2(-(-width // self.DELTA_EDGE_SHARE)),
+                  self.DELTA_MIN_EDGES)
+        if headroom is not None:
+            # the buffers' bytes are linear in the capacity: one slot of
+            # every block, over the parts one device holds
+            slot = HostDelta(snap, 1).nbytes()
+            if not self.local_mode and snap.num_parts == self.mesh_size:
+                slot = -(-slot // snap.num_parts)
+            while cap > 1 and \
+                    cap * slot > headroom // self.DELTA_HBM_SHARE:
+                cap //= 2
+        return cap
 
     @staticmethod
     def _delta_slack() -> int:
@@ -829,7 +904,7 @@ class TpuRuntime:
                     return dev
         dflag = self._delta_flag()
         snap = self._build_fresh(store, space, dflag)
-        self._check_hbm_budget(snap, space)
+        headroom = self._check_hbm_budget(snap, space)
         # the device_put runs under the WRITE side of the dispatch
         # gate: in-flight dispatches drain first, new ones wait — the
         # jaxlib serve-while-repin race window is closed, and the
@@ -860,12 +935,13 @@ class TpuRuntime:
             # stale-epoch jitted fns are keyed by epoch; drop them
             self._fns = {k: v for k, v in self._fns.items()
                          if not (k[0] == space and k[1] != dev.epoch)}
-            self._arm_delta(store, dev, snap, dflag)
+            self._arm_delta(store, dev, snap, dflag, headroom)
         finally:
             self._gate.release_write()
         stats().observe("tpu_repin_wait_us", int(wait_s * 1e6))
         stats().inc("tpu_pins")
         self._emit_hbm_gauges()
+        self._emit_delta_gauges(dev)
         return dev
 
     def _build_fresh(self, store, space: str, dflag: int):
@@ -873,7 +949,7 @@ class TpuRuntime:
         is on, the store starts (or keeps) watching dirty keys BEFORE
         the export — a key noted between watch and export is merely
         re-read at apply time, so there is no lost-write window."""
-        if dflag > 0 and hasattr(store, "delta_watch"):
+        if dflag != 0 and hasattr(store, "delta_watch"):
             store.delta_watch(space)
         if hasattr(store, "build_csr_snapshot"):
             # cluster store: bulk per-part CSR export over RPC (the
@@ -891,42 +967,68 @@ class TpuRuntime:
         else:
             snap = build_snapshot(
                 store, space,
-                vmax_extra=self._delta_slack() if dflag > 0 else 0)
+                vmax_extra=self._delta_slack() if dflag != 0 else 0)
         return self._maybe_degree_split(snap)
 
-    def _arm_delta(self, store, dev, snap, dflag: int) -> None:
-        """Allocate the EMPTY delta plane at pin time (gate held).
+    def _arm_delta(self, store, dev, snap, dflag: int,
+                   headroom: Optional[int]) -> None:
+        """Allocate the EMPTY delta plane at pin time (gate held),
+        wherever the store feeds one (`delta_records`, `delta_reader`).
         Lazy allocation would change kernel input shapes on the first
         write and recompile every cached program; an empty plane costs
-        one small put and compiles once.  Degree-split snapshots opt
-        out: hub rows re-home edges, so delta row identity breaks."""
-        if dflag <= 0 or getattr(snap, "hub_dense", None) is not None:
+        one small put, compiles once, and costs a read nothing until it
+        holds something (hop.py `_delta_live`).  Degree-split snapshots
+        opt out: hub rows re-home edges, so delta row identity breaks.
+        `dflag` > 0 fixes the capacity, < 0 takes `_delta_capacity`."""
+        if dflag == 0 or getattr(snap, "hub_dense", None) is not None:
             return
         if not (hasattr(store, "delta_records")
                 and hasattr(store, "delta_reader")):
             return
-        put_delta_blocks(dev, HostDelta(snap, dflag))
+        cap = dflag if dflag > 0 else self._delta_capacity(snap, headroom)
+        put_delta_blocks(dev, HostDelta(snap, cap))
 
     def _try_delta_update(self, store, space: str, cur):
         """Advance a delta-armed snapshot to the store's epoch without
         re-pinning.  Returns the snapshot on success, None to signal
         the full-rebuild path (log broken/overflow/unsupported key —
-        the rebuild discards every partially-mutated mirror)."""
-        rec = store.delta_records(space)
+        the rebuild discards every partially-mutated mirror).
+
+        One `tpu:delta_apply` span (phase `delta_apply`) with a child
+        for each thing a fresh read waits for: the store's census
+        (`tpu:delta_census`, twice: before and under the gate), the
+        gate (`tpu:delta_gate`), the re-read of every dirty key
+        (`tpu:delta_reread`) and the put of the changed blocks
+        (`device:delta_put`).  `tpu_delta_apply_s` is entry to return,
+        whatever the outcome."""
+        t0 = time.perf_counter()
+        try:
+            with _t.span("tpu:delta_apply", space=space):
+                return self._delta_update(store, space, cur)
+        finally:
+            _metrics().add_value("tpu_delta_apply_s",
+                                 time.perf_counter() - t0)
+
+    def _delta_update(self, store, space: str, cur):
+        def census():
+            with _t.span("tpu:delta_census"):
+                return store.delta_records(space)
+
+        rec = census()
         if rec is None:
             return None
         _, _, floor = rec
         if floor > cur.delta.applied_epoch:
             return None                 # log gap: keys before floor lost
-        from ..utils.stats import stats
-        wait_s = self._gate.acquire_write()
+        with _t.span("tpu:delta_gate"):
+            wait_s = self._gate.acquire_write()
         try:
             dev = self.snapshots.get(space)
             if dev is not cur or dev.retired or dev.delta is None:
                 return None
             # re-read under the gate: writers that landed while we
             # waited are folded into this same apply
-            rec = store.delta_records(space)
+            rec = census()
             if rec is None:
                 return None
             keys, target, floor = rec
@@ -935,36 +1037,46 @@ class TpuRuntime:
             if target == dev.delta.applied_epoch:
                 return dev               # a concurrent update got there
             try:
-                changes = dev.delta.host.apply(
-                    store.delta_reader(space), keys)
+                with _t.span("tpu:delta_reread", keys=len(keys)):
+                    changes = dev.delta.host.apply(
+                        store.delta_reader(space), keys)
             except (DeltaOverflow, DeltaUnsupported):
                 return None
-            put_delta_blocks(dev, dev.delta.host, sorted(changes.blocks))
-            host = dev.delta.host.snap
-            putter = None
-            if changes.num_vertices:
-                from .device import make_putter
-                putter = make_putter(dev.mesh, dev.num_parts)
-                dev.num_vertices = putter(
-                    np.asarray(host.num_vertices, np.int32))
-            if changes.tag_cols:
-                from .device import make_putter
-                putter = putter or make_putter(dev.mesh, dev.num_parts)
-                for tag, colname in sorted(changes.tag_cols):
-                    dt = dev.tags.get(tag)
-                    tt = host.tags.get(tag)
-                    if dt is None or tt is None:
-                        continue
-                    if colname == "present":
-                        dt.present = putter(tt.present)
-                    else:
-                        dt.props[colname] = putter(tt.props[colname])
+            t_put = time.perf_counter()
+            with _t.span("device:delta_put", blocks=len(changes.blocks)):
+                put_delta_blocks(dev, dev.delta.host,
+                                 sorted(changes.blocks))
+                host = dev.delta.host.snap
+                putter = None
+                if changes.num_vertices:
+                    from .device import make_putter
+                    putter = make_putter(dev.mesh, dev.num_parts)
+                    dev.num_vertices = putter(
+                        np.asarray(host.num_vertices, np.int32))
+                if changes.tag_cols:
+                    from .device import make_putter
+                    putter = putter or make_putter(dev.mesh,
+                                                   dev.num_parts)
+                    for tag, colname in sorted(changes.tag_cols):
+                        dt = dev.tags.get(tag)
+                        tt = host.tags.get(tag)
+                        if dt is None or tt is None:
+                            continue
+                        if colname == "present":
+                            dt.present = putter(tt.present)
+                        else:
+                            dt.props[colname] = putter(tt.props[colname])
+            _metrics().add_value("tpu_delta_put_s",
+                                 time.perf_counter() - t_put)
             dev.delta.applied_epoch = target
             store.delta_trim(space, keys)
         finally:
             self._gate.release_write()
-        st = stats()
-        st.observe("tpu_repin_wait_us", int(wait_s * 1e6))
+        st = _metrics()
+        # the apply's own wait for the gate: `tpu_repin_wait_us` is a
+        # re-pin's, which this avoided
+        st.observe("tpu_delta_gate_wait_us", int(wait_s * 1e6))
+        st.add_value("tpu_delta_keys", len(keys))
         st.inc("tpu_repin_avoided")
         self._emit_delta_gauges(dev)
         self._maybe_compact(store, space, dev)
@@ -1015,6 +1127,10 @@ class TpuRuntime:
         st.gauge("tpu_delta_edges",
                  float(hd.total_edges() + hd.total_tombs()))
         st.gauge("tpu_delta_bytes", float(hd.nbytes()))
+        # per (block, part): fill is the fullest buffer's rows or
+        # tombstones over it
+        st.gauge("tpu_delta_capacity_edges", float(hd.dcap))
+        st.gauge("tpu_delta_fill_ratio", float(hd.fill_ratio()))
         per = hd.edges_per_part()
         tpp = hd.tombs_per_part()
         for p in range(dev.num_parts):
@@ -1053,6 +1169,10 @@ class TpuRuntime:
             with _t.span("tpu:compaction", space=space):
                 dflag = self._delta_flag()
                 snap = self._build_fresh(store, space, dflag)
+                # the new base with the writes folded in is a few rows
+                # larger than the one it replaces: it is held to the
+                # same budget, and a refusal leaves the old one serving
+                headroom = self._check_hbm_budget(snap, space)
                 fail.hit("tpu:compact_swap", key=space)
                 self._gate.acquire_write()
                 try:
@@ -1066,7 +1186,7 @@ class TpuRuntime:
                     self._fns = {k: v for k, v in self._fns.items()
                                  if not (k[0] == space
                                          and k[1] != new.epoch)}
-                    self._arm_delta(store, new, snap, dflag)
+                    self._arm_delta(store, new, snap, dflag, headroom)
                 finally:
                     self._gate.release_write()
                 stats().inc("tpu_compactions")
@@ -1079,10 +1199,13 @@ class TpuRuntime:
         finally:
             dev._compacting = False
 
-    def _check_hbm_budget(self, snap, space: str) -> None:
+    def _check_hbm_budget(self, snap, space: str) -> Optional[int]:
         """HBM budget (SURVEY §2 row 5: device memory is the scarce
         resource): refuse to pin past the PER-DEVICE limit; the caller
         falls back to the host path instead of OOMing the chip.
+        Returns the bytes a device keeps free under the limit once
+        `snap` is pinned (what `_delta_capacity` sizes the delta plane
+        within); None when no limit is set.
 
         The limit is per device — that is the scale-out contract: a
         snapshot sharded P ways parks hbm_bytes/P on each chip, so an
@@ -1091,7 +1214,7 @@ class TpuRuntime:
         from ..utils.memtracker import get_config as _gc  # flag defined there
         limit = int(_gc().get("tpu_hbm_limit_bytes"))
         if not limit:
-            return
+            return None
         P = self.mesh_size if (not self.local_mode
                                and snap.num_parts == self.mesh_size) else 1
         est = -(-snap.hbm_bytes() // P)
@@ -1105,6 +1228,7 @@ class TpuRuntime:
                 f"snapshot needs {est:,}B HBM per device "
                 f"({P} shard(s)); {others:,}B already pinned per device, "
                 f"limit {limit:,} (flag tpu_hbm_limit_bytes)")
+        return limit - est - others
 
     @staticmethod
     def _maybe_degree_split(snap):
@@ -1139,6 +1263,7 @@ class TpuRuntime:
         stats().observe("tpu_repin_wait_us", int(wait_s * 1e6))
         stats().inc("tpu_pins")
         self._emit_hbm_gauges()
+        self._emit_delta_gauges(dev)
         return dev
 
     def unpin(self, space: str):
@@ -1899,10 +2024,12 @@ class TpuRuntime:
         dview, blocks = self._block_leaves(dev, block_keys,
                                            prop_names | set(yield_cols))
         blocks_data = tuple(blocks)
-        if fetch_keys is not None and dview is not None:
+        if fetch_keys is not None and any(
+                _delta_rows_of(dview, bk) for bk in block_keys):
             # delta rows interleave with base rows in canonical CSR
             # order at materialize time — the host re-sort needs every
-            # identity column regardless of what the yields read
+            # identity column regardless of what the yields read; a
+            # plane that holds no row of these blocks adds no column
             fetch_keys |= {"src", "dst", "rank", "eidx"}
         hub_dense = getattr(dev.host, "hub_dense", None)
         hub_n = 0 if hub_dense is None else len(hub_dense)
@@ -2073,26 +2200,15 @@ class TpuRuntime:
                      for et, _ in block_keys}
         def make_decode(et, dirn, sgn):
             hb = host.blocks[(et, dirn)]
-            de = None if dview is None else dview[1].get((et, dirn))
-            ext_cache: Dict[str, np.ndarray] = {}
-
-            def _ecol(n):
-                # delta rows gather at virtual eidx = Emax + slot: the
-                # base column extends with the view's numpy mirror
-                if de is None:
-                    return hb.props[n]
-                c = ext_cache.get(n)
-                if c is None:
-                    c = ext_cache[n] = np.concatenate(
-                        [hb.props[n], de["np"]["d_props"][n]], axis=1)
-                return c
+            de = _delta_rows_of(dview, (et, dirn))
 
             def decode_seg(payload, offs):
                 ss, dd, rr, ee, sel_p = payload
                 ss, dd = ss[offs], dd[offs]
                 rr, ee, sp = rr[offs], ee[offs], sel_p[offs]
                 props = {n: decode_prop_column(
-                    hb.prop_types[n], _ecol(n)[sp, ee], host.pool)
+                    hb.prop_types[n],
+                    _merged_gather(hb.props[n], de, n, sp, ee), host.pool)
                     for n in hb.props}
                 sv = ss if d2v_id else d2v_arr[ss]
                 dvv = dd if d2v_id else d2v_arr[dd]
@@ -2128,12 +2244,12 @@ class TpuRuntime:
                 if not pids:
                     continue
                 perms = None
-                if dview is not None \
-                        and dview[1].get((et, dirn)) is not None:
+                de = _delta_rows_of(dview, (et, dirn))
+                if de is not None:
                     perms = self._delta_perms(
                         cap["src"][:, h], cap["dst"][:, h],
                         cap["rank"][:, h], bi, pids, P,
-                        d2v_arr, d2v_id)
+                        d2v_arr, d2v_id, de["rows"])
 
                 def catp(name, dtype=None):
                     return _cat_rows([cap[name][p, h, bi] for p in pids],
@@ -2199,13 +2315,11 @@ class TpuRuntime:
         rev_of = {"out": "in", "in": "out"}
         rev_keys = [(et, rev_of[d]) for et, d in block_keys
                     if d in rev_of]
-        # direction-optimizing is OFF while a delta plane is armed:
-        # bottom-up scans the reverse adjacency with swapped endpoint
-        # semantics the delta merge doesn't model — forcing top-down
-        # keeps every level's expansion delta-correct (have_rev is in
-        # the jit key, and delta-armed is stable per pin, so this never
-        # flip-flops compilations)
-        have_rev = (self.local_mode and dev.delta is None
+        # with a delta plane armed the program itself keeps a level
+        # top-down while the plane holds anything (bfs.py: bottom-up
+        # scans the reverse adjacency, which the merge does not model),
+        # so an armed, empty plane changes no level's direction
+        have_rev = (self.local_mode
                     and len(rev_keys) == len(block_keys)
                     and all(rk in dev.blocks for rk in rev_keys))
         pnames = {n for n in pred_cols if not n.startswith("_")}
@@ -2258,7 +2372,7 @@ class TpuRuntime:
 
     @staticmethod
     def _delta_perms(cap_src, cap_dst, cap_rank, bi, pids, P,
-                     d2v_arr, d2v_id):
+                     d2v_arr, d2v_id, rows):
         """Per-part permutations restoring canonical CSR slot order over
         the merged base+delta capture: within a part, base rows sit in
         (local_src, rank, dst_key) order and delta rows are appended —
@@ -2266,9 +2380,14 @@ class TpuRuntime:
         have placed the new rows.  dst_key matches native.kernels.
         dst_sort_key: the vid itself for int vids, code-point string
         order otherwise (np.unique ordinals preserve it).  Keys are
-        unique per live edge, so the sort is deterministic."""
+        unique per live edge, so the sort is deterministic.  A part
+        whose delta buffer holds no row (`rows[p]` == 0) keeps its
+        order: None in its place; None for all when no part needs one."""
         perms = []
         for p in pids:
+            if not rows[p]:
+                perms.append(None)
+                continue
             s_ = _whole(cap_src[p, bi]).astype(np.int64)
             d_ = _whole(cap_dst[p, bi]).astype(np.int64)
             r_ = _whole(cap_rank[p, bi])
@@ -2279,7 +2398,7 @@ class TpuRuntime:
                 if dk.dtype == object:
                     dk = dk.astype("U")
             perms.append(np.lexsort((dk, r_, s_ // P)))
-        return perms
+        return perms if any(pm is not None for pm in perms) else None
 
     def _block_columns(self, store: GraphStore, space: str,
                        dev: DeviceSnapshot, block_keys, cap,
@@ -2306,7 +2425,7 @@ class TpuRuntime:
         P = kcount.shape[0]
         for bi, (et, dirn) in enumerate(block_keys):
             hb = host.blocks[(et, dirn)]
-            de = None if dview is None else dview[1].get((et, dirn))
+            de = _delta_rows_of(dview, (et, dirn))
             # kept entries are a device-compacted PREFIX per part row —
             # selection is contiguous slices, not a 2D fancy gather
             # (nonzero + fancy indexing cost ~60% of materialization at
@@ -2320,7 +2439,7 @@ class TpuRuntime:
             if de is not None:
                 perms = self._delta_perms(
                     cap["src"], cap["dst"], cap["rank"], bi, pids, P,
-                    d2v_arr, d2v_id)
+                    d2v_arr, d2v_id, de["rows"])
 
             def catp(name, dtype=None):
                 return _cat_rows([cap[name][p, bi] for p in pids],
@@ -2344,15 +2463,10 @@ class TpuRuntime:
                         ee_parts = [_whole(cap["eidx"][p, bi])
                                     for p in pids]
                         if perms is not None:
-                            ee_parts = [a[pm] for a, pm in
-                                        zip(ee_parts, perms)]
-                    col = hb.props[n]
-                    if de is not None:
-                        # extend with the delta mirror: delta rows carry
-                        # virtual eidx = Emax + slot
-                        col = np.concatenate(
-                            [col, de["np"]["d_props"][n]], axis=1)
-                    raw = [col[p][e] for p, e in zip(pids, ee_parts)]
+                            ee_parts = [a if pm is None else a[pm]
+                                        for a, pm in zip(ee_parts, perms)]
+                    raw = [_merged_gather(hb.props[n], de, n, p, e)
+                           for p, e in zip(pids, ee_parts)]
                     raw = np.concatenate(raw) if len(raw) > 1 else raw[0]
                 else:
                     continue

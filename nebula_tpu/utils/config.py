@@ -212,15 +212,25 @@ define_flag("graph_statement_capacity_qps", 0,
             "drain warm-up; bench use: model per-coordinator "
             "capacity for the fleet scale-out sweep on hosts whose "
             "cores can't isolate graphds (ISSUE 20)")
-define_flag("tpu_delta_max_edges", 0,
-            "device delta-CSR capacity per (block, part) in edges "
-            "(rounded up to a power of two; 0 = delta plane off, "
-            "every epoch bump re-pins the full snapshot).  With the "
-            "delta on, group-committed writes land as a small "
-            "device_put into a padded delta buffer that every "
-            "traversal kernel merges with the base CSR each hop")
+define_flag("tpu_delta_max_edges", -1,
+            "device delta-CSR capacity per (block, part) in edges.  "
+            "Negative (the default): the delta plane is armed wherever "
+            "the store feeds one (a dirty-key log and a key re-reader) "
+            "and the snapshot is not degree-split, with the capacity "
+            "worked out at pin time: 1/64 of the part's padded edge "
+            "width as a power of two, at least 1024 so that a serving "
+            "window's writes stay under tpu_delta_compact_watermark, "
+            "halved until all delta buffers of a device fit 1/64 of "
+            "the HBM headroom under tpu_hbm_limit_bytes "
+            "(TpuRuntime._delta_capacity).  A positive value fixes the "
+            "capacity (rounded up to a power of two); 0 turns the plane "
+            "off: every epoch bump re-exports and re-pins the full "
+            "snapshot.  With the plane armed, group-committed writes "
+            "land as a small device_put into a padded delta buffer that "
+            "the traversal kernels merge with the base CSR where it "
+            "holds something; an empty plane costs a read nothing")
 define_flag("tpu_delta_compact_watermark", 0.75,
-            "delta fill ratio (of tpu_delta_max_edges, insert or "
+            "delta fill ratio (of the delta plane's capacity, insert or "
             "tombstone side) above which the background compaction "
             "job rebuilds the base CSR off the gate and swaps it "
             "under a short write-side hold")

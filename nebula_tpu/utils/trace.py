@@ -305,6 +305,11 @@ class _TraceGuard:
             us, n = fold_phases(spans)
             stats().inc_labeled_many(
                 "phase", {PHASE_US: us, PHASE_N: n})
+            # a mutating statement: entry to its LAST write request's
+            # acknowledgement (the store marks each)
+            acked = [s["t0"] for s in spans if s["name"] == WRITE_ACKED]
+            if acked:
+                stats().add_value("write_ack_s", max(acked) - rec["t0"])
         trace_store().add(self._ctx.tid, rec["name"], spans)
         return False
 
@@ -427,9 +432,13 @@ PHASE_US, PHASE_N = "stmt_phase_us", "stmt_phase_n"
 #: the FIXED phase vocabulary (at most 16 labels): where a statement's
 #: time went, by the self time of its spans.  `other` is the root's own
 #: self time — what no child span explains.
-PHASES = ("parse", "plan", "admit", "exec", "snapshot_check", "rpc_wait",
-          "remote", "queue", "put", "dispatch", "fetch", "materialise",
-          "encode", "other")
+# zero-length marker a store leaves when a write request of the statement
+# is acknowledged, every part's reply in (cluster/dstore.py)
+WRITE_ACKED = "storage:write_acked"
+
+PHASES = ("parse", "plan", "admit", "exec", "snapshot_check", "delta_apply",
+          "rpc_wait", "remote", "queue", "put", "dispatch", "fetch",
+          "materialise", "encode", "other")
 
 # span name -> phase, by the first prefix that matches; None = a
 # zero-length marker that is not a unit of work.  What no prefix names
@@ -439,12 +448,18 @@ _PHASE_BY_PREFIX = (
     ("graphd:parse", "parse"), ("graphd:plan", "plan"),
     ("graphd:admit", "admit"), ("graphd:encode", "encode"),
     ("tpu:snapshot_check", "snapshot_check"),
+    # a fresh read folding acknowledged writes into the resident delta
+    # plane (`TpuRuntime._try_delta_update`): its own bookkeeping, the
+    # wait for the gate and the put; its census and key re-reads are
+    # RPCs and read `rpc_wait` / `remote`
+    ("tpu:delta_", "delta_apply"), ("device:delta_put", "delta_apply"),
     ("device:queue", "queue"), ("device:launch_wait", "queue"),
     ("device:put", "put"),
     ("device:dispatch", "dispatch"), ("device:fetch", "fetch"),
     ("device:materialise", "materialise"),
     ("rpc:retry", None), ("rpc:breaker", None),
     ("storage:dedup_hit", None), ("storage:follower_read", None),
+    (WRITE_ACKED, None),
     ("storage:", "rpc_wait"), ("rpc:", "rpc_wait"), ("meta:", "rpc_wait"),
     ("query:", "other"))
 _phase_cache: Dict[str, Optional[str]] = {}

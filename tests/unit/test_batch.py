@@ -355,11 +355,16 @@ def test_bucket_jit_and_kept_size_keys_keep_their_form(clean):
     program and its kept size are cached under `key_fn(ebs)`."""
     st = batched_store()
     rt = TpuRuntime(make_mesh(1))
-    epoch = rt.pin(st, "bt").epoch
+    dev = rt.pin(st, "bt")
+    epoch = dev.epoch
+    # the last element is the delta plane's static shape: armed at
+    # default flags, at the capacity the pin worked out
+    sig = rt._delta_sig(dev)
+    assert sig == ("delta", dev.delta.host.dcap, dev.delta.host.tcap)
 
     def key(ebs):
         return ("bt", epoch, (("E", "out"),), 2, ebs, None, True, (), (),
-                0, None)
+                0, sig)
     ebs = (4096, 8192)
     assert rt.init_eb < ebs[0]
     rt._buckets[(key(()), 2)] = (0, ebs)

@@ -32,11 +32,15 @@ from test_tpu import _hubby_store                            # noqa: E402
 PC = 64                 # two words of 32 ids a trip
 WHOLE = 1 << 30
 EBS = (2048, 2048)
-PLANES = ("plain", "hubs", "delta")
+PLANES = ("plain", "hubs", "delta", "armed")
 FRONTIERS = ("empty", "one", "sparse", "every", "degree0", "one-part")
-FLAGS = {"hubs": {"tpu_degree_split_threshold": 8},
+# "plain" holds no delta plane (the flag's explicit 0); "armed" is the
+# default flags' plane, armed at the capacity a pin works out and empty
+FLAGS = {"plain": {"tpu_delta_max_edges": 0},
+         "hubs": {"tpu_degree_split_threshold": 8},
          "delta": {"tpu_delta_max_edges": 64,
-                   "tpu_delta_compact_watermark": 2.0}}
+                   "tpu_delta_compact_watermark": 2.0},
+         "armed": {}}
 
 
 @functools.lru_cache(maxsize=None)
@@ -83,6 +87,10 @@ def _plane(name):
         assert len(kw["hub_dense"]) > 0
     if name == "delta":
         assert blocks[0]["d_src"].shape[-1] and blocks[0]["d_valid"].any()
+    if name == "armed":
+        assert blocks[0]["d_src"].shape[-1] and not blocks[0]["d_valid"].any()
+    if name == "plain":
+        assert "d_src" not in blocks[0]
     return blocks, kw, P
 
 
@@ -308,8 +316,8 @@ def test_plan_counters_move_only_over_a_wide_bitmap(monkeypatch):
     st = store_p(1, n=240)
     vids = [1, 2, 3]
     before = moved()
-    rows, ts = TpuRuntime(make_mesh(1)).traverse(
-        st, "g", vids, ["knows"], "out", 2)
+    rt = TpuRuntime(make_mesh(1))
+    rows, ts = rt.traverse(st, "g", vids, ["knows"], "out", 2)
     assert rows and moved() == before
     assert (ts.plan_run, ts.plan_budget) == (0, 0)
 
@@ -323,8 +331,11 @@ def test_plan_counters_move_only_over_a_wide_bitmap(monkeypatch):
     assert len(rows2) == len(rows)
     assert 0 < run < budget
     assert (ts2.plan_run, ts2.plan_budget) == (run, budget)
-    # two hops over one part's 240 ids: two scatters of 240 each
-    assert budget == 2 * 2 * 240
+    # two hops over one part's 240 ids (and the rows the armed delta
+    # plane keeps for new vertices): two scatters of that width each
+    width = rt.snapshots["g"].vmax
+    assert width == 240 + int(get_config().get("tpu_delta_vmax_slack"))
+    assert budget == 2 * 2 * width
 
 
 # -- by shapes alone: the four-chip cell's program at its full size ----------

@@ -84,10 +84,27 @@ def test_pin_and_hbm(rt):
     assert dev.hbm_bytes() > 0
     # same epoch → cached object
     assert rt.pin(st, "g") is dev
-    # write bumps epoch → re-pin
+    # a write bumps the epoch: at default flags the armed delta plane
+    # takes it (the snapshot stays, its served epoch advances) ...
+    assert dev.delta is not None
     st.insert_edge("g", 0, "knows", 1, 9, {"w": 1, "f": .5, "tag": "x"})
     dev2 = rt.pin(st, "g")
-    assert dev2 is not dev and dev2.epoch != dev.epoch
+    assert dev2 is dev and rt._served_epoch(dev) == st.space("g").epoch
+    assert rt._served_epoch(dev) != dev.epoch
+    # ... and with the plane off (the flag's explicit 0) it re-pins
+    from nebula_tpu.utils.config import get_config
+    cfg = get_config()
+    cfg.set_dynamic("tpu_delta_max_edges", 0)
+    try:
+        rt0 = TpuRuntime(make_mesh(P))
+        dev = rt0.pin(st, "g")
+        assert dev.delta is None
+        st.insert_edge("g", 0, "knows", 2, 9, {"w": 1, "f": .5, "tag": "x"})
+        dev2 = rt0.pin(st, "g")
+        assert dev2 is not dev and dev2.epoch != dev.epoch
+    finally:
+        with cfg.lock:
+            cfg.dynamic_layer.pop("tpu_delta_max_edges", None)
 
 
 @pytest.mark.parametrize("steps", [1, 2, 3])

@@ -59,13 +59,21 @@ def _struct(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _block(P, vmax, E, sharding, props=("w",), rev=False):
-    """One CSR block's kernel leaves as shapes (runtime.py `_bd`)."""
+def _block(P, vmax, E, sharding, props=("w",), rev=False, dcap=0):
+    """One CSR block's kernel leaves as shapes (runtime.py `_bd`); with
+    `dcap`, an armed delta plane's too (`_grab_delta`)."""
+    def cols(width):
+        return {n: _struct((P, width), np.float64 if n == "f" else np.int64,
+                           sharding) for n in props}
     b = {"indptr": _struct((P, vmax + 1), np.int32, sharding),
          "nbr": _struct((P, E), np.int32, sharding),
          "rank": _struct((P, E), np.int32, sharding),
-         "props": {n: _struct((P, E), np.float64 if n == "f" else np.int64,
-                              sharding) for n in props}}
+         "props": cols(E)}
+    if dcap:
+        b.update({k: _struct((P, dcap), np.bool_ if k == "d_valid"
+                             else np.int32, sharding)
+                  for k in ("d_src", "d_dst", "d_rank", "d_valid", "d_tomb")},
+                 d_props=cols(dcap))
     if rev:
         b.update(rev_indptr=b["indptr"], rev_nbr=b["nbr"],
                  rev_rank=b["rank"], rev_props={})
@@ -130,6 +138,33 @@ def test_proxy_cell_go3_by_need_loops_compile(one_chip):
     assert secs < 120, f"the proxy cell's program took {secs:.0f}s to compile"
     # the second hop's expansion, the last hop's, its property gathers
     assert compiled.as_text().count(" while(") >= 3
+
+
+@pytest.mark.parametrize("filtered", [False, True], ids=["go1", "go3w"])
+def test_served_go_with_an_armed_delta_plane_compiles(one_chip, filtered):
+    """What the served cells run since the delta plane is armed at
+    default flags (10,000 persons over 8 parts, the capacity the rule
+    gives: 1,024): a capture EB + 1,024 wide whose merge stages each sit
+    behind a `cond` on the plane's live count.  The TPU compiler has to
+    take a `cond` inside a by-need loop's body and around one."""
+    from nebula_tpu.tpu.hop import build_traverse_fn
+    P, vmax, E, dcap = 8, 1250 + 64, 40_960, 1 << 10
+    if filtered:
+        fn = build_traverse_fn(None, P, (8192,) * 3, 3, n_blocks=1,
+                               capture=True, pred=lambda c: c["w"] > 50,
+                               pred_cols=("w",), yield_cols=("w",))
+        props = ("w",)
+    else:
+        fn = build_traverse_fn(None, P, (8192,), 1, n_blocks=1,
+                               capture=True, yield_cols=("f", "w"))
+        props = ("f", "w")
+    compiled, secs = _compile(
+        fn, (_block(P, vmax, E, one_chip, props=props, dcap=dcap),),
+        _struct((P, vmax), np.bool_, one_chip))
+    assert secs < 60, f"an armed program took {secs:.0f}s to compile"
+    # the tombstone test, the rows, the compaction or the prefix, the
+    # delta column's gathers: each a conditional in the compiled program
+    assert compiled.as_text().count(" conditional(") >= 4
 
 
 @pytest.mark.parametrize("shape", [(P8, 1, 1 << 22), (1, 1, 1 << 19)],
