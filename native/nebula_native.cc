@@ -282,4 +282,14 @@ long long row_decode(const unsigned char* in, long long len,
     return n16;
 }
 
+// A fetched property column's 32-bit halves (device.py split_halves)
+// joined into its 64-bit host column: out[i] = lo[i] | hi[i] << 32, one
+// pass at a copy's rate.  numpy has no one-pass interleave: two strided
+// stores a row cost twice the copy (runtime.py _join_halves).
+void join_halves(const uint32_t* lo, const uint32_t* hi, uint64_t* out,
+                 long long n) {
+    for (long long i = 0; i < n; ++i)
+        out[i] = (uint64_t)lo[i] | ((uint64_t)hi[i] << 32);
+}
+
 }  // extern "C"

@@ -31,7 +31,8 @@ import jax
 import jax.numpy as jnp
 
 from ..tpu.hop import (_delta_cap, _delta_live, _drop_live_tombstones,
-                       _expand_block, _live_rows, _mark, _mark_rows)
+                       _expand_block, _live_rows, _mark, _mark_rows,
+                       take_halves)
 
 __all__ = ["expand_part", "top_down_step", "bottom_up_step",
            "sharded_level_step", "delta_live"]
@@ -52,7 +53,7 @@ def _keep(block, src, dst, rk, eidx, ve, pred, pred_cols,
     cols = {"_rank": rk, "_src": ps, "_dst": pd}
     for name in pred_cols:
         if not name.startswith("_"):
-            cols[name] = block["props"][name][eidx]
+            cols[name] = take_halves(block["props"][name], eidx)
     return pred(cols) & ve
 
 

@@ -82,6 +82,23 @@ def _numpy_coo_csr(src_dense, dst_dense, rank, dst_key, P, vmax, emax):
     return indptr, nbr, rk, perm, emax
 
 
+def join_halves(pair: np.ndarray, out: np.ndarray) -> None:
+    """A piece `(2, n)` `uint32` of a property column's 32-bit halves,
+    low half first (tpu/device.py `split_halves`), joined into the `n`
+    64-bit slots of `out`.  One pass in the library, at a copy's rate
+    and without the GIL; numpy's two strided stores a row, at half that
+    rate, where the library is missing."""
+    n = pair.shape[-1]
+    lib = get_lib()
+    if (lib is not None and pair.dtype == np.uint32
+            and pair.strides[-1] == 4 and out.flags.c_contiguous):
+        lib.join_halves(pair[0].ctypes.data, pair[1].ctypes.data,
+                        out.ctypes.data, n)
+        return
+    words = out.view(np.uint32).reshape(n, 2)
+    words[:, 0], words[:, 1] = pair[0], pair[1]
+
+
 def dst_sort_key(dst_vids: Sequence) -> np.ndarray:
     """int64 ordering key per neighbor: the vid itself for ints, the
     sorted-unique ordinal for strings (matches _nbr_key)."""

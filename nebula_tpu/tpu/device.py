@@ -10,10 +10,12 @@ from __future__ import annotations
 
 import logging
 import os
+import sys
 from dataclasses import dataclass, field
 from typing import Any, Dict, Optional, Tuple
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 from jax import shard_map  # noqa: F401 — re-exported to hop.py / bfs.py
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
@@ -201,6 +203,101 @@ def mesh_parts(mesh: Mesh) -> int:
     return int(dict(mesh.shape).get("part", 1))
 
 
+def split_halves(col: np.ndarray) -> np.ndarray:
+    """A 64-bit property column `(..., E)` as its two 32-bit bit halves
+    `(..., 2, E)` `uint32`, low half first: what a chip works on in the
+    column's place.
+
+    A TPU has no 64-bit lanes: a program handed a 64-bit operand splits
+    the WHOLE operand into such a pair at the top of every run (two
+    column-sized passes a column, whatever the statement reads of it).
+    Edge property columns therefore live on the device as the pair and
+    nowhere as a 64-bit array (`pin_snapshot`, `put_delta_blocks`); the
+    host snapshot keeps its 64-bit columns.  One rule for both dtypes a
+    column has (csr.py `_col_dtype`): an `int64` (ints, bools, string
+    codes, temporal) and a `float64` alike travel as their bits, so a
+    carried value comes back to the bit on every backend.  hop.py
+    `take_halves` gathers a pair, `join_halves` rebuilds a gathered
+    slot's 64-bit value for a predicate, runtime.py `_join_halves`
+    joins fetched halves on the host."""
+    if col.dtype.itemsize != 8 or sys.byteorder != "little":
+        raise TypeError(f"not a 64-bit little-endian column: {col.dtype}")
+    w = np.ascontiguousarray(col).view(np.uint32).reshape(col.shape + (2,))
+    return np.ascontiguousarray(np.moveaxis(w, -1, -2))
+
+
+def nan_halves(pair):
+    """Which gathered slots of a `float64` column's halves hold a NaN
+    (the column's NULL), read off the bits: on every backend the stored
+    double's own answer."""
+    lo, hi = pair[..., 0, :], pair[..., 1, :]
+    return ((hi & 0x7FF00000) == 0x7FF00000) & (((hi & 0xFFFFF) | lo) != 0)
+
+
+def _double_of_halves(pair):
+    """The double a chip computes with for a stored double's bit halves:
+    `float64(h) + float64(l) + float64(r)`, three float32 worked out of
+    the bits in 32-bit integer arithmetic.  `h` is the double rounded to
+    float32 (nearest, ties to even) and `l` the rest rounded the same
+    way: the pair a host-to-chip transfer makes of a double (numpy:
+    `h = float32(x)`, `l = float32(x - float64(h))`), which is what a
+    chip without 64-bit lanes holds for a `float64` and what a predicate
+    read before the columns were pinned as halves.  `r` is what `l`
+    leaves, so where doubles are native the sum is the stored double to
+    the bit.  Outside float32's range as the transfer has it: an
+    infinity over it, zero under its smallest normal number; a NaN stays
+    one (`nan_halves` is the NULL test, not this value)."""
+    lo, hi = pair[..., 0, :], pair[..., 1, :]
+    i32, f32 = jnp.int32, jnp.float32
+    sign = hi & 0x80000000
+    e = ((hi >> 20) & 0x7FF).astype(i32)          # the double's exponent field
+    top = ((hi & 0xFFFFF) << 3) | (lo >> 29) | 0x800000   # leading 24 bits
+    rest = (lo & 0x1FFFFFFF).astype(i32)          # the 29 under them
+    up = (rest > (1 << 28)) | ((rest == (1 << 28)) & ((top & 1) == 1))
+    # float32's exponent field is e - 896; a significand that rounds up
+    # to 2^24 carries into it
+    sig = top.astype(i32) + up
+    e32 = e - 896 + (sig >> 24)
+    over, under = e32 >= 255, e32 <= 0  # over: NaN and infinity too
+    hbits = ((jnp.clip(e, 896, 1151) - 896) << 23) + (sig - 0x800000)
+    hbits = jnp.where(nan_halves(pair), 0x7FC00000,
+                      jnp.where(over, 0x7F800000,
+                                jnp.where(under, 0, hbits)))
+    h = jax.lax.bitcast_convert_type(sign | hbits.astype(jnp.uint32), f32)
+    # the rest in units of 2^(e - 1075), at most 2^28 either way, then
+    # scaled by two exact powers of two (one alone leaves the range)
+    d = rest - (up.astype(i32) << 29)
+    d1 = d.astype(f32)
+    d2 = (d - d1.astype(i32)).astype(f32)
+    k = e - 1075
+    ka = jnp.clip(k, -126, 127)
+    kb = jnp.clip(k - ka, -126, 0)
+    scale = (jax.lax.bitcast_convert_type((ka + 127) << 23, f32),
+             jax.lax.bitcast_convert_type((kb + 127) << 23, f32),
+             jnp.where(sign != 0, f32(-1), f32(1)))
+    keep = ~(over | under)
+    out = h.astype(jnp.float64)
+    for part in (d1, d2):
+        for m in scale:
+            part = part * m
+        out = out + jnp.where(keep, part, f32(0)).astype(jnp.float64)
+    return out
+
+
+def join_halves(pair, dtype):
+    """The 64-bit values (`dtype`: int64 or float64) of gathered halves
+    `(..., 2, n)` -> `(..., n)` inside a device program, what a compiled
+    predicate reads (exprjit.py).  Per gathered slot, never over a
+    column.  An integer is the shift-or of its halves.  A double is
+    built from 32-bit pieces (`_double_of_halves`): a chip without
+    64-bit lanes has no exact reinterpretation of 64 bits as one."""
+    if jnp.dtype(dtype) == jnp.float64:
+        return _double_of_halves(pair)
+    lo, hi = pair[..., 0, :], pair[..., 1, :]
+    bits = (hi.astype(jnp.uint64) << 32) | lo.astype(jnp.uint64)
+    return jax.lax.bitcast_convert_type(bits, dtype)
+
+
 @dataclass
 class DeviceBlock:
     """One (edge type, direction) CSR block resident on the mesh."""
@@ -209,7 +306,8 @@ class DeviceBlock:
     indptr: Any                       # (P, Vmax+1) i32, sharded on axis 0
     nbr: Any                          # (P, Emax)   i32
     rank: Any                         # (P, Emax)   i32
-    props: Dict[str, Any] = field(default_factory=dict)   # (P, Emax)
+    # (P, 2, Emax) u32: a column's 32-bit halves (`split_halves`)
+    props: Dict[str, Any] = field(default_factory=dict)
     prop_types: Dict[str, PropType] = field(default_factory=dict)
 
 
@@ -230,7 +328,8 @@ class DeviceDelta:
     (graphstore.delta.HostDelta) the arrays are rebuilt from."""
     host: Any
     # bk → {"d_src","d_dst","d_rank","d_valid","d_tomb": device arrays,
-    #        "d_props": {name: device array},
+    #        "d_props": {name: device array, the column's 32-bit halves
+    #                    (P, 2, Dcap) as a block's props},
     #        "np": the numpy block_arrays dict these were put from,
     #        "rows": live delta rows per part when these were put}
     blocks: Dict[Tuple[str, str], Dict[str, Any]] = field(
@@ -336,7 +435,9 @@ def make_putter(mesh: Mesh, num_parts: int):
     mode puts partition p's row directly onto column-p device(s) and
     assembles with make_array_from_single_device_arrays (no host-side
     concat, no all-device broadcast copy), replicated down the lane
-    axis."""
+    axis.  `put(a, prep)` places `prep` of the array, a part at a time
+    where parts travel apart: the host never holds `prep` of a whole
+    sharded column (`split_halves` of 180 M values)."""
     P = mesh_parts(mesh)
     L = mesh_lanes(mesh)
     if P == 1:
@@ -344,20 +445,21 @@ def make_putter(mesh: Mesh, num_parts: int):
         # the local (vmap) kernel runs the same program without ICI
         dev0 = mesh.devices.reshape(-1)[0]
 
-        def put(a: np.ndarray):
-            return jax.device_put(a, dev0)
+        def put(a: np.ndarray, prep=None):
+            return jax.device_put(a if prep is None else prep(a), dev0)
         return put
     if num_parts == P:
         part0 = NamedSharding(mesh, PartitionSpec("part"))
         grid = mesh.devices.reshape(L, P)
 
-        def put(a: np.ndarray):
-            shards = []
-            for row in grid:                     # lane replicas
-                for p, d in enumerate(row):      # one partition per column
-                    shards.append(jax.device_put(a[p:p + 1], d))
+        def put(a: np.ndarray, prep=None):
+            shards = [None] * (L * P)
+            for p in range(P):                   # one partition per column
+                row = a[p:p + 1] if prep is None else prep(a[p:p + 1])
+                for lane in range(L):            # lane replicas
+                    shards[lane * P + p] = jax.device_put(row, grid[lane][p])
             return jax.make_array_from_single_device_arrays(
-                a.shape, part0, shards)
+                (P,) + row.shape[1:], part0, shards)
         return put
     raise TpuUnavailable(
         f"snapshot has {num_parts} parts but mesh has {P} devices; "
@@ -387,7 +489,7 @@ def put_delta_blocks(dev: DeviceSnapshot, host_delta,
             "np": arrs, "rows": [len(per) for per in host_delta.ins[bk]]}
         for k, v in arrs.items():
             if k == "d_props":
-                placed[k] = {n: put(a) for n, a in v.items()}
+                placed[k] = {n: put(a, split_halves) for n, a in v.items()}
                 moved += sum(a.nbytes for a in v.values())
             else:
                 placed[k] = put(v)
@@ -410,6 +512,11 @@ def pin_snapshot(snap: CsrSnapshot, mesh: Mesh) -> DeviceSnapshot:
     no all-device broadcast copy ever materialises. On a 2-axis
     ("lane", "part") mesh the CSR rows are replicated down each lane-axis
     column (each lane row sees its own resident copy of partition p).
+
+    An edge property column is placed as its 32-bit halves
+    (`split_halves`), the same bytes.  A tag's columns stay 64-bit: no
+    device program takes one as an operand (exprjit.py and pipeline.py
+    read them from the host snapshot).
     """
     put = make_putter(mesh, snap.num_parts)
     dev = DeviceSnapshot(space=snap.space, epoch=snap.epoch,
@@ -420,7 +527,7 @@ def pin_snapshot(snap: CsrSnapshot, mesh: Mesh) -> DeviceSnapshot:
         dev.blocks[key] = DeviceBlock(
             etype=b.etype, direction=b.direction,
             indptr=put(b.indptr), nbr=put(b.nbr), rank=put(b.rank),
-            props={k: put(v) for k, v in b.props.items()},
+            props={k: put(v, split_halves) for k, v in b.props.items()},
             prop_types=dict(b.prop_types))
     for name, t in snap.tags.items():
         dev.tags[name] = DeviceTag(

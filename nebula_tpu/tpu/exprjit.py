@@ -32,6 +32,7 @@ from ..core import expr as E
 from ..core.value import NullValue
 from ..graphstore.csr import INT_NULL, StringPool
 from ..graphstore.schema import PropType
+from .device import join_halves, nan_halves
 
 
 class CannotCompile(Exception):
@@ -259,11 +260,18 @@ def compile_predicate(e: E.Expr, prop_types: Dict[str, PropType],
             kind = _kind_of(pt)
             name = pname
             needed.add(name)
-            if kind == "float":
-                return lambda c: (c[name], jnp.isnan(c[name]), "float")
-            if kind == "bool":
-                return lambda c: (c[name] != 0, c[name] == INT_NULL, "bool")
-            return lambda c: (c[name], c[name] == INT_NULL, kind)
+            # the kernels hand a property column's gathered slots over
+            # as their 32-bit halves (device.py `split_halves`)
+            dtype = jnp.float64 if kind == "float" else jnp.int64
+
+            def g(c):
+                v = join_halves(c[name], dtype)
+                if kind == "float":
+                    return (v, nan_halves(c[name]), "float")
+                if kind == "bool":
+                    return (v != 0, v == INT_NULL, "bool")
+                return (v, v == INT_NULL, kind)
+            return g
         if isinstance(x, E.Unary):
             return _unary(x.op, build(x.operand))
         if isinstance(x, E.Binary):

@@ -391,6 +391,15 @@ def _expand_block(indptr, nbr, rank, fbm, EB: int, P: int, pid,
                          pid, vmax_local, hub_dense) + (total, ovf)
 
 
+def take_halves(col, i):
+    """One part's pinned property column `(2, E)` (device.py
+    `split_halves`) at the edge indices `i`: both 32-bit halves by the
+    one index array, `(2,) + i.shape`.  The only way a program reads a
+    property column: every operand of a gather is 32-bit, so no run
+    splits a whole column on a chip without 64-bit lanes."""
+    return col[:, i]
+
+
 def _drop_tombstoned(tomb, eidx, ve):
     """The delta merge's per-slot half: a searchsorted membership test
     drops base slots whose eidx was deleted/overwritten since the pin.
@@ -420,8 +429,9 @@ def _live_rows(over, b, pid, fbm, P: int, has_rows, rank_dtype,
     slots and only where the plane holds any (`has_rows`, a traced
     scalar): (src, dst, rank, kept, active), each lead + (Dcap,), all
     off where it holds none.  `pred` sees a delta row's own columns
-    (`d_props`) as its predicate columns.  Delta snapshots are never
-    hub-extended, so `fbm` is the plain membership row."""
+    (`d_props`, their halves) as its predicate columns.  Delta
+    snapshots are never hub-extended, so `fbm` is the plain membership
+    row."""
     dcap = _delta_cap(b)
     lead = fbm.shape[:-1]
 
@@ -431,7 +441,8 @@ def _live_rows(over, b, pid, fbm, P: int, has_rows, rank_dtype,
         k = act
         if pred is not None:
             k = pred({"_rank": r, "_src": s, "_dst": d, **{
-                c: jnp.broadcast_to(b["d_props"][c], s.shape)
+                c: jnp.broadcast_to(b["d_props"][c], s.shape[:-1]
+                                    + b["d_props"][c].shape[-2:])
                 for c in pcols}}) & act
         return s, d, r.astype(rank_dtype), k, act
 
@@ -475,17 +486,18 @@ def _delta_live(b):
 
 
 def _gather_merged(over, b, pid, c: str, e, emax: int, has_rows):
-    """Column `c` of a block at the (virtual) edge indices `e`: the base
-    column's values, and where the plane holds rows the delta column's
-    at the indices from `emax` on.  Two gathers, each from its own
-    column: the base column is never copied to be extended."""
-    take = over(lambda col, _p, i: col[i])
+    """Column `c` of a block at the (virtual) edge indices `e`, as its
+    halves (`take_halves`): the base column's values, and where the
+    plane holds rows the delta column's at the indices from `emax` on.
+    Two gathers, each from its own column: the base column is never
+    copied to be extended."""
+    take = over(lambda col, _p, i: take_halves(col, i))
     got = take(b["props"][c], pid, jnp.minimum(e, emax - 1))
     dcap = b["d_props"][c].shape[-1]
     return _when(
         has_rows,
         lambda g, i: jnp.where(
-            i >= emax,
+            (i >= emax)[..., None, :],
             take(b["d_props"][c], pid, jnp.clip(i - emax, 0, dcap - 1)), g),
         lambda g, i: g, got, e)
 
@@ -720,7 +732,8 @@ def _traverse(over, nlead: int, blocks, fbm, pid, extend, exchange, *,
                         blk["indptr"], blk["nbr"], blk["rank"], pl, tot,
                         lo, size, EB, P, pd, vmax, hubs_c)
                     with jax.named_scope("hop/pred_gather"):
-                        g = tuple(blk["props"][c][e] for c in hcols)
+                        g = tuple(take_halves(blk["props"][c], e)
+                                  for c in hcols)
                     return (s, d, r, e, v) + g
                 vals = over(part)(b, pid, plan, total)
                 if dcap:
@@ -732,10 +745,12 @@ def _traverse(over, nlead: int, blocks, fbm, pid, extend, exchange, *,
             lead = total.shape
             fills = [(-1, jnp.int32), (-1, jnp.int32),
                      (0, b["rank"].dtype), (0, jnp.int32), (False, bool)]
-            fills += [(0, b["props"][c].dtype) for c in hcols]
-            outs, r, bd = _by_need(
-                expand, tuple(jnp.full(lead + (EB,), f, dt)
-                              for f, dt in fills), n, EB, chunk=chunk)
+            outs = tuple(jnp.full(lead + (EB,), f, dt) for f, dt in fills)
+            # a predicate's columns ride the loop as their halves, which
+            # the compiled predicate joins (exprjit.py)
+            outs += tuple(jnp.zeros(lead + (2, EB), b["props"][c].dtype)
+                          for c in hcols)
+            outs, r, bd = _by_need(expand, outs, n, EB, chunk=chunk)
             run, budget = run + r, budget + bd
             src, dst, rk, eidx, ve = outs[:5]
             pcols = dict(zip(hcols, outs[5:]))
@@ -810,7 +825,8 @@ def _traverse(over, nlead: int, blocks, fbm, pid, extend, exchange, *,
                                     g = _gather_merged(over, b, pid, c, e,
                                                        emax, has_rows)
                                 else:
-                                    g = over(lambda col, _p, i: col[i])(
+                                    g = over(lambda col, _p, i:
+                                             take_halves(col, i))(
                                         b["props"][c], pid, e)
                                 got.append(_put(o, g, lo))
                         return tuple(got)
@@ -819,9 +835,12 @@ def _traverse(over, nlead: int, blocks, fbm, pid, extend, exchange, *,
                     # the fullest part's kept count, which reaches the
                     # tail only where it passes the budget
                     kmax = jnp.max(kc)
+                    # a yielded column stays its halves through the
+                    # loop and into the capture: lead + (2, EB + tail)
                     got, r, bd = _by_need(
-                        props, tuple(jnp.zeros(ce.shape, b["props"][c].dtype)
-                                     for c in yield_cols),
+                        props, tuple(jnp.zeros(
+                            ce.shape[:-1] + (2, ce.shape[-1]),
+                            b["props"][c].dtype) for c in yield_cols),
                         kmax, EB, dcap, chunk, kmax > EB if dcap else None)
                     run, budget = run + r, budget + bd
                     for c, g in zip(yield_cols, got):
@@ -925,15 +944,17 @@ def build_traverse_fn(mesh, P: int, EB, steps: int, n_blocks: int, *,
     values are gathered ON DEVICE from the pinned prop columns at the
     compacted final-hop slots and captured as `prop:<name>` arrays, so
     the host fetches exactly the result columns instead of eidx + a
-    host-side gather (GO capture mode only; x64 is enabled, so device
-    gathers are bit-exact with the host decode).
+    host-side gather (GO capture mode only).  A column is gathered and
+    captured as its 32-bit halves (`take_halves`), which the host joins:
+    a carried value comes back to the bit.
 
     chunk: the by-need loops' chunk (`_by_need`); plan_chunk: the
     member plan's trip and the bitmap width over which it is compiled
     (`_expand_plan`); the module constants everywhere but in tests.
 
     blocks_data (runtime arg): tuple of n_blocks dicts with keys
-      indptr (P, vmax+1), nbr (P, E), rank (P, E), props {name: (P, E)}
+      indptr (P, vmax+1), nbr (P, E), rank (P, E),
+      props {name: (P, 2, E) 32-bit halves}
     where props holds the columns the predicate needs PLUS yield_cols
     (any other result prop decodes on host via the captured eidx); the
     delta plane's d_* leaves carry the part axis too.
@@ -950,7 +971,8 @@ def build_traverse_fn(mesh, P: int, EB, steps: int, n_blocks: int, *,
         where the bitmap is no wider than plan_chunk)
       ovf_expand lead, bool: some hop's expansion exceeded EB
       cap (if capture): dict of lead + (n_blocks, EB) arrays
-        src, dst, rank, eidx, prop:<name> per yield_col — the final
+        src, dst, rank, eidx, and lead + (n_blocks, 2, EB) halves
+        prop:<name> per yield_col — the final
         hop's edge set (kept entries compacted to a prefix;
         kcount lead + (n_blocks,) gives the counts; what a prop array
         holds past its kept count is unspecified)

@@ -20,6 +20,7 @@ from contextlib import contextmanager, nullcontext
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
@@ -30,6 +31,7 @@ from ..graphstore.csr import (build_snapshot, decode_prop_column,
 from ..graphstore.delta import (DeltaOverflow, DeltaUnsupported, HostDelta,
                                 pow2 as _delta_pow2)
 from ..graphstore.store import GraphStore
+from ..native.kernels import join_halves as native_join_halves
 from ..utils import trace as _t
 from ..utils.stats import stats as _metrics
 from .device import (DeviceSnapshot, TpuUnavailable, make_mesh,
@@ -125,13 +127,32 @@ def _cap_keys_for_yields(yields, device_props=()) -> Optional[set]:
     return need
 
 
+def _join_halves(parts, dtype) -> np.ndarray:
+    """Fetched pieces of a property column's 32-bit halves, each
+    `(2, n)` (device.py `split_halves`), as ONE owned 64-bit column of
+    `dtype`: the join rides the pass that concatenates the pieces
+    (native/kernels.py `join_halves`: one pass a piece)."""
+    n = sum(a.shape[-1] for a in parts)
+    out = np.empty(n, dtype)
+    at = 0
+    for a in parts:
+        to = at + a.shape[-1]
+        native_join_halves(a, out[at:to])
+        at = to
+    return out
+
+
 def _cat_parts(parts, dtype=None):
     """Concatenate per-part kept-prefix slices of a capture array (the
     device compacts kept entries to the front of each part row) —
     contiguous slices instead of a 2D fancy gather, preserving
-    (part, slot) order.  Always returns an owned array: a view of the
-    K-padded capture buffer must not escape into long-lived results
-    (it would pin the whole bucket for a handful of rows)."""
+    (part, slot) order; a property column's halves are joined on the
+    way into the 64-bit `dtype` of its host column (`_join_halves`).
+    Always returns an owned array: a view of the K-padded capture
+    buffer must not escape into long-lived results (it would pin the
+    whole bucket for a handful of rows)."""
+    if parts[0].ndim == 2:
+        return _join_halves(parts, dtype)
     if dtype is not None:
         if len(parts) > 1:
             return np.concatenate(parts, dtype=dtype)   # one pass
@@ -143,7 +164,7 @@ def _cat_parts(parts, dtype=None):
 
 def _whole(pieces) -> np.ndarray:
     """A fetched capture row (its pieces in slot order) as one array."""
-    return pieces[0] if len(pieces) == 1 else np.concatenate(pieces)
+    return pieces[0] if len(pieces) == 1 else np.concatenate(pieces, axis=-1)
 
 
 def _cat_rows(rows, perms=None, dtype=None):
@@ -152,7 +173,8 @@ def _cat_rows(rows, perms=None, dtype=None):
     first (the delta plane's canonical CSR order)."""
     if perms is None:
         return _cat_parts([a for pieces in rows for a in pieces], dtype)
-    return _cat_parts([_whole(pieces) if pm is None else _whole(pieces)[pm]
+    return _cat_parts([_whole(pieces) if pm is None
+                       else _whole(pieces)[..., pm]
                        for pieces, pm in zip(rows, perms)], dtype)
 
 
@@ -282,14 +304,14 @@ _FETCHED = ("hop_edges", "ovf_expand", "kcount", "frontier_sizes",
 # every row, `v[..., :k]`, k a power of two from SLICE_MIN up (the
 # whole width last), speculated from the program's last run.
 #
-# Wider: every row apart, in flat pieces cut on the device that holds
-# it, so the bytes follow each row's own count (not the fullest row's,
-# rounded up, for all) and a 64-bit column comes as 1-D arrays, which
-# the one-chip host moves at 3 GB/s where it moves the slices' [8, k]
-# at 0.2.  What the chips charge (PERF.md section 6, PR 31, has the
-# tables): a piece 0.6 ms on the four-chip host (its launch and a
-# transfer a column) to 3 ms on one chip (a 64-bit operand of 268 MB is
-# split into its halves whole before the slice) however small it is, a
+# Wider: every row apart, in pieces cut on the device that holds it, so
+# the bytes follow each row's own count (not the fullest row's, rounded
+# up, for all).  What the chips charged when the constants below were
+# settled (PERF.md section 6, PR 31, has the tables): a piece 0.6 ms on
+# the four-chip host (its launch and a transfer a column) to 3 ms on
+# one chip however small it is (there most of it the split of a whole
+# 64-bit operand before the slice, which no capture holds since PR 35:
+# a property column is its 32-bit halves, `(2, size)` a piece), a
 # byte 0.3 to 0.5 ns, and a second round trip waits behind whatever
 # another session has on the chips.  Hence: a row comes in ONE piece of
 # the smallest of PIECES that holds it unless a second piece saves
@@ -311,14 +333,21 @@ def _head(cap, k: int):
 
 @functools.partial(jax.jit, static_argnames="size")
 def _piece(cap, at, size: int):
-    """`size` slots of one row of each of these capture columns, flat,
-    from `at` = the row's index and the first slot, both traced: one
+    """`size` slots of one row of each of these capture columns, from
+    `at` = the row's index and the first slot, both traced: one
     executable a capture shape, column set and size.  A start past
-    W - size is clamped to it (`lax.dynamic_slice`)."""
-    return {n: jax.lax.dynamic_slice(
-        v, [at[i] for i in range(v.ndim)],
-        (1,) * (v.ndim - 1) + (size,)).reshape(size)
-        for n, v in cap.items()}
+    W - size is clamped to it (`lax.dynamic_slice`).  A column comes
+    flat, `(size,)`; a property column (one axis more, its halves,
+    before the slots) as `(2, size)`: ONE array and one transfer a
+    column either way."""
+    row = [at[i] for i in range(at.shape[0] - 1)]
+
+    def cut(v):
+        halves = (2,) * (v.ndim - at.shape[0])
+        return jax.lax.dynamic_slice(
+            v, row + [jnp.int32(0)] * len(halves) + [at[-1]],
+            (1,) * len(row) + halves + (size,)).reshape(halves + (size,))
+    return {n: cut(v) for n, v in cap.items()}
 
 
 def _nbytes(tree) -> int:
@@ -341,20 +370,28 @@ class _Heads:
     beyond what was asked before; `got` takes them once on the host;
     `rows` is the fetched capture: per column an object array over the
     rows, of each row's pieces in slot order, trimmed to its kept
-    count.  The programs run over the wanted columns together (one
-    launch, not one a column), so they are compiled for a program's
-    key AND the columns its statement reads (`TpuRuntime._warm_fetch`)."""
+    count (a property column's pieces are its halves, `(2, n)`, which
+    `_cat_rows` joins).  The programs run over the wanted columns
+    together (one launch, not one a column), so they are compiled for a
+    program's key AND the columns its statement reads
+    (`TpuRuntime._warm_fetch`)."""
 
     def __init__(self, cap_dev, want=None):
         self.dev = cap_dev
         self.want = [n for n in cap_dev if want is None or n in want]
         self.W = next(iter(cap_dev.values())).shape[-1]
+        # the axes that index a row, lead + (nb,): all but the slots,
+        # and but a property column's halves
+        self.nrow = min(v.ndim for v in cap_dev.values()) - 1
         self.k = 0
         self.host: Dict[str, np.ndarray] = {}
         self.nbytes = 0
 
     def item_bytes(self) -> int:
-        return sum(self.dev[n].dtype.itemsize for n in self.want)
+        """Bytes of one kept entry over the wanted columns (a property
+        column's two halves: 8)."""
+        return sum(self.dev[n].dtype.itemsize * (self.dev[n].ndim - self.nrow)
+                   for n in self.want)
 
     def _k(self, n: int) -> int:
         return min(self.W, max(SLICE_MIN, _pow2(n)))
@@ -383,7 +420,7 @@ class _Heads:
         for n, a in self.host.items():
             col = cap[n] = np.empty(kc.shape, object)
             for idx in np.ndindex(kc.shape):
-                col[idx] = [a[idx][:kc[idx]]]
+                col[idx] = [a[idx][..., :kc[idx]]]
         return cap
 
 
@@ -402,7 +439,8 @@ class _Pieces(_Heads):
         for n in self.want:
             for s in cap_dev[n].addressable_shards:
                 if s.replica_id == 0:
-                    base = tuple(sl.start or 0 for sl in s.index[:-1])
+                    base = tuple(sl.start or 0
+                                 for sl in s.index[:self.nrow])
                     self.shards.setdefault(base, {})[n] = s.data
         self.sizes = [c for c in PIECES if c <= self.W]
         self.have: Dict[Tuple, int] = {}    # row -> slots asked for
@@ -423,19 +461,19 @@ class _Pieces(_Heads):
 
     def _rows(self):
         for base, cols in self.shards.items():
-            lead = next(iter(cols.values())).shape[:-1]
+            lead = next(iter(cols.values())).shape[:self.nrow]
             for idx in np.ndindex(lead):
                 yield cols, idx, tuple(b + i for b, i in zip(base, idx))
 
     def warm(self):
-        at = np.zeros(next(iter(self.dev.values())).ndim, np.int32)
+        at = np.zeros(self.nrow + 1, np.int32)
         for cols in self.shards.values():
             for c in self.sizes:
                 _piece(cols, at, c)
 
     def speculate(self, counts):
         counts = np.broadcast_to(counts, next(
-            iter(self.dev.values())).shape[:-1])
+            iter(self.dev.values())).shape[:self.nrow])
         out = []
         for cols, idx, row in self._rows():
             if 0 < counts[row] <= SPEC_ROWS:
@@ -470,7 +508,7 @@ class _Pieces(_Heads):
             end = skip + min(c, int(kc[row]) - slot)
             if end > skip:
                 for n, col in cap.items():
-                    col[row].append(piece[n][skip:end])
+                    col[row].append(piece[n][..., skip:end])
         return cap
 
 
@@ -699,6 +737,7 @@ class TpuRuntime:
         # (PR 12 composition fix)
         self._mesh_epoch = 0
         self.snapshots: Dict[str, DeviceSnapshot] = {}
+        # program key → (the program, bytes of its 64-bit operands)
         self._fns: Dict[Tuple, Any] = {}
         # program key → last kept-prefix fetch size: arms the
         # speculative single-phase result fetch (one device round trip
@@ -1326,7 +1365,6 @@ class TpuRuntime:
         fn = self._seed_fns.get(key)
         if fn is not None:
             return key, fn
-        import jax.numpy as jnp
         if not isinstance(target, jax.sharding.Sharding):
             sh = jax.sharding.SingleDeviceSharding(target)
         else:
@@ -1704,11 +1742,16 @@ class TpuRuntime:
                 # age out with their snapshot like solo programs do;
                 # the mesh key separates per-grid compilations
                 key += ("lanes", L, self._mesh_key())
-            fn = self._fns.get(key)
-            compiled = fn is None
+            hit = self._fns.get(key)
+            compiled = hit is None
             if compiled:
-                fn = self._fns[key] = build_fn(ebs)
+                # with the program, the bytes of its 64-bit operands:
+                # fixed by what it is built over (tpu_wide_operand_bytes)
+                hit = self._fns[key] = (build_fn(ebs), sum(
+                    a.nbytes for a in jax.tree.leaves(inputs_fn(ebs))
+                    if a.dtype.itemsize == 8))
                 info["compiles"] += 1
+            fn, wide = hit
             # per-rung bookkeeping stays PLAIN-PYTHON here (ints and a
             # list append on locals): the dispatch neighborhood is
             # timing-sensitive under concurrent serve-while-repin (a
@@ -1769,6 +1812,10 @@ class TpuRuntime:
         if "chunks_run" in res:
             for k in _ENGAGEMENT:
                 m.inc(f"tpu_hop_{k}", int(res[k].sum()))
+        # what pinning property columns as their halves removed: bytes
+        # of 64-bit operands of the program just run, each of which a
+        # chip without 64-bit lanes splits WHOLE at the top of the run
+        m.add_value("tpu_wide_operand_bytes", float(wide))
         m.add_value("tpu_kernel_s", info["device_s"])
         m.add_value("tpu_put_s", info["put_s"])
         m.add_value("tpu_fetch_s", info["fetch_s"])
@@ -2456,8 +2503,9 @@ class TpuRuntime:
             for n in (hb.props if prop_names is None else
                       [x for x in prop_names if x in hb.props]):
                 if ("prop:" + n) in cap:
-                    # device-gathered yield column: fetched ready-made
-                    raw = catp("prop:" + n)
+                    # device-gathered yield column: fetched ready-made,
+                    # its halves joined as the pieces are concatenated
+                    raw = catp("prop:" + n, hb.props[n].dtype)
                 elif "eidx" in cap:
                     if ee_parts is None:
                         ee_parts = [_whole(cap["eidx"][p, bi])

@@ -21,6 +21,7 @@ from nebula_tpu.utils.stats import stats
 
 tpu = pytest.importorskip("nebula_tpu.tpu")
 from nebula_tpu.tpu import TpuRuntime, make_mesh, runtime    # noqa: E402
+from nebula_tpu.tpu.device import split_halves               # noqa: E402
 
 from test_batch import (GO_TMPL, _concurrent, _run_stmt,     # noqa: E402,F401
                         clean, company, device_engine)
@@ -70,22 +71,34 @@ def fetched(monkeypatch):
 
 
 def _capture(P=2, nb=2, W=40):
+    """A capture as a traverse program returns it: the identity columns
+    lead + (nb, W), a property column its halves lead + (nb, 2, W)."""
     n = P * nb * W
     return {"src": jax.numpy.arange(n, dtype=np.int32).reshape(P, nb, W),
             "eidx": jax.numpy.arange(n, dtype=np.int32).reshape(P, nb, W) * 3,
-            "prop:f": jax.numpy.arange(n, dtype=np.float64).reshape(P, nb, W) / 7}
+            "prop:f": jax.numpy.asarray(split_halves(
+                (np.arange(n, dtype=np.float64) / 7).reshape(P, nb, W)))}
 
 
 def _held(cap_dev, rows, kc):
-    """Each fetched row is its kept prefix, piece after piece."""
+    """Each fetched row is its kept prefix, piece after piece; the
+    pieces of a property column are its halves, which join to the
+    column's values."""
     for n, col in rows.items():
         want = np.asarray(cap_dev[n])
         for idx in np.ndindex(kc.shape):
             got = col[idx]
             if kc[idx]:
                 np.testing.assert_array_equal(
-                    np.concatenate(got), want[idx][:kc[idx]], str((n, idx)))
-            assert sum(len(a) for a in got) == kc[idx]
+                    np.concatenate(got, axis=-1), want[idx][..., :kc[idx]],
+                    str((n, idx)))
+            assert sum(a.shape[-1] for a in got) == kc[idx]
+        if n.startswith("prop:") and kc.any():
+            live = np.arange(want.shape[-1]) < kc[..., None]
+            np.testing.assert_array_equal(
+                runtime._cat_rows(list(col.flat), dtype=np.float64),
+                (np.arange(want.size // 2, dtype=np.float64) / 7).reshape(
+                    live.shape)[live])
 
 
 @pytest.mark.parametrize("kc,cuts", [
@@ -159,7 +172,8 @@ def test_heads_slice_every_row_at_one_power_of_two():
     assert take.speculate(np.asarray(100)).keys() == {"src", "prop:f"}
     assert take.k == runtime.SLICE_MIN          # the floor
     got = take.ask(kc)
-    assert {v.shape for v in got.values()} == {(2, 2, 512)}
+    assert {n: v.shape for n, v in got.items()} == {
+        "src": (2, 2, 512), "prop:f": (2, 2, 2, 512)}
     take.got(jax.device_get(got))
     _held(cap_dev, take.rows(kc), kc)
     assert take.ask(kc) is None and take.nbytes == 2 * 2 * 512 * (4 + 8)
@@ -279,12 +293,13 @@ def test_a_statement_after_a_smaller_one_of_its_program(path, fetched, request, 
     kept, leaves = moved["tpu_fetch_bytes_kept"], jax.tree.leaves(second)
     assert first_bytes == warm_bytes            # what the small statement needed
     if path == "pieces":
-        # flat pieces alone, and with the speculated piece no more
-        # than the rows kept and the last piece's slack (one part, one
-        # block: one row)
-        assert all(a.ndim == 1 and a.shape[0] in SIZES for a in leaves)
+        # flat pieces alone (a property column's halves side by side),
+        # and with the speculated piece no more than the rows kept and
+        # the last piece's slack (one part, one block: one row)
+        assert all(a.shape in [(c,) for c in SIZES] + [(2, c) for c in SIZES]
+                   for a in leaves)
         assert second_bytes < kept < moved["tpu_fetch_bytes"] \
-            < first_bytes + kept + 16 * max(a.shape[0] for a in leaves)
+            < first_bytes + kept + 16 * max(a.shape[-1] for a in leaves)
     else:
         assert all(a.ndim == 3 for a in leaves) and second_bytes >= kept
     assert sorted(map(repr, rows)) == sorted(map(repr, TpuRuntime(

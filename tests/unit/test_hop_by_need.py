@@ -20,6 +20,7 @@ from nebula_tpu.utils.stats import stats
 tpu = pytest.importorskip("nebula_tpu.tpu")
 from nebula_tpu.tpu import TpuRuntime, make_mesh, runtime    # noqa: E402
 from nebula_tpu.tpu import hop                               # noqa: E402
+from nebula_tpu.tpu.device import join_halves, split_halves  # noqa: E402
 
 from test_delta import store_p                               # noqa: E402
 from test_tpu import _hubby_store                            # noqa: E402
@@ -54,8 +55,9 @@ def _block(seed=5):
     return {"indptr": indptr,
             "nbr": rng.integers(0, P * VMAX, (P, E)).astype(np.int32),
             "rank": rng.integers(0, 3, (P, E)).astype(np.int32),
-            "props": {"w": rng.integers(-5, 100, (P, E)),
-                      "f": rng.uniform(0, 1, (P, E))}}
+            # as pinned: a column's 32-bit halves (P, 2, E)
+            "props": {"w": split_halves(rng.integers(-5, 100, (P, E))),
+                      "f": split_halves(rng.uniform(0, 1, (P, E)))}}
 
 
 def _frontier(total):
@@ -65,6 +67,15 @@ def _frontier(total):
     f[0, DEGS.index(total)] = True
     f[1, 1 if total else 0] = True
     return f
+
+
+def _halves_same(g, w, kc, tag):
+    """A captured property column, lead + (nb, 2, W) halves, is the
+    same in both programs up to each row's kept count."""
+    assert g.dtype == np.uint32 and g.shape[-2] == 2, tag
+    live = np.broadcast_to(
+        (np.arange(g.shape[-1]) < kc[..., None])[..., None, :], g.shape)
+    assert np.array_equal(g[live], w[live]), tag
 
 
 def _same(got, want, tag=""):
@@ -78,17 +89,15 @@ def _same(got, want, tag=""):
         assert g.dtype == w.dtype and g.shape == w.shape, (tag, k)
         if k in IDENT:
             assert np.array_equal(g, w), (tag, k)
-        else:       # a property column: defined up to the kept count
-            live = np.arange(g.shape[-1]) < kc[..., None]
-            assert np.array_equal(g[live].view(np.int64),
-                                  w[live].view(np.int64)), (tag, k)
+        else:
+            _halves_same(g, w, kc, (tag, k))
     assert not want["chunks_run"].any() and not want["chunks_budget"].any()
     assert (got["chunks_run"] <= got["chunks_budget"]).all(), tag
     return got
 
 
 def _w_over_50(cols):
-    return cols["w"] > 50
+    return join_halves(cols["w"], np.int64) > 50
 
 
 @pytest.mark.parametrize("with_pred", [False, True],

@@ -26,7 +26,8 @@ from nebula_tpu.tpu import TpuRuntime, make_mesh, runtime    # noqa: E402
 from nebula_tpu.tpu import hop                               # noqa: E402
 
 from test_delta import store_p                               # noqa: E402
-from test_hop_by_need import GO_Q, IDENT, LAYOUTS, META, _rows  # noqa: E402
+from test_hop_by_need import (GO_Q, IDENT, LAYOUTS, META,      # noqa: E402
+                              _halves_same, _rows)
 from test_tpu import _hubby_store                            # noqa: E402
 
 PC = 64                 # two words of 32 ids a trip
@@ -148,10 +149,8 @@ def _same(got, want, tag):
         assert g.dtype == w.dtype and g.shape == w.shape, (tag, k)
         if k in IDENT:
             assert np.array_equal(g, w), (tag, k)
-        else:       # a property column: defined up to the kept count
-            live = np.arange(g.shape[-1]) < kc[..., None]
-            assert np.array_equal(g[live].view(np.int64),
-                                  w[live].view(np.int64)), (tag, k)
+        else:
+            _halves_same(g, w, kc, (tag, k))
     for k in ("chunks_run", "chunks_budget"):
         assert np.array_equal(got[k], want[k]), (tag, k)
     assert not want["plan_run"].any() and not want["plan_budget"].any()
@@ -379,8 +378,8 @@ def test_no_gather_or_scatter_is_as_wide_as_the_bitmap(plan_chunk, want):
         return jax.ShapeDtypeStruct(shape, dtype)
     block = {"indptr": s((P, vmax + 1), np.int32),
              "nbr": s((P, width), np.int32), "rank": s((P, width), np.int32),
-             "props": {"f": s((P, width), np.float64),
-                       "w": s((P, width), np.int64)}}
+             "props": {"f": s((P, 2, width), np.uint32),
+                       "w": s((P, 2, width), np.uint32)}}
     jaxpr = jax.make_jaxpr(fn)((block,), s((P, vmax), np.bool_))
     found = _indexed_ops(jaxpr.jaxpr, vmax, [])
     assert len(found) == want, found

@@ -169,3 +169,27 @@ def test_snapshot_uses_native_and_matches_host_order():
                 for (_, _, rank, dst, props, _) in st.get_neighbors(
                     "n", [vid], ["e"], "out")]
         assert got == want, vid
+
+
+@pytest.mark.parametrize("dtype", [np.int64, np.float64])
+def test_join_halves_is_the_numpy_join_to_the_bit(dtype, monkeypatch):
+    """A piece of a column's 32-bit halves joined by the library and by
+    numpy's strided stores (the fallback): the same 64-bit column, for a
+    contiguous piece, a slice of a wider one and an empty one."""
+    from nebula_tpu.native import kernels
+    from nebula_tpu.tpu.device import split_halves
+    rng = np.random.default_rng(35)
+    col = rng.integers(-2**63, 2**63 - 1, 10_001).view(dtype)
+    wide = np.zeros((2, col.size + 9), np.uint32)
+    wide[:, 4:4 + col.size] = split_halves(col)
+    for pair in (split_halves(col), wide[:, 4:4 + col.size],
+                 split_halves(col[:0])):
+        want = col[:pair.shape[-1]].view(np.int64)
+        got = np.full(pair.shape[-1], -1, dtype)
+        kernels.join_halves(pair, got)
+        assert (got.view(np.int64) == want).all()
+        with monkeypatch.context() as m:
+            m.setattr(kernels, "get_lib", lambda: None)
+            got = np.full(pair.shape[-1], -1, dtype)
+            kernels.join_halves(pair, got)
+        assert (got.view(np.int64) == want).all()

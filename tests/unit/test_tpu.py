@@ -163,6 +163,9 @@ def test_frontier_oracle(rt):
     "(knows.w & 1) == 0",
     "(knows.w ^ 3) > 40",
     "(knows.w | 8) < 60",
+    # both halves of an int64 and of a double rebuilt per slot (PR 35)
+    "knows.w < 0 OR knows.f >= 0.75",
+    "knows.f > 0.25 AND knows.f < 0.5 AND knows.w != -3",
 ])
 def test_predicate_parity(rt, where):
     st = random_store(5)

@@ -15,6 +15,7 @@ file for the same reason.  The persistent compile cache is off around
 them: an entry compiled for a described device cannot be read back.
 """
 import os
+import re
 import time
 
 import numpy as np
@@ -60,11 +61,13 @@ def _struct(shape, dtype, sharding):
 
 
 def _block(P, vmax, E, sharding, props=("w",), rev=False, dcap=0):
-    """One CSR block's kernel leaves as shapes (runtime.py `_bd`); with
-    `dcap`, an armed delta plane's too (`_grab_delta`)."""
+    """One CSR block's kernel leaves as shapes (runtime.py
+    `_block_leaves`); with `dcap`, an armed delta plane's too
+    (`_grab_delta`).  A property column is pinned as its 32-bit bit
+    halves (device.py `split_halves`)."""
     def cols(width):
-        return {n: _struct((P, width), np.float64 if n == "f" else np.int64,
-                           sharding) for n in props}
+        return {n: _struct((P, 2, width), np.uint32, sharding)
+                for n in props}
     b = {"indptr": _struct((P, vmax + 1), np.int32, sharding),
          "nbr": _struct((P, E), np.int32, sharding),
          "rank": _struct((P, E), np.int32, sharding),
@@ -120,24 +123,58 @@ def test_go_three_steps_capture_compiles(topo, one_chip, meshed, lanes):
     assert (compiled.as_text().count(" all-to-all(") == 2) == meshed
 
 
-def test_proxy_cell_go3_by_need_loops_compile(one_chip):
+def _w_over_50(cols):
+    from nebula_tpu.tpu.device import join_halves
+    return join_halves(cols["w"], np.int64) > 50
+
+
+def _f_over_half(cols):
+    from nebula_tpu.tpu.device import join_halves, nan_halves
+    return (join_halves(cols["f"], np.float64) > 0.5) & ~nan_halves(cols["f"])
+
+
+# a statement that carries `w` and `f`, and one that also filters on `w`
+USES = pytest.mark.parametrize("filtered", [False, True],
+                               ids=["carried", "filtered"])
+
+
+def _no_column_is_split(text):
+    """No run of this program splits a 64-bit operand into its halves
+    (`X64SplitLow` / `X64SplitHigh` over a whole `E`-slot column, two
+    passes a column, was 28% of the four-chip cell's device time): the
+    columns arrive as the halves, a predicate's 64-bit value is rebuilt
+    per gathered slot (device.py `join_halves`).  Nor does it ask the
+    compiler to reinterpret 64 bits as a double, which a TPU cannot do
+    exactly (it answers NaN for a finite double beyond float32's range):
+    a double is built from 32-bit pieces."""
+    assert "X64Split" not in text
+    assert not re.search(r"= [fsu]64\[[^=]*bitcast-convert\(", text)
+
+
+@pytest.mark.parametrize("filtered", [False, "w", "f"],
+                         ids=["carried", "filtered", "filtered_f"])
+def test_proxy_cell_go3_by_need_loops_compile(one_chip, filtered):
     """`snb-sf100-proxy.go3`'s program (benchmarks/configs): budgets
     (2048, 2^20, 2^22) a part, YIELD dst, w, f — the last two hops run
     their gathers in by-need loops (hop.py `_by_need`; the int64 `w` and
-    the float64 `f` ride the loop carry as 32-bit pairs on a TPU).  A
-    loop the TPU compiler rejects, or takes minutes over, fails here
-    and not first on the chip."""
+    the float64 `f` are 32-bit pairs as operands, through the loop
+    carry and in the capture).  A loop the TPU compiler rejects, or
+    takes minutes over, fails here and not first on the chip."""
     from nebula_tpu.tpu.hop import CHUNK, build_traverse_fn
     ebs = (1 << 11, 1 << 20, 1 << 22)
     assert ebs[0] <= CHUNK < ebs[1], "the cell no longer exercises the loop"
+    kw = {"w": dict(pred=_w_over_50, pred_cols=("w",)),
+          "f": dict(pred=_f_over_half, pred_cols=("f",))}.get(filtered, {})
     fn = build_traverse_fn(None, P8, ebs, 3, n_blocks=1, capture=True,
-                           yield_cols=("f", "w"))
+                           yield_cols=("f", "w"), **kw)
     compiled, secs = _compile(
         fn, (_block(P8, VMAX8, E8, one_chip, props=("f", "w")),),
         _struct((P8, VMAX8), np.bool_, one_chip))
     assert secs < 120, f"the proxy cell's program took {secs:.0f}s to compile"
+    text = compiled.as_text()
     # the second hop's expansion, the last hop's, its property gathers
-    assert compiled.as_text().count(" while(") >= 3
+    assert text.count(" while(") >= 3
+    _no_column_is_split(text)
 
 
 @pytest.mark.parametrize("filtered", [False, True], ids=["go1", "go3w"])
@@ -151,7 +188,7 @@ def test_served_go_with_an_armed_delta_plane_compiles(one_chip, filtered):
     P, vmax, E, dcap = 8, 1250 + 64, 40_960, 1 << 10
     if filtered:
         fn = build_traverse_fn(None, P, (8192,) * 3, 3, n_blocks=1,
-                               capture=True, pred=lambda c: c["w"] > 50,
+                               capture=True, pred=_w_over_50,
                                pred_cols=("w",), yield_cols=("w",))
         props = ("w",)
     else:
@@ -176,17 +213,17 @@ def test_fetch_pieces_compile(one_chip, shape):
     fits: the one-chip cell's (8, 1, 2^22) columns and one shard of the
     four-chip cell's."""
     from nebula_tpu.tpu import runtime
-    cap = {n: _struct(shape, dt, one_chip) for n, dt in [
-        ("src", np.int32), ("dst", np.int32), ("rank", np.int32),
-        ("eidx", np.int32), ("prop:w", np.int64), ("prop:f", np.float64)]}
+    cap = {n: _struct(shape, np.int32, one_chip)
+           for n in ("src", "dst", "rank", "eidx")}
+    cap.update({n: _struct(shape[:-1] + (2, shape[-1]), dt, one_chip)
+                for n, dt in [("prop:w", np.uint32), ("prop:f", np.uint32)]})
     at = _struct((len(shape),), np.int32, one_chip)
     for size in (c for c in runtime.PIECES if c <= shape[-1]):
         compiled, secs = _compile(runtime._piece, cap, at, size)
         assert secs < 20
-        # a 64-bit column is split into its halves WHOLE before the
-        # slice: the fixed cost a piece of a 268 MB operand read on the
-        # chip, and why pieces are few
-        assert compiled.as_text().count("X64SplitLow") == 2
+        # a property column is captured as its halves: no piece splits
+        # a 268 MB operand whole before its slice any more
+        _no_column_is_split(compiled.as_text())
 
 
 def test_match_var_len_capture_hops_compiles(one_chip):
@@ -198,7 +235,8 @@ def test_match_var_len_capture_hops_compiles(one_chip):
              _struct((P8, VMAX8), np.bool_, one_chip))
 
 
-def test_mesh_cell_go3_compiles_for_the_four_chip_host(topo):
+@USES
+def test_mesh_cell_go3_compiles_for_the_four_chip_host(topo, filtered):
     """`snb-sf300-proxy.go3-4chip`'s program (benchmarks/configs): 6 M
     persons over 4 parts of 50,331,648 padded slots, budgets (2048, 2^16,
     2^21) a part, YIELD dst, w, f, one partition per chip of the 2x2
@@ -211,14 +249,17 @@ def test_mesh_cell_go3_compiles_for_the_four_chip_host(topo):
     vmax, width = 1_500_000, 50_331_648
     mesh = Mesh(np.asarray(topo.devices[:P4]), ("part",))
     part = NamedSharding(mesh, PartitionSpec("part"))
+    kw = dict(pred=_w_over_50, pred_cols=("w",)) if filtered else {}
     fn = build_traverse_fn(mesh, P4, (1 << 11, 1 << 16, 1 << 21), 3,
-                           n_blocks=1, capture=True, yield_cols=("f", "w"))
+                           n_blocks=1, capture=True, yield_cols=("f", "w"),
+                           **kw)
     compiled, secs = _compile(
         fn, (_block(P4, vmax, width, part, props=("f", "w")),),
         _struct((P4, vmax), np.bool_, part))
     assert secs < 120, f"the mesh cell's program took {secs:.0f}s to compile"
     text = compiled.as_text()
     assert text.count(" all-to-all(") == 2 and " all-reduce(" not in text
+    _no_column_is_split(text)
     # each chip sends a row of ceil(vmax / 32) words to each of the four
     assert f"u32[{P4},1,{-(-vmax // 32)}]" in text
     assert a2a_payload_bytes(P4, vmax) == P4 * P4 * -(-vmax // 32) * 4
