@@ -4,12 +4,11 @@ since edge property columns are pinned as their 32-bit halves no traverse
 program takes a 64-bit operand (series `tpu_wide_operand_bytes`,
 tpu/runtime.py `_escalate_locked`).
 
-The reader is NOT in `BENCHMARK.json` yet: `test_write_read.py` holds the
-manifest's last six per-layer entries to be PR 32's, and a PR may neither
-edit that file nor put an entry anywhere but last.  The `benchmark` PR
-that frees the tail adds the entry (MB, lower, `program_counter`, layer
-"kernels", moves `stmts_per_s`, cells `snb-sf300-proxy.go3-4chip` and
-`snb-sf100-proxy.go3`); PERF.md section 7 says so."""
+In `BENCHMARK.json` since PR 36 (MB, lower, `program_counter`, layer
+"kernels", moves `stmts_per_s`, cells `snb-sf100-proxy.go3` and
+`snb-sf300-proxy.go3-4chip`): PR 35 brought the reader and the series and
+could not append the entry, because `test_write_read.py` then held the
+manifest's last six per-layer entries."""
 from __future__ import annotations
 
 import json
@@ -28,6 +27,15 @@ from benchmarks.lib import loader  # noqa: E402
 from test_phase_metrics import jax_config_restored  # noqa: E402,F401
 
 NAME = "kernel.wide_operand_mb"
+MANIFEST = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
+
+
+def test_the_manifest_entry():
+    m = next(m for m in MANIFEST["per_layer"] if m["name"] == NAME)     # wherever it stands
+    assert (m["unit"], m["better"], m["source"], m["layer"], m["moves"]) == \
+        ("MB", "lower", "program_counter", "kernels", "stmts_per_s")
+    assert {"snb-sf100-proxy.go3", "snb-sf300-proxy.go3-4chip"} <= set(m["workloads"])
+    assert set(m["workloads"]) <= {w["name"] for w in MANIFEST["workloads"]}
 
 
 def _read(moved):
@@ -62,3 +70,5 @@ def test_reads_zero_in_the_proxy_cells_rehearsal(cell, capsys, jax_config_restor
     moved = {k: v - c0.get(k, 0) for k, v in c1.items() if isinstance(v, (int, float))}
     assert moved["tpu_wide_operand_bytes.count"] == moved["tpu_kernel_runs"] > 0
     assert _read(moved) == 0.0
+    # and the run's own line carries it, now that the manifest names it for the cell
+    assert line["metrics"][NAME] == {"value": 0.0, "unit": "MB"}
