@@ -15,6 +15,7 @@ row 14).
 """
 from __future__ import annotations
 
+import contextlib
 import itertools
 import uuid
 from typing import Any, Dict, Iterable, List, Optional
@@ -164,6 +165,21 @@ class DistributedStore:
         with self._delta_lock:
             for k in keys:
                 log.note(k)
+
+    @contextlib.contextmanager
+    def _dirty(self, space: str, *keys):
+        """Note `keys` around the writes that change them: BEFORE they
+        ship (a write that fails half way leaves a superset, harmless:
+        an apply re-reads per key) and AGAIN once they are acknowledged.
+        The second note is what a concurrent apply cannot lose: one that
+        re-read a key before its write committed trims the generation it
+        was handed (`DeltaLog.trim`), not the one noted here, so the
+        first apply after the acknowledgement still finds the key."""
+        self._dnote(space, *keys)
+        try:
+            yield
+        finally:
+            self._dnote(space, *keys)
 
     def _dbreak(self, space: str) -> None:
         log = self._delta_logs.get(space)
@@ -318,8 +334,8 @@ class DistributedStore:
             row = apply_defaults(sv, props, insert_names)
             by_part.setdefault(self.sc.part_of(space, vid), []).append(
                 ("vertex", vid, tag, sv.version, row))
-        self._dnote(space, *(("v", r[0]) for r in rows))
-        self._write_many(space, by_part)
+        with self._dirty(space, *(("v", r[0]) for r in rows)):
+            self._write_many(space, by_part)
 
     def _chain_write(self, space: str, src: Any, dst: Any,
                      out_cmd: tuple, in_cmd: list):
@@ -404,19 +420,19 @@ class DistributedStore:
             by_dst.setdefault(dst_pid, []).append(tuple(in_cmd))
             dones.setdefault(src_pid, []).append(
                 ("chain_done", src_pid, cid))
-        self._dnote(space, *(("e", etype, src, dst, rank)
-                             for src, dst, rank, _props in rows))
         # out-halves (with journals) first — the source of truth — then
         # the in-halves, then the retirements.  The failpoints bracket
         # the two crash windows a batched TOSS chain has: after the
         # journaled out-halves (janitor re-drives the in-halves) and
         # after the in-halves (janitor retires stale journals)
-        fail.hit("toss:pre_out")
-        self._write_many(space, by_src)
-        fail.hit("toss:pre_in")
-        self._write_many(space, by_dst)
-        fail.hit("toss:pre_done")
-        self._write_many(space, dones)
+        with self._dirty(space, *(("e", etype, src, dst, rank)
+                                  for src, dst, rank, _props in rows)):
+            fail.hit("toss:pre_out")
+            self._write_many(space, by_src)
+            fail.hit("toss:pre_in")
+            self._write_many(space, by_dst)
+            fail.hit("toss:pre_done")
+            self._write_many(space, dones)
 
     def delete_vertex(self, space: str, vid: Any, with_edges: bool = True):
         if with_edges:
@@ -424,28 +440,31 @@ class DistributedStore:
             for (s, et, rank, other, _props, sd) in self.get_neighbors(
                     space, [vid], None, "both"):
                 if sd > 0:      # out-edge vid→other; mirror in-half at other
-                    self._dnote(space, ("e", et, vid, other, rank))
-                    self._write(space, self.sc.part_of(space, other),
-                                ("del_edge_half", vid, et, other, rank, "in"))
+                    with self._dirty(space, ("e", et, vid, other, rank)):
+                        self._write(space, self.sc.part_of(space, other),
+                                    ("del_edge_half", vid, et, other, rank,
+                                     "in"))
                 else:           # in-edge other→vid; mirror out-half at other
-                    self._dnote(space, ("e", et, other, vid, rank))
-                    self._write(space, self.sc.part_of(space, other),
-                                ("del_edge_half", other, et, vid, rank,
-                                 "out"))
-        self._dnote(space, ("v", vid))
-        self._write(space, self.sc.part_of(space, vid), ("del_vertex", vid))
+                    with self._dirty(space, ("e", et, other, vid, rank)):
+                        self._write(space, self.sc.part_of(space, other),
+                                    ("del_edge_half", other, et, vid, rank,
+                                     "out"))
+        with self._dirty(space, ("v", vid)):
+            self._write(space, self.sc.part_of(space, vid),
+                        ("del_vertex", vid))
 
     def delete_tag(self, space: str, vid: Any, tags: List[str]):
-        self._dnote(space, ("v", vid))
-        self._write(space, self.sc.part_of(space, vid),
-                    ("del_tag", vid, tags))
+        with self._dirty(space, ("v", vid)):
+            self._write(space, self.sc.part_of(space, vid),
+                        ("del_tag", vid, tags))
 
     def delete_edge(self, space: str, src: Any, etype: str, dst: Any,
                     rank: int):
-        self._dnote(space, ("e", etype, src, dst, rank))
-        self._chain_write(space, src, dst,
-                          ("del_edge_half", src, etype, dst, rank, "out"),
-                          ["del_edge_half", src, etype, dst, rank, "in"])
+        with self._dirty(space, ("e", etype, src, dst, rank)):
+            self._chain_write(
+                space, src, dst,
+                ("del_edge_half", src, etype, dst, rank, "out"),
+                ["del_edge_half", src, etype, dst, rank, "in"])
 
     def update_vertex(self, space: str, vid: Any, tag: str,
                       updates: Dict[str, Any]) -> bool:
@@ -456,9 +475,9 @@ class DistributedStore:
         tv = self.get_vertex(space, vid)
         if tv is None or tag not in tv:
             return False
-        self._dnote(space, ("v", vid))
-        self._write(space, self.sc.part_of(space, vid),
-                    ("upd_vertex", vid, tag, updates))
+        with self._dirty(space, ("v", vid)):
+            self._write(space, self.sc.part_of(space, vid),
+                        ("upd_vertex", vid, tag, updates))
         return True
 
     def update_edge(self, space: str, src: Any, etype: str, dst: Any,
@@ -469,11 +488,11 @@ class DistributedStore:
                 raise SchemaError(f"unknown prop `{k}'")
         if self.get_edge(space, src, etype, dst, rank) is None:
             return False
-        self._dnote(space, ("e", etype, src, dst, rank))
-        self._chain_write(
-            space, src, dst,
-            ("upd_edge_half", src, etype, dst, rank, updates, "out"),
-            ["upd_edge_half", src, etype, dst, rank, updates, "in"])
+        with self._dirty(space, ("e", etype, src, dst, rank)):
+            self._chain_write(
+                space, src, dst,
+                ("upd_edge_half", src, etype, dst, rank, updates, "out"),
+                ["upd_edge_half", src, etype, dst, rank, updates, "in"])
         return True
 
     # ---- read ----
@@ -756,7 +775,7 @@ class DistributedStore:
             # pin probe catches up; a FOREIGN write in the window bumps
             # the epoch past target, so the next probe re-runs this
             # census and breaks.  Either way no stale read is served.
-            keys = list(log.keys)
+            keys = log.records()
             floor = log.floor_epoch
         target = max((e for e, _t, _m in probe.values()), default=0)
         return keys, target, floor

@@ -25,9 +25,17 @@ Two host-side pieces live here (device placement is tpu/'s job):
   arrays the kernels consume.  Row encoding mirrors
   ``csr._build_block`` exactly (defaults, NULL sentinels, shared string
   pool) so merged results stay byte-identical to a full rebuild.
+
+A compaction folds the mirror into a fresh base without asking the
+store for anything: ``fold_base`` lays ``base − tombstones + rows`` of
+a frozen copy of the mirror out in canonical CSR order, and
+``HostDelta.adopt`` re-expresses, against that new base, the keys that
+were applied while it was being built.  The new base plus its plane is
+the old base plus its plane, edge for edge.
 """
 from __future__ import annotations
 
+import threading
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Set, Tuple
 
@@ -60,14 +68,23 @@ class DeltaLog:
     ``("v", vid)`` — identity only, no payload.  ``note()`` is called
     by every write path while a device snapshot is watching; the store
     holds its own lock around calls, so the log needs none.
+
+    Every note stamps its key with a new GENERATION, and ``trim()``
+    drops a key only at the generation the apply was handed: a key
+    noted again while an apply re-read it (a second writer, or the
+    same write noted before it shipped and again once acknowledged)
+    stays in the log for the next apply, whatever the first one saw.
     """
 
-    __slots__ = ("floor_epoch", "keys", "broken", "cap", "part_epochs")
+    __slots__ = ("floor_epoch", "keys", "broken", "cap", "part_epochs",
+                 "_gen")
 
     def __init__(self, floor_epoch: int = 0, cap: int = 65536):
         self.floor_epoch = int(floor_epoch)
         self.cap = int(cap)
-        self.keys: Dict[tuple, None] = {}
+        # key → generation of its newest note
+        self.keys: Dict[tuple, int] = {}
+        self._gen = 0
         self.broken = False
         # cluster feed: highest store epoch seen in a write ack, per
         # part (the group-commit ack path carries it) — the coverage
@@ -77,7 +94,8 @@ class DeltaLog:
     def note(self, key: tuple) -> None:
         if self.broken:
             return
-        self.keys[key] = None
+        self._gen += 1
+        self.keys[key] = self._gen
         if len(self.keys) > self.cap:
             self.broken = True
 
@@ -88,10 +106,18 @@ class DeltaLog:
         if epoch > self.part_epochs.get(pid, 0):
             self.part_epochs[pid] = epoch
 
+    def records(self) -> Dict[tuple, int]:
+        """The dirty keys now, each with its generation: what an apply
+        folds in and hands back to ``trim()``."""
+        return dict(self.keys)
+
     def trim(self, keys) -> None:
-        """Drop keys a successful delta apply consumed."""
-        for k in keys:
-            self.keys.pop(k, None)
+        """Drop the keys a successful delta apply consumed: `keys` is
+        what ``records()`` handed it, and a key noted since (another
+        generation) is kept."""
+        for k, gen in keys.items():
+            if self.keys.get(k) == gen:
+                del self.keys[k]
 
 
 @dataclass
@@ -136,6 +162,9 @@ class HostDelta:
             bk: [set() for _ in range(P)] for bk in snap.blocks}
         # per (etype,) cached encoded ALTER defaults keyed by prop name
         self._defaults: Dict[tuple, Dict[str, Any]] = {}
+        # held by whoever mutates rows and tombstones (`apply`, `adopt`)
+        # and by `freeze`, which copies them for a compaction's fold
+        self.lock = threading.Lock()
 
     # -- occupancy -------------------------------------------------------
 
@@ -227,20 +256,23 @@ class HostDelta:
         ch = changes or DeltaChanges()
         if self.snap.hub_dense is not None:
             raise DeltaUnsupported("degree-split snapshot")
-        for key in keys:
-            if key[0] == "e":
-                self._apply_edge(reader, key, ch)
-            elif key[0] == "v":
-                self._apply_vertex(reader, key[1], ch)
-            else:
-                raise DeltaUnsupported(f"unknown delta key {key[0]!r}")
-        P = self.snap.num_parts
-        for bk in self.ins:
-            for p in range(P):
+        with self.lock:
+            for key in keys:
+                if key[0] == "e":
+                    self._apply_edge(reader, key, ch)
+                elif key[0] == "v":
+                    self._apply_vertex(reader, key[1], ch)
+                else:
+                    raise DeltaUnsupported(f"unknown delta key {key[0]!r}")
+        self._held_to_caps(self.ins)
+        return ch
+
+    def _held_to_caps(self, blocks) -> None:
+        for bk in blocks:
+            for p in range(self.snap.num_parts):
                 if len(self.ins[bk][p]) > self.dcap or \
                         len(self.tomb[bk][p]) > self.tcap:
                     raise DeltaOverflow(f"{bk} part {p}")
-        return ch
 
     def _apply_edge(self, reader, key, ch: DeltaChanges) -> None:
         _, etype, src, dst, rank = key
@@ -381,6 +413,58 @@ class HostDelta:
                     ch.tag_cols.add((tag, name))
         self._kill_caches()
 
+    # -- compaction ------------------------------------------------------
+
+    def freeze(self):
+        """(rows, tombstones) as they stand between two applies, per
+        (block, part): what ``fold_base`` folds in.  A row's encoded
+        dict is replaced, never mutated, so the copies are shallow."""
+        with self.lock:
+            return ({bk: [dict(d) for d in per]
+                     for bk, per in self.ins.items()},
+                    {bk: [set(t) for t in per]
+                     for bk, per in self.tomb.items()})
+
+    def content(self, bk, p: int, li: int, nbr: int,
+                rank: int) -> Optional[Dict[str, Any]]:
+        """What base plus plane hold for one edge half: its encoded
+        row, or None where it is absent."""
+        enc = self.ins[bk][p].get((li, nbr, rank))
+        if enc is not None:
+            return enc
+        base = self._base_eidx(bk, p, li, nbr, rank)
+        if base is None or base in self.tomb[bk][p]:
+            return None
+        blk = self.snap.blocks[bk]
+        return {name: col[p, base].item() for name, col in blk.props.items()}
+
+    def adopt(self, old: "HostDelta", keys, dense_of) -> Set[Tuple[str, str]]:
+        """Carry into this (fresh) mirror what `old` holds for the edge
+        `keys`, relative to THIS mirror's base: the catch-up of a
+        compaction whose base was folded from a frozen copy of `old`
+        while `keys` were still being applied to it.  Reads nothing
+        from the store.  Vertex keys need nothing: a folded base shares
+        its vertex tables, counts and dictionary with the old one.
+        Returns the blocks that changed; raises like ``apply``."""
+        P = self.snap.num_parts
+        changed: Set[Tuple[str, str]] = set()
+        for key in keys:
+            if key[0] != "e":
+                continue
+            _, etype, src, dst, rank = key
+            s, d = dense_of(src), dense_of(dst)
+            if s is None or d is None:
+                continue                # never pinned, never applied
+            for bk, p, li, nbr in (((etype, "out"), s % P, s // P, d),
+                                   ((etype, "in"), d % P, d // P, s)):
+                if bk not in self.ins or li >= self.snap.vmax:
+                    continue
+                if self._apply_half(bk, p, li, nbr, rank,
+                                    old.content(bk, p, li, nbr, rank)):
+                    changed.add(bk)
+        self._held_to_caps(changed)
+        return changed
+
     # -- padded arrays (host copies; the runtime device_puts them) -------
 
     def block_arrays(self, bk) -> Dict[str, Any]:
@@ -422,6 +506,132 @@ class HostDelta:
             total += self.snap.num_parts * (
                 self.dcap * per_row + self.tcap * 4)
         return total
+
+
+def padded_width(rows: int, cap: int) -> int:
+    """A part's padded edge width for `rows` live slots under a delta
+    plane of `cap` edges a (block, part): at least `cap` free slots,
+    rounded up to a multiple of `cap`.  A compaction folds at most
+    `cap` rows into a part, so the first fold of a pinned base always
+    fits the width its programs were compiled for."""
+    cap = max(int(cap), 1)
+    return -(-(int(rows) + cap) // cap) * cap
+
+
+def _resized(a: np.ndarray, width: int, fill) -> np.ndarray:
+    """A new array of `a`'s rows at `width` slots (never narrower than
+    `a`), the slots past `a`'s own filled."""
+    width = max(width, a.shape[-1])
+    out = np.full(a.shape[:-1] + (width,), fill, a.dtype)
+    out[..., :a.shape[-1]] = a
+    return out
+
+
+def _prop_fill(dtype):
+    return np.nan if dtype == np.float64 else INT_NULL
+
+
+def pad_edge_width(snap: CsrSnapshot, cap: int) -> CsrSnapshot:
+    """Widen every block of `snap` (in place) to ``padded_width`` of
+    its fullest part: the slack a compaction folds into.  Pad slots
+    read as any other slot past a part's last row."""
+    for blk in snap.blocks.values():
+        width = padded_width(int(blk.indptr[:, -1].max(initial=0)), cap)
+        blk.nbr = _resized(blk.nbr, width, -1)
+        blk.rank = _resized(blk.rank, width, 0)
+        for name, col in blk.props.items():
+            blk.props[name] = _resized(col, width, _prop_fill(col.dtype))
+    return snap
+
+
+def _nbr_keyer(snap: CsrSnapshot):
+    """-> keys(nbr): the neighbour ordering key of
+    ``native.kernels.dst_sort_key`` for dense ids: the vid itself for
+    int vids, code-point string order otherwise; a neighbour without a
+    dense id keeps its place among equals (the sort is stable)."""
+    d2v = snap.dense_to_vid
+    sample = next((v for v in d2v if v is not None), None)
+    if isinstance(sample, int) and not isinstance(sample, bool):
+        table = np.asarray([-1 if v is None else v for v in d2v], np.int64)
+        none = -1
+    else:
+        table = np.asarray(["" if v is None else str(v) for v in d2v],
+                           dtype="U")
+        none = ""
+
+    def keys(nbr: np.ndarray) -> np.ndarray:
+        ok = (nbr >= 0) & (nbr < table.size)
+        return np.where(ok, table[np.where(ok, nbr, 0)], none)
+    return keys
+
+
+def fold_base(snap: CsrSnapshot, ins, tomb, cap: int) -> CsrSnapshot:
+    """A fresh base that holds `snap`'s edges minus the tombstoned
+    slots plus the plane's rows (`ins`, `tomb`: ``HostDelta.freeze()``),
+    each part in the canonical CSR order a rebuild would give (local
+    row, rank, neighbour key).  Nothing is read from the store, dense
+    ids keep their meaning, and the vertex side (tag tables, counts,
+    dictionary, string pool) is SHARED with `snap`: vertex changes are
+    applied to it in place, so the two never differ there.  A block
+    keeps its padded width while its fullest part fits, and grows to
+    ``padded_width`` where it does not.  The epoch is `snap`'s: the
+    programs compiled over it stay valid over the new base."""
+    P = snap.num_parts
+    out = CsrSnapshot(space=snap.space, epoch=snap.epoch, num_parts=P,
+                      vmax=snap.vmax, num_vertices=snap.num_vertices,
+                      tags=snap.tags, pool=snap.pool,
+                      dense_to_vid=snap.dense_to_vid,
+                      hub_dense=snap.hub_dense)
+    keys = None
+    for bk, blk in snap.blocks.items():
+        counts = [int(blk.indptr[p, -1]) - len(tomb[bk][p])
+                  + len(ins[bk][p]) for p in range(P)]
+        width = blk.nbr.shape[1]
+        if max(counts, default=0) > width:
+            width = padded_width(max(counts), cap)
+        indptr = blk.indptr.copy()
+        nbr = _resized(blk.nbr, width, -1)
+        rank = _resized(blk.rank, width, 0)
+        props = {n: _resized(c, width, _prop_fill(c.dtype))
+                 for n, c in blk.props.items()}
+        for p in range(P):
+            rows, dead = ins[bk][p], tomb[bk][p]
+            if not rows and not dead:
+                continue
+            n0 = int(blk.indptr[p, -1])
+            keep = np.ones(n0, bool)
+            if dead:
+                keep[np.fromiter(dead, np.int64, len(dead))] = False
+            local = np.repeat(np.arange(snap.vmax, dtype=np.int64),
+                              np.diff(blk.indptr[p]))[keep]
+            new = list(rows.items())
+            local = np.concatenate(
+                [local, np.asarray([k[0] for k, _ in new], np.int64)])
+            nb = np.concatenate(
+                [blk.nbr[p, :n0][keep],
+                 np.asarray([k[1] for k, _ in new], blk.nbr.dtype)])
+            rk = np.concatenate(
+                [blk.rank[p, :n0][keep],
+                 np.asarray([k[2] for k, _ in new], blk.rank.dtype)])
+            keys = keys or _nbr_keyer(snap)
+            order = np.lexsort((keys(nb), rk, local))
+            n1 = order.size
+            nbr[p, :n1], nbr[p, n1:] = nb[order], -1
+            rank[p, :n1], rank[p, n1:] = rk[order], 0
+            for name, col in blk.props.items():
+                vals = np.concatenate(
+                    [col[p, :n0][keep],
+                     np.asarray([enc[name] for _, enc in new], col.dtype)])
+                props[name][p, :n1] = vals[order]
+                props[name][p, n1:] = _prop_fill(col.dtype)
+            indptr[p, 0] = 0
+            np.cumsum(np.bincount(local, minlength=snap.vmax)[:snap.vmax],
+                      out=indptr[p, 1:])
+        out.blocks[bk] = type(blk)(
+            etype=blk.etype, direction=blk.direction, indptr=indptr,
+            nbr=nbr, rank=rank, props=props,
+            prop_types=dict(blk.prop_types))
+    return out
 
 
 class LocalStoreReader:

@@ -397,7 +397,7 @@ class GraphStore:
             log = getattr(sd, "delta_log", None)
             if log is None or log.broken:
                 return None
-            return list(log.keys), sd.epoch, log.floor_epoch
+            return log.records(), sd.epoch, log.floor_epoch
 
     def delta_trim(self, space: str, keys) -> None:
         sd = self.space(space)

@@ -36,6 +36,13 @@ class TpuUnavailable(Exception):
     to the host execution path."""
 
 
+class SnapshotRetired(TpuUnavailable):
+    """The snapshot a statement was assembled against gave its buffers
+    up (a re-pin, a compaction's swap) before the statement reached the
+    device.  The space is still served: `TpuRuntime` pins again and runs
+    the statement on the snapshot that replaced it."""
+
+
 def note_host_fallback(site: str, ex: BaseException) -> str:
     """Record ONE execute-time device→host fallback: the statement is
     about to be answered by the host engine with identical rows (the

@@ -14,6 +14,7 @@ a predicate needs them.
 from __future__ import annotations
 
 import functools
+import logging
 import threading
 import time
 from contextlib import contextmanager, nullcontext
@@ -29,17 +30,38 @@ from ..core.value import ColumnarDataSet, Edge
 from ..graphstore.csr import (build_snapshot, decode_prop_column,
                               decode_prop_column_np)
 from ..graphstore.delta import (DeltaOverflow, DeltaUnsupported, HostDelta,
+                                fold_base, pad_edge_width,
                                 pow2 as _delta_pow2)
 from ..graphstore.store import GraphStore
 from ..native.kernels import join_halves as native_join_halves
 from ..utils import trace as _t
 from ..utils.stats import stats as _metrics
-from .device import (DeviceSnapshot, TpuUnavailable, make_mesh,
-                     mesh_lanes, mesh_parts, note_host_fallback,
+from .device import (DeviceDelta, DeviceSnapshot, SnapshotRetired,
+                     TpuUnavailable, make_mesh, mesh_lanes, mesh_parts, note_host_fallback,
                      pin_snapshot, put_delta_blocks)
 from .exprjit import (CannotCompile, compile_predicate, eval_yield_column,
                       eval_yield_column_np)
 from .hop import a2a_payload_bytes, build_traverse_fn
+
+
+_log = logging.getLogger(__name__)
+
+
+def _on_live_snapshot(fn):
+    """A device statement that met a swap is served from the snapshot
+    that replaced its own: `SnapshotRetired` (raised under the read
+    gate, before anything ran) pins again and runs the statement anew.
+    Only a space that keeps being replaced under one statement (a few
+    times over) is handed to the caller's fallback."""
+    @functools.wraps(fn)
+    def run(self, *args, **kw):
+        for _ in range(self.RETIRED_RETRIES):
+            try:
+                return fn(self, *args, **kw)
+            except SnapshotRetired:
+                _metrics().inc("tpu_stmt_retired_retries")
+        return fn(self, *args, **kw)
+    return run
 
 
 def _pow2(n: int) -> int:
@@ -886,9 +908,24 @@ class TpuRuntime:
     # device together take at most 1/DELTA_HBM_SHARE of the HBM headroom
     # `_check_hbm_budget` finds under `tpu_hbm_limit_bytes`, halving until
     # they do: the plane never costs a graph its pin.
+    # Past the watermark a compaction folds the plane's host mirror
+    # into a fresh base (`_compact`: no export; base rows minus
+    # tombstones plus delta rows, dense ids and epoch kept) and swaps
+    # it in under the gate's write side.  The writes that land while it
+    # builds keep going to the OLD plane, whose last quarter (capacity x
+    # (1 - watermark) slots a (block, part)) absorbs them; the swap
+    # carries them to the new plane (`HostDelta.adopt`).  The base is
+    # pinned with at least one capacity of free edge slots a part
+    # (`pad_edge_width`), so a fold keeps every padded width and the
+    # programs compiled over the old base serve the new one.
     DELTA_EDGE_SHARE = 64
     DELTA_MIN_EDGES = 1 << 10
     DELTA_HBM_SHARE = 64
+    # a failed compaction is tried again after this many seconds,
+    # doubling with every failure in a row up to the maximum
+    COMPACT_BACKOFF_S = (1.0, 60.0)
+    # re-runs of one statement on the snapshot that replaced its own
+    RETIRED_RETRIES = 3
 
     def _delta_capacity(self, snap, headroom: Optional[int]) -> int:
         """Per-(block, part) delta capacity in edges for `snap` (the
@@ -920,30 +957,42 @@ class TpuRuntime:
     def pin(self, store: GraphStore, space: str,
             force: bool = False) -> DeviceSnapshot:
         sd = store.space(space)
-        cur = self.snapshots.get(space)
-        # uid guards the (space-name, epoch) cache against a DIFFERENT
-        # store object whose same-named space happens to share the epoch
-        # value (one shared runtime + two stores served the wrong graph);
-        # accessors without a uid (cluster _SpaceView, bench shims) keep
-        # the plain epoch check
-        if cur is not None and not force and getattr(
-                cur, "space_uid", None) == getattr(sd, "uid", None):
+        for _ in range(1 + self.RETIRED_RETRIES):
+            cur = self.snapshots.get(space)
+            # uid guards the (space-name, epoch) cache against a
+            # DIFFERENT store object whose same-named space happens to
+            # share the epoch value (one shared runtime + two stores
+            # served the wrong graph); accessors without a uid (cluster
+            # _SpaceView, bench shims) keep the plain epoch check
+            if cur is None or force or getattr(
+                    cur, "space_uid", None) != getattr(sd, "uid", None):
+                break
             # the freshness probe every dispatch pays: on a cluster
             # store `sd.epoch` is a part_stats fan-out, one RPC per part
             with _t.span("tpu:snapshot_check", space=space):
                 fresh = self._served_epoch(cur) == sd.epoch
             if fresh:
                 return cur
-            if cur.delta is not None and hasattr(store, "delta_records"):
-                # ISSUE 19 fast path: fold the dirty-key log into the
-                # resident delta plane (one small put per commit group)
-                # instead of a graph-sized rebuild + re-pin
-                dev = self._try_delta_update(store, space, cur)
-                if dev is not None:
-                    return dev
+            if cur.delta is None or not hasattr(store, "delta_records"):
+                break
+            # ISSUE 19 fast path: fold the dirty-key log into the
+            # resident delta plane (one small put per commit group)
+            # instead of a graph-sized rebuild + re-pin
+            dev = self._try_delta_update(store, space, cur)
+            if dev is not None:
+                return dev
+            if self.snapshots.get(space) is cur:
+                break                   # the plane cannot take it: rebuild
+            # a compaction's swap (or another pin) replaced `cur` while
+            # the apply waited for the gate: the snapshot that serves
+            # now takes the delta, not a whole export
         dflag = self._delta_flag()
         snap = self._build_fresh(store, space, dflag)
         headroom = self._check_hbm_budget(snap, space)
+        cap = self._plane_capacity(store, snap, dflag, headroom)
+        if cap:
+            # the free edge slots a compaction folds the plane into
+            pad_edge_width(snap, cap)
         # the device_put runs under the WRITE side of the dispatch
         # gate: in-flight dispatches drain first, new ones wait — the
         # jaxlib serve-while-repin race window is closed, and the
@@ -974,7 +1023,14 @@ class TpuRuntime:
             # stale-epoch jitted fns are keyed by epoch; drop them
             self._fns = {k: v for k, v in self._fns.items()
                          if not (k[0] == space and k[1] != dev.epoch)}
-            self._arm_delta(store, dev, snap, dflag, headroom)
+            if cap:
+                # an EMPTY plane, allocated at pin time (gate held):
+                # lazy allocation would change kernel input shapes on
+                # the first write and recompile every cached program;
+                # an empty plane costs one small put, compiles once, and
+                # costs a read nothing until it holds something (hop.py
+                # `_delta_live`)
+                put_delta_blocks(dev, HostDelta(snap, cap))
         finally:
             self._gate.release_write()
         stats().observe("tpu_repin_wait_us", int(wait_s * 1e6))
@@ -1009,23 +1065,36 @@ class TpuRuntime:
                 vmax_extra=self._delta_slack() if dflag != 0 else 0)
         return self._maybe_degree_split(snap)
 
-    def _arm_delta(self, store, dev, snap, dflag: int,
-                   headroom: Optional[int]) -> None:
-        """Allocate the EMPTY delta plane at pin time (gate held),
-        wherever the store feeds one (`delta_records`, `delta_reader`).
-        Lazy allocation would change kernel input shapes on the first
-        write and recompile every cached program; an empty plane costs
-        one small put, compiles once, and costs a read nothing until it
-        holds something (hop.py `_delta_live`).  Degree-split snapshots
-        opt out: hub rows re-home edges, so delta row identity breaks.
-        `dflag` > 0 fixes the capacity, < 0 takes `_delta_capacity`."""
-        if dflag == 0 or getattr(snap, "hub_dense", None) is not None:
-            return
-        if not (hasattr(store, "delta_records")
-                and hasattr(store, "delta_reader")):
-            return
-        cap = dflag if dflag > 0 else self._delta_capacity(snap, headroom)
-        put_delta_blocks(dev, HostDelta(snap, cap))
+    def _plane_capacity(self, store, snap, dflag: int,
+                        headroom: Optional[int]) -> int:
+        """The delta plane's capacity in edges a (block, part) for a
+        pin of `snap` from `store`, 0 where the pin arms none: the flag
+        is an explicit 0, the store feeds no plane (`delta_records`,
+        `delta_reader`), or the snapshot is degree-split (hub rows
+        re-home edges, so delta row identity breaks).  `dflag` > 0
+        fixes it; < 0 takes `_delta_capacity` within `headroom`, halved
+        on until the free edge slots the base is pinned with beside it
+        (`pad_edge_width`: one capacity a part of every block) also fit
+        1/DELTA_HBM_SHARE of that headroom: neither the plane nor the
+        slack costs a graph its pin."""
+        if dflag == 0 or getattr(snap, "hub_dense", None) is not None \
+                or not (hasattr(store, "delta_records")
+                        and hasattr(store, "delta_reader")):
+            return 0
+        if dflag > 0:
+            return dflag
+        cap = self._delta_capacity(snap, headroom)
+        if headroom is not None:
+            slot = sum(b.nbr.shape[0] * (
+                b.nbr.itemsize + b.rank.itemsize
+                + sum(c.itemsize for c in b.props.values()))
+                for b in snap.blocks.values())
+            if not self.local_mode and snap.num_parts == self.mesh_size:
+                slot = -(-slot // snap.num_parts)
+            while cap > 1 and \
+                    cap * slot > headroom // self.DELTA_HBM_SHARE:
+                cap //= 2
+        return cap
 
     def _try_delta_update(self, store, space: str, cur):
         """Advance a delta-armed snapshot to the store's epoch without
@@ -1109,6 +1178,11 @@ class TpuRuntime:
                                  time.perf_counter() - t_put)
             dev.delta.applied_epoch = target
             store.delta_trim(space, keys)
+            carry = getattr(dev, "_compact_carry", None)
+            if carry is not None:
+                # a compaction is folding a copy of the mirror taken
+                # before this apply: its swap carries these keys over
+                carry.update(keys)
         finally:
             self._gate.release_write()
         st = _metrics()
@@ -1178,8 +1252,9 @@ class TpuRuntime:
 
     def _maybe_compact(self, store, space: str, dev) -> None:
         """Watermark check after a delta apply: past the fill threshold,
-        kick the background compaction (REPARTITION-style: build the new
-        base off the gate, swap under a short exclusive hold)."""
+        kick the background compaction (fold the plane into a new base
+        off the gate, swap under a short exclusive hold).  One at a
+        time, and after a failure not before its back-off has run."""
         from ..utils.config import get_config
         try:
             wm = float(get_config().get("tpu_delta_compact_watermark"))
@@ -1189,7 +1264,8 @@ class TpuRuntime:
             return
         if dev.delta.host.fill_ratio() < wm:
             return
-        if getattr(dev, "_compacting", False):
+        if getattr(dev, "_compacting", False) or \
+                time.monotonic() < getattr(dev, "_compact_not_before", 0.0):
             return
         dev._compacting = True
         t = threading.Thread(target=self._compact,
@@ -1199,43 +1275,109 @@ class TpuRuntime:
         t.start()
 
     def _compact(self, store, space: str, dev) -> None:
-        """Fold the delta back into a fresh base CSR: the whole build
-        runs OFF the dispatch gate (reads keep flowing against the old
-        base + delta); only the buffer swap takes the write side."""
+        """Fold the delta back into a fresh base CSR, asking the store
+        for nothing.  Three steps, a span and a series each:
+
+        `tpu:compact_build`, OFF the gate: a copy of the plane's host
+        mirror (taken between two applies, under the mirror's own lock:
+        a compaction does not queue at the gate to read) is folded into
+        a new base, base rows minus tombstones plus delta rows in
+        canonical CSR order (`fold_base`).  Dense ids, the vid dictionary, the string
+        pool, the vertex tables and the epoch are the old base's, so
+        seeds resolve the same before and after and the compiled
+        programs stay valid; the padded widths stay while the rows fit
+        (`pad_edge_width` left a capacity of free slots).  Reads and
+        applies keep flowing against the old base and plane meanwhile;
+        every key applied from the copy on is noted
+        (`dev._compact_carry`).
+
+        `tpu:compact_gate`: the wait for the gate's write side.
+
+        `tpu:compact_swap`, the hold: the noted keys are carried into
+        the new plane from the old mirror (`HostDelta.adopt`: what base
+        plus plane held for each, no store read), the old buffers are
+        given up, the new base pinned and its plane armed at the old
+        plane's capacity and `applied_epoch`.  New base plus new plane
+        is old base plus old plane, edge for edge: no write applied
+        before, during or after the build is lost, and the log, which
+        was never re-watched, still holds what is not applied yet.  A
+        statement that holds the old snapshot meets `SnapshotRetired`
+        at the gate and runs again on the new one.
+
+        A compaction that raises is counted by cause
+        (`tpu_compaction_failures`), logged once, and backs off; the
+        old snapshot keeps serving."""
         from ..utils.failpoints import FailpointError, fail
-        from ..utils.stats import stats
+        st = _metrics()
+        new = None
         try:
-            with _t.span("tpu:compaction", space=space):
-                dflag = self._delta_flag()
-                snap = self._build_fresh(store, space, dflag)
-                # the new base with the writes folded in is a few rows
-                # larger than the one it replaces: it is held to the
-                # same budget, and a refusal leaves the old one serving
-                headroom = self._check_hbm_budget(snap, space)
+            with _t.start_trace("tpu:compaction", service="graphd",
+                                space=space):
+                t0 = time.perf_counter()
+                with _t.span("tpu:compact_build"):
+                    old_hd = dev.delta.host
+                    # first the note, then the copy: an apply that the
+                    # copy misses finds the note and leaves its keys
+                    dev._compact_carry = {}
+                    ins, tomb = old_hd.freeze()
+                    snap = fold_base(old_hd.snap, ins, tomb, old_hd.dcap)
+                    # the new base is a few rows larger than the one it
+                    # replaces: it is held to the same budget, and a
+                    # refusal leaves the old one serving
+                    self._check_hbm_budget(snap, space)
+                st.add_value("tpu_compact_build_s",
+                             time.perf_counter() - t0)
                 fail.hit("tpu:compact_swap", key=space)
-                self._gate.acquire_write()
+                with _t.span("tpu:compact_gate"):
+                    self._gate.acquire_write()
+                t1 = time.perf_counter()
                 try:
-                    if self.snapshots.get(space) is not dev \
-                            or dev.retired:
-                        return           # superseded while building
-                    dev.delete_buffers()
-                    new = pin_snapshot(snap, self.mesh)
-                    new.space_uid = dev.space_uid
-                    self.snapshots[space] = new
-                    self._fns = {k: v for k, v in self._fns.items()
-                                 if not (k[0] == space
-                                         and k[1] != new.epoch)}
-                    self._arm_delta(store, new, snap, dflag, headroom)
+                    with _t.span("tpu:compact_swap"):
+                        if self.snapshots.get(space) is not dev \
+                                or dev.retired:
+                            return       # superseded while building
+                        carried = dev._compact_carry
+                        new_hd = HostDelta(snap, old_hd.dcap, old_hd.tcap)
+                        new_hd.adopt(old_hd, carried,
+                                     store.delta_reader(space).dense_of)
+                        dev.delete_buffers()
+                        new = pin_snapshot(snap, self.mesh)
+                        new.space_uid = dev.space_uid
+                        # the plane covers what the old one covered, and
+                        # its device epoch runs on: a lane assembled over
+                        # the old plane never joins one of the new
+                        new.delta = DeviceDelta(
+                            host=new_hd,
+                            applied_epoch=dev.delta.applied_epoch,
+                            epoch=dev.delta.epoch)
+                        put_delta_blocks(new, new_hd)
+                        self.snapshots[space] = new
+                        st.inc("tpu_compact_carried_keys", len(carried))
                 finally:
                     self._gate.release_write()
-                stats().inc("tpu_compactions")
+                    if new is not None:
+                        st.add_value("tpu_compact_swap_s",
+                                     time.perf_counter() - t1)
+                st.inc("tpu_compactions")
                 self._emit_delta_gauges(new)
                 self._emit_hbm_gauges()
         except FailpointError:
             pass                         # KILL test hook: abort cleanly
-        except Exception:  # noqa: BLE001 — background thread must not die
-            pass
+        except Exception as ex:  # noqa: BLE001 — the thread ends here
+            fails = dev._compact_fails = \
+                getattr(dev, "_compact_fails", 0) + 1
+            first, most = self.COMPACT_BACKOFF_S
+            wait = min(first * 2 ** (fails - 1), most)
+            dev._compact_not_before = time.monotonic() + wait
+            st.inc("tpu_compaction_failures")
+            st.inc_labeled("tpu_compaction_failures_by_cause",
+                           {"cause": type(ex).__name__})
+            _log.warning(
+                "compaction of %s failed (%s: %s); the old base keeps "
+                "serving, next attempt in %.0f s at the earliest",
+                space, type(ex).__name__, ex, wait)
         finally:
+            dev._compact_carry = None
             dev._compacting = False
 
     def _check_hbm_budget(self, snap, space: str) -> Optional[int]:
@@ -1682,9 +1824,10 @@ class TpuRuntime:
         launch however many statements share it, which is precisely how
         the ledger proves the sharing is real."""
         if getattr(dev, "retired", False):
-            # a concurrent re-pin donated this snapshot's buffers while
-            # we were queued at the gate; the caller re-pins / falls back
-            raise TpuUnavailable(
+            # a re-pin or a compaction's swap gave this snapshot's
+            # buffers up while we were queued at the gate: the statement
+            # runs again on the one that replaced it (_on_live_snapshot)
+            raise SnapshotRetired(
                 "device snapshot retired by a concurrent re-pin")
         base = self.init_eb
         if min_eb is not None:
@@ -2122,6 +2265,7 @@ class TpuRuntime:
         stats.mat_s = time.perf_counter() - t_mat
         _metrics().add_value("tpu_mat_s", stats.mat_s)
 
+    @_on_live_snapshot
     def traverse(self, store: GraphStore, space: str, vids: Sequence[Any],
                  etypes: Sequence[str], direction: str, steps: int,
                  edge_filter: Optional[E.Expr] = None,
@@ -2196,6 +2340,7 @@ class TpuRuntime:
 
     # -- MATCH device plane: layered hop frames --------------------------
 
+    @_on_live_snapshot
     def traverse_hops(self, store: GraphStore, space: str,
                       vids: Sequence[Any], etypes: Sequence[str],
                       direction: str, max_hop: int,
@@ -2334,6 +2479,7 @@ class TpuRuntime:
 
     # -- BFS (FIND SHORTEST PATH device plane) ---------------------------
 
+    @_on_live_snapshot
     def bfs(self, store: GraphStore, space: str, srcs: Sequence[Any],
             etypes: Sequence[str], direction: str, max_steps: int,
             edge_filter: Optional[E.Expr] = None

@@ -228,12 +228,23 @@ define_flag("tpu_delta_max_edges", -1,
             "snapshot.  With the plane armed, group-committed writes "
             "land as a small device_put into a padded delta buffer that "
             "the traversal kernels merge with the base CSR where it "
-            "holds something; an empty plane costs a read nothing")
+            "holds something; an empty plane costs a read nothing.  The "
+            "capacity is also the free edge slots a part's base is pinned "
+            "with, and a compaction folds at most that many rows into a "
+            "part, so the fold keeps the padded widths and the compiled "
+            "programs")
 define_flag("tpu_delta_compact_watermark", 0.75,
             "delta fill ratio (of the delta plane's capacity, insert or "
             "tombstone side) above which the background compaction "
-            "job rebuilds the base CSR off the gate and swaps it "
-            "under a short write-side hold")
+            "job folds the plane's host mirror into a fresh base CSR off "
+            "the gate (no export: base rows minus tombstones plus delta "
+            "rows; dense ids, epoch and compiled programs kept) and swaps "
+            "it in under a short write-side hold.  Writes that land while "
+            "it builds keep going to the old plane, whose remaining share "
+            "(1 - watermark of the capacity) absorbs them, and are carried "
+            "to the new plane at the swap; a statement that meets the swap "
+            "runs again on the new snapshot.  A failed compaction is "
+            "counted (tpu_compaction_failures) and backs off")
 define_flag("tpu_delta_vmax_slack", 64,
             "extra padded local-vertex rows reserved at snapshot "
             "build when the delta plane is on, so freshly inserted "
