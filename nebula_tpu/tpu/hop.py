@@ -203,6 +203,17 @@ def _frontier_starts(indptr, fbm):
     return deg, ends - deg, ends[..., -1]
 
 
+def _row_offsets(indptr, starts):
+    """The one per-vertex table the per-slot half reads: slot j of a
+    frontier vertex `row` holds edge `indptr[row] + (j - starts[row])`,
+    and both terms are known before the slots are walked, so their
+    difference is one streaming pass here and ONE gather a slot there
+    (`_expand_slots`: `off[row] + j`), integer for integer the same
+    index.  `starts` carries the plan's leading axes, which `indptr`
+    may lack (a shard's CSR under its lanes)."""
+    return indptr[..., :-1] - starts
+
+
 def _plan_whole(indptr, fbm, EB: int):
     """One part's expansion plan by two scatters over EVERY local
     vertex, whatever the frontier holds: the plan of a bitmap no wider
@@ -229,7 +240,7 @@ def _plan_whole(indptr, fbm, EB: int):
         bump = jnp.zeros((EB,), jnp.int32).at[
             jnp.where(has, starts, EB)].add(1, mode="drop")
         crow = jnp.cumsum(bump) - 1               # (EB,)
-    return total, total > EB, (vid_of, starts, crow)
+    return total, total > EB, (vid_of, _row_offsets(indptr, starts), crow)
 
 
 @_stage("hop/expand")
@@ -299,7 +310,8 @@ def _plan_members(indptr, fbm, EB: int, chunk: int):
     row = jnp.maximum(jax.lax.cummax(first.reshape(lead + (EB,)),
                                      axis=len(lead)) - 1, 0)
     run = W + (trips if looped else 1) * CW * B
-    return total, total > EB, (None, starts, row), run, 2 * vmax
+    return (total, total > EB, (None, _row_offsets(indptr, starts), row),
+            run, 2 * vmax)
 
 
 def _expand_plan(over, blk, pid, fbm, EB: int, chunk: int):
@@ -335,24 +347,30 @@ def _expand_plan(over, blk, pid, fbm, EB: int, chunk: int):
     return _plan_members(blk["indptr"], fbm, EB, chunk)
 
 
-def _expand_slots(indptr, nbr, rank, plan, total, lo, size: int, EB: int,
+def _expand_slots(nbr, rank, plan, total, lo, size: int, EB: int,
                   P: int, pid, vmax_local: int = 0, hub_dense=None):
     """The per-slot half: slots [lo, lo + size) of the expansion
     `_expand_plan` laid out.  Returns arrays of length `size`:
-      src (frontier dense id), dst, rk, eidx (index into the block's
-      edge arrays — the host uses it to decode properties), ve (slot
-      valid)."""
-    vid_of, starts, crow = plan
+      src (frontier dense id), dst, rk (the edge's rank; None where
+      `rank` is: a program gathers it only for a consumer that reads
+      it), eidx (index into the block's edge arrays — the host uses it
+      to decode properties), ve (slot valid).
+
+    Every gather here takes one index a slot, and a slot costs what its
+    gathers cost (14 to 20 ns an index on the chip, PERF.md section 5):
+    `nbr[eidx]`, the plan's row-offset table `off[row]`
+    (`_row_offsets`), `vid_of[row]` on the whole-bitmap plan, `rank[eidx]`
+    on demand, `hub_dense` with a degree split."""
+    vid_of, off, crow = plan
     with jax.named_scope("hop/expand"):
         row = jnp.maximum(_window(crow, lo, size), 0)
         if vid_of is not None:      # the whole-bitmap plan's compact rows
             row = vid_of[row]
         j = lo + jnp.arange(size, dtype=jnp.int32)
-        eidx = indptr[row] + (j - starts[row])
         ve = j < jnp.minimum(total, EB)
-        eidx = jnp.where(ve, eidx, 0).astype(jnp.int32)
+        eidx = jnp.where(ve, off[row] + j, 0).astype(jnp.int32)
     with jax.named_scope("hop/gather"):
-        # the neighbour and rank gathers over the slots
+        # the neighbour gather over the slots, and the rank's if asked
         dst = jnp.where(ve, nbr[eidx], -1)
         if hub_dense is None:
             src_id = row * P + pid
@@ -362,8 +380,15 @@ def _expand_slots(indptr, nbr, rank, plan, total, lo, size: int, EB: int,
                 hub_dense[jnp.clip(row - vmax_local, 0,
                                    hub_dense.shape[0] - 1)])
         src = jnp.where(ve, src_id, -1)
-        rk = jnp.where(ve, rank[eidx], 0)
+        rk = None if rank is None else jnp.where(ve, rank[eidx], 0)
     return src, dst, rk, eidx, ve
+
+
+def _slot_gathers(plan, rank_on: bool, pred_cols: int, hubs: bool) -> int:
+    """Gathers with one index a slot that the expansion stage of a hop
+    issues over `plan` (`_expand_slots`, and the predicate's columns
+    beside it): what a slot of that hop costs, as a count."""
+    return 2 + (plan[0] is not None) + rank_on + pred_cols + hubs
 
 
 def _expand_block(indptr, nbr, rank, fbm, EB: int, P: int, pid,
@@ -382,13 +407,15 @@ def _expand_block(indptr, nbr, rank, fbm, EB: int, P: int, pid,
     the caller); a hub row's source dense id comes from `hub_dense`
     instead of the local-row arithmetic.
 
-    Returns the per-edge-slot arrays of `_expand_slots` at length EB,
-    plus (total, ovf): true expansion size and overflow flag.
+    Returns (src, dst, rk, eidx, ve) of `_expand_slots` at length EB
+    (a level body whose predicate reads no rank leaves `rk` unused, and
+    its gather is then no part of the compiled program), plus
+    (total, ovf): true expansion size and overflow flag.
     """
     total, ovf, plan, _, _ = _expand_plan(
         lambda f: f, {"indptr": indptr}, pid, fbm, EB, PLAN_CHUNK)
-    return _expand_slots(indptr, nbr, rank, plan, total, 0, EB, EB, P,
-                         pid, vmax_local, hub_dense) + (total, ovf)
+    return _expand_slots(nbr, rank, plan, total, 0, EB, EB, P, pid,
+                         vmax_local, hub_dense) + (total, ovf)
 
 
 def take_halves(col, i):
@@ -572,10 +599,12 @@ def a2a_payload_bytes(P: int, vmax: int, lanes: int = 1) -> int:
 
 
 @_stage("hop/compact")
-def _compact_cap(src, dst, rk, eidx, keep, n, EB: int, tail: int = 0,
+def _compact_cap(cols, keep, n, EB: int, tail: int = 0,
                  chunk: int = CHUNK, tail_live=None):
     """Stable-partition the kept edge slots to the FRONT of each capture
-    row (cumsum scatter, O(slots)) and return the kept count.
+    row (cumsum scatter, O(slots)) and return the kept count.  `cols`
+    are the capture's identity columns by name (`_CAP_FILL`'s, of which
+    a program holds `rank` only on demand).
 
     Why: capture arrays are EB-padded and EB is sized for the worst hop
     (millions of slots); fetching them wholesale ships mostly padding
@@ -585,14 +614,15 @@ def _compact_cap(src, dst, rk, eidx, keep, n, EB: int, tail: int = 0,
     ascending-eidx invariant the host materializers rely on survives.
 
     The arrays carry the builder's leading axes before the EB + tail
-    slots.  The cumsum is a streaming pass and stays whole; the four
-    scatters run by need (only the first `n` slots and, where
-    `tail_live`, the tail can hold a kept entry) into FLAT outputs, every row at its own offset:
+    slots.  The cumsum is a streaming pass and stays whole; the
+    scatters, one a column, run by need (only the first `n` slots and,
+    where `tail_live`, the tail can hold a kept entry) into FLAT
+    outputs, every row at its own offset:
     a scatter on the chip works on a flat operand, and a loop that
     carried the rows as rows would re-lay all of them out on every
     trip.
 
-    Returns (src, dst, rank, eidx, kcount, chunks run, chunks budgeted).
+    Returns (compacted cols, kcount, chunks run, chunks budgeted).
     """
     W = EB + tail
     rows = int(np.prod(keep.shape[:-1], dtype=np.int64))
@@ -601,7 +631,8 @@ def _compact_cap(src, dst, rk, eidx, keep, n, EB: int, tail: int = 0,
     pos = jnp.where(keep,
                     jnp.cumsum(keep, axis=-1, dtype=jnp.int32) - 1 + row0,
                     rows * W).astype(jnp.int32)
-    vals = (src, jnp.where(keep, dst, -1), rk, eidx)
+    vals = tuple(jnp.where(keep, v, -1) if k == "dst" else v
+                 for k, v in cols.items())
 
     def scatter(outs, lo, size):
         at = _window(pos, lo, size).reshape(-1)
@@ -609,13 +640,12 @@ def _compact_cap(src, dst, rk, eidx, keep, n, EB: int, tail: int = 0,
                                   mode="drop")
                      for o, v in zip(outs, vals))
 
-    init = tuple(jnp.full((rows * W,), fill, v.dtype)
-                 for v, fill in zip(vals, (-1, -1, 0, 0)))
+    init = tuple(jnp.full((rows * W,), _CAP_FILL[k], v.dtype)
+                 for k, v in cols.items())
     outs, run, budget = _by_need(scatter, init, n, EB, tail, chunk,
                                  tail_live)
-    cs, cd, cr, ce = (o.reshape(keep.shape) for o in outs)
-    return (cs, cd, cr, ce, jnp.sum(keep, axis=-1, dtype=jnp.int32),
-            run, budget)
+    return ({k: o.reshape(keep.shape) for k, o in zip(cols, outs)},
+            jnp.sum(keep, axis=-1, dtype=jnp.int32), run, budget)
 
 
 def _norm_ebs(EB, steps: int, capture_hops: bool):
@@ -664,13 +694,17 @@ def _extend_fbm_local(fbm, hub_owner, hub_local, P: int):
         [fbm, jnp.broadcast_to(bits, (P, bits.shape[0]))], axis=1)
 
 
-_CAP_KEYS = ("src", "dst", "rank", "eidx", "kcount")
+# The identity columns a capture CAN hold, in their order, each with the
+# value its slots keep where the hop put nothing.  `rank` is held by the
+# programs built to carry it (`build_traverse_fn`'s `carry_rank`); beside
+# them a capture has `kcount` and a `prop:<name>` a yielded column.
+_CAP_FILL = {"src": -1, "dst": -1, "rank": 0, "eidx": 0}
 
 
 def _traverse(over, nlead: int, blocks, fbm, pid, extend, exchange, *,
               P: int, ebs, pred, pred_cols, capture: bool,
-              capture_hops: bool, yield_cols, hubs_c, chunk: int,
-              plan_chunk: int):
+              capture_hops: bool, yield_cols, carry_rank: bool, hubs_c,
+              chunk: int, plan_chunk: int, noted: dict):
     """The N-hop program, written once for every layout of
     `build_traverse_fn`.
 
@@ -687,7 +721,10 @@ def _traverse(over, nlead: int, blocks, fbm, pid, extend, exchange, *,
     then run the per-slot stages by need (`_by_need`) — the expansion's
     gathers with the delta plane's tombstone test and the predicate's
     column gathers in one loop, the capture's compaction scatters in a
-    second, the yielded property gathers in a third.
+    second, the yielded property gathers in a third.  An edge's rank is
+    gathered, carried, compacted and captured only with `carry_rank`:
+    without it no hop reads a block's `rank` leaf.  `noted` takes what
+    the trace settles of the program (`slot_gathers`).
 
     Returns the result dict of `build_traverse_fn` without its shard
     axis."""
@@ -697,6 +734,8 @@ def _traverse(over, nlead: int, blocks, fbm, pid, extend, exchange, *,
     cap_scope = "match/frame_capture" if capture_hops else "hop/capture"
     gcols = [c for c in pred_cols if not c.startswith("_")] \
         if pred is not None else []
+    # the identity columns this program's slots carry, in capture order
+    names = tuple(k for k in _CAP_FILL if carry_rank or k != "rank")
     hop_edges, frontier_sizes = [], []     # popcount entering each hop
     chunks_run, chunks_budget = [], []
     plan_run, plan_budget = [], []
@@ -708,7 +747,7 @@ def _traverse(over, nlead: int, blocks, fbm, pid, extend, exchange, *,
         last = hop == steps - 1
         marks = None
         edges = run = budget = prun = pbudget = 0
-        caps = {k: [] for k in _CAP_KEYS}
+        caps = {k: [] for k in names + ("kcount",)}
         efbm = fbm if hubs_c is None else extend(fbm)
         want_pred = pred is not None and (last or capture_hops)
         want_cap = capture and (last or capture_hops)
@@ -722,6 +761,9 @@ def _traverse(over, nlead: int, blocks, fbm, pid, extend, exchange, *,
             total, ovf, plan, r, bd = _expand_plan(
                 over, b, pid, efbm, EB, plan_chunk)
             prun, pbudget = prun + r, pbudget + bd
+            if last:
+                noted["slot_gathers"] = _slot_gathers(
+                    plan, carry_rank, len(hcols), hubs_c is not None)
             # the live slots of the fullest part (or lane): the trip
             # count of every by-need loop of this block
             n = jnp.minimum(jnp.max(total), EB)
@@ -729,34 +771,39 @@ def _traverse(over, nlead: int, blocks, fbm, pid, extend, exchange, *,
             def expand(outs, lo, size):
                 def part(blk, pd, pl, tot):
                     s, d, r, e, v = _expand_slots(
-                        blk["indptr"], blk["nbr"], blk["rank"], pl, tot,
-                        lo, size, EB, P, pd, vmax, hubs_c)
+                        blk["nbr"], blk["rank"] if carry_rank else None,
+                        pl, tot, lo, size, EB, P, pd, vmax, hubs_c)
                     with jax.named_scope("hop/pred_gather"):
                         g = tuple(take_halves(blk["props"][c], e)
                                   for c in hcols)
-                    return (s, d, r, e, v) + g
+                    got = {"src": s, "dst": d, "rank": r, "eidx": e}
+                    return (v,) + tuple(got[k] for k in names) + g
                 vals = over(part)(b, pid, plan, total)
                 if dcap:
-                    v = _drop_live_tombstones(over, b, pid, *vals[3:5],
-                                              has_tomb)
-                    vals = vals[:4] + (v,) + vals[5:]
+                    v = _drop_live_tombstones(
+                        over, b, pid, vals[len(names)], vals[0], has_tomb)
+                    vals = (v,) + vals[1:]
                 return tuple(_put(o, v, lo) for o, v in zip(outs, vals))
 
             lead = total.shape
-            fills = [(-1, jnp.int32), (-1, jnp.int32),
-                     (0, b["rank"].dtype), (0, jnp.int32), (False, bool)]
-            outs = tuple(jnp.full(lead + (EB,), f, dt) for f, dt in fills)
+            rank_dtype = b["rank"].dtype
+            outs = (jnp.zeros(lead + (EB,), bool),) + tuple(
+                jnp.full(lead + (EB,), _CAP_FILL[k],
+                         rank_dtype if k == "rank" else jnp.int32)
+                for k in names)
             # a predicate's columns ride the loop as their halves, which
             # the compiled predicate joins (exprjit.py)
             outs += tuple(jnp.zeros(lead + (2, EB), b["props"][c].dtype)
                           for c in hcols)
             outs, r, bd = _by_need(expand, outs, n, EB, chunk=chunk)
             run, budget = run + r, budget + bd
-            src, dst, rk, eidx, ve = outs[:5]
-            pcols = dict(zip(hcols, outs[5:]))
+            ve = outs[0]
+            ident = dict(zip(names, outs[1:]))
+            pcols = dict(zip(hcols, outs[1 + len(names):]))
+            dst = ident["dst"]
             if dcap:
                 tsrc, tdst, trk, tkeep, tact = _live_rows(
-                    over, b, pid, efbm, P, has_rows, rk.dtype,
+                    over, b, pid, efbm, P, has_rows, rank_dtype,
                     pred if want_pred else None, hcols)
                 base_total = total
                 total = total + jnp.sum(tact, axis=-1, dtype=jnp.int32)
@@ -765,31 +812,29 @@ def _traverse(over, nlead: int, blocks, fbm, pid, extend, exchange, *,
 
             if want_pred:
                 with jax.named_scope("hop/predicate"):
-                    keep = pred({"_rank": rk, "_src": src, "_dst": dst,
-                                 **pcols}) & ve
+                    # `_src`, `_dst`, and `_rank` where it is carried
+                    keep = pred({**{"_" + k: v for k, v in ident.items()
+                                    if k != "eidx"}, **pcols}) & ve
             else:
                 keep = ve
             if want_cap:
                 if dcap:
-                    def wide(x, t):
-                        return jnp.concatenate([x, t], axis=-1)
-
-                    def compact(*a):
-                        cs, cd, cr, ce, kc, r, bd = _compact_cap(
-                            *a, n, EB, dcap, chunk, has_rows)
-                        return (cs, cd, cr, ce, kc,
-                                jnp.asarray(r, jnp.int32),
+                    def compact(c, k):
+                        got, kc, r, bd = _compact_cap(
+                            c, k, n, EB, dcap, chunk, has_rows)
+                        return (got, kc, jnp.asarray(r, jnp.int32),
                                 jnp.asarray(bd, jnp.int32))
 
-                    teidx = jnp.broadcast_to(
-                        emax + jnp.arange(dcap, dtype=jnp.int32),
-                        lead + (dcap,))
-                    wides = (wide(src, tsrc), wide(dst, tdst),
-                             wide(rk, trk), wide(eidx, teidx),
-                             wide(keep, tkeep))
+                    tail = {"src": tsrc, "dst": tdst, "rank": trk,
+                            "eidx": jnp.broadcast_to(
+                                emax + jnp.arange(dcap, dtype=jnp.int32),
+                                lead + (dcap,))}
+                    wides = {k: jnp.concatenate([ident[k], tail[k]], axis=-1)
+                             for k in names}
+                    wkeep = jnp.concatenate([keep, tkeep], axis=-1)
                     with jax.named_scope(cap_scope):
                         if want_pred:
-                            got = compact(*wides)
+                            got = compact(wides, wkeep)
                         else:
                             # nothing tombstoned and no row appended:
                             # the live slots already are the prefix,
@@ -797,24 +842,25 @@ def _traverse(over, nlead: int, blocks, fbm, pid, extend, exchange, *,
                             z = jnp.zeros((), jnp.int32)
                             got = _when(
                                 has_tomb | has_rows, compact,
-                                lambda s, d, r, e, k: (
-                                    s, d, r, e,
-                                    jnp.minimum(base_total, EB), z, z),
-                                *wides)
-                    cs, cd, cr, ce, kc, r, bd = got
+                                lambda c, _k: (
+                                    c, jnp.minimum(base_total, EB), z, z),
+                                wides, wkeep)
+                    kept, kc, r, bd = got
                     run, budget = run + r, budget + bd
                 elif want_pred:
                     with jax.named_scope(cap_scope):
-                        cs, cd, cr, ce, kc, r, bd = _compact_cap(
-                            src, dst, rk, eidx, keep, n, EB, dcap, chunk)
+                        kept, kc, r, bd = _compact_cap(
+                            ident, keep, n, EB, dcap, chunk)
                     run, budget = run + r, budget + bd
                 else:
                     # nothing filtered a slot out: the expansion's live
                     # slots already are the prefix, fills and all
-                    cs, cd, cr, ce = src, dst, rk, eidx
+                    kept = ident
                     kc = jnp.minimum(total, EB)
-                for k, v in zip(_CAP_KEYS, (cs, cd, cr, ce, kc)):
-                    caps[k].append(v)
+                for k in names:
+                    caps[k].append(kept[k])
+                caps["kcount"].append(kc)
+                ce = kept["eidx"]
                 if last and not capture_hops and yield_cols:
                     def props(outs, lo, size):
                         e = _window(ce, lo, size)
@@ -889,7 +935,7 @@ def _traverse(over, nlead: int, blocks, fbm, pid, extend, exchange, *,
             with jax.named_scope("match/frame_stack"):
                 # lead + (steps, nb, EB); kcount lead + (steps, nb)
                 cap = {k: jnp.stack([hc[k] for hc in hop_caps], axis=nlead)
-                       for k in _CAP_KEYS}
+                       for k in hop_caps[0]}
         else:
             cap = dict(hop_caps[-1])
         res["kcount"] = cap.pop("kcount")   # small: fetched with the meta
@@ -910,6 +956,7 @@ def build_traverse_fn(mesh, P: int, EB, steps: int, n_blocks: int, *,
                       capture: bool = True,
                       capture_hops: bool = False,
                       yield_cols: Sequence[str] = (),
+                      carry_rank: bool = True,
                       hub_dense=None, chunk: int = CHUNK,
                       plan_chunk: int = PLAN_CHUNK):
     """Compile the N-step traversal program for one bucket configuration.
@@ -948,6 +995,14 @@ def build_traverse_fn(mesh, P: int, EB, steps: int, n_blocks: int, *,
     captured as its 32-bit halves (`take_halves`), which the host joins:
     a carried value comes back to the bit.
 
+    carry_rank: whether the program gathers each expanded edge's rank
+    and carries it into the capture.  The caller says False for a
+    statement of which nothing reads it (runtime.py `_run_traverse` has
+    the rule): the expansion loop then runs one gather a slot fewer, no
+    `rank` buffer of the hop's budget exists and `cap` has no `rank`
+    entry.  A predicate over `_rank` carries it whatever the caller
+    said.
+
     chunk: the by-need loops' chunk (`_by_need`); plan_chunk: the
     member plan's trip and the bitmap width over which it is compiled
     (`_expand_plan`); the module constants everywhere but in tests.
@@ -960,7 +1015,9 @@ def build_traverse_fn(mesh, P: int, EB, steps: int, n_blocks: int, *,
     delta plane's d_* leaves carry the part axis too.
 
     Returns jitted fn(blocks_data, frontier) -> dict with (lead = (P,),
-    or (L, P) with lanes):
+    or (L, P) with lanes; `fn.noted` holds, once fn was traced at its
+    first run, `slot_gathers`: the gathers with one index a slot in the
+    last hop's expansion stage, `_slot_gathers`):
       frontier lead + (vmax,) bool, fcount lead: next frontier after
         the LAST hop (mid-hop frontiers never leave the device)
       hop_edges lead + (steps,): pre-filter expansion size per hop
@@ -971,7 +1028,8 @@ def build_traverse_fn(mesh, P: int, EB, steps: int, n_blocks: int, *,
         where the bitmap is no wider than plan_chunk)
       ovf_expand lead, bool: some hop's expansion exceeded EB
       cap (if capture): dict of lead + (n_blocks, EB) arrays
-        src, dst, rank, eidx, and lead + (n_blocks, 2, EB) halves
+        src, dst, rank (with carry_rank), eidx, and lead +
+        (n_blocks, 2, EB) halves
         prop:<name> per yield_col — the final
         hop's edge set (kept entries compacted to a prefix;
         kcount lead + (n_blocks,) gives the counts; what a prop array
@@ -986,10 +1044,22 @@ def build_traverse_fn(mesh, P: int, EB, steps: int, n_blocks: int, *,
     """
     ebs = _norm_ebs(EB, steps, capture_hops)
     hubs_c, hub_owner, hub_local = _hub_consts(hub_dense, P)
+    # what `_traverse` settles of the program while it is traced, at its
+    # first run: `slot_gathers`, the per-slot gathers of the last hop's
+    # expansion stage (`_slot_gathers`)
+    noted: Dict[str, int] = {}
     kw = dict(P=P, ebs=ebs, pred=pred, pred_cols=pred_cols,
               capture=capture, capture_hops=capture_hops,
-              yield_cols=yield_cols, hubs_c=hubs_c, chunk=chunk,
-              plan_chunk=plan_chunk)
+              yield_cols=yield_cols,
+              carry_rank=carry_rank or (pred is not None
+                                        and "_rank" in pred_cols),
+              hubs_c=hubs_c, chunk=chunk, plan_chunk=plan_chunk,
+              noted=noted)
+
+    def program(f):
+        fn = jax.jit(f)
+        fn.noted = noted
+        return fn
 
     if mesh is None:
         pids = jnp.arange(P, dtype=jnp.int32)
@@ -1000,7 +1070,7 @@ def build_traverse_fn(mesh, P: int, EB, steps: int, n_blocks: int, *,
                 lambda f: _extend_fbm_local(f, hub_owner, hub_local, P),
                 lambda marks: marks.any(axis=0), **kw)
 
-        return jax.jit(jax.vmap(fn, in_axes=(None, 0)) if lanes else fn)
+        return program(jax.vmap(fn, in_axes=(None, 0)) if lanes else fn)
 
     from jax.sharding import PartitionSpec
     csr_spec = PartitionSpec("part")
@@ -1032,4 +1102,4 @@ def build_traverse_fn(mesh, P: int, EB, steps: int, n_blocks: int, *,
 
     smapped = _shard_map(kernel, mesh=mesh,
                          in_specs=(csr_spec, fr_spec), out_specs=fr_spec)
-    return jax.jit(smapped)
+    return program(smapped)

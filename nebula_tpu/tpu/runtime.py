@@ -6,8 +6,9 @@ the serve-epoch-N-while-building-N+1 model of SURVEY §7 hard-part #6 in
 its simplest correct form), the jit cache keyed by bucket configuration,
 and the power-of-two escalation loop around the hop kernel.
 
-The host materialization contract: the device returns (src, dst, rank,
-eidx, keep) per block; property decode happens on host straight out of
+The host materialization contract: the device returns (src, dst, eidx,
+and rank where something reads it) per block, kept entries compacted to
+a prefix; property decode happens on host straight out of
 the numpy CsrSnapshot columns at eidx — properties cross HBM only when
 a predicate needs them.
 """
@@ -1959,6 +1960,13 @@ class TpuRuntime:
         # of 64-bit operands of the program just run, each of which a
         # chip without 64-bit lanes splits WHOLE at the top of the run
         m.add_value("tpu_wide_operand_bytes", float(wide))
+        # a traverse program's per-slot gathers in its last hop's
+        # expansion stage, settled when it was traced (hop.py
+        # `_slot_gathers`): what a slot of the widest hop costs
+        noted = getattr(fn, "noted", None)
+        if noted:
+            m.add_value("tpu_hop_slot_gathers",
+                        float(noted["slot_gathers"]))
         m.add_value("tpu_kernel_s", info["device_s"])
         m.add_value("tpu_put_s", info["put_s"])
         m.add_value("tpu_fetch_s", info["fetch_s"])
@@ -2221,6 +2229,15 @@ class TpuRuntime:
             # identity column regardless of what the yields read; a
             # plane that holds no row of these blocks adds no column
             fetch_keys |= {"src", "dst", "rank", "eidx"}
+        # the program gathers and carries an edge's rank only for a
+        # consumer: the fetch (all of the capture, or a yield that reads
+        # rank), the predicate, a MATCH frame (edge identities), or an
+        # armed delta plane, whose first row puts rank into the fetch
+        # above and must not need a second program for it.  What is
+        # left is a statement over an unarmed snapshot that reads none
+        carry_rank = (fetch_keys is None or "rank" in fetch_keys
+                      or "_rank" in pred_cols or hops
+                      or any("d_src" in b for b in blocks))
         hub_dense = getattr(dev.host, "hub_dense", None)
         hub_n = 0 if hub_dense is None else len(hub_dense)
 
@@ -2229,16 +2246,21 @@ class TpuRuntime:
                 None if self.local_mode else self.mesh, dev.num_parts,
                 ebs, steps, len(block_keys), lanes=lanes, pred=pred_fn,
                 pred_cols=pred_cols, capture=capture, capture_hops=hops,
-                yield_cols=yield_cols, hub_dense=hub_dense)
+                yield_cols=yield_cols, carry_rank=carry_rank,
+                hub_dense=hub_dense)
 
         def key_fn(ebs):
             if hops:
                 return (space, dev.epoch, "hops", tuple(block_keys),
                         steps, ebs, pred_key, tuple(pred_cols), hub_n,
                         self._delta_sig(dev))
+            # a program that carries rank keeps the key it always had
+            # (`.tpu_buckets.json` is read across versions); the one
+            # that does not shares neither program nor bucket with it
             return (space, dev.epoch, tuple(block_keys), steps, ebs,
                     pred_key, capture, tuple(pred_cols), yield_cols,
-                    hub_n, self._delta_sig(dev))
+                    hub_n, self._delta_sig(dev)) + (
+                        () if carry_rank else ("rank-free",))
 
         launch = dict(key_fn=key_fn, inputs_fn=lambda ebs: (blocks_data,),
                       n_hops=steps, uniform=hops, fetch_keys=fetch_keys,
@@ -2309,8 +2331,8 @@ class TpuRuntime:
                 yield_cols = yield_cols[:4]
 
         # fetch only the capture arrays the yields actually read (each
-        # is a kept-sized column — src+rank+eidx are most of the result
-        # transfer on a dst+prop GO, the common shape)
+        # is a kept-sized column); what none of them reads the program
+        # need not carry either (`_run_traverse`: rank)
         fetch_keys = (_cap_keys_for_yields(yields, yield_cols)
                       if capture else None)
         if fetch_keys is not None and fetch_keys & {"src", "dst"} \

@@ -93,16 +93,22 @@ def _compile(fn, *args):
     return compiled, time.perf_counter() - t0
 
 
-@pytest.mark.parametrize("meshed,lanes", [
-    (False, False), (True, False), (False, True), (True, True)],
-    ids=["one-chip", "mesh", "one-chip-lanes", "mesh-lanes"])
-def test_go_three_steps_capture_compiles(topo, one_chip, meshed, lanes):
+@pytest.mark.parametrize("meshed,lanes,carry_rank", [
+    (False, False, True), (True, False, True), (False, True, True),
+    (True, True, True), (False, True, False), (True, True, False)],
+    ids=["one-chip", "mesh", "one-chip-lanes", "mesh-lanes",
+         "one-chip-lanes-rank-free", "mesh-lanes-rank-free"])
+def test_go_three_steps_capture_compiles(topo, one_chip, meshed, lanes,
+                                         carry_rank):
     """The north-star statement: 3-step GO, final hop captured with an
     int64 prop gathered on device (YIELD dst(edge), KNOWS.w), in each
     layout of the one builder entry: all eight parts on one chip, or one
     partition per chip of the 2x2 host, whose frontier exchange must
     still be an all-to-all in the compiled program (ONE a hop also
-    under four query lanes); solo, or four lanes to a launch."""
+    under four query lanes); solo, or four lanes to a launch; the lane
+    layouts also as a statement that reads no rank gets them (PR 38:
+    no `rank` gather, carry or capture; the solo layouts' rank-free
+    programs are the two cells' below)."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
     from nebula_tpu.tpu.hop import build_traverse_fn
@@ -117,10 +123,14 @@ def test_go_three_steps_capture_compiles(topo, one_chip, meshed, lanes):
         mesh, part, fr = None, one_chip, one_chip
         P, vmax, E, ebs = P8, VMAX8, E8, (1 << 12, 1 << 17, 1 << 22)
     fn = build_traverse_fn(mesh, P, ebs, 3, n_blocks=1, lanes=lanes,
-                           capture=True, yield_cols=("w",))
+                           capture=True, yield_cols=("w",),
+                           carry_rank=carry_rank)
     compiled, _ = _compile(fn, (_block(P, vmax, E, part),),
                            _struct(L + (P, vmax), np.bool_, fr))
     assert (compiled.as_text().count(" all-to-all(") == 2) == meshed
+    # a slot of the last hop gathers `nbr` and the row offsets (bitmaps
+    # this wide take the member plan), and the rank where it is carried
+    assert fn.noted["slot_gathers"] == 2 + carry_rank
 
 
 def _w_over_50(cols):
@@ -131,11 +141,6 @@ def _w_over_50(cols):
 def _f_over_half(cols):
     from nebula_tpu.tpu.device import join_halves, nan_halves
     return (join_halves(cols["f"], np.float64) > 0.5) & ~nan_halves(cols["f"])
-
-
-# a statement that carries `w` and `f`, and one that also filters on `w`
-USES = pytest.mark.parametrize("filtered", [False, True],
-                               ids=["carried", "filtered"])
 
 
 def _no_column_is_split(text):
@@ -151,20 +156,26 @@ def _no_column_is_split(text):
     assert not re.search(r"= [fsu]64\[[^=]*bitcast-convert\(", text)
 
 
-@pytest.mark.parametrize("filtered", [False, "w", "f"],
-                         ids=["carried", "filtered", "filtered_f"])
+@pytest.mark.parametrize("filtered", [False, "w", "f", "rank-free"],
+                         ids=["carried", "filtered", "filtered_f",
+                              "carried-rank-free"])
 def test_proxy_cell_go3_by_need_loops_compile(one_chip, filtered):
     """`snb-sf100-proxy.go3`'s program (benchmarks/configs): budgets
     (2048, 2^20, 2^22) a part, YIELD dst, w, f — the last two hops run
     their gathers in by-need loops (hop.py `_by_need`; the int64 `w` and
     the float64 `f` are 32-bit pairs as operands, through the loop
     carry and in the capture).  A loop the TPU compiler rejects, or
-    takes minutes over, fails here and not first on the chip."""
+    takes minutes over, fails here and not first on the chip.  The
+    cell's own program since PR 38 is `carried-rank-free`: its
+    statements read no rank and `pin_prebuilt` arms no plane, so its
+    last hop gathers `nbr` and the row offsets alone and no buffer of
+    8 x 2^22 ranks exists."""
     from nebula_tpu.tpu.hop import CHUNK, build_traverse_fn
     ebs = (1 << 11, 1 << 20, 1 << 22)
     assert ebs[0] <= CHUNK < ebs[1], "the cell no longer exercises the loop"
     kw = {"w": dict(pred=_w_over_50, pred_cols=("w",)),
-          "f": dict(pred=_f_over_half, pred_cols=("f",))}.get(filtered, {})
+          "f": dict(pred=_f_over_half, pred_cols=("f",)),
+          "rank-free": dict(carry_rank=False)}.get(filtered, {})
     fn = build_traverse_fn(None, P8, ebs, 3, n_blocks=1, capture=True,
                            yield_cols=("f", "w"), **kw)
     compiled, secs = _compile(
@@ -175,6 +186,14 @@ def test_proxy_cell_go3_by_need_loops_compile(one_chip, filtered):
     # the second hop's expansion, the last hop's, its property gathers
     assert text.count(" while(") >= 3
     _no_column_is_split(text)
+    free = filtered == "rank-free"
+    assert fn.noted["slot_gathers"] == {
+        False: 3, "w": 4, "f": 4, "rank-free": 2}[filtered]
+    # src, dst, eidx and two yielded columns' halves come back; rank
+    # only from a program that carries it
+    out = compiled.memory_analysis().output_size_in_bytes
+    col = P8 * ebs[-1] * 4      # one identity column of the capture
+    assert (7 if free else 8) * col < out < (7.5 if free else 8.5) * col
 
 
 @pytest.mark.parametrize("filtered", [False, True], ids=["go1", "go3w"])
@@ -235,7 +254,8 @@ def test_match_var_len_capture_hops_compiles(one_chip):
              _struct((P8, VMAX8), np.bool_, one_chip))
 
 
-@USES
+@pytest.mark.parametrize("filtered", [False, True, "rank-free"],
+                         ids=["carried", "filtered", "carried-rank-free"])
 def test_mesh_cell_go3_compiles_for_the_four_chip_host(topo, filtered):
     """`snb-sf300-proxy.go3-4chip`'s program (benchmarks/configs): 6 M
     persons over 4 parts of 50,331,648 padded slots, budgets (2048, 2^16,
@@ -249,7 +269,8 @@ def test_mesh_cell_go3_compiles_for_the_four_chip_host(topo, filtered):
     vmax, width = 1_500_000, 50_331_648
     mesh = Mesh(np.asarray(topo.devices[:P4]), ("part",))
     part = NamedSharding(mesh, PartitionSpec("part"))
-    kw = dict(pred=_w_over_50, pred_cols=("w",)) if filtered else {}
+    kw = {True: dict(pred=_w_over_50, pred_cols=("w",)),
+          "rank-free": dict(carry_rank=False)}.get(filtered, {})
     fn = build_traverse_fn(mesh, P4, (1 << 11, 1 << 16, 1 << 21), 3,
                            n_blocks=1, capture=True, yield_cols=("f", "w"),
                            **kw)
@@ -264,7 +285,12 @@ def test_mesh_cell_go3_compiles_for_the_four_chip_host(topo, filtered):
     assert f"u32[{P4},1,{-(-vmax // 32)}]" in text
     assert a2a_payload_bytes(P4, vmax) == P4 * P4 * -(-vmax // 32) * 4
     args = compiled.memory_analysis().argument_size_in_bytes
-    assert 1.1e9 < args < 1.3e9, args
+    # the cell's own program since PR 38 is the rank-free one: a part's
+    # `rank` column (4 bytes a slot) is not even an argument of it
+    free = filtered == "rank-free"
+    assert 1.1e9 - free * 4 * width < args < 1.3e9 - free * 4 * width, args
+    assert fn.noted["slot_gathers"] == {
+        False: 3, True: 4, "rank-free": 2}[filtered]
 
 
 def test_bfs_compiles(one_chip):
