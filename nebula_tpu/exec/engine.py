@@ -636,60 +636,63 @@ class QueryEngine:
         res = self._execute_inner(session, stmt, text, t0, cached_plan,
                                   cache_key, obs, fp=fp)
         us = int((time.perf_counter() - t0) * 1e6)
-        stats().inc("num_queries")
-        stats().add_value("query_latency_us", us)
-        stats().observe("query_latency_us_hist", us, {"kind": kind})
-        if _bumps_write_epoch(kind):
-            # one bump per mutating statement, SUCCESS OR FAILURE — a
-            # failed multi-part write may still have committed some
-            # parts (fan-out is not atomic), so only statements that
-            # provably touched nothing may skip the bump.  A PR 5
-            # dedup-replayed write still acks as one statement, so it
-            # bumps (and invalidates the result cache) exactly once.
-            self.qctx.bump_write_epoch()
-            self.result_cache.note_invalidated()
-        if res.ok and result_key is not None and res.plan_desc is None \
-                and not isinstance(stmt, A.ExplainSentence) \
-                and kind in _CACHEABLE_KINDS:
-            from ..core.wire import to_wire
-            self.result_cache.put(
-                result_key,
-                to_wire(res.data) if res.data is not None else None,
-                res.space)
-        slow_us = self.slow_query_us
-        if not res.ok:
-            stats().inc("num_query_errors")
-        elif us > slow_us:
-            stats().inc("num_slow_queries")
-            self.slow_log.append({"stmt": text, "latency_us": us,
-                                  "ts": time.time(),
-                                  "trace_id": tg.trace_id
-                                  if tg is not None else None,
-                                  "fingerprint": fp or ""})
-        if fp is not None:
-            # the one aggregate update per statement (ISSUE 16): the
-            # live row was deregistered in _execute_inner's finally but
-            # stays readable — its queue/device/lane attribution folds
-            # into the per-fingerprint totals here
-            lv = getattr(obs, "live", None)
-            self.insights.record(
-                fp=fp, text=text, kind=kind, space=space0,
-                latency_us=us, error=res.error,
-                rows=(len(res.data.rows) if res.data is not None else 0),
-                queue_us=(lv.queue_us if lv is not None else 0),
-                device_us=(lv.device_us if lv is not None else 0),
-                dispatches=(lv.dispatches if lv is not None else 0),
-                plan_hash=getattr(obs, "plan_hash", None),
-                plan_cache_hit=cached_plan is not None,
-                lanes=(lv.batch_lanes if lv is not None else 0))
-        from ..utils.flight import flight_recorder
-        flight_recorder().record(
-            stmt=text, kind=kind, latency_us=us, error=res.error,
-            trace_id=tg.trace_id if tg is not None else None,
-            session=session.id,
-            operators=obs.operators,
-            work=(obs.work.as_dict if obs.work is not None else None),
-            slow_us=slow_us, fingerprint=fp)
+        # the statement's bookkeeping, a span of its own (phase
+        # `record`): what it costs is not the root's unexplained time
+        with trace.span("graphd:record"):
+            stats().inc("num_queries")
+            stats().add_value("query_latency_us", us)
+            stats().observe("query_latency_us_hist", us, {"kind": kind})
+            if _bumps_write_epoch(kind):
+                # one bump per mutating statement, SUCCESS OR FAILURE — a
+                # failed multi-part write may still have committed some
+                # parts (fan-out is not atomic), so only statements that
+                # provably touched nothing may skip the bump.  A PR 5
+                # dedup-replayed write still acks as one statement, so it
+                # bumps (and invalidates the result cache) exactly once.
+                self.qctx.bump_write_epoch()
+                self.result_cache.note_invalidated()
+            if res.ok and result_key is not None and res.plan_desc is None \
+                    and not isinstance(stmt, A.ExplainSentence) \
+                    and kind in _CACHEABLE_KINDS:
+                from ..core.wire import to_wire
+                self.result_cache.put(
+                    result_key,
+                    to_wire(res.data) if res.data is not None else None,
+                    res.space)
+            slow_us = self.slow_query_us
+            if not res.ok:
+                stats().inc("num_query_errors")
+            elif us > slow_us:
+                stats().inc("num_slow_queries")
+                self.slow_log.append({"stmt": text, "latency_us": us,
+                                      "ts": time.time(),
+                                      "trace_id": tg.trace_id
+                                      if tg is not None else None,
+                                      "fingerprint": fp or ""})
+            if fp is not None:
+                # the one aggregate update per statement (ISSUE 16): the
+                # live row was deregistered in _execute_inner's finally but
+                # stays readable — its queue/device/lane attribution folds
+                # into the per-fingerprint totals here
+                lv = getattr(obs, "live", None)
+                self.insights.record(
+                    fp=fp, text=text, kind=kind, space=space0,
+                    latency_us=us, error=res.error,
+                    rows=(len(res.data.rows) if res.data is not None else 0),
+                    queue_us=(lv.queue_us if lv is not None else 0),
+                    device_us=(lv.device_us if lv is not None else 0),
+                    dispatches=(lv.dispatches if lv is not None else 0),
+                    plan_hash=getattr(obs, "plan_hash", None),
+                    plan_cache_hit=cached_plan is not None,
+                    lanes=(lv.batch_lanes if lv is not None else 0))
+            from ..utils.flight import flight_recorder
+            flight_recorder().record(
+                stmt=text, kind=kind, latency_us=us, error=res.error,
+                trace_id=tg.trace_id if tg is not None else None,
+                session=session.id,
+                operators=obs.operators,
+                work=(obs.work.as_dict if obs.work is not None else None),
+                slow_us=slow_us, fingerprint=fp)
         return res
 
     def _execute_inner(self, session: Session, stmt: A.Sentence,

@@ -36,6 +36,7 @@ from ..graphstore.delta import (DeltaOverflow, DeltaUnsupported, HostDelta,
 from ..graphstore.store import GraphStore
 from ..native.kernels import join_halves as native_join_halves
 from ..utils import trace as _t
+from ..utils.config import get_config
 from ..utils.stats import stats as _metrics
 from .device import (DeviceDelta, DeviceSnapshot, SnapshotRetired,
                      TpuUnavailable, make_mesh, mesh_lanes, mesh_parts, note_host_fallback,
@@ -49,19 +50,36 @@ _log = logging.getLogger(__name__)
 
 
 def _on_live_snapshot(fn):
-    """A device statement that met a swap is served from the snapshot
-    that replaced its own: `SnapshotRetired` (raised under the read
-    gate, before anything ran) pins again and runs the statement anew.
-    Only a space that keeps being replaced under one statement (a few
-    times over) is handed to the caller's fallback."""
-    @functools.wraps(fn)
-    def run(self, *args, **kw):
+    """The entry of a device statement (`traverse`, `traverse_hops`,
+    `bfs`).  One that met a swap is served from the snapshot that
+    replaced its own: `SnapshotRetired` (raised under the read gate,
+    before anything ran) pins again and runs the statement anew.  Only a
+    space that keeps being replaced under one statement (a few times
+    over) is handed to the caller's fallback.
+
+    A statement that arrives with no trace active (an embedded runtime:
+    `pin_prebuilt`, the tools, the benchmark's proxy cells) is rooted
+    HERE, around its retries too, as `query:tpu.<entry>`: the spans
+    below are then live, fold into the phase ledger when the root
+    closes and reach `/traces`.  Under graphd the statement's root is
+    active and none is opened; the flag is the one graphd's root obeys."""
+    entry = fn.__name__
+
+    def attempts(self, *args, **kw):
         for _ in range(self.RETIRED_RETRIES):
             try:
                 return fn(self, *args, **kw)
             except SnapshotRetired:
                 _metrics().inc("tpu_stmt_retired_retries")
         return fn(self, *args, **kw)
+
+    @functools.wraps(fn)
+    def run(self, store, space, *args, **kw):
+        if _t.current_ctx() is not None or \
+                not get_config().get("enable_query_tracing"):
+            return attempts(self, store, space, *args, **kw)
+        with _t.start_trace(f"query:tpu.{entry}", service="tpu", space=space):
+            return attempts(self, store, space, *args, **kw)
     return run
 
 
@@ -809,7 +827,6 @@ class TpuRuntime:
         # same hazard on real ICI).  Local-mode programs are
         # collective-free and keep full dispatch concurrency.
         self._launch_mutex = threading.Lock()
-        from ..utils.config import get_config
         # the bitmap frontier (round-4 redesign) has no size bucket;
         # the only escalating budget left is the per-block edge budget
         self.init_eb = int(get_config().get("tpu_init_edge_budget"))
@@ -891,7 +908,6 @@ class TpuRuntime:
         part) capacity worked out at pin time (`_delta_capacity`); a
         positive value fixes that capacity; 0 = delta plane off (every
         epoch bump re-pins; byte-identical to the pre-delta runtime)."""
-        from ..utils.config import get_config
         try:
             return int(get_config().get("tpu_delta_max_edges"))
         except Exception:  # noqa: BLE001 — config missing in odd embeds
@@ -949,7 +965,6 @@ class TpuRuntime:
 
     @staticmethod
     def _delta_slack() -> int:
-        from ..utils.config import get_config
         try:
             return max(int(get_config().get("tpu_delta_vmax_slack")), 0)
         except Exception:  # noqa: BLE001
@@ -1256,7 +1271,6 @@ class TpuRuntime:
         kick the background compaction (fold the plane into a new base
         off the gate, swap under a short exclusive hold).  One at a
         time, and after a failure not before its back-off has run."""
-        from ..utils.config import get_config
         try:
             wm = float(get_config().get("tpu_delta_compact_watermark"))
         except Exception:  # noqa: BLE001
@@ -1417,7 +1431,6 @@ class TpuRuntime:
         """Apply the supernode degree-split at pin time when the flag
         is set (SURVEY §7 hard-part #4): the pinned copy AND its host
         mirror share the split layout, so eidx decode is unchanged."""
-        from ..utils.config import get_config
         try:
             thr = int(get_config().get("tpu_degree_split_threshold"))
         except Exception:  # noqa: BLE001 — config missing in odd embeds
@@ -1859,13 +1872,15 @@ class TpuRuntime:
         if uniform:
             EBs = [max(EBs)] * n_hops
 
-        seed_pad, seed_fn = self._seed_frontier_prep(dev, lane_dense, lanes)
-        L = seed_pad.shape[0] if lanes else 1
         info: Dict[str, Any] = {
             "lanes": len(lane_dense), "rungs": [], "compiles": 0,
             "refetches": 0, "gate_wait_us": wait_us, "phases": [],
             "fetch_bytes": 0, "fetch_bytes_kept": 0}
         phases, rungs = info["phases"], info["rungs"]
+        with self._phase(phases, "tpu:seed_prep"):
+            seed_pad, seed_fn = self._seed_frontier_prep(
+                dev, lane_dense, lanes)
+        L = seed_pad.shape[0] if lanes else 1
         with self._phase(phases, "device:put"), \
                 self._collective_launch():
             frontier = seed_fn(seed_pad)
@@ -1914,12 +1929,17 @@ class TpuRuntime:
             info["device_s"] = phases[-1][2]
             rungs.append((int(info["device_s"] * 1e6), compiled))
             if "cap" in res:
-                self._warm_fetch(res["cap"], key, fetch_keys)
-            # rebinding `res` releases the rung's device buffers, after
-            # the fetch has timed itself; a failed rung's capture is so
-            # dropped BEFORE the larger rung runs: holding both nearly
-            # doubles peak HBM and can fail the retry
-            res = self._fetch(res, key, fetch_keys, info)
+                self._warm_fetch(res["cap"], key, fetch_keys, phases)
+            # the rung's device buffers are released after the fetch
+            # has timed itself, and the release times itself too: it
+            # waits its turn for the GIL and is no part of the fetch.  A
+            # failed rung's capture is so dropped BEFORE the larger rung
+            # runs: holding both nearly doubles peak HBM and can fail
+            # the retry
+            host, held = self._fetch(res, key, fetch_keys, info)
+            with self._phase(phases, "device:release"):
+                res = held = None
+            res = host
             if not res["ovf_expand"].any():
                 break
             # hop_edges reports the true per-part pre-filter expansion
@@ -1940,114 +1960,128 @@ class TpuRuntime:
         else:
             raise TpuUnavailable("bucket escalation did not converge")
 
-        info["retries"], info["ebs"] = attempt, list(EBs)
-        if self._buckets.get(bkey) != (0, ebs):
-            self._buckets[bkey] = (0, ebs)
-            # bound by evicting oldest entries — a wholesale clear()
-            # would also wipe the persistent cache file on the next
-            # save, re-exposing every converged query shape to the
-            # recompile ladder
-            while len(self._buckets) > 512:
-                self._buckets.pop(next(iter(self._buckets)))
-            self._save_buckets()
-        m = _metrics()
-        m.inc("tpu_kernel_runs")
-        m.inc("tpu_edges_traversed", int(np.asarray(res["hop_edges"]).sum()))
-        if "chunks_run" in res:
-            for k in _ENGAGEMENT:
-                m.inc(f"tpu_hop_{k}", int(res[k].sum()))
-        # what pinning property columns as their halves removed: bytes
-        # of 64-bit operands of the program just run, each of which a
-        # chip without 64-bit lanes splits WHOLE at the top of the run
-        m.add_value("tpu_wide_operand_bytes", float(wide))
-        # a traverse program's per-slot gathers in its last hop's
-        # expansion stage, settled when it was traced (hop.py
-        # `_slot_gathers`): what a slot of the widest hop costs
-        noted = getattr(fn, "noted", None)
-        if noted:
-            m.add_value("tpu_hop_slot_gathers",
-                        float(noted["slot_gathers"]))
-        m.add_value("tpu_kernel_s", info["device_s"])
-        m.add_value("tpu_put_s", info["put_s"])
-        m.add_value("tpu_fetch_s", info["fetch_s"])
-        m.add_value("tpu_queue_s", wait_us / 1e6)
-        m.inc("tpu_escalation_retries", attempt)
-        m.inc("tpu_refetches", info["refetches"])
-        # every byte the launch's fetches brought to the host (meta,
-        # overflowed rungs and discarded speculation included) and those
-        # of them that are kept capture entries
-        m.inc("tpu_fetch_bytes", info["fetch_bytes"])
-        m.inc("tpu_fetch_bytes_kept", info["fetch_bytes_kept"])
-        # device kernel ledger (ISSUE 8 tentpole): per-RUNG dispatch µs
-        # and compile-vs-cache dispositions were accumulated as plain
-        # locals in the loop (every escalation rung is a real dispatch —
-        # counting only the converged run would skew the ratios under
-        # retries); emit them to histograms/counters HERE, outside the
-        # timing-sensitive dispatch neighborhood
-        for r_us, r_compiled in rungs:
-            m.observe("tpu_dispatch_us", r_us, {"kernel": kernel})
-            if r_compiled:
-                m.inc_labeled("tpu_kernel_compiles", {"kernel": kernel})
-            else:
-                m.inc_labeled("tpu_kernel_cache_hits", {"kernel": kernel})
-        hbm = info["hbm_bytes"] = self.hbm_bytes()
-        self._hbm_high_water = max(
-            getattr(self, "_hbm_high_water", 0), hbm)
-        m.gauge("tpu_hbm_high_water_bytes", float(self._hbm_high_water))
-        # per-shard dispatch/exchange facts (PR 17): the bit-packed
-        # frontier all_to_all payload this converged run moved over ICI
-        # — BFS exchanges every level, the traverse kernels skip the
-        # final hop's exchange; a shared launch's single per-hop
-        # all_to_all carries the whole L-lane payload
-        xhops = n_hops if kernel == "bfs" else max(n_hops - 1, 0)
-        xbytes = xhops * a2a_payload_bytes(self.mesh_size, dev.vmax, lanes=L)
-        info["shards"], info["exchange_bytes"] = self.mesh_size, xbytes
-        m.gauge("tpu_shards", float(self.mesh_size))
-        from ..utils.flight import kernel_ledger
-        kernel_ledger().record(
-            kernel=kernel, shape=([L] if lanes else []) + list(EBs),
-            steps=n_hops, compiled=bool(info["compiles"]),
-            dispatch_us=int(info["device_s"] * 1e6), hbm_bytes=hbm,
-            retries=attempt, shards=self.mesh_size, exchange_bytes=xbytes)
-        if xbytes:
-            m.inc("tpu_all_to_all_bytes", xbytes)
-        # a shared launch is traced under the LAUNCHING member's
-        # statement, whose context the launch suppressed: the launch
-        # itself, from the seed put to the last fetch
-        with _t.use_ctx(launcher_ctx) if lanes else nullcontext():
-            if lanes:
-                tp = phases[0][1]
-                _t.record_phase("tpu:batch", tp, time.perf_counter() - tp,
-                                lanes=len(lane_dense), kernel=kernel,
-                                eb=list(EBs))
+        # the launch's accounting, once a converged launch: a phase of
+        # its own, so that neither a statement's root nor a shared
+        # launch's members keep it as unexplained time
+        with self._phase(phases, "tpu:launch_account"):
+            info["retries"], info["ebs"] = attempt, list(EBs)
+            if self._buckets.get(bkey) != (0, ebs):
+                self._buckets[bkey] = (0, ebs)
+                # bound by evicting oldest entries — a wholesale clear()
+                # would also wipe the persistent cache file on the next
+                # save, re-exposing every converged query shape to the
+                # recompile ladder
+                while len(self._buckets) > 512:
+                    self._buckets.pop(next(iter(self._buckets)))
+                self._save_buckets()
+            m = _metrics()
+            m.inc("tpu_kernel_runs")
+            m.inc("tpu_edges_traversed", int(np.asarray(res["hop_edges"]).sum()))
+            if "chunks_run" in res:
+                for k in _ENGAGEMENT:
+                    m.inc(f"tpu_hop_{k}", int(res[k].sum()))
+            # what pinning property columns as their halves removed: bytes
+            # of 64-bit operands of the program just run, each of which a
+            # chip without 64-bit lanes splits WHOLE at the top of the run
+            m.add_value("tpu_wide_operand_bytes", float(wide))
+            # a traverse program's per-slot gathers in its last hop's
+            # expansion stage, settled when it was traced (hop.py
+            # `_slot_gathers`): what a slot of the widest hop costs
+            noted = getattr(fn, "noted", None)
+            if noted:
+                m.add_value("tpu_hop_slot_gathers",
+                            float(noted["slot_gathers"]))
+            m.add_value("tpu_kernel_s", info["device_s"])
+            m.add_value("tpu_put_s", info["put_s"])
+            m.add_value("tpu_fetch_s", info["fetch_s"])
+            m.add_value("tpu_queue_s", wait_us / 1e6)
+            m.inc("tpu_escalation_retries", attempt)
+            m.inc("tpu_refetches", info["refetches"])
+            # every byte the launch's fetches brought to the host (meta,
+            # overflowed rungs and discarded speculation included) and those
+            # of them that are kept capture entries
+            m.inc("tpu_fetch_bytes", info["fetch_bytes"])
+            m.inc("tpu_fetch_bytes_kept", info["fetch_bytes_kept"])
+            # device kernel ledger (ISSUE 8 tentpole): per-RUNG dispatch µs
+            # and compile-vs-cache dispositions were accumulated as plain
+            # locals in the loop (every escalation rung is a real dispatch —
+            # counting only the converged run would skew the ratios under
+            # retries); emit them to histograms/counters HERE, outside the
+            # timing-sensitive dispatch neighborhood
+            for r_us, r_compiled in rungs:
+                m.observe("tpu_dispatch_us", r_us, {"kernel": kernel})
+                if r_compiled:
+                    m.inc_labeled("tpu_kernel_compiles", {"kernel": kernel})
+                else:
+                    m.inc_labeled("tpu_kernel_cache_hits", {"kernel": kernel})
+            hbm = info["hbm_bytes"] = self.hbm_bytes()
+            self._hbm_high_water = max(
+                getattr(self, "_hbm_high_water", 0), hbm)
+            m.gauge("tpu_hbm_high_water_bytes", float(self._hbm_high_water))
+            # per-shard dispatch/exchange facts (PR 17): the bit-packed
+            # frontier all_to_all payload this converged run moved over ICI
+            # — BFS exchanges every level, the traverse kernels skip the
+            # final hop's exchange; a shared launch's single per-hop
+            # all_to_all carries the whole L-lane payload
+            xhops = n_hops if kernel == "bfs" else max(n_hops - 1, 0)
+            xbytes = xhops * a2a_payload_bytes(self.mesh_size, dev.vmax, lanes=L)
+            info["shards"], info["exchange_bytes"] = self.mesh_size, xbytes
+            m.gauge("tpu_shards", float(self.mesh_size))
+            from ..utils.flight import kernel_ledger
+            kernel_ledger().record(
+                kernel=kernel, shape=([L] if lanes else []) + list(EBs),
+                steps=n_hops, compiled=bool(info["compiles"]),
+                dispatch_us=int(info["device_s"] * 1e6), hbm_bytes=hbm,
+                retries=attempt, shards=self.mesh_size, exchange_bytes=xbytes)
             if xbytes:
-                # the exchange runs inside the fused program — its span
-                # carries payload facts, not a separate timing
-                _t.mark("tpu:shard_exchange", bytes=xbytes, hops=xhops,
-                        shards=self.mesh_size,
-                        **({"lanes": L} if lanes else {}))
-        return res, info
+                m.inc("tpu_all_to_all_bytes", xbytes)
+            # a shared launch is traced under the LAUNCHING member's
+            # statement, whose context the launch suppressed: the launch
+            # itself, from its seed prep to here
+            with _t.use_ctx(launcher_ctx) if lanes else nullcontext():
+                if lanes:
+                    tp = phases[0][1]
+                    _t.record_phase("tpu:batch", tp, time.perf_counter() - tp,
+                                    lanes=len(lane_dense), kernel=kernel,
+                                    eb=list(EBs))
+                if xbytes:
+                    # the exchange runs inside the fused program — its span
+                    # carries payload facts, not a separate timing
+                    _t.mark("tpu:shard_exchange", bytes=xbytes, hops=xhops,
+                            shards=self.mesh_size,
+                            **({"lanes": L} if lanes else {}))
+            return res, info
 
-    def _warm_fetch(self, cap_dev, key, fetch_keys: Optional[set]):
+    def _warm_fetch(self, cap_dev, key, fetch_keys: Optional[set],
+                    phases: list):
         """Compile the fetch programs of this capture (every slice or
         piece size its width admits, on each device that holds a shard)
         when its program first runs for these columns, outside every
         timed phase: no statement meets one for the first time through
-        the size of what it kept."""
+        the size of what it kept.  The one statement that does the
+        compiling carries it as `tpu:fetch_warm`."""
         wk = (key, None if fetch_keys is None else frozenset(fetch_keys))
         if wk not in self._fetch_warm:
-            _taker(cap_dev, fetch_keys).warm()
+            with self._phase(phases, "tpu:fetch_warm"):
+                _taker(cap_dev, fetch_keys).warm()
             if len(self._fetch_warm) > 4096:
                 self._fetch_warm.clear()
             self._fetch_warm.add(wk)
 
     def _fetch(self, res, key, fetch_keys: Optional[set], info):
-        """Bring one rung's result to the host and return it; the
-        launch's `info` takes its phases, undershoots, seconds and
-        bytes.  It times itself, as its last statement: the caller
-        holds the device result and this frame the slices taken of it
-        until then, because releasing device buffers waits its turn
-        (tens of ms under eight sessions) and is no part of the fetch.
+        """Bring one rung's result to the host: -> (the host result,
+        what this frame still held of the device's); the launch's `info`
+        takes its phases, undershoots, seconds and bytes.  It times
+        itself, as its last statement, and hands the device references it
+        took (the leaves, the slices cut of the capture) back to the
+        caller, who holds the device result too: releasing device buffers
+        waits its turn (tens of ms under eight sessions), is no part of
+        the fetch and is timed by the caller as `device:release`.  The
+        spans of phase `fetch` cover the clock from end to end:
+        `device:fetch` the two transfers (the first with the taker's
+        set-up, the second nested), `device:fetch.rows` the host's side
+        of a kept capture (the pieces asked for by its kept counts, cut
+        on the device and assembled into rows).
 
         What comes: the leaves a caller reads (`_FETCHED`) and, of the
         capture columns the yields read, each row's kept prefix
@@ -2064,38 +2098,39 @@ class TpuRuntime:
         slice dropped."""
         t0 = time.perf_counter()
         phases = info["phases"]
-        meta = {k: res[k] for k in _FETCHED if k in res}
-        take = spec = None
-        if "cap" in res:
-            take = _taker(res["cap"], fetch_keys)
-            spec = self._kmax.get(key)
+        take = first = more = None
         with self._phase(phases, "device:fetch"):
-            first = None if spec is None else take.speculate(spec)
+            meta = {k: res[k] for k in _FETCHED if k in res}
+            if "cap" in res:
+                take = _taker(res["cap"], fetch_keys)
+                spec = self._kmax.get(key)
+                first = None if spec is None else take.speculate(spec)
             host, got = jax.device_get((meta, first))
             if first is not None:
                 take.got(got)
         info["fetch_bytes"] += _nbytes(host)
         info["refetches"] = 0
         if take is not None and not host["ovf_expand"].any():
-            kc = host["kcount"]
-            more = take.ask(kc)
-            if more is not None:
-                # the capture's own fetch: the second phase where
-                # nothing was speculated, else a refetch
-                info["refetches"] = int(first is not None)
-                with self._phase(phases, "device:fetch",
-                                 refetch=first is not None):
-                    take.got(jax.device_get(more))
-            host["cap"] = take.rows(kc)
-            host["cap"]["kcount"] = kc
-            info["fetch_bytes_kept"] += int(kc.sum()) * take.item_bytes()
-            self._kmax[key] = kc
-            while len(self._kmax) > 512:
-                self._kmax.pop(next(iter(self._kmax)))
+            with self._phase(phases, "device:fetch.rows"):
+                kc = host["kcount"]
+                more = take.ask(kc)
+                if more is not None:
+                    # the capture's own fetch: the second phase where
+                    # nothing was speculated, else a refetch
+                    info["refetches"] = int(first is not None)
+                    with self._phase(phases, "device:fetch",
+                                     refetch=first is not None):
+                        take.got(jax.device_get(more))
+                host["cap"] = take.rows(kc)
+                host["cap"]["kcount"] = kc
+                info["fetch_bytes_kept"] += int(kc.sum()) * take.item_bytes()
+                self._kmax[key] = kc
+                while len(self._kmax) > 512:
+                    self._kmax.pop(next(iter(self._kmax)))
         if take is not None:
             info["fetch_bytes"] += take.nbytes
         info["fetch_s"] = time.perf_counter() - t0
-        return host
+        return host, (meta, take, first, more)
 
     @staticmethod
     def _attribute(info, res, lane: Optional[int],
@@ -2160,9 +2195,10 @@ class TpuRuntime:
         if lv is not None:
             lv.add("queue_us", queue_us)
         # this lane's view of the shared launch: it waited (former +
-        # gate) until the seed put began, then the launch's own phases
-        t_put = info["phases"][0][1]
-        _t.record_phase("device:queue", t_put - stats.queue_s,
+        # gate) until the launch began (its seed prep), then the
+        # launch's own phases
+        t_launch = info["phases"][0][1]
+        _t.record_phase("device:queue", t_launch - stats.queue_s,
                         stats.queue_s, lanes=info["lanes"])
         for name, start, dur, attrs in info["phases"]:
             _t.record_phase(name, start, dur, **attrs)
@@ -2174,23 +2210,26 @@ class TpuRuntime:
         """What every device statement starts with: the pinned
         snapshot, its stats, the blocks it reads, the compiled edge
         predicate as (pred, pred_cols, pred_key) and the dense seed
-        ids.  Raises CannotCompile if the filter does not vectorize."""
+        ids, under a `tpu:prep` span (the pin's `tpu:snapshot_check`
+        inside it).  Raises CannotCompile if the filter does not
+        vectorize."""
         t_start = time.perf_counter()
-        dev = self.pin(store, space)
-        sd = store.space(space)
-        stats = TraverseStats()
-        stats.steps = steps
-        stats.pin_s = time.perf_counter() - t_start
-        block_keys = [(et, d) for et in etypes for d in ("out", "in")
-                      if direction in (d, "both")]
-        pred: Tuple[Any, List[str], Optional[str]] = (None, [], None)
-        if edge_filter is not None:
-            # single-etype constraint is enforced by the optimizer rule
-            bl = dev.blocks[block_keys[0]]
-            pred = compile_predicate(
-                edge_filter, bl.prop_types, dev.pool,
-                vid_to_dense=sd.dense_id) + (E.to_text(edge_filter),)
-        dense = [d for d in (sd.dense_id(v) for v in vids) if d >= 0]
+        with _t.span("tpu:prep"):
+            dev = self.pin(store, space)
+            sd = store.space(space)
+            stats = TraverseStats()
+            stats.steps = steps
+            stats.pin_s = time.perf_counter() - t_start
+            block_keys = [(et, d) for et in etypes for d in ("out", "in")
+                          if direction in (d, "both")]
+            pred: Tuple[Any, List[str], Optional[str]] = (None, [], None)
+            if edge_filter is not None:
+                # single-etype constraint is enforced by the optimizer rule
+                bl = dev.blocks[block_keys[0]]
+                pred = compile_predicate(
+                    edge_filter, bl.prop_types, dev.pool,
+                    vid_to_dense=sd.dense_id) + (E.to_text(edge_filter),)
+            dense = [d for d in (sd.dense_id(v) for v in vids) if d >= 0]
         return t_start, dev, stats, block_keys, pred, dense
 
     def _block_leaves(self, dev: DeviceSnapshot, block_keys, prop_names):
@@ -2214,68 +2253,76 @@ class TpuRuntime:
         "traverse": GO, the last hop captured; "hops": MATCH, every hop
         a frame at one uniform budget): the blocks' leaves with ONE
         consistent delta view, the program's jit key, then a shared
-        launch if the batch former finds company, else a solo one.
+        launch if the batch former finds company, else a solo one; all
+        of it one `tpu:launch` span, the launch's phases inside it.
         Returns (res, dview)."""
-        pred_fn, pred_cols, pred_key = pred
-        hops = kernel == "hops"
-        prop_names = {n for n in pred_cols if not n.startswith("_")}
-        dview, blocks = self._block_leaves(dev, block_keys,
-                                           prop_names | set(yield_cols))
-        blocks_data = tuple(blocks)
-        if fetch_keys is not None and any(
-                _delta_rows_of(dview, bk) for bk in block_keys):
-            # delta rows interleave with base rows in canonical CSR
-            # order at materialize time — the host re-sort needs every
-            # identity column regardless of what the yields read; a
-            # plane that holds no row of these blocks adds no column
-            fetch_keys |= {"src", "dst", "rank", "eidx"}
-        # the program gathers and carries an edge's rank only for a
-        # consumer: the fetch (all of the capture, or a yield that reads
-        # rank), the predicate, a MATCH frame (edge identities), or an
-        # armed delta plane, whose first row puts rank into the fetch
-        # above and must not need a second program for it.  What is
-        # left is a statement over an unarmed snapshot that reads none
-        carry_rank = (fetch_keys is None or "rank" in fetch_keys
-                      or "_rank" in pred_cols or hops
-                      or any("d_src" in b for b in blocks))
-        hub_dense = getattr(dev.host, "hub_dense", None)
-        hub_n = 0 if hub_dense is None else len(hub_dense)
+        # `tpu:launch` holds, as its own time, the host's steps around
+        # the launch's phases: this assembly, the former's and the
+        # gate's bookkeeping, a rung's key and program lookup, the
+        # charge to the statement
+        with _t.span("tpu:launch", kernel=kernel):
+            pred_fn, pred_cols, pred_key = pred
+            hops = kernel == "hops"
+            prop_names = {n for n in pred_cols if not n.startswith("_")}
+            dview, blocks = self._block_leaves(dev, block_keys,
+                                               prop_names | set(yield_cols))
+            blocks_data = tuple(blocks)
+            if fetch_keys is not None and any(
+                    _delta_rows_of(dview, bk) for bk in block_keys):
+                # delta rows interleave with base rows in canonical CSR
+                # order at materialize time — the host re-sort needs every
+                # identity column regardless of what the yields read; a
+                # plane that holds no row of these blocks adds no column
+                fetch_keys |= {"src", "dst", "rank", "eidx"}
+            # the program gathers and carries an edge's rank only for a
+            # consumer: the fetch (all of the capture, or a yield that reads
+            # rank), the predicate, a MATCH frame (edge identities), or an
+            # armed delta plane, whose first row puts rank into the fetch
+            # above and must not need a second program for it.  What is
+            # left is a statement over an unarmed snapshot that reads none
+            carry_rank = (fetch_keys is None or "rank" in fetch_keys
+                          or "_rank" in pred_cols or hops
+                          or any("d_src" in b for b in blocks))
+            hub_dense = getattr(dev.host, "hub_dense", None)
+            hub_n = 0 if hub_dense is None else len(hub_dense)
 
-        def build(ebs, lanes=False):
-            return build_traverse_fn(
-                None if self.local_mode else self.mesh, dev.num_parts,
-                ebs, steps, len(block_keys), lanes=lanes, pred=pred_fn,
-                pred_cols=pred_cols, capture=capture, capture_hops=hops,
-                yield_cols=yield_cols, carry_rank=carry_rank,
-                hub_dense=hub_dense)
+            def build(ebs, lanes=False):
+                return build_traverse_fn(
+                    None if self.local_mode else self.mesh, dev.num_parts,
+                    ebs, steps, len(block_keys), lanes=lanes, pred=pred_fn,
+                    pred_cols=pred_cols, capture=capture, capture_hops=hops,
+                    yield_cols=yield_cols, carry_rank=carry_rank,
+                    hub_dense=hub_dense)
 
-        def key_fn(ebs):
-            if hops:
-                return (space, dev.epoch, "hops", tuple(block_keys),
-                        steps, ebs, pred_key, tuple(pred_cols), hub_n,
-                        self._delta_sig(dev))
-            # a program that carries rank keeps the key it always had
-            # (`.tpu_buckets.json` is read across versions); the one
-            # that does not shares neither program nor bucket with it
-            return (space, dev.epoch, tuple(block_keys), steps, ebs,
-                    pred_key, capture, tuple(pred_cols), yield_cols,
-                    hub_n, self._delta_sig(dev)) + (
-                        () if carry_rank else ("rank-free",))
+            def key_fn(ebs):
+                if hops:
+                    return (space, dev.epoch, "hops", tuple(block_keys),
+                            steps, ebs, pred_key, tuple(pred_cols), hub_n,
+                            self._delta_sig(dev))
+                # a program that carries rank keeps the key it always had
+                # (`.tpu_buckets.json` is read across versions); the one
+                # that does not shares neither program nor bucket with it
+                return (space, dev.epoch, tuple(block_keys), steps, ebs,
+                        pred_key, capture, tuple(pred_cols), yield_cols,
+                        hub_n, self._delta_sig(dev)) + (
+                            () if carry_rank else ("rank-free",))
 
-        launch = dict(key_fn=key_fn, inputs_fn=lambda ebs: (blocks_data,),
-                      n_hops=steps, uniform=hops, fetch_keys=fetch_keys,
-                      kernel=kernel, stats=stats)
-        # multi-lane batched dispatch (ISSUE 15): concurrent compatible
-        # statements share ONE launch; None falls through to the solo
-        # path (batching off / no company / capture-less program)
-        res = None
-        if capture:
-            res = self._try_batched(
-                dense, dev, build_fn=functools.partial(build, lanes=True),
-                delta_epoch=dview[0] if dview is not None else None,
-                **launch)
-        if res is None:
-            res = self._escalate(dev, dense, build_fn=build, **launch)
+            launch = dict(key_fn=key_fn, inputs_fn=lambda ebs: (blocks_data,),
+                          n_hops=steps, uniform=hops, fetch_keys=fetch_keys,
+                          kernel=kernel, stats=stats)
+            # multi-lane batched dispatch (ISSUE 15): concurrent
+            # compatible statements share ONE launch; None falls through
+            # to the solo path (batching off / no company / capture-less
+            # program)
+            res = None
+            if capture:
+                res = self._try_batched(
+                    dense, dev,
+                    build_fn=functools.partial(build, lanes=True),
+                    delta_epoch=dview[0] if dview is not None else None,
+                    **launch)
+            if res is None:
+                res = self._escalate(dev, dense, build_fn=build, **launch)
         return res, dview
 
     @contextmanager
@@ -2466,8 +2513,9 @@ class TpuRuntime:
                         d2v_arr, d2v_id, de["rows"])
 
                 def catp(name, dtype=None):
-                    return _cat_rows([cap[name][p, h, bi] for p in pids],
-                                     perms, dtype)
+                    with _t.span("device:materialise.concat", col=name):
+                        return _cat_rows(
+                            [cap[name][p, h, bi] for p in pids], perms, dtype)
 
                 ss = catp("src", np.int64)
                 dd = catp("dst", np.int64)
@@ -2522,65 +2570,66 @@ class TpuRuntime:
         if not dense:
             return np.full((dev.num_parts, dev.vmax), -1, np.int32), stats
 
-        P = dev.num_parts
-        # direction-optimizing leg (single chip): each block's REVERSE
-        # twin rides along so dense levels can go bottom-up (a vertex
-        # scans its in-neighbors against the resident frontier bitmap).
-        # 'both' already traverses both planes — no distinct reverse.
-        rev_of = {"out": "in", "in": "out"}
-        rev_keys = [(et, rev_of[d]) for et, d in block_keys
-                    if d in rev_of]
-        # with a delta plane armed the program itself keeps a level
-        # top-down while the plane holds anything (bfs.py: bottom-up
-        # scans the reverse adjacency, which the merge does not model),
-        # so an armed, empty plane changes no level's direction
-        have_rev = (self.local_mode
-                    and len(rev_keys) == len(block_keys)
-                    and all(rk in dev.blocks for rk in rev_keys))
-        pnames = {n for n in pred_cols if not n.startswith("_")}
-        _, blocks = self._block_leaves(dev, block_keys, pnames)
-        if have_rev:
-            for d, rk in zip(blocks, rev_keys):
-                rb = dev.blocks[rk]
-                d.update(rev_indptr=rb.indptr, rev_nbr=rb.nbr,
-                         rev_rank=rb.rank,
-                         rev_props={n: rb.props[n] for n in pnames})
-        blocks_data = tuple(blocks)
+        with _t.span("tpu:launch", kernel="bfs"):
+            P = dev.num_parts
+            # direction-optimizing leg (single chip): each block's REVERSE
+            # twin rides along so dense levels can go bottom-up (a vertex
+            # scans its in-neighbors against the resident frontier bitmap).
+            # 'both' already traverses both planes — no distinct reverse.
+            rev_of = {"out": "in", "in": "out"}
+            rev_keys = [(et, rev_of[d]) for et, d in block_keys
+                        if d in rev_of]
+            # with a delta plane armed the program itself keeps a level
+            # top-down while the plane holds anything (bfs.py: bottom-up
+            # scans the reverse adjacency, which the merge does not model),
+            # so an armed, empty plane changes no level's direction
+            have_rev = (self.local_mode
+                        and len(rev_keys) == len(block_keys)
+                        and all(rk in dev.blocks for rk in rev_keys))
+            pnames = {n for n in pred_cols if not n.startswith("_")}
+            _, blocks = self._block_leaves(dev, block_keys, pnames)
+            if have_rev:
+                for d, rk in zip(blocks, rev_keys):
+                    rb = dev.blocks[rk]
+                    d.update(rev_indptr=rb.indptr, rev_nbr=rb.nbr,
+                             rev_rank=rb.rank,
+                             rev_props={n: rb.props[n] for n in pnames})
+            blocks_data = tuple(blocks)
 
-        n_phantom = int(P * dev.vmax
-                        - np.asarray(dev.num_vertices).sum())
-        hub_dense = getattr(dev.host, "hub_dense", None)
-        hub_n = 0 if hub_dense is None else len(hub_dense)
+            n_phantom = int(P * dev.vmax
+                            - np.asarray(dev.num_vertices).sum())
+            hub_dense = getattr(dev.host, "hub_dense", None)
+            hub_n = 0 if hub_dense is None else len(hub_dense)
 
-        def build(ebs):
-            if self.local_mode:
-                return build_bfs_fn_local(P, ebs, max_steps,
-                                          len(block_keys), dev.vmax,
-                                          pred=pred, pred_cols=pred_cols,
-                                          have_rev=have_rev,
-                                          n_phantom=n_phantom,
-                                          hub_dense=hub_dense)
-            return build_bfs_fn(self.mesh, P, ebs, max_steps,
-                                len(block_keys), dev.vmax,
-                                pred=pred, pred_cols=pred_cols,
-                                hub_dense=hub_dense)
+            def build(ebs):
+                if self.local_mode:
+                    return build_bfs_fn_local(P, ebs, max_steps,
+                                              len(block_keys), dev.vmax,
+                                              pred=pred, pred_cols=pred_cols,
+                                              have_rev=have_rev,
+                                              n_phantom=n_phantom,
+                                              hub_dense=hub_dense)
+                return build_bfs_fn(self.mesh, P, ebs, max_steps,
+                                    len(block_keys), dev.vmax,
+                                    pred=pred, pred_cols=pred_cols,
+                                    hub_dense=hub_dense)
 
-        # Per-LEVEL edge budgets (like the traverse kernel's per-hop
-        # buckets): a BFS's first and last levels examine orders of
-        # magnitude fewer edges than its middle, so one uniform bucket
-        # made every level pay the widest level's padding.  The kernel
-        # reports exact per-level counts, so the ladder jumps straight
-        # to each level's bucket; the persistent bucket cache remembers
-        # the converged shape across runs.
-        res = self._escalate(
-            dev, dense,
-            key_fn=lambda ebs: (space, dev.epoch, "bfs",
-                                tuple(block_keys), max_steps, ebs,
-                                pred_key, tuple(pred_cols), have_rev,
-                                hub_n, self._delta_sig(dev)),
-            build_fn=build,
-            inputs_fn=lambda ebs: (blocks_data,),
-            stats=stats, n_hops=max_steps, kernel="bfs")
+            # Per-LEVEL edge budgets (like the traverse kernel's per-hop
+            # buckets): a BFS's first and last levels examine orders of
+            # magnitude fewer edges than its middle, so one uniform bucket
+            # made every level pay the widest level's padding.  The kernel
+            # reports exact per-level counts, so the ladder jumps straight
+            # to each level's bucket; the persistent bucket cache remembers
+            # the converged shape across runs.
+            res = self._escalate(
+                dev, dense,
+                key_fn=lambda ebs: (space, dev.epoch, "bfs",
+                                    tuple(block_keys), max_steps, ebs,
+                                    pred_key, tuple(pred_cols), have_rev,
+                                    hub_n, self._delta_sig(dev)),
+                build_fn=build,
+                inputs_fn=lambda ebs: (blocks_data,),
+                stats=stats, n_hops=max_steps, kernel="bfs")
         return res["dist"], stats
 
     # -- host materialization --------------------------------------------
@@ -2656,9 +2705,18 @@ class TpuRuntime:
                     cap["src"], cap["dst"], cap["rank"], bi, pids, P,
                     d2v_arr, d2v_id, de["rows"])
 
+            # one span a column and block, none a row: the pieces joined
+            # into a column (`mat_concat`), a column decoded (`mat_decode`)
             def catp(name, dtype=None):
-                return _cat_rows([cap[name][p, bi] for p in pids],
-                                 perms, dtype)
+                with _t.span("device:materialise.concat", col=name):
+                    return _cat_rows([cap[name][p, bi] for p in pids],
+                                     perms, dtype)
+
+            def vids(name, dense):
+                if dense is None or d2v_id:
+                    return dense
+                with _t.span("device:materialise.decode", col=name):
+                    return d2v_arr[dense]
 
             # arrays the caller's yields never read were not fetched
             # (fetch_keys) — and are not decoded here either
@@ -2675,25 +2733,26 @@ class TpuRuntime:
                     # its halves joined as the pieces are concatenated
                     raw = catp("prop:" + n, hb.props[n].dtype)
                 elif "eidx" in cap:
-                    if ee_parts is None:
-                        ee_parts = [_whole(cap["eidx"][p, bi])
-                                    for p in pids]
-                        if perms is not None:
-                            ee_parts = [a if pm is None else a[pm]
-                                        for a, pm in zip(ee_parts, perms)]
-                    raw = [_merged_gather(hb.props[n], de, n, p, e)
-                           for p, e in zip(pids, ee_parts)]
-                    raw = np.concatenate(raw) if len(raw) > 1 else raw[0]
+                    raw = None      # the host column at the captured eidx
                 else:
                     continue
-                props[n] = dec(hb.prop_types[n], raw, host.pool)
+                with _t.span("device:materialise.decode", col=n):
+                    if raw is None:
+                        if ee_parts is None:
+                            ee_parts = [_whole(cap["eidx"][p, bi])
+                                        for p in pids]
+                            if perms is not None:
+                                ee_parts = [
+                                    a if pm is None else a[pm]
+                                    for a, pm in zip(ee_parts, perms)]
+                        raw = [_merged_gather(hb.props[n], de, n, p, e)
+                               for p, e in zip(pids, ee_parts)]
+                        raw = np.concatenate(raw) if len(raw) > 1 else raw[0]
+                    props[n] = dec(hb.prop_types[n], raw, host.pool)
             eid = etype_ids[et]
+            sv, dv = vids("src", ss), vids("dst", dd)
             yield {"et": et, "dirn": dirn, "etype": eid if dirn == "out"
-                   else -eid, "n": n_rows,
-                   "sv": (ss if d2v_id else d2v_arr[ss])
-                   if ss is not None else None,
-                   "dv": (dd if d2v_id else d2v_arr[dd])
-                   if dd is not None else None,
+                   else -eid, "n": n_rows, "sv": sv, "dv": dv,
                    "rr": rr, "props": props,
                    "prop_types": hb.prop_types}
 

@@ -37,7 +37,13 @@ interpreter work, PERF.md section 6, PR 24).  When a statement's root
 (`query:*`) closes, the self times of
 its spans are folded ONCE into `stmt_phase_us{phase}` /
 `stmt_phase_n{phase}` (`fold_phases`): the closed per-statement time
-budget `/metrics` and the benchmark read.
+budget `/metrics` and the benchmark read.  A statement's root is
+opened where the statement ENTERS: by graphd (`query:<kind>`,
+`exec/engine.py` `statement_trace`) or, for a device statement that
+arrives with no trace active (an embedded `TpuRuntime`: `pin_prebuilt`,
+the tools, the benchmark's proxy cells), by the runtime's own entry
+(`query:tpu.<entry>`, `tpu/runtime.py` `_on_live_snapshot`); both obey
+`enable_query_tracing`, and a statement never has two.
 """
 from __future__ import annotations
 
@@ -167,7 +173,7 @@ class _SpanGuard:
         self._ctx = ctx
         if ctx is None:
             return
-        self._rec = {"tid": ctx.tid, "sid": _new_id("s"),
+        self._rec = {"tid": ctx.tid, "sid": f"s{_PROC}-{next(_span_seq)}",
                      "psid": ctx.sid, "name": name, "svc": ctx.service,
                      "t0": 0.0, "dur_us": 0}
         if attrs:
@@ -191,12 +197,13 @@ class _SpanGuard:
         if self._ann is not None:
             self._ann.__exit__(None, None, None)
         ctx.sid = self._prev_sid
-        self._rec["t0"] = ((_EPOCH_OFFSET_NS + self._t0) // 1000) / 1e6
-        self._rec["dur_us"] = (t1 - self._t0) // 1000
+        rec, t0 = self._rec, self._t0
+        rec["t0"] = ((_EPOCH_OFFSET_NS + t0) // 1000) / 1e6
+        rec["dur_us"] = (t1 - t0) // 1000
         if exc is not None:
-            self._rec.setdefault("attrs", {})["error"] = \
+            rec.setdefault("attrs", {})["error"] = \
                 f"{type(exc).__name__}: {exc}"
-        ctx.sink.append(self._rec)
+        ctx.sink.append(rec)
         return False
 
 
@@ -364,7 +371,8 @@ class TraceStore:
         self._lock = threading.Lock()
 
     def add(self, tid: str, name: str, spans: List[dict]):
-        root = next((s for s in spans if not s.get("psid")), None)
+        # a trace's root closes last: found from the end
+        root = next((s for s in reversed(spans) if not s.get("psid")), None)
         entry = {"tid": tid, "name": name,
                  "t0": root["t0"] if root else time.time(),
                  "dur_us": root["dur_us"] if root else 0,
@@ -429,7 +437,7 @@ def render_tree(entry: dict) -> str:
 
 PHASE_US, PHASE_N = "stmt_phase_us", "stmt_phase_n"
 
-#: the FIXED phase vocabulary (at most 16 labels): where a statement's
+#: the FIXED phase vocabulary (at most 20 labels): where a statement's
 #: time went, by the self time of its spans.  `other` is the root's own
 #: self time — what no child span explains.
 # zero-length marker a store leaves when a write request of the statement
@@ -438,15 +446,22 @@ WRITE_ACKED = "storage:write_acked"
 
 PHASES = ("parse", "plan", "admit", "exec", "snapshot_check", "delta_apply",
           "rpc_wait", "remote", "queue", "put", "dispatch", "fetch",
-          "materialise", "encode", "other")
+          "release", "materialise", "mat_concat", "mat_decode", "encode",
+          "record", "other")
 
 # span name -> phase, by the first prefix that matches; None = a
 # zero-length marker that is not a unit of work.  What no prefix names
-# (`exec:*`, `tpu:*`, and `store:*` / `raft:*` run in-process) is the
-# executors' own Python and row assembly.
+# reads `exec`: the executors' own Python and row assembly (`exec:*`,
+# and `store:*` / `raft:*` run in-process) and the device runtime's
+# host work around a launch (`tpu:prep`, `tpu:launch`, `tpu:seed_prep`,
+# `tpu:launch_account`, `tpu:fetch_warm`).
 _PHASE_BY_PREFIX = (
     ("graphd:parse", "parse"), ("graphd:plan", "plan"),
     ("graphd:admit", "admit"), ("graphd:encode", "encode"),
+    # the statement's bookkeeping after its executor (`exec/engine.py`
+    # `_execute_parsed`: result cache, slow log, insights, flight
+    # recorder)
+    ("graphd:record", "record"),
     ("tpu:snapshot_check", "snapshot_check"),
     # a fresh read folding acknowledged writes into the resident delta
     # plane (`TpuRuntime._try_delta_update`): its own bookkeeping, the
@@ -456,6 +471,13 @@ _PHASE_BY_PREFIX = (
     ("device:queue", "queue"), ("device:launch_wait", "queue"),
     ("device:put", "put"),
     ("device:dispatch", "dispatch"), ("device:fetch", "fetch"),
+    # giving a rung's device result up, apart from its fetch
+    ("device:release", "release"),
+    # inside `device:materialise`, whose own self time stays
+    # `materialise`: the fetched pieces joined into columns, and the
+    # dense-to-vid and property decodes
+    ("device:materialise.concat", "mat_concat"),
+    ("device:materialise.decode", "mat_decode"),
     ("device:materialise", "materialise"),
     ("rpc:retry", None), ("rpc:breaker", None),
     ("storage:dedup_hit", None), ("storage:follower_read", None),
@@ -493,36 +515,48 @@ def self_times(spans: List[dict]) -> Dict[str, float]:
             continue
         sids.add(s["sid"])
         p = s["psid"]
-        if p:
-            kids.setdefault(p, []).append(s)
-        else:
+        if not p:
             root = s
+        elif p in kids:
+            kids[p].append(s)
+        else:
+            kids[p] = [s]
     if root is None:
         return {}
     for p in [p for p in kids if p not in sids]:
         kids.setdefault(root["sid"], []).extend(kids.pop(p))
     out: Dict[str, float] = {}
     a = round(root["t0"] * 1e6)
+    # (span, start, end, weight) of the spans that have spans below
+    # them; a leaf's self time is its clipped length, set where its
+    # parent places it (most spans of a statement are leaves)
     stack = [(root, a, a + root["dur_us"], 1.0)]
     while stack:
         s, a, b, w = stack.pop()
         covered = 0
-        ch = kids.get(s["sid"])
-        if ch:
-            ivs = []
-            for c in ch:
-                ca = round(c["t0"] * 1e6)
-                cb = min(ca + c["dur_us"], b)
-                ca = min(max(ca, a), b)
-                if cb > ca:
-                    ivs.append((ca, cb, c))
-                else:
-                    out[c["sid"]] = 0.0     # a marker, or clipped away
+        ivs = []
+        for c in kids.get(s["sid"], ()):
+            ca = round(c["t0"] * 1e6)
+            cb = ca + c["dur_us"]
+            if cb > b:                      # clipped to its parent
+                cb = b
+            if ca < a:
+                ca = a
+            elif ca > b:
+                ca = b
+            if cb > ca:
+                ivs.append((ca, cb, c))
+            elif c["sid"] in kids:
+                # clipped away, or empty: it reads 0, and so does
+                # whatever is open below it
+                stack.append((c, ca, ca, w))
+            else:
+                out[c["sid"]] = 0.0         # a marker
+        if ivs:
+            f = w
             if len(ivs) == 1:
-                ca, cb, c = ivs[0]
-                covered = cb - ca
-                stack.append((c, ca, cb, w))
-            elif ivs:
+                covered = ivs[0][1] - ivs[0][0]
+            else:
                 ivs.sort(key=_by_start)
                 total, end = 0, a
                 for ca, cb, c in ivs:
@@ -530,9 +564,13 @@ def self_times(spans: List[dict]) -> Dict[str, float]:
                     if cb > end:
                         covered += cb - max(ca, end)
                         end = cb
-                f = w * covered / total
-                for ca, cb, c in ivs:
+                if covered != total:        # siblings overlap
+                    f = w * covered / total
+            for ca, cb, c in ivs:
+                if c["sid"] in kids:
                     stack.append((c, ca, cb, f))
+                else:
+                    out[c["sid"]] = (cb - ca) * f
         out[s["sid"]] = (b - a - covered) * w
     return out
 

@@ -313,10 +313,10 @@ def test_an_overflowed_rung_returns_meta_alone(pieces, monkeypatch):
 
     def fetch(self, res, key, fetch_keys, info):
         before = info["fetch_bytes_kept"]
-        host = real(self, res, key, fetch_keys, info)
+        host, held = real(self, res, key, fetch_keys, info)
         rungs.append((bool(host["ovf_expand"].any()), "cap" in host,
                       info["fetch_bytes_kept"] - before))
-        return host
+        return host, held
     monkeypatch.setattr(TpuRuntime, "_fetch", fetch)
     st = _hubby_store()
     rt = TpuRuntime(make_mesh(1))
