@@ -286,10 +286,34 @@ long long row_decode(const unsigned char* in, long long len,
 // joined into its 64-bit host column: out[i] = lo[i] | hi[i] << 32, one
 // pass at a copy's rate.  numpy has no one-pass interleave: two strided
 // stores a row cost twice the copy (runtime.py _join_halves).
-void join_halves(const uint32_t* lo, const uint32_t* hi, uint64_t* out,
-                 long long n) {
-    for (long long i = 0; i < n; ++i)
-        out[i] = (uint64_t)lo[i] | ((uint64_t)hi[i] << 32);
+//
+// The pass also answers the decode's one question about the column
+// (csr.py decode_prop_column_np): does any joined slot hold the NULL
+// sentinel?  For a float column (is_float) that is a NaN of any
+// pattern, exponent all ones and a mantissa bit set; for an integer
+// one INT_NULL, the sign bit alone.  Both are tested on the halves in
+// 32-bit arithmetic, which the compiler vectorises without 64-bit
+// compares, and OR-ed into one word: no branch in the loop.  Returns 1
+// if some slot does, else 0.
+int join_halves(const uint32_t* lo, const uint32_t* hi, uint64_t* out,
+                long long n, int is_float) {
+    uint32_t hit = 0;
+    if (is_float) {
+        for (long long i = 0; i < n; ++i) {
+            uint32_t l = lo[i], h = hi[i];
+            out[i] = (uint64_t)l | ((uint64_t)h << 32);
+            int32_t a = (int32_t)(h & 0x7FFFFFFFu);
+            hit |= (uint32_t)(a > 0x7FF00000) |
+                   ((uint32_t)(a == 0x7FF00000) & (uint32_t)(l != 0));
+        }
+    } else {
+        for (long long i = 0; i < n; ++i) {
+            uint32_t l = lo[i], h = hi[i];
+            out[i] = (uint64_t)l | ((uint64_t)h << 32);
+            hit |= (uint32_t)(h == 0x80000000u) & (uint32_t)(l == 0);
+        }
+    }
+    return hit != 0;
 }
 
 }  // extern "C"

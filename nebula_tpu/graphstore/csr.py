@@ -155,26 +155,45 @@ def decode_prop_column(pt: PropType, raw: "np.ndarray",
     return [NULL if r == INT_NULL else r for r in vals]
 
 
+# The integer kinds that decode to themselves, and with the floats the
+# kinds whose only decode is the NULL sentinel's: a null-free column of
+# one is its own decoded column.
+_SELF_INTS = (PropType.INT64, PropType.INT32, PropType.INT16, PropType.INT8,
+              PropType.TIMESTAMP)
+NUMERIC_KINDS = (PropType.FLOAT, PropType.DOUBLE) + _SELF_INTS
+
+
 def decode_prop_column_np(pt: PropType, raw: "np.ndarray",
-                          pool: StringPool) -> "np.ndarray":
+                          pool: StringPool,
+                          has_null: Optional[bool] = None) -> "np.ndarray":
     """decode_prop_column, columnar: returns a numpy array — native
     numeric dtype on the null-free fast paths, object dtype otherwise —
     creating NO per-element Python objects on the fast paths.  Feeds the
     ColumnarDataSet result handle (device results stay columnar until
-    the wire/print boundary)."""
+    the wire/print boundary).
+
+    `raw` is the caller's to give away: a null-free numeric column that
+    already has its host dtype is returned as it came, not copied.
+    `has_null` is the answer to the one question the numeric fast paths
+    ask (does any slot hold the kind's NULL sentinel?) where the pass
+    that built `raw` has it (tpu/runtime.py `_join_halves`); without it
+    the column is scanned here."""
     if pt in (PropType.FLOAT, PropType.DOUBLE):
-        a = raw.astype(np.float64)
-        if not np.isnan(a).any():
+        a = raw.astype(np.float64, copy=False)
+        if has_null is None:
+            has_null = bool(np.isnan(a).any())
+        if not has_null:
             return a
     elif pt in (PropType.STRING, PropType.FIXED_STRING):
         av = raw.astype(np.int64)
         ns = len(pool.strings)
         if av.size == 0 or ((av >= 0) & (av < ns)).all():
             return pool.obj_array()[av]
-    elif pt not in (PropType.BOOL, PropType.DATE, PropType.DATETIME,
-                    PropType.TIME, PropType.DURATION, PropType.GEOGRAPHY):
-        av = raw.astype(np.int64)
-        if not (av == INT_NULL).any():
+    elif pt in _SELF_INTS:
+        av = raw.astype(np.int64, copy=False)
+        if has_null is None:
+            has_null = bool((av == INT_NULL).any())
+        if not has_null:
             return av
     out = np.empty(len(raw), dtype=object)
     out[:] = decode_prop_column(pt, raw, pool)

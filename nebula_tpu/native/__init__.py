@@ -93,10 +93,10 @@ def get_lib() -> Optional[ctypes.CDLL]:
                 ctypes.POINTER(ctypes.c_double),
                 ctypes.POINTER(ctypes.c_longlong),
                 ctypes.POINTER(ctypes.c_int), ctypes.c_int]
-            lib.join_halves.restype = None
+            lib.join_halves.restype = ctypes.c_int
             lib.join_halves.argtypes = [
                 ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
-                ctypes.c_longlong]
+                ctypes.c_longlong, ctypes.c_int]
         except (OSError, AttributeError):
             # unloadable OR stale .so missing a symbol — fall back to
             # the Python paths rather than crashing callers

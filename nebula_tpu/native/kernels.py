@@ -82,21 +82,35 @@ def _numpy_coo_csr(src_dense, dst_dense, rank, dst_key, P, vmax, emax):
     return indptr, nbr, rk, perm, emax
 
 
-def join_halves(pair: np.ndarray, out: np.ndarray) -> None:
+# graphstore/csr.py `INT_NULL` (csr.py imports this module, not the reverse)
+_INT_NULL = np.iinfo(np.int64).min
+
+
+def join_halves(pair: np.ndarray, out: np.ndarray) -> bool:
     """A piece `(2, n)` `uint32` of a property column's 32-bit halves,
     low half first (tpu/device.py `split_halves`), joined into the `n`
     64-bit slots of `out`.  One pass in the library, at a copy's rate
     and without the GIL; numpy's two strided stores a row, at half that
-    rate, where the library is missing."""
+    rate, where the library is missing.
+
+    The same pass answers what the decode asks of the column
+    (graphstore/csr.py `decode_prop_column_np`): whether any joined slot
+    holds the NULL sentinel of `out`'s kind, a NaN of any bit pattern
+    for a float column, `INT_NULL` for an integer one."""
     n = pair.shape[-1]
+    if out.shape != (n,) or out.dtype.itemsize != 8:
+        raise ValueError(f"join_halves: {pair.shape} halves into "
+                         f"{out.dtype}{out.shape}")
+    is_float = out.dtype.kind == "f"
     lib = get_lib()
     if (lib is not None and pair.dtype == np.uint32
             and pair.strides[-1] == 4 and out.flags.c_contiguous):
-        lib.join_halves(pair[0].ctypes.data, pair[1].ctypes.data,
-                        out.ctypes.data, n)
-        return
+        return bool(lib.join_halves(pair[0].ctypes.data, pair[1].ctypes.data,
+                                    out.ctypes.data, n, int(is_float)))
     words = out.view(np.uint32).reshape(n, 2)
     words[:, 0], words[:, 1] = pair[0], pair[1]
+    return bool(np.isnan(out).any() if is_float
+                else (out.view(np.int64) == _INT_NULL).any())
 
 
 def dst_sort_key(dst_vids: Sequence) -> np.ndarray:

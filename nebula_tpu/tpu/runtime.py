@@ -16,8 +16,10 @@ from __future__ import annotations
 
 import functools
 import logging
+import os
 import threading
 import time
+from concurrent.futures import ThreadPoolExecutor, wait as futures_wait
 from contextlib import contextmanager, nullcontext
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -28,8 +30,8 @@ from jax.sharding import NamedSharding, PartitionSpec
 
 from ..core import expr as E
 from ..core.value import ColumnarDataSet, Edge
-from ..graphstore.csr import (build_snapshot, decode_prop_column,
-                              decode_prop_column_np)
+from ..graphstore.csr import (NUMERIC_KINDS, build_snapshot,
+                              decode_prop_column, decode_prop_column_np)
 from ..graphstore.delta import (DeltaOverflow, DeltaUnsupported, HostDelta,
                                 fold_base, pad_edge_width,
                                 pow2 as _delta_pow2)
@@ -168,32 +170,35 @@ def _cap_keys_for_yields(yields, device_props=()) -> Optional[set]:
     return need
 
 
-def _join_halves(parts, dtype) -> np.ndarray:
+def _join_halves(parts, dtype) -> Tuple[np.ndarray, bool]:
     """Fetched pieces of a property column's 32-bit halves, each
     `(2, n)` (device.py `split_halves`), as ONE owned 64-bit column of
-    `dtype`: the join rides the pass that concatenates the pieces
-    (native/kernels.py `join_halves`: one pass a piece)."""
-    n = sum(a.shape[-1] for a in parts)
-    out = np.empty(n, dtype)
+    `dtype`, and whether any slot of it holds the kind's NULL sentinel:
+    the join rides the pass that concatenates the pieces, and the
+    decode's one question rides the join (native/kernels.py
+    `join_halves`: one pass a piece)."""
+    out = np.empty(sum(a.shape[-1] for a in parts), dtype)
+    return out, any([native_join_halves(a, o)
+                     for a, o in _piece_slices(parts, out)])
+
+
+def _piece_slices(parts, out):
+    """Each piece beside the slice of `out` it fills, in slot order."""
     at = 0
     for a in parts:
         to = at + a.shape[-1]
-        native_join_halves(a, out[at:to])
+        yield a, out[at:to]
         at = to
-    return out
 
 
 def _cat_parts(parts, dtype=None):
     """Concatenate per-part kept-prefix slices of a capture array (the
     device compacts kept entries to the front of each part row) —
     contiguous slices instead of a 2D fancy gather, preserving
-    (part, slot) order; a property column's halves are joined on the
-    way into the 64-bit `dtype` of its host column (`_join_halves`).
+    (part, slot) order.
     Always returns an owned array: a view of the K-padded capture
     buffer must not escape into long-lived results (it would pin the
     whole bucket for a handful of rows)."""
-    if parts[0].ndim == 2:
-        return _join_halves(parts, dtype)
     if dtype is not None:
         if len(parts) > 1:
             return np.concatenate(parts, dtype=dtype)   # one pass
@@ -208,15 +213,70 @@ def _whole(pieces) -> np.ndarray:
     return pieces[0] if len(pieces) == 1 else np.concatenate(pieces, axis=-1)
 
 
-def _cat_rows(rows, perms=None, dtype=None):
+def _pieces(rows, perms=None):
     """The fetched rows of one capture column, each its pieces in slot
-    order (`_fetch`), as one owned array; `perms` re-orders each row
-    first (the delta plane's canonical CSR order)."""
+    order (`_fetch`), as one flat list of pieces; `perms` re-orders
+    each row first (the delta plane's canonical CSR order)."""
     if perms is None:
-        return _cat_parts([a for pieces in rows for a in pieces], dtype)
-    return _cat_parts([_whole(pieces) if pm is None
-                       else _whole(pieces)[..., pm]
-                       for pieces, pm in zip(rows, perms)], dtype)
+        return [a for pieces in rows for a in pieces]
+    return [_whole(pieces) if pm is None else _whole(pieces)[..., pm]
+            for pieces, pm in zip(rows, perms)]
+
+
+def _cat_rows(rows, perms=None, dtype=None):
+    """The fetched rows of an identity column (src, dst, rank, eidx) as
+    one owned array of `dtype`."""
+    return _cat_parts(_pieces(rows, perms), dtype)
+
+
+# Row assembly hands a LARGE statement's piece-passes to a few threads:
+# a pass writes a disjoint slice of its column, the native join holds no
+# GIL and neither does numpy's typed copy, so the pieces of a block's
+# columns (parts x columns of them) are independent tasks.  Under
+# POOL_MIN_ROWS kept rows the handoff costs more than it buys and the
+# serial passes run (PERF.md section 6, PR 40, has the sweep on the
+# chip's host).  One pool a process, made at the first statement that
+# needs it; none on a host with one core.
+POOL_MIN_ROWS = 1 << 20
+_POOL_WIDTH = min(4, os.cpu_count() or 1)
+_pool_lock = threading.Lock()
+_pool: Optional[ThreadPoolExecutor] = None
+
+
+def _assembly_pool() -> Optional[ThreadPoolExecutor]:
+    global _pool
+    if _pool is None and _POOL_WIDTH > 1:
+        with _pool_lock:
+            if _pool is None:
+                _pool = ThreadPoolExecutor(_POOL_WIDTH,
+                                           thread_name_prefix="tpu-mat")
+    return _pool
+
+
+def _fill(piece, out) -> bool:
+    """One piece-pass into its slice of a column: a property column's
+    halves joined (-> the NULL answer), an identity column's piece
+    copied into the column's dtype."""
+    if piece.ndim == 2:
+        return native_join_halves(piece, out)
+    out[:] = piece
+    return False
+
+
+def _cat_side_by_side(pool, columns):
+    """`columns` ([(pieces, dtype)], each a flat piece list as `_pieces`
+    gives it) assembled by `pool`, every piece a task: -> [(column,
+    NULL answer)] as `_cat_parts` and `_join_halves` would give them one
+    after another.  A pass that raises is the statement's error, once
+    every other pass has ended."""
+    outs = [np.empty(sum(a.shape[-1] for a in parts),
+                     parts[0].dtype if dtype is None else dtype)
+            for parts, dtype in columns]
+    tasks = [[pool.submit(_fill, a, o) for a, o in _piece_slices(parts, out)]
+             for (parts, _), out in zip(columns, outs)]
+    futures_wait([f for fs in tasks for f in fs])
+    return [(out, any([f.result() for f in fs]))
+            for out, fs in zip(outs, tasks)]
 
 
 def _merged_gather(col, de, name: str, p, e):
@@ -412,7 +472,7 @@ class _Heads:
     `rows` is the fetched capture: per column an object array over the
     rows, of each row's pieces in slot order, trimmed to its kept
     count (a property column's pieces are its halves, `(2, n)`, which
-    `_cat_rows` joins).  The programs run over the wanted columns
+    `_join_halves` joins).  The programs run over the wanted columns
     together (one launch, not one a column), so they are compiled for a
     program's key AND the columns its statement reads
     (`TpuRuntime._warm_fetch`)."""
@@ -2687,6 +2747,11 @@ class TpuRuntime:
                      for et, _ in block_keys}
         kcount = cap["kcount"]              # (P, nb); arrays (P, nb, K)
         P = kcount.shape[0]
+        # what the statement's assembly did, observed once at its end
+        # (`tpu_mat_*`): rows assembled and those whose pieces went side
+        # by side, numeric columns decoded and those whose NULL answer
+        # the assembling pass gave
+        rows = pooled_rows = numeric_cols = one_pass_cols = 0
         for bi, (et, dirn) in enumerate(block_keys):
             hb = host.blocks[(et, dirn)]
             de = _delta_rows_of(dview, (et, dirn))
@@ -2705,13 +2770,6 @@ class TpuRuntime:
                     cap["src"], cap["dst"], cap["rank"], bi, pids, P,
                     d2v_arr, d2v_id, de["rows"])
 
-            # one span a column and block, none a row: the pieces joined
-            # into a column (`mat_concat`), a column decoded (`mat_decode`)
-            def catp(name, dtype=None):
-                with _t.span("device:materialise.concat", col=name):
-                    return _cat_rows([cap[name][p, bi] for p in pids],
-                                     perms, dtype)
-
             def vids(name, dense):
                 if dense is None or d2v_id:
                     return dense
@@ -2719,21 +2777,31 @@ class TpuRuntime:
                     return d2v_arr[dense]
 
             # arrays the caller's yields never read were not fetched
-            # (fetch_keys) — and are not decoded here either
-            ss = catp("src", np.int64) if "src" in cap else None
-            dd = catp("dst", np.int64) if "dst" in cap else None
-            rr = catp("rank") if "rank" in cap else None
+            # (fetch_keys) — and are not assembled here either; a
+            # device-gathered yield column is fetched ready-made, its
+            # halves joined as the pieces are concatenated
+            names = [n for n in dict.fromkeys(
+                hb.props if prop_names is None else prop_names)
+                if n in hb.props]
+            want = [(k, dt) for k, dt in (("src", np.int64),
+                                          ("dst", np.int64), ("rank", None))
+                    if k in cap]
+            want += [("prop:" + n, hb.props[n].dtype) for n in names
+                     if ("prop:" + n) in cap]
+            got, pooled = self._assemble(cap, bi, pids, perms, want, n_rows)
+            rows += n_rows
+            pooled_rows += n_rows * pooled
+            ss, dd, rr = (got.get(k, (None,))[0]
+                          for k in ("src", "dst", "rank"))
             props = {}
-            dec = decode_prop_column_np if as_np else decode_prop_column
             ee_parts = None
-            for n in (hb.props if prop_names is None else
-                      [x for x in prop_names if x in hb.props]):
+            for n in names:
+                pt = hb.prop_types[n]
                 if ("prop:" + n) in cap:
-                    # device-gathered yield column: fetched ready-made,
-                    # its halves joined as the pieces are concatenated
-                    raw = catp("prop:" + n, hb.props[n].dtype)
+                    raw, has_null = got["prop:" + n]
                 elif "eidx" in cap:
-                    raw = None      # the host column at the captured eidx
+                    # the host column at the captured eidx
+                    raw, has_null = None, None
                 else:
                     continue
                 with _t.span("device:materialise.decode", col=n):
@@ -2748,13 +2816,52 @@ class TpuRuntime:
                         raw = [_merged_gather(hb.props[n], de, n, p, e)
                                for p, e in zip(pids, ee_parts)]
                         raw = np.concatenate(raw) if len(raw) > 1 else raw[0]
-                    props[n] = dec(hb.prop_types[n], raw, host.pool)
+                    if as_np:
+                        props[n] = decode_prop_column_np(
+                            pt, raw, host.pool, has_null)
+                        if pt in NUMERIC_KINDS:
+                            numeric_cols += 1
+                            one_pass_cols += has_null is not None
+                    else:
+                        props[n] = decode_prop_column(pt, raw, host.pool)
             eid = etype_ids[et]
             sv, dv = vids("src", ss), vids("dst", dd)
             yield {"et": et, "dirn": dirn, "etype": eid if dirn == "out"
                    else -eid, "n": n_rows, "sv": sv, "dv": dv,
                    "rr": rr, "props": props,
                    "prop_types": hb.prop_types}
+        m = _metrics()
+        m.add_value("tpu_mat_rows", rows)
+        m.add_value("tpu_mat_pooled_rows", pooled_rows)
+        m.add_value("tpu_mat_numeric_cols", numeric_cols)
+        m.add_value("tpu_mat_one_pass_cols", one_pass_cols)
+
+    @staticmethod
+    def _assemble(cap, bi, pids, perms, want, n_rows):
+        """The fetched pieces of block `bi`'s columns `want` ([(capture
+        key, host dtype)]) joined into owned columns: -> ({key: (column,
+        a property column's NULL answer)}, whether side by side).  One
+        after another, a span a column (`mat_concat`), as a rule; side
+        by side under ONE span where the statement is large
+        (`POOL_MIN_ROWS`) and the rows keep their order (a delta plane's
+        re-sort gathers a whole row first)."""
+        def pieces(key):
+            return _pieces([cap[key][p, bi] for p in pids], perms)
+
+        pool = (_assembly_pool()
+                if perms is None and n_rows >= POOL_MIN_ROWS else None)
+        if pool is not None:
+            with _t.span("device:materialise.concat", col="*",
+                         pooled=len(want)):
+                return dict(zip((k for k, _ in want), _cat_side_by_side(
+                    pool, [(pieces(k), dt) for k, dt in want]))), True
+        got = {}
+        for key, dt in want:
+            with _t.span("device:materialise.concat", col=key):
+                got[key] = (_join_halves(pieces(key), dt)
+                            if key.startswith("prop:")
+                            else (_cat_parts(pieces(key), dt), False))
+        return got, False
 
     def _materialize(self, store: GraphStore, space: str,
                      dev: DeviceSnapshot, block_keys, cap, dview=None

@@ -96,7 +96,8 @@ def _held(cap_dev, rows, kc):
         if n.startswith("prop:") and kc.any():
             live = np.arange(want.shape[-1]) < kc[..., None]
             np.testing.assert_array_equal(
-                runtime._cat_rows(list(col.flat), dtype=np.float64),
+                runtime._join_halves(
+                    runtime._pieces(list(col.flat)), np.float64)[0],
                 (np.arange(want.size // 2, dtype=np.float64) / 7).reshape(
                     live.shape)[live])
 

@@ -193,3 +193,65 @@ def test_join_halves_is_the_numpy_join_to_the_bit(dtype, monkeypatch):
             got = np.full(pair.shape[-1], -1, dtype)
             kernels.join_halves(pair, got)
         assert (got.view(np.int64) == want).all()
+
+
+_QNAN, _SNAN, _NEG_NAN = 0x7FF8000000000000, 0x7FF0000000000001, 0xFFF8000000000000
+_LOW_HALF_NAN = 0x7FF00000FFFFFFFF       # the mantissa's set bits all in the low half
+_HIGH_HALF_NAN = 0x7FF0000100000000      # ... all in the high half
+_INF, _NEG_INF = 0x7FF0000000000000, 0xFFF0000000000000
+_INT_NULL = 0x8000000000000000
+# word -> is it the kind's NULL sentinel
+SENTINELS = {
+    np.float64: [(_QNAN, True), (_SNAN, True), (_NEG_NAN, True),
+                 (_LOW_HALF_NAN, True), (_HIGH_HALF_NAN, True),
+                 (0xFFFFFFFFFFFFFFFF, True), (_INF, False), (_NEG_INF, False),
+                 (0x7FEFFFFFFFFFFFFF, False),        # the largest finite double
+                 (_INT_NULL, False)],                # -0.0
+    np.int64: [(_INT_NULL, True), (_INT_NULL + 1, False),
+               (0x8000000100000000, False),          # the high half alone is not it
+               (0x0000000080000000, False),          # nor that word as a LOW half
+               (0, False), (0xFFFFFFFFFFFFFFFF, False), (_QNAN, False)],
+}
+
+
+@pytest.mark.parametrize("lib", ["native", "numpy"])
+@pytest.mark.parametrize("n", [1, 2, 3, 4, 5, 8, 1001])
+@pytest.mark.parametrize("at", ["none", "first", "last", "all"])
+@pytest.mark.parametrize("dtype", [np.int64, np.float64])
+def test_join_halves_answers_the_null_question(dtype, at, n, lib, monkeypatch):
+    """The joining pass's answer is the decode's scan of the joined
+    column, `np.isnan(col).any()` / `(col == INT_NULL).any()`: with no
+    NULL, one at the first slot, at the last (in the vector loop's tail
+    and out of it), in every slot; for every NaN bit pattern and for no
+    infinity; by the library and by the numpy fallback.  The joined
+    words are the column's, whatever they hold."""
+    from nebula_tpu.graphstore.csr import INT_NULL
+    from nebula_tpu.native import kernels
+    from nebula_tpu.tpu.device import split_halves
+    if lib == "numpy":
+        monkeypatch.setattr(kernels, "get_lib", lambda: None)
+    elif not available():
+        pytest.skip("no native library")
+    rng = np.random.default_rng(n)
+    for word, is_null in SENTINELS[dtype]:
+        # finite doubles / small ints: no sentinel among them
+        col = rng.integers(-2**40, 2**40, n).astype(dtype)
+        where = {"none": [], "first": [0], "last": [n - 1],
+                 "all": range(n)}[at]
+        col.view(np.uint64)[list(where)] = word
+        with np.errstate(invalid="ignore"):
+            scan = bool(np.isnan(col).any() if dtype == np.float64
+                        else (col == INT_NULL).any())
+        assert scan == (is_null and at != "none")
+        got = np.full(n, -1, dtype)
+        assert kernels.join_halves(split_halves(col), got) is scan, hex(word)
+        assert (got.view(np.uint64) == col.view(np.uint64)).all()
+
+
+def test_join_halves_refuses_a_column_of_another_shape():
+    from nebula_tpu.native import kernels
+    pair = np.zeros((2, 5), np.uint32)
+    for out in (np.zeros(4, np.int64), np.zeros(5, np.int32),
+                np.zeros((5, 1), np.int64)):
+        with pytest.raises(ValueError):
+            kernels.join_halves(pair, out)

@@ -15,6 +15,7 @@ infinity).
 """
 import threading
 import traceback
+from concurrent.futures import ThreadPoolExecutor
 
 import jax
 import numpy as np
@@ -22,7 +23,9 @@ import pytest
 
 from nebula_tpu.core.value import NULL
 from nebula_tpu.exec.engine import QueryEngine
-from nebula_tpu.graphstore.csr import INT_NULL
+from nebula_tpu.core import expr as E
+from nebula_tpu.graphstore.csr import (INT_NULL, decode_prop_column,
+                                       decode_prop_column_np)
 from nebula_tpu.graphstore.schema import PropDef, PropType
 from nebula_tpu.graphstore.store import GraphStore
 from nebula_tpu.utils.config import get_config
@@ -44,7 +47,7 @@ FS = [NULL, 1e300, -1e300, 5e-324, -0.0, 0.1, 1.0 + 2.0 ** -52,
       3.4028236e38, float("inf"), 0.75, -2.5e-310, 1 / 3]
 
 
-def halves_store(parts):
+def halves_store(parts, ws=WS, fs=FS):
     """Vertex v knows v+1 .. v+5 (mod N); edge number i carries WS[i %
     12] and FS[(i // 5) % 12], so that every pairing of neighbours comes
     up and every CSR row mixes NULLs with values."""
@@ -59,8 +62,8 @@ def halves_store(parts):
     for v in range(N):
         for k in range(1, 6):
             st.insert_edge("h", v, "knows", (v + k) % N, 0,
-                           {"w": WS[i % len(WS)],
-                            "f": FS[(i // 5) % len(FS)]})
+                           {"w": ws[i % len(ws)],
+                            "f": fs[(i // 5) % len(fs)]})
             i += 1
     return st
 
@@ -293,11 +296,14 @@ def test_halves_join_to_the_bit(dtype):
         np.testing.assert_array_equal(back, col)
     # the fetched form: each row its pieces (2, n) in slot order
     rows = [[h[0][:, :5], h[0][:, 5:]], [h[1]]]
-    got = runtime._cat_rows(rows, dtype=dtype)
+    got, has_null = runtime._join_halves(runtime._pieces(rows), dtype)
+    assert has_null is True
     np.testing.assert_array_equal(got.view(np.int64),
                                   col.reshape(-1).view(np.int64))
     pm = np.random.default_rng(3).permutation(col.shape[1])
-    got = runtime._cat_rows(rows, perms=[pm, None], dtype=dtype)
+    got, has_null = runtime._join_halves(
+        runtime._pieces(rows, [pm, None]), dtype)
+    assert has_null is True
     np.testing.assert_array_equal(
         got.view(np.int64),
         np.concatenate([col[0][pm], col[1]]).view(np.int64))
@@ -345,3 +351,275 @@ def test_a_predicates_double_is_the_pair_a_transfer_makes():
     under = a < 2.0 ** -126 * (1 - 2.0 ** -25)
     assert under.sum() > 100 and (v[under] == 0).all()
     assert np.isnan(v[np.isnan(f)]).all()
+
+
+# -- a fetched column becomes its decoded host column in one pass (PR 40) --
+
+def _column(dtype, n, nulls):
+    """A column of `n` values that hold no sentinel, then the kind's
+    NULL sentinel at `nulls`."""
+    col = np.random.default_rng(n).integers(-2 ** 40, 2 ** 40, n).astype(dtype)
+    col[list(nulls)] = INT_NULL if dtype == np.int64 else np.nan
+    return col
+
+
+def _scan(col):
+    """The decode's question, as the parent asked it of a whole column."""
+    return bool(np.isnan(col).any() if col.dtype == np.float64
+                else (col == INT_NULL).any())
+
+
+def _decode_as_the_parent_did(pt, raw, pool):
+    """graphstore/csr.py `decode_prop_column_np` of the numeric kinds at
+    b26b7fb: a copy, a scan through a temporary, the object array."""
+    a = raw.astype(np.float64 if pt == PropType.DOUBLE else np.int64)
+    if not _scan(a):
+        return a
+    out = np.empty(len(raw), dtype=object)
+    out[:] = decode_prop_column(pt, raw, pool)
+    return out
+
+
+CUTS = (5, 22)                  # three uneven pieces of a column of 23
+WHERE = {"none": [], "first": [0], "last": [22], "before-a-boundary": [4],
+         "after-a-boundary": [5], "both-sides": [21, 22], "all": range(23)}
+
+
+@pytest.mark.parametrize("lib", ["native", "numpy"])
+@pytest.mark.parametrize("where", sorted(WHERE))
+@pytest.mark.parametrize("dtype", [np.int64, np.float64])
+def test_the_join_answers_the_decode_and_the_decode_copies_nothing(
+        dtype, where, lib, monkeypatch):
+    """`_join_halves` over a column's pieces: the column bit for bit
+    and the answer the decode would have scanned for, wherever the NULL
+    sits among the pieces.  On that answer the decode of a null-free
+    column IS the joined array; one with a NULL is the parent's object
+    array element for element; and without an answer the decode scans,
+    and still hands back a null-free array of its own dtype uncopied."""
+    from nebula_tpu.native import kernels
+    if lib == "numpy":
+        monkeypatch.setattr(kernels, "get_lib", lambda: None)
+    pt = PropType.INT64 if dtype == np.int64 else PropType.DOUBLE
+    col = _column(dtype, 23, WHERE[where])
+    parts = np.split(split_halves(col), CUTS, axis=-1)
+    got, has_null = runtime._join_halves(parts, dtype)
+    assert got.dtype == dtype and got.flags.owndata
+    np.testing.assert_array_equal(got.view(np.int64), col.view(np.int64))
+    assert has_null is _scan(col) is (where != "none")
+    want = _decode_as_the_parent_did(pt, col, None)
+    for answer in (has_null, None):
+        dec = decode_prop_column_np(pt, got, None, answer)
+        assert dec.dtype == want.dtype
+        if where == "none":
+            assert dec is got and np.shares_memory(dec, got)
+            np.testing.assert_array_equal(dec.view(np.int64),
+                                          want.view(np.int64))
+        else:
+            assert dec.dtype == object and not np.shares_memory(dec, got)
+            assert [repr(x) for x in dec] == [repr(x) for x in want]
+            assert [x is NULL for x in dec] == [i in WHERE[where]
+                                                for i in range(23)]
+    # a column of another dtype than its host kind's is still converted
+    if dtype == np.int64 and where == "none":
+        narrow = col.astype(np.int32)
+        dec = decode_prop_column_np(pt, narrow, None)
+        assert dec.dtype == np.int64 and not np.shares_memory(dec, narrow)
+
+
+@pytest.fixture
+def pool():
+    own = None
+    p = runtime._assembly_pool()
+    if p is None:                       # a host with one core makes none
+        p = own = ThreadPoolExecutor(2)
+    yield p
+    if own is not None:
+        own.shutdown()
+
+
+def _serial(columns):
+    return [runtime._join_halves(parts, dt) if parts[0].ndim == 2
+            else (runtime._cat_parts(parts, dt), False)
+            for parts, dt in columns]
+
+
+LAYOUTS = {
+    "one-piece": [[7]], "uneven": [[1, 30, 2, 11]],
+    "an-empty-part": [[4, 0, 9], [0, 5]],
+    "three-columns-of-eight": [[3, 1, 4, 1, 5, 9, 2, 6]] * 3,
+    "24-tasks": [[2] * 8, [5] * 8, [1, 2, 3, 4, 5, 6, 7, 8]],
+    **{f"{k}-tasks": [[3] * k] for k in (2, 5, 13)},
+}
+
+
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+def test_side_by_side_is_the_serial_assembly(layout, pool):
+    """1 to 24 piece-passes handed to the pool fill the columns the
+    serial passes fill, to the bit, with the same NULL answers: an
+    identity column widened, an int64 and a float64 property column
+    joined (a NULL in the last piece of each)."""
+    kinds = [(np.int32, np.int64), (np.int64, np.int64),
+             (np.float64, np.float64)]
+    columns = []
+    for i, sizes in enumerate(LAYOUTS[layout]):
+        src, dt = kinds[i % 3]
+        n = sum(sizes)
+        if src == np.int32:
+            col = np.arange(n, dtype=np.int32) - 3
+            whole = col
+        else:
+            col = _column(src, n, [n - 1])
+            whole = split_halves(col)
+        columns.append((np.split(whole, np.cumsum(sizes)[:-1], axis=-1), dt))
+    got, want = runtime._cat_side_by_side(pool, columns), _serial(columns)
+    assert len(got) == len(want)
+    for (g, gn), (w, wn), (parts, dt) in zip(got, want, columns):
+        assert g.dtype == w.dtype == dt and g.flags.owndata
+        np.testing.assert_array_equal(g.view(np.int64), w.view(np.int64))
+        assert gn is wn is (parts[0].ndim == 2)
+
+
+def test_a_failed_piece_pass_is_the_statements_error(pool, monkeypatch):
+    """One pass of many raises: the assembly raises it once the others
+    have ended, and the pool assembles the next statement's columns."""
+    col = _column(np.int64, 40, [])
+    columns = [(np.split(split_halves(col), [9, 20, 33], axis=-1), np.int64)]
+    real, seen = runtime.native_join_halves, []
+
+    def faulty(pair, out):
+        seen.append(pair.shape[-1])
+        if pair.shape[-1] == 11:
+            raise MemoryError("a piece-pass failed")
+        return real(pair, out)
+    monkeypatch.setattr(runtime, "native_join_halves", faulty)
+    with pytest.raises(MemoryError, match="a piece-pass failed"):
+        runtime._cat_side_by_side(pool, columns)
+    assert sorted(seen) == [7, 9, 11, 13]     # every pass ran to its end
+    monkeypatch.setattr(runtime, "native_join_halves", real)
+    (got, has_null), = runtime._cat_side_by_side(pool, columns)
+    np.testing.assert_array_equal(got, col)
+    assert has_null is False
+
+
+def test_many_statements_share_the_pool(pool):
+    """More statements than cores hand their pieces to the one pool at
+    once: each gets its own columns back, to the bit, and none waits for
+    ever (a pass never submits a pass)."""
+    import sys
+    n_threads, rounds = 16, 40
+    cols = [_column(np.float64 if t % 2 else np.int64, 200 + 37 * t, [t])
+            for t in range(n_threads)]
+    wrong, done = [], []
+
+    def statement(t):
+        col = cols[t]
+        columns = [(np.split(split_halves(col), [3, 50, 51, 120], axis=-1),
+                    col.dtype)] * 2
+        for _ in range(rounds):
+            for got, has_null in runtime._cat_side_by_side(pool, columns):
+                if has_null is not True or not (
+                        got.view(np.int64) == col.view(np.int64)).all():
+                    wrong.append(t)
+        done.append(t)
+    was = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=statement, args=(t,), daemon=True)
+                   for t in range(n_threads)]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(60)
+    finally:
+        sys.setswitchinterval(was)
+    assert not any(th.is_alive() for th in threads)
+    assert sorted(done) == list(range(n_threads)) and not wrong
+
+
+NO_NULL_WS = [w for w in WS if w is not NULL]
+NO_NULL_FS = [f for f in FS if f is not NULL]
+YIELDS = [(E.FunctionCall("dst", [E.EdgeExpr()]), "d"),
+          (E.EdgeProp("knows", "w"), "w"), (E.EdgeProp("knows", "f"), "f")]
+# vertex 12's five edges carry a NULL `f`; it is reached from 8
+GO = "GO 2 STEPS FROM 0, 1, 2, 3, 8, 17 OVER knows YIELD dst(edge), knows.w, knows.f"
+
+
+def _moved(s0, s1, key):
+    return s1.get(key, 0) - s0.get(key, 0)
+
+
+@pytest.mark.parametrize("side_by_side", [False, True],
+                         ids=["serial", "side-by-side"])
+@pytest.mark.parametrize("parts", [1, 2], ids=["one-chip", "two-shards"])
+def test_traverse_with_yields_over_stored_nulls(parts, side_by_side,
+                                                monkeypatch):
+    """`TpuRuntime.traverse(yields=[dst, w, f])`: over final-hop edges
+    that carry a NULL `w` and a NULL `f` the rows are the host
+    engine's, `NULL` where stored; the sibling statement over a store
+    without NULLs returns columns of their native dtype, and both of its
+    property columns took their NULL answer from the assembling pass.
+    Under the threshold the pool is never asked for; with the threshold
+    at one row every row's pieces go through it, under ONE concat span a
+    block where the serial passes open one a column."""
+    if side_by_side:
+        monkeypatch.setattr(runtime, "POOL_MIN_ROWS", 1)
+        if runtime._assembly_pool() is None:
+            pytest.skip("one core: no pool is made")
+    else:
+        def no_pool():
+            raise AssertionError("a small statement asked for the pool")
+        monkeypatch.setattr(runtime, "_assembly_pool", no_pool)
+    for ws, fs, dtypes in ((WS, FS, (np.int64, object, object)),
+                           (NO_NULL_WS, NO_NULL_FS,
+                            (np.int64, np.int64, np.float64))):
+        st = halves_store(parts, ws, fs)
+        rt = TpuRuntime(make_mesh(parts))
+        s0 = stats().snapshot()
+        ds, _ = rt.traverse(st, "h", [0, 1, 2, 3, 8, 17], ["knows"], "out",
+                            2, yields=YIELDS)
+        s1 = stats().snapshot()
+        cols = [ds.column_array(n) for n in "dwf"]
+        assert tuple(c.dtype for c in cols) == dtypes
+        assert all(c.flags.owndata for c in cols)
+        n = len(cols[0])
+        assert sorted(map(repr, ds.rows)) == _rows(QueryEngine(st), GO) and n
+        assert _moved(s0, s1, "tpu_mat_rows.sum") == n
+        assert _moved(s0, s1, "tpu_mat_pooled_rows.sum") == n * side_by_side
+        assert _moved(s0, s1, "tpu_mat_numeric_cols.sum") == 2
+        assert _moved(s0, s1, "tpu_mat_one_pass_cols.sum") == 2
+        # one block: a concat span a column, or one for the pooled block
+        assert _moved(s0, s1, "stmt_phase_n{phase=mat_concat}") == \
+            (1 if side_by_side else 3)
+        assert _moved(s0, s1, "stmt_phase_n{phase=mat_decode}") == 2
+
+
+def test_a_host_gathered_column_is_scanned_by_the_decode():
+    """A property the program does not gather (a fifth yielded column
+    falls back to the captured `eidx`) has no assembling pass to answer
+    for it: its decode scans, the rows are still the host engine's, and
+    it counts against `tpu_mat_one_pass_cols`."""
+    st = GraphStore()
+    st.create_space("h", partition_num=1, vid_type="INT64")
+    st.catalog.create_tag("h", "person", [PropDef("age", PropType.INT64)])
+    names = ["a", "b", "c", "d", "e"]
+    st.catalog.create_edge("h", "knows",
+                           [PropDef(n, PropType.INT64) for n in names])
+    for v in range(12):
+        st.insert_vertex("h", v, "person", {"age": v})
+    for v in range(12):
+        for k in (1, 2):
+            st.insert_edge("h", v, "knows", (v + k) % 12, 0,
+                           {n: (NULL if n == "e" and v == 3 else v * 10 + i)
+                            for i, n in enumerate(names)})
+    rt = TpuRuntime(make_mesh(1))
+    s0 = stats().snapshot()
+    ds, _ = rt.traverse(st, "h", [0, 1, 2], ["knows"], "out", 2,
+                        yields=[(E.EdgeProp("knows", n), n) for n in names])
+    s1 = stats().snapshot()
+    q = "GO 2 STEPS FROM 0, 1, 2 OVER knows YIELD " + \
+        ", ".join("knows." + n for n in names)
+    assert ds.column_array("e").dtype == object     # v == 3 is reached
+    assert ds.column_array("a").dtype == np.int64
+    assert sorted(map(repr, ds.rows)) == _rows(QueryEngine(st), q)
+    assert _moved(s0, s1, "tpu_mat_numeric_cols.sum") == 5
+    assert _moved(s0, s1, "tpu_mat_one_pass_cols.sum") == 4
