@@ -1,32 +1,18 @@
-"""Device BFS — the FIND SHORTEST PATH kernel (bitmap design).
+"""Device BFS: the program behind FIND SHORTEST PATH.
 
-Level-synchronous BFS over the sharded CSR: each chip expands its shard
-of the frontier, marks candidate destinations in a per-owner bitmap,
-and exchanges the bitmaps with ONE bool `lax.all_to_all` over ICI; the
-receiving chip's first-visit filter is two elementwise ops against its
-dist array (the visited bitmap of SURVEY §5, sharded by vid ownership).
-The kernel returns the dist array; the host reconstructs ALL shortest
-paths by walking predecessors (dist[u] == dist[v]-1) backwards —
-identical path sets to the host oracle's multi-parent BFS
-(exec/algorithms.py), which is the parity contract.
+Level-synchronous over the pinned CSR with a bitmap frontier: a level
+expands the frontier's out-edges, marks the far ends in a per-owner
+bitmap and keeps those `dist` has not seen (`new = cand & (dist < 0)`),
+so there is no sort and no frontier overflow; the edge budget of a level
+is the only size that escalates.  The level bodies live in
+algo/frontier.py (shared with the vertex-program plane); this module
+composes them with the `dist` update and, on one chip with the reverse
+blocks at hand, the per-level switch to the bottom-up body.
 
-Round-4 redesign (VERDICT r3 item 3): the previous BFS shared the
-sorted-frontier machinery (sort-unique, argsort routing, merge sort,
-plus a scatter-based visit pass) — all gone; the frontier bitmap IS the
-visited-set currency, so BFS is now expand → mark → exchange →
-`new = cand & (dist < 0)` with no sorts and no frontier/route overflow.
-
-ISSUE 13 refactor: the per-level expansion bodies (top-down expand +
-mark, bottom-up reverse scan, the sharded expand + mark) moved to
-nebula_tpu/algo/frontier.py — ONE frontier-iteration code path shared
-with the graph-analytics vertex-program plane.  This module now only
-composes those steps with the BFS-specific state update (dist/level
-bookkeeping and the direction-optimizing switch).
-
-Reference analog: BFSShortestPathExecutor's per-hop storage fan-out +
-host hash-set frontiers (src/graph/executor/algo [UNVERIFIED — empty
-mount, SURVEY §0]), replaced by on-device expansion.
-"""
+Both builders return `dist` (the depth of every vertex, -1 unreached),
+`hop_edges` (slots each level really expanded, a part), `ovf_expand` and
+`bottom_up` (the direction each level took).  The host walks
+predecessors back from the target (tpu/paths.py)."""
 from __future__ import annotations
 
 import jax
@@ -51,11 +37,11 @@ def bfs_exchange_bytes(P: int, vmax: int, max_steps: int,
     return max_steps * a2a_payload_bytes(P, vmax, lanes)
 
 
-def build_bfs_fn(mesh, P: int, EB, max_steps: int,
-                 n_blocks: int, vmax: int, pred=None, pred_cols=(),
-                 hub_dense=None):
+def build_bfs_fn(mesh, P: int, EB, max_steps: int, vmax: int,
+                 pred=None, pred_cols=(), hub_dense=None):
     """Sharded BFS program: (blocks_data, frontier) →
-    {dist (P, vmax), ovf_expand, hop_edges (P, steps)}.
+    {dist (P, vmax), ovf_expand, hop_edges (P, steps),
+    bottom_up (steps,) bool, all false: every level is top-down here}.
 
     frontier: (P, vmax) bool seed bitmap.  pred/pred_cols: optional
     compiled edge predicate (exprjit) — a filtered FIND SHORTEST PATH
@@ -101,13 +87,14 @@ def build_bfs_fn(mesh, P: int, EB, max_steps: int,
     spec = PartitionSpec("part")
     smapped = _shard_map(kernel, mesh=mesh,
                          in_specs=(spec, spec), out_specs=spec)
-    return jax.jit(smapped)
+    return jax.jit(lambda blocks_data, frontier: dict(
+        smapped(blocks_data, frontier),
+        bottom_up=jnp.zeros((max_steps,), bool)))
 
 
-def build_bfs_fn_local(P: int, EB, max_steps: int,
-                       n_blocks: int, vmax: int, pred=None, pred_cols=(),
-                       have_rev: bool = False, n_phantom: int = 0,
-                       hub_dense=None):
+def build_bfs_fn_local(P: int, EB, max_steps: int, vmax: int,
+                       pred=None, pred_cols=(), have_rev: bool = False,
+                       n_phantom: int = 0, hub_dense=None):
     """Single-chip variant (vmap over parts, OR-reduce as all_to_all).
 
     With `have_rev` (blocks_data carries each block's REVERSE-direction
@@ -121,7 +108,8 @@ def build_bfs_fn_local(P: int, EB, max_steps: int,
     the level body via lax.cond; the classic switch heuristic
     (frontier edges vs unvisited edges, Beamer-style) degrades to a
     frontier-population threshold since degrees are already summed by
-    the expansion itself."""
+    the expansion itself.  `bottom_up` (steps,) says which way each
+    level went (all false without `have_rev`)."""
     pids = jnp.arange(P, dtype=jnp.int32)
     ebs = _norm_ebs(EB, max_steps, False)
     hubs_c, hub_owner, hub_local = _hub_consts(hub_dense, P)
@@ -136,7 +124,7 @@ def build_bfs_fn_local(P: int, EB, max_steps: int,
         armed = any("d_src" in b for b in blocks_data)
         dist = jnp.where(fbm, 0, -1).astype(jnp.int32)   # (P, vmax)
         ovf_e = jnp.zeros((P,), bool)
-        hop_edges = []
+        hop_edges, went_bu = [], []
 
         def top_down(blocks, f, EBl):
             return top_down_step(blocks, ext(f), EBl, P, vmax, pids,
@@ -170,7 +158,9 @@ def build_bfs_fn_local(P: int, EB, max_steps: int,
                     lambda args: top_down(blocks_data, args[0], EBl),
                     (fbm, unvis))
             else:
+                use_bu = jnp.zeros((), bool)
                 cand, edges, ovf = top_down(blocks_data, fbm, EBl)
+            went_bu.append(use_bu)
             ovf_e = ovf_e | ovf
             hop_edges.append(edges)
             new = cand & (dist < 0)
@@ -178,6 +168,6 @@ def build_bfs_fn_local(P: int, EB, max_steps: int,
             fbm = new
 
         return {"dist": dist, "hop_edges": jnp.stack(hop_edges, axis=1),
-                "ovf_expand": ovf_e}
+                "ovf_expand": ovf_e, "bottom_up": jnp.stack(went_bu)}
 
     return jax.jit(fn)

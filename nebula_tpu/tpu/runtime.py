@@ -384,11 +384,12 @@ _ENGAGEMENT = ("chunks_run", "chunks_budget", "plan_run", "plan_budget")
 
 # The result leaves some caller reads, the only ones `_fetch` brings to
 # the host besides the capture: the ladder's counts and flags, the kept
-# counts, the work counters, BFS's depths.  The post-final `frontier`
-# bitmap and its `fcount` stay on the device and die with the rung
-# (vmax bools a part: more bytes than a mean four-chip statement's rows).
+# counts, the work counters, BFS's depths and the direction each of its
+# levels took.  The post-final `frontier` bitmap and its `fcount` stay on
+# the device and die with the rung (vmax bools a part: more bytes than a
+# mean four-chip statement's rows).
 _FETCHED = ("hop_edges", "ovf_expand", "kcount", "frontier_sizes",
-            "dist") + _ENGAGEMENT
+            "dist", "bottom_up") + _ENGAGEMENT
 
 # How a capture leaves the device (`_fetch`).  A ROW is one index of a
 # capture array's lead + (nb,) axes with its own kept count; kept
@@ -620,7 +621,7 @@ class TraverseStats:
                  "compiles", "hbm_bytes", "segments", "queue_s",
                  "shards", "exchange_bytes", "chunks_run",
                  "chunks_budget", "plan_run", "plan_budget",
-                 "fetch_bytes", "fetch_bytes_kept")
+                 "fetch_bytes", "fetch_bytes_kept", "bottom_up")
 
     def __init__(self):
         self.hop_edges: List[int] = []
@@ -669,6 +670,9 @@ class TraverseStats:
         # them that are kept capture entries (`_fetch`)
         self.fetch_bytes = 0
         self.fetch_bytes_kept = 0
+        # a BFS's levels that went bottom-up (bfs.py's switch), one flag
+        # a level; empty for every other program
+        self.bottom_up: List[bool] = []
 
     def edges_traversed(self) -> int:
         return int(sum(self.hop_edges))
@@ -2036,7 +2040,19 @@ class TpuRuntime:
                 self._save_buckets()
             m = _metrics()
             m.inc("tpu_kernel_runs")
-            m.inc("tpu_edges_traversed", int(np.asarray(res["hop_edges"]).sum()))
+            edges = int(np.asarray(res["hop_edges"]).sum())
+            m.inc("tpu_edges_traversed", edges)
+            if kernel == "bfs":
+                # what the BFS program did: the levels it ran and which of
+                # them bottom-up, the slots they really expanded (in-edges
+                # of the unvisited for a bottom-up level) and the slots of
+                # the converged budgets, which the level bodies ran whole
+                m.inc("tpu_bfs_runs")
+                m.inc("tpu_bfs_levels", n_hops)
+                m.inc("tpu_bfs_levels_bottom_up",
+                      int(np.asarray(res["bottom_up"]).sum()))
+                m.inc("tpu_bfs_edges", edges)
+                m.inc("tpu_bfs_budget_slots", dev.num_parts * sum(EBs))
             if "chunks_run" in res:
                 for k in _ENGAGEMENT:
                     m.inc(f"tpu_hop_{k}", int(res[k].sum()))
@@ -2630,7 +2646,7 @@ class TpuRuntime:
         if not dense:
             return np.full((dev.num_parts, dev.vmax), -1, np.int32), stats
 
-        with _t.span("tpu:launch", kernel="bfs"):
+        with _t.span("tpu:launch", kernel="bfs") as launch:
             P = dev.num_parts
             # direction-optimizing leg (single chip): each block's REVERSE
             # twin rides along so dense levels can go bottom-up (a vertex
@@ -2663,14 +2679,12 @@ class TpuRuntime:
 
             def build(ebs):
                 if self.local_mode:
-                    return build_bfs_fn_local(P, ebs, max_steps,
-                                              len(block_keys), dev.vmax,
+                    return build_bfs_fn_local(P, ebs, max_steps, dev.vmax,
                                               pred=pred, pred_cols=pred_cols,
                                               have_rev=have_rev,
                                               n_phantom=n_phantom,
                                               hub_dense=hub_dense)
-                return build_bfs_fn(self.mesh, P, ebs, max_steps,
-                                    len(block_keys), dev.vmax,
+                return build_bfs_fn(self.mesh, P, ebs, max_steps, dev.vmax,
                                     pred=pred, pred_cols=pred_cols,
                                     hub_dense=hub_dense)
 
@@ -2690,6 +2704,10 @@ class TpuRuntime:
                 build_fn=build,
                 inputs_fn=lambda ebs: (blocks_data,),
                 stats=stats, n_hops=max_steps, kernel="bfs")
+            stats.bottom_up = [bool(b) for b in res["bottom_up"]]
+            if launch is not None:
+                launch["attrs"].update(levels=max_steps, eb=list(stats.e_cap),
+                                       bottom_up=sum(stats.bottom_up))
         return res["dist"], stats
 
     # -- host materialization --------------------------------------------

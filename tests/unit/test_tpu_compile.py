@@ -296,7 +296,7 @@ def test_mesh_cell_go3_compiles_for_the_four_chip_host(topo, filtered):
 def test_bfs_compiles(one_chip):
     """FIND SHORTEST PATH's direction-optimizing single-chip BFS."""
     from nebula_tpu.tpu.bfs import build_bfs_fn_local
-    fn = build_bfs_fn_local(P8, 1 << 22, 5, 1, VMAX8, have_rev=True)
+    fn = build_bfs_fn_local(P8, 1 << 22, 5, VMAX8, have_rev=True)
     _compile(fn, (_block(P8, VMAX8, E8, one_chip, props=(), rev=True),),
              _struct((P8, VMAX8), np.bool_, one_chip))
 
