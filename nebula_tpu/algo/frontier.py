@@ -1,17 +1,15 @@
-"""The shared frontier-iteration step (ISSUE 13 satellite).
+"""The BFS level bodies, by need (ISSUE 13 satellite; PR 42).
 
-Before this module, the per-level "expand the frontier bitmap through
-every CSR block, apply the predicate, mark candidate destinations"
-body lived INSIDE tpu/bfs.py's two kernel builders (local and sharded),
-so any new frontier-style program would have re-implemented it.  The
-step now lives here, defined once:
+One level of a frontier iteration, defined once for every layout of
+tpu/bfs.py's two builders, which are its only callers (the
+vertex-program engine, algo/engine.py, runs the flat edge-list form of
+algo/graph.py and algo/kernels.py and calls nothing here):
 
-  * `expand_part`        — one part × one block expansion + predicate
-                           mask (the former bfs `one_part`, including
-                           the bottom-up endpoint swap);
   * `top_down_step`      — single-chip level body: expand every block
-                           from the frontier bitmap, OR the ownership
-                           marks (the degenerate all_to_all);
+                           from the frontier bitmap and mark the far
+                           ends in the (P, vmax) candidate bitmap (the
+                           degenerate all_to_all: every part's slots
+                           scatter into the one bitmap);
   * `bottom_up_step`     — single-chip direction-optimizing level body:
                            unvisited vertices scan their REVERSE
                            adjacency against the resident frontier
@@ -19,23 +17,45 @@ step now lives here, defined once:
   * `sharded_level_step` — the shard_map level body: expand + mark,
                            the caller exchanges marks over ICI.
 
-tpu/bfs.py composes its kernels from these; the vertex-program engine
-(algo/engine.py) drives its frontier-style algorithms through the same
-helpers when a program is expansion-shaped (the dense whole-edge-list
-algorithms — PageRank's SpMV — use the flat form in algo/graph.py
-instead, which has no frontier to expand).
+All three are `_level_marks`.  Per block it lays the expansion out
+(hop.py `_expand_plan`, which also gives the level's true size `total`
+and its overflow flag) and then runs EVERY per-slot stage inside ONE
+`_by_need` loop whose trip count is `ceil(min(max total, EB) / chunk)`:
+`_expand_slots` for the trip's window (`rank[eidx]` and the predicate's
+columns only where a predicate reads them), the live-tombstone test of
+an armed delta plane, the predicate (`_keep`), bottom-up's membership
+gather, and the mark scatter.  The loop carries the level's FLAT mark
+bitmap (hop.py `_mark_flat`) and nothing else: no array of EB slots
+leaves `_expand_plan`.  A level whose budget fits one chunk is the
+straight-line program (`_by_need`'s static choice).  A level costs what
+it expands, not what its edge budget holds.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-from ..tpu.hop import (_delta_cap, _delta_live, _drop_live_tombstones,
-                       _expand_block, _live_rows, _mark, _mark_rows,
+from ..tpu import hop
+from ..tpu.hop import (_by_need, _delta_cap, _delta_live,
+                       _drop_live_tombstones, _expand_plan, _expand_slots,
+                       _live_rows, _mark_flat, _part_view, _when,
                        take_halves)
 
-__all__ = ["expand_part", "top_down_step", "bottom_up_step",
-           "sharded_level_step", "delta_live"]
+__all__ = ["top_down_step", "bottom_up_step", "sharded_level_step",
+           "delta_live", "LEVEL_CHUNK"]
+
+# Slots of one part that one trip of a level's loop handles (`_by_need`);
+# a level whose budget is no larger runs straight-line.  Not hop.py's
+# CHUNK: the level loops carry one flat bitmap where `_traverse`'s carry
+# budget-wide columns, and the chip reads them flat up to 2^13 where it
+# read those flat up to 2^15.  Settled on the chip (PERF.md section 6,
+# PR 42: the 30 M-edge BFS cell's six requests, both directions of its
+# dense levels, mean device time of a five-level statement): 576.3 ms
+# at 2^13, 577.0 at 2^12, 580.0 at 2^11, 596.5 at 2^10, 632.7 at 2^9;
+# 715.2 at 2^14, 812.3 at 2^15, 845.7 at 2^16 (what grows past 2^13 is
+# the row-offset gather of a bottom-up trip).  The largest trip that
+# was flat.
+LEVEL_CHUNK = 1 << 13
 
 
 def _keep(block, src, dst, rk, eidx, ve, pred, pred_cols,
@@ -46,7 +66,7 @@ def _keep(block, src, dst, rk, eidx, ve, pred, pred_cols,
     REVERSE adjacency, so the expansion source is the traversal
     DESTINATION (the newly reached vertex) and the neighbor is the
     frontier side — the endpoint columns the predicate sees are
-    swapped."""
+    swapped.  `rk` is None unless the predicate reads `_rank`."""
     if pred is None:
         return ve
     ps, pd = (dst, src) if swap_ends else (src, dst)
@@ -55,24 +75,6 @@ def _keep(block, src, dst, rk, eidx, ve, pred, pred_cols,
         if not name.startswith("_"):
             cols[name] = take_halves(block["props"][name], eidx)
     return pred(cols) & ve
-
-
-def expand_part(block, fbm, pid, EB: int, P: int, vmax: int,
-                pred=None, pred_cols=(), hub_dense=None,
-                swap_ends: bool = False):
-    """Expand ONE part's frontier bitmap through ONE block's BASE CSR
-    and apply the compiled edge predicate (`_keep`).  The delta plane
-    is the level bodies' to merge (`_block_marks`): bottom-up never
-    sees it — a level goes top-down while the plane holds anything (the
-    reverse adjacency has no delta).
-
-    Returns (src, dst, keep, total, ovf) per the _expand_block slot
-    contract with the predicate folded into `keep`."""
-    src, dst, rk, eidx, ve, total, ovf = _expand_block(
-        block["indptr"], block["nbr"], block["rank"], fbm, EB, P,
-        pid, vmax_local=vmax, hub_dense=hub_dense)
-    return src, dst, _keep(block, src, dst, rk, eidx, ve, pred,
-                           pred_cols, swap_ends), total, ovf
 
 
 def delta_live(blocks_data):
@@ -86,127 +88,127 @@ def delta_live(blocks_data):
     return live
 
 
-def _block_marks(over, b, efbm, pid, EB: int, P: int, vmax: int,
-                 pred, pred_cols, hub_dense, acc=None):
-    """One block's top-down level: expand the base CSR over all EB
-    slots, merge an armed delta plane BY WHAT IT HOLDS (ISSUE 19; the
-    stages of hop.py's `_traverse`: tombstoned base slots dropped, the
-    plane's rows marked beside the base's, each behind the plane's live
-    count, so that an empty plane costs a level what no plane costs),
-    apply the predicate, mark the destinations.  `over` maps a per-part
-    function over the layout's leading axes as in `_traverse`; `acc` is
-    a mark matrix to mark into.
+def _level_marks(over, blocks, pid, efbm, EB: int, P: int, vmax: int,
+                 pred, pred_cols, hub_dense, chunk: int, member_of=None):
+    """One level over every block: the flat (P * vmax,) bitmap of the
+    vertices it reaches.  `over` maps a per-part function over the
+    layout's leading axes as in hop.py's `_traverse` (`jax.vmap` on one
+    chip, the identity inside a shard); each block holds `indptr`,
+    `nbr`, `rank`, `props` and, armed, the delta plane's `d_*` leaves.
 
-    -> (marks, edges, ovf)"""
-    dcap = _delta_cap(b)
-    src, dst, rk, eidx, ve, total, ovf = over(
-        lambda blk, pd, f: _expand_block(
-            blk["indptr"], blk["nbr"], blk["rank"], f, EB, P, pd,
-            vmax_local=vmax, hub_dense=hub_dense))(b, pid, efbm)
-    if dcap:
-        has_tomb, has_rows = _delta_live(b)
-        ve = _drop_live_tombstones(over, b, pid, eidx, ve, has_tomb)
-    keep = over(lambda blk, _p, *a: _keep(blk, *a, pred, pred_cols))(
-        b, pid, src, dst, rk, eidx, ve)
-    if acc is None:
-        marks = over(lambda _b, _p, d, k: _mark(d, k, P, vmax))(
-            None, None, dst, keep)
-    else:
-        marks = over(lambda _b, _p, m, d, k: _mark(d, k, P, vmax, m))(
-            None, None, acc, dst, keep)
-    if dcap:
-        _s, tdst, _r, tkeep, tact = _live_rows(
-            over, b, pid, efbm, P, has_rows, rk.dtype, pred,
-            [c for c in pred_cols if not c.startswith("_")])
-        marks = _mark_rows(over, marks, tdst, tkeep, P, vmax, has_rows)
-        total = total + jnp.sum(tact, axis=-1, dtype=jnp.int32)
-    return marks, total, ovf
+    Top-down, a kept slot marks its far end.  With `member_of` (the
+    resident (P, vmax) frontier bitmap) the level is BOTTOM-UP: the
+    blocks are the reverse adjacency, `efbm` the unvisited, and a slot
+    marks its SOURCE, routed to its owner row (a degree-split hub row's
+    source belongs to another part), where its neighbour is a member.
+
+    An armed delta plane is merged BY WHAT IT HOLDS (ISSUE 19; the
+    stages of `_traverse`): tombstoned base slots dropped inside the
+    loop, the plane's appended rows marked after it, each behind the
+    plane's live count, so that an empty plane costs a level what no
+    plane costs.  Bottom-up never sees a plane (bfs.py keeps a level
+    top-down while one holds anything).
+
+    -> (marks, edges, ovf, trips run, trips budgeted), all but `marks`
+    with the leading axes: the trip counts are `_by_need`'s summed over
+    the blocks (0 where no loop was emitted), the same for every part
+    under a vmap, which runs a loop to its fullest part's count."""
+    bottom_up = member_of is not None
+    want_rank = pred is not None and "_rank" in pred_cols
+    marks = jnp.zeros((P * vmax,), bool)
+    edges = ovf = None
+    run = budget = 0
+    for b in blocks:
+        dcap = _delta_cap(b)
+        has_tomb, has_rows = _delta_live(b) if dcap else (None, None)
+        total, ov, plan, _, _ = _expand_plan(over, b, pid, efbm, EB,
+                                             hop.PLAN_CHUNK)
+
+        def window(outs, lo, size):
+            src, dst, rk, eidx, ve = over(
+                lambda blk, pd, pl, tot: _expand_slots(
+                    blk["nbr"], blk["rank"] if want_rank else None, pl,
+                    tot, lo, size, EB, P, pd, vmax, hub_dense))(
+                b, pid, plan, total)
+            if dcap:
+                ve = _drop_live_tombstones(over, b, pid, eidx, ve,
+                                           has_tomb)
+            keep = ve if pred is None else over(
+                lambda blk, _p, *a: _keep(blk, *a, pred, pred_cols,
+                                          bottom_up))(
+                b, pid, src, dst, rk, eidx, ve)
+            if bottom_up:
+                nb = jnp.where(keep, dst, 0)
+                keep = keep & member_of[nb % P, nb // P]
+            return (_mark_flat(outs[0], src if bottom_up else dst, keep,
+                               P, vmax),)
+
+        # the live slots of the fullest part: the loop's trip count
+        (marks,), r, bd = _by_need(
+            window, (marks,), jnp.minimum(jnp.max(total), EB), EB,
+            chunk=chunk)
+        run, budget = run + r, budget + bd
+        if dcap:
+            _s, tdst, _r, tkeep, tact = _live_rows(
+                over, b, pid, efbm, P, has_rows, b["rank"].dtype, pred,
+                [c for c in pred_cols if not c.startswith("_")])
+            marks = _when(
+                has_rows, lambda m, d, k: _mark_flat(m, d, k, P, vmax),
+                lambda m, d, k: m, marks, tdst, tkeep)
+            total = total + jnp.sum(tact, axis=-1, dtype=jnp.int32)
+        edges = total if edges is None else edges + total
+        ovf = ov if ovf is None else ovf | ov
+    zero = jnp.zeros_like(edges)
+    return marks, edges, ovf, zero + run, zero + budget
 
 
 def top_down_step(blocks_data, efbm, EB: int, P: int, vmax: int, pids,
-                  pred=None, pred_cols=(), hub_dense=None):
+                  pred=None, pred_cols=(), hub_dense=None,
+                  chunk: int = LEVEL_CHUNK):
     """Single-chip level body, forward direction: expand every block
-    from the (possibly hub-extended) frontier bitmap `efbm`, mark
-    destinations in the (P, vmax) ownership bitmap, OR-reduce the
-    per-source mark matrices (the degenerate all_to_all).
+    from the (possibly hub-extended) frontier bitmap `efbm` and mark
+    the destinations in the one (P, vmax) ownership bitmap (every leaf
+    of a block, the delta plane's among them, has a leading part axis).
 
-    -> (cand (P, vmax) bool, edges (P,) i32, ovf (P,) bool)."""
-    marks = None
-    edges = jnp.zeros((P,), jnp.int32)
-    ovf = jnp.zeros((P,), bool)
-    for b in blocks_data:
-        # vmap the whole block dict: every leaf (indptr/nbr/rank/props
-        # and the d_* delta plane when present) has a leading part axis
-        blk_marks, total, ov = _block_marks(
-            jax.vmap, b, efbm, pids, EB, P, vmax, pred, pred_cols,
-            hub_dense)
-        ovf = ovf | ov
-        edges = edges + total
-        marks = blk_marks if marks is None else marks | blk_marks
-    return marks.any(axis=0), edges, ovf
+    -> (cand (P, vmax) bool, edges (P,) i32, ovf (P,) bool, trips run
+    and budgeted (P,) i32)."""
+    marks, *rest = _level_marks(jax.vmap, blocks_data, pids, efbm, EB, P,
+                                vmax, pred, pred_cols, hub_dense, chunk)
+    return (marks.reshape(P, vmax), *rest)
 
 
 def bottom_up_step(blocks_data, fbm, eunvis, EB: int, P: int,
                    vmax: int, pids, pred=None, pred_cols=(),
-                   hub_dense=None):
+                   hub_dense=None, chunk: int = LEVEL_CHUNK):
     """Single-chip direction-optimizing level body: expand the REVERSE
-    adjacency of unvisited vertices (`eunvis`, hub-extended by the
-    caller); a vertex joins the frontier if any in-neighbor's bit is
-    set in the resident frontier bitmap `fbm`.  Needs NO routing
-    exchange: each owner decides its own vertices from the global
-    bitmap.
+    adjacency (each block's `rev_*` leaves) of unvisited vertices
+    (`eunvis`, hub-extended by the caller); a vertex joins the frontier
+    if any in-neighbor's bit is set in the resident frontier bitmap
+    `fbm`.  Needs NO routing exchange: each owner decides its own
+    vertices from the global bitmap.  It expands every in-edge of every
+    unvisited vertex, which is what `edges` counts.
 
-    -> (cand (P, vmax) bool, edges (P,) i32, ovf (P,) bool)."""
-    cand = jnp.zeros((P, vmax), bool)
-    edges = jnp.zeros((P,), jnp.int32)
-    ovf = jnp.zeros((P,), bool)
-    for bi in range(len(blocks_data)):
-        b = blocks_data[bi]
-        src, nb, keep, total, ov = jax.vmap(
-            lambda ip, nbr, rkk, prp, f, pd: expand_part(
-                {"indptr": ip, "nbr": nbr, "rank": rkk,
-                 "props": prp}, f, pd, EB, P, vmax,
-                pred=pred, pred_cols=pred_cols, hub_dense=hub_dense,
-                swap_ends=True)
-        )(b["rev_indptr"], b["rev_nbr"], b["rev_rank"],
-          b.get("rev_props", {}), eunvis, pids)
-        ovf = ovf | ov
-        edges = edges + total
-        member = fbm[nb % P, nb // P] & keep       # (P, EB)
-        # route the reached vertex to its OWNER row (a degree-split
-        # hub row's src belongs to another part, so the plain
-        # local-index scatter would mis-home it)
-        blk = jax.vmap(lambda s, m: _mark(s, m, P, vmax))(src, member)
-        cand = cand | blk.any(axis=0)
-    return cand, edges, ovf
+    -> as `top_down_step`."""
+    rev = [{"indptr": b["rev_indptr"], "nbr": b["rev_nbr"],
+            "rank": b["rev_rank"], "props": b.get("rev_props", {})}
+           for b in blocks_data]
+    marks, *rest = _level_marks(jax.vmap, rev, pids, eunvis, EB, P, vmax,
+                                pred, pred_cols, hub_dense, chunk,
+                                member_of=fbm)
+    return (marks.reshape(P, vmax), *rest)
 
 
 def sharded_level_step(blocks_data, efbm, EB: int, P: int, pid,
                        vmax: int, pred=None, pred_cols=(),
-                       hub_dense=None):
+                       hub_dense=None, chunk: int = LEVEL_CHUNK):
     """shard_map level body (one part per chip): expand every block
-    from this shard's (hub-extended) expansion bitmap and accumulate
-    the (P, vmax) mark matrix; the caller ships row d to part d with
-    the packed all_to_all exchange.
+    from this shard's (hub-extended) expansion bitmap into the shard's
+    (P, vmax) mark matrix; the caller ships row d to part d with the
+    packed all_to_all exchange.
 
-    -> (marks (P, vmax) bool, edges () i32, ovf () bool)."""
-    marks = None
-    edges = jnp.zeros((), jnp.int32)
-    ovf = jnp.zeros((), bool)
-    for bi in range(len(blocks_data)):
-        b = blocks_data[bi]
-        blk = {"indptr": b["indptr"][0], "nbr": b["nbr"][0],
-               "rank": b["rank"][0],
-               "props": {n: v[0]
-                         for n, v in b.get("props", {}).items()}}
-        if "d_src" in b:
-            for k in ("d_src", "d_dst", "d_rank", "d_valid", "d_tomb"):
-                blk[k] = b[k][0]
-            blk["d_props"] = {n: v[0]
-                              for n, v in b.get("d_props", {}).items()}
-        marks, total, ov = _block_marks(
-            lambda f: f, blk, efbm, pid, EB, P, vmax, pred, pred_cols,
-            hub_dense, acc=marks)
-        ovf = ovf | ov
-        edges = edges + total
-    return marks, edges, ovf
+    -> (marks (P, vmax) bool, edges () i32, ovf () bool, trips run and
+    budgeted () i32)."""
+    marks, *rest = _level_marks(lambda f: f, _part_view(blocks_data), pid,
+                                efbm, EB, P, vmax, pred, pred_cols,
+                                hub_dense, chunk)
+    return (marks.reshape(P, vmax), *rest)

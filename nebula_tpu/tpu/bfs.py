@@ -4,21 +4,27 @@ Level-synchronous over the pinned CSR with a bitmap frontier: a level
 expands the frontier's out-edges, marks the far ends in a per-owner
 bitmap and keeps those `dist` has not seen (`new = cand & (dist < 0)`),
 so there is no sort and no frontier overflow; the edge budget of a level
-is the only size that escalates.  The level bodies live in
-algo/frontier.py (shared with the vertex-program plane); this module
-composes them with the `dist` update and, on one chip with the reverse
-blocks at hand, the per-level switch to the bottom-up body.
+is the only size that escalates, and it bounds a level's SHAPES, not its
+work.  The level bodies live in algo/frontier.py and run by need: every
+per-slot stage of a level sits in one device loop whose trip count is
+what the level expands (hop.py `_by_need`), and a level whose budget
+fits one chunk is the straight-line program.  This module composes them
+with the `dist` update and, on one chip with the reverse blocks at hand,
+the per-level switch to the bottom-up body.
 
 Both builders return `dist` (the depth of every vertex, -1 unreached),
-`hop_edges` (slots each level really expanded, a part), `ovf_expand` and
-`bottom_up` (the direction each level took).  The host walks
-predecessors back from the target (tpu/paths.py)."""
+`hop_edges` (slots each level really expanded, a part), `ovf_expand`,
+`bottom_up` (the direction each level took) and `chunks_run` /
+`chunks_budget` (the trips each level's loops ran and the trips its
+budget holds, a part; 0 where no loop was emitted), and carry the
+trip's size as `fn.chunk`.  The host walks predecessors back from the
+target (tpu/paths.py)."""
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-from ..algo.frontier import (bottom_up_step, delta_live,
+from ..algo.frontier import (LEVEL_CHUNK, bottom_up_step, delta_live,
                              sharded_level_step, top_down_step)
 from .hop import (_exchange_marks, _extend_fbm_local,
                   _extend_fbm_sharded, _hub_consts, _norm_ebs,
@@ -38,9 +44,11 @@ def bfs_exchange_bytes(P: int, vmax: int, max_steps: int,
 
 
 def build_bfs_fn(mesh, P: int, EB, max_steps: int, vmax: int,
-                 pred=None, pred_cols=(), hub_dense=None):
+                 pred=None, pred_cols=(), hub_dense=None,
+                 chunk: int = LEVEL_CHUNK):
     """Sharded BFS program: (blocks_data, frontier) →
     {dist (P, vmax), ovf_expand, hop_edges (P, steps),
+    chunks_run, chunks_budget (P, steps): each shard's own trips,
     bottom_up (steps,) bool, all false: every level is top-down here}.
 
     frontier: (P, vmax) bool seed bitmap.  pred/pred_cols: optional
@@ -51,7 +59,9 @@ def build_bfs_fn(mesh, P: int, EB, max_steps: int, vmax: int,
     Mesh contract (PR 17): in_specs name only the 'part' axis, so the
     same program runs on the legacy 1-D ('part',) mesh and on the
     2-axis ('lane', 'part') grid (CSR + dist replicated over the lane
-    rows); the per-level exchange payload is bfs_exchange_bytes."""
+    rows); the per-level exchange payload is bfs_exchange_bytes.
+    chunk: the level loops' trip size, in slots of one part
+    (frontier.py's `LEVEL_CHUNK` everywhere but in tests)."""
 
     ebs = _norm_ebs(EB, max_steps, False)
     hubs_c, hub_owner, hub_local = _hub_consts(hub_dense, P)
@@ -61,17 +71,20 @@ def build_bfs_fn(mesh, P: int, EB, max_steps: int, vmax: int,
         pid = jax.lax.axis_index("part").astype(jnp.int32)
         dist = jnp.where(fbm, 0, -1).astype(jnp.int32)
         ovf_e = jnp.zeros((), bool)
-        hop_edges = []
+        hop_edges, runs, budgets = [], [], []
 
         for level in range(1, max_steps + 1):
             EBl = ebs[level - 1]
             efbm = fbm if hubs_c is None else _extend_fbm_sharded(
                 fbm, pid, hub_owner, hub_local)
-            marks, edges, ovf = sharded_level_step(
+            marks, edges, ovf, run, budget = sharded_level_step(
                 blocks_data, efbm, EBl, P, pid, vmax,
-                pred=pred, pred_cols=pred_cols, hub_dense=hubs_c)
+                pred=pred, pred_cols=pred_cols, hub_dense=hubs_c,
+                chunk=chunk)
             ovf_e = ovf_e | ovf
             hop_edges.append(edges)
+            runs.append(run)
+            budgets.append(budget)
             cand = _exchange_marks(marks, P, vmax)
             new = cand & (dist < 0)
             dist = jnp.where(new, level, dist)
@@ -79,6 +92,8 @@ def build_bfs_fn(mesh, P: int, EB, max_steps: int, vmax: int,
 
         return {"dist": dist[None],
                 "hop_edges": jnp.stack(hop_edges)[None],
+                "chunks_run": jnp.stack(runs)[None],
+                "chunks_budget": jnp.stack(budgets)[None],
                 "ovf_expand": ovf_e[None]}
 
     from jax.sharding import PartitionSpec
@@ -87,15 +102,19 @@ def build_bfs_fn(mesh, P: int, EB, max_steps: int, vmax: int,
     spec = PartitionSpec("part")
     smapped = _shard_map(kernel, mesh=mesh,
                          in_specs=(spec, spec), out_specs=spec)
-    return jax.jit(lambda blocks_data, frontier: dict(
+    fn = jax.jit(lambda blocks_data, frontier: dict(
         smapped(blocks_data, frontier),
         bottom_up=jnp.zeros((max_steps,), bool)))
+    fn.chunk = chunk
+    return fn
 
 
 def build_bfs_fn_local(P: int, EB, max_steps: int, vmax: int,
                        pred=None, pred_cols=(), have_rev: bool = False,
-                       n_phantom: int = 0, hub_dense=None):
-    """Single-chip variant (vmap over parts, OR-reduce as all_to_all).
+                       n_phantom: int = 0, hub_dense=None,
+                       chunk: int = LEVEL_CHUNK):
+    """Single-chip variant (vmap over parts; every part's slots mark into
+    the one candidate bitmap, the degenerate all_to_all).
 
     With `have_rev` (blocks_data carries each block's REVERSE-direction
     twin under "rev_*" keys) the kernel is DIRECTION-OPTIMIZING: on
@@ -109,7 +128,10 @@ def build_bfs_fn_local(P: int, EB, max_steps: int, vmax: int,
     (frontier edges vs unvisited edges, Beamer-style) degrades to a
     frontier-population threshold since degrees are already summed by
     the expansion itself.  `bottom_up` (steps,) says which way each
-    level went (all false without `have_rev`)."""
+    level went (all false without `have_rev`); `chunks_run` and
+    `chunks_budget` (P, steps) hold the trips of the branch it took, the
+    same on every part (a vmapped loop runs to its fullest part's
+    count)."""
     pids = jnp.arange(P, dtype=jnp.int32)
     ebs = _norm_ebs(EB, max_steps, False)
     hubs_c, hub_owner, hub_local = _hub_consts(hub_dense, P)
@@ -124,17 +146,17 @@ def build_bfs_fn_local(P: int, EB, max_steps: int, vmax: int,
         armed = any("d_src" in b for b in blocks_data)
         dist = jnp.where(fbm, 0, -1).astype(jnp.int32)   # (P, vmax)
         ovf_e = jnp.zeros((P,), bool)
-        hop_edges, went_bu = [], []
+        hop_edges, went_bu, runs, budgets = [], [], [], []
 
         def top_down(blocks, f, EBl):
             return top_down_step(blocks, ext(f), EBl, P, vmax, pids,
                                  pred=pred, pred_cols=pred_cols,
-                                 hub_dense=hubs_c)
+                                 hub_dense=hubs_c, chunk=chunk)
 
         def bottom_up(blocks, f, unvis, EBl):
             return bottom_up_step(blocks, f, ext(unvis), EBl, P, vmax,
                                   pids, pred=pred, pred_cols=pred_cols,
-                                  hub_dense=hubs_c)
+                                  hub_dense=hubs_c, chunk=chunk)
 
         for level in range(1, max_steps + 1):
             EBl = ebs[level - 1]
@@ -151,7 +173,7 @@ def build_bfs_fn_local(P: int, EB, max_steps: int, vmax: int,
                     # top-down while the plane holds anything, and an
                     # armed plane that holds nothing changes no level
                     use_bu = use_bu & ~delta_live(blocks_data)
-                cand, edges, ovf = jax.lax.cond(
+                cand, edges, ovf, run, budget = jax.lax.cond(
                     use_bu,
                     lambda args: bottom_up(blocks_data, args[0], args[1],
                                            EBl),
@@ -159,15 +181,22 @@ def build_bfs_fn_local(P: int, EB, max_steps: int, vmax: int,
                     (fbm, unvis))
             else:
                 use_bu = jnp.zeros((), bool)
-                cand, edges, ovf = top_down(blocks_data, fbm, EBl)
+                cand, edges, ovf, run, budget = top_down(
+                    blocks_data, fbm, EBl)
             went_bu.append(use_bu)
             ovf_e = ovf_e | ovf
             hop_edges.append(edges)
+            runs.append(run)
+            budgets.append(budget)
             new = cand & (dist < 0)
             dist = jnp.where(new, level, dist)
             fbm = new
 
         return {"dist": dist, "hop_edges": jnp.stack(hop_edges, axis=1),
+                "chunks_run": jnp.stack(runs, axis=1),
+                "chunks_budget": jnp.stack(budgets, axis=1),
                 "ovf_expand": ovf_e, "bottom_up": jnp.stack(went_bu)}
 
-    return jax.jit(fn)
+    fn = jax.jit(fn)
+    fn.chunk = chunk
+    return fn

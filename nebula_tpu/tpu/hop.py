@@ -41,8 +41,10 @@ or scatter on the chip costs what its slots cost (20 to 27 ns apiece,
 PERF.md section 5), so every per-slot stage of a hop runs over
 ceil(need / CHUNK) chunks inside one device loop whose trip count is
 the expansion's own size (`_by_need`).  A hop whose budget fits one chunk
-compiles to the straight-line program.  Since PR 29 the expansion PLAN
-follows need too (`_expand_plan`): over a bitmap wider than PLAN_CHUNK
+compiles to the straight-line program.  The BFS level bodies
+(algo/frontier.py) run the same way since PR 42: one loop a level and
+block, whose carry is the level's mark bitmap (`_mark_flat`).  Since
+PR 29 the expansion PLAN follows need too (`_expand_plan`): over a bitmap wider than PLAN_CHUNK
 its scatters are sized by the words of the bitmap that hold an
 expanding vertex (one update a word to list them, PLAN_BLOCK updates a
 listed word, by need), not by the part's local vertices; what still
@@ -391,33 +393,6 @@ def _slot_gathers(plan, rank_on: bool, pred_cols: int, hubs: bool) -> int:
     return 2 + (plan[0] is not None) + rank_on + pred_cols + hubs
 
 
-def _expand_block(indptr, nbr, rank, fbm, EB: int, P: int, pid,
-                  vmax_local: int = 0, hub_dense=None):
-    """Vectorized CSR expansion of one block from one part's frontier
-    bitmap, all EB slots at once (the BFS and algo level bodies; the
-    traverse builders run `_expand_slots` by need).
-
-    indptr: (vmax+1,) local CSR row pointers; nbr/rank: (E,) edge
-    arrays; fbm: (vmax,) bool frontier membership; pid: this part's id
-    (dense id = local * P + pid).
-
-    With a degree-split snapshot (graphstore.csr.degree_split) the
-    block carries H extra HUB rows after the vmax_local local rows, and
-    fbm arrives EXTENDED to vmax_local+H (hub-active bits appended by
-    the caller); a hub row's source dense id comes from `hub_dense`
-    instead of the local-row arithmetic.
-
-    Returns (src, dst, rk, eidx, ve) of `_expand_slots` at length EB
-    (a level body whose predicate reads no rank leaves `rk` unused, and
-    its gather is then no part of the compiled program), plus
-    (total, ovf): true expansion size and overflow flag.
-    """
-    total, ovf, plan, _, _ = _expand_plan(
-        lambda f: f, {"indptr": indptr}, pid, fbm, EB, PLAN_CHUNK)
-    return _expand_slots(nbr, rank, plan, total, 0, EB, EB, P, pid,
-                         vmax_local, hub_dense) + (total, ovf)
-
-
 def take_halves(col, i):
     """One part's pinned property column `(2, E)` (device.py
     `split_halves`) at the edge indices `i`: both 32-bit halves by the
@@ -544,6 +519,20 @@ def _mark(dst, keep, P: int, vmax: int, acc=None):
     loc = jnp.where(keep, dst // P, 0).astype(jnp.int32)
     m = jnp.zeros((P, vmax), bool) if acc is None else acc
     return m.at[owner, loc].max(keep)
+
+
+@_stage("hop/mark")
+def _mark_flat(acc, ids, keep, P: int, vmax: int):
+    """`_mark` into a FLAT (P * vmax,) ownership bitmap that a by-need
+    loop carries (`_by_need`; algo/frontier.py's level bodies): bit
+    `(id % P) * vmax + id // P` is set for every kept dense id of `ids`,
+    whatever leading axes `ids` has, so every part's window of a level
+    marks into the one bitmap (on one chip the OR over source parts is
+    the scatter itself).  Flat because a scatter on the chip works on a
+    flat operand: a loop that carried the rows as rows would re-lay
+    them out on every trip (`_compact_cap`)."""
+    at = jnp.where(keep, (ids % P) * vmax + ids // P, P * vmax)
+    return acc.at[at.reshape(-1)].set(True, mode="drop")
 
 
 def _pack_bits(m):

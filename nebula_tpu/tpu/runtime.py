@@ -379,7 +379,8 @@ class _DispatchGate:
 
 # Result keys of a traverse program (hop.py `_traverse`) that say how far
 # its by-need loops and member plans engaged, lead + (steps,); summed
-# they are the TraverseStats fields of the same names.
+# they are the TraverseStats fields of the same names.  A BFS program
+# (bfs.py) returns the first two for its level loops.
 _ENGAGEMENT = ("chunks_run", "chunks_budget", "plan_run", "plan_budget")
 
 # The result leaves some caller reads, the only ones `_fetch` brings to
@@ -2045,15 +2046,26 @@ class TpuRuntime:
             if kernel == "bfs":
                 # what the BFS program did: the levels it ran and which of
                 # them bottom-up, the slots they really expanded (in-edges
-                # of the unvisited for a bottom-up level) and the slots of
-                # the converged budgets, which the level bodies ran whole
+                # of the unvisited for a bottom-up level), the trips its
+                # level loops ran and were budgeted (under names of their
+                # own: `tpu_hop_chunks_*` are the traverse programs') and
+                # the slots the level bodies RAN: a looped level's trips
+                # times the trip's size, a part; a straight-line level's
+                # whole budget
+                run, budget = (np.asarray(res[k]).reshape(-1, n_hops)
+                               for k in ("chunks_run", "chunks_budget"))
                 m.inc("tpu_bfs_runs")
                 m.inc("tpu_bfs_levels", n_hops)
                 m.inc("tpu_bfs_levels_bottom_up",
                       int(np.asarray(res["bottom_up"]).sum()))
                 m.inc("tpu_bfs_edges", edges)
-                m.inc("tpu_bfs_budget_slots", dev.num_parts * sum(EBs))
-            if "chunks_run" in res:
+                m.inc("tpu_bfs_chunks_run", int(run.sum()))
+                m.inc("tpu_bfs_chunks_budget", int(budget.sum()))
+                m.inc("tpu_bfs_budget_slots", int(run.sum()) * fn.chunk
+                      + dev.num_parts * sum(
+                          e for e, looped in zip(EBs, budget.any(axis=0))
+                          if not looped))
+            elif "chunks_run" in res:
                 for k in _ENGAGEMENT:
                     m.inc(f"tpu_hop_{k}", int(res[k].sum()))
             # what pinning property columns as their halves removed: bytes
@@ -2225,8 +2237,8 @@ class TpuRuntime:
         if "frontier_sizes" in res:
             stats.frontier_sizes = [
                 int(x) for x in mine("frontier_sizes").sum(axis=0)]
-        if "chunks_run" in res:
-            for k in _ENGAGEMENT:
+        for k in _ENGAGEMENT:
+            if k in res:        # a BFS's levels lay no member-plan count
                 setattr(stats, k, int(mine(k).sum()))
         stats.retries = info["retries"]
         stats.compiles = info["compiles"]
@@ -2707,7 +2719,9 @@ class TpuRuntime:
             stats.bottom_up = [bool(b) for b in res["bottom_up"]]
             if launch is not None:
                 launch["attrs"].update(levels=max_steps, eb=list(stats.e_cap),
-                                       bottom_up=sum(stats.bottom_up))
+                                       bottom_up=sum(stats.bottom_up),
+                                       chunks_run=stats.chunks_run,
+                                       chunks_budget=stats.chunks_budget)
         return res["dist"], stats
 
     # -- host materialization --------------------------------------------

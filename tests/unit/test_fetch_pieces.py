@@ -436,8 +436,9 @@ def test_the_fetched_leaves_are_the_named_list(kernel, fetched, monkeypatch):
     else:
         rt.bfs(st, "g", [1, 2], ["knows"], "out", 3)
     engaged = set(runtime._ENGAGEMENT)
-    want = {"dist", "hop_edges", "ovf_expand", "bottom_up"} if kernel == "bfs" else \
-        {"hop_edges", "ovf_expand", "kcount", "frontier_sizes"} | engaged
+    # a BFS's level loops say their trips too (PR 42); it lays no member-plan count
+    want = {"dist", "hop_edges", "ovf_expand", "bottom_up", "chunks_run", "chunks_budget"} \
+        if kernel == "bfs" else {"hop_edges", "ovf_expand", "kcount", "frontier_sizes"} | engaged
     metas = [set(tree[0]) for tree, _ in fetched if isinstance(tree, tuple)]
     assert metas and all(m == want for m in metas), metas
     assert want <= set(runtime._FETCHED)

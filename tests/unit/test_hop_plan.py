@@ -255,24 +255,33 @@ def test_a_narrow_bitmap_compiles_the_whole_bitmap_plan():
     assert got["plan_run"].shape == got["chunks_run"].shape == (P, 2)
 
 
-# -- `_expand_block`: the BFS and algo level bodies ---------------------------
+# -- the BFS level bodies (algo/frontier.py), which lay their plans out the same way --
 
 
 @pytest.mark.parametrize("case", FRONTIERS)
-def test_expand_block_under_vmap_takes_the_one_plan(monkeypatch, case):
+def test_a_level_body_under_vmap_takes_the_one_plan(monkeypatch, case):
+    """`top_down_step` over the plain plane's block, every part under
+    one vmap, in trips of 64 slots: the member plan (a bitmap wider than
+    `PLAN_CHUNK`) and the whole-bitmap plan mark the same vertices and
+    count the same edges, overflow flags and trips."""
+    from nebula_tpu.algo import frontier
     blocks, kw, P = _plane("plain")
-    b = blocks[0]
     f = _frontier(case, blocks, kw, P)
+    vmax = _vmax(blocks, kw)
     pids = np.arange(P, dtype=np.int32)
 
     def run(plan_chunk):
         monkeypatch.setattr(hop, "PLAN_CHUNK", plan_chunk)
-        return jax.device_get(jax.jit(jax.vmap(
-            lambda ip, nb, rk, fb, pd: hop._expand_block(
-                ip, nb, rk, fb, 1024, P, pd)))(
-            b["indptr"], b["nbr"], b["rank"], f, pids))
-    for g, w in zip(run(PC), run(WHOLE)):
+        return jax.device_get(jax.jit(
+            lambda bs, fb: frontier.top_down_step(
+                bs, fb, 1024, P, vmax, pids, chunk=64))(blocks, f))
+    got, want = run(PC), run(WHOLE)
+    for g, w in zip(got, want):
         assert np.array_equal(g, w)
+    cand, edges, ovf, trips, budget = got
+    assert cand.shape == (P, vmax) and (budget == 1024 // 64).all()
+    assert (trips == -(-min(int(edges.max()), 1024) // 64)).all()
+    assert cand.any() == bool(edges.sum())
 
 
 # -- through the runtime: the counters, MATCH frames, BFS ---------------------
@@ -281,8 +290,9 @@ def test_expand_block_under_vmap_takes_the_one_plan(monkeypatch, case):
 @pytest.fixture()
 def small_plans(monkeypatch):
     """Every traverse program the runtime builds lays its plans out
-    from the members (plan_chunk 32: one word a trip), and
-    `_expand_block` with them."""
+    from the members (plan_chunk 32: one word a trip), and the BFS
+    level bodies theirs (frontier.py reads `hop.PLAN_CHUNK` as it is
+    traced)."""
     real = hop.build_traverse_fn
     monkeypatch.setattr(
         runtime, "build_traverse_fn",

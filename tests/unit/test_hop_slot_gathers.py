@@ -183,6 +183,19 @@ def _oracle(indptr, f, EB):
     return rows, indptr[rows] + (j - starts[rows]), int(deg.sum())
 
 
+def _slots_by_windows(indptr, nbr, rank, f, EB, P, pid, size=96):
+    """What a by-need level body (algo/frontier.py `_level_marks`) asks
+    of the expansion: the plan once, then `_expand_slots` a window at a
+    time (windows of 96 slots, which tile neither budget here: the last
+    is short), put side by side -> (src, dst, rk, eidx, ve, total, ovf)."""
+    import jax.numpy as jnp
+    total, ovf, plan, _, _ = hop._expand_plan(
+        lambda fn: fn, {"indptr": indptr}, pid, f, EB, hop.PLAN_CHUNK)
+    cols = [hop._expand_slots(nbr, rank, plan, total, lo, min(size, EB - lo),
+                              EB, P, pid) for lo in range(0, EB, size)]
+    return tuple(jnp.concatenate(c) for c in zip(*cols)) + (total, ovf)
+
+
 @pytest.mark.parametrize("plan_chunk", [32, WHOLE], ids=["members", "whole"])
 @pytest.mark.parametrize("case", ["empty", "one", "sparse", "dense",
                                   "overflow", "exact"])
@@ -206,7 +219,7 @@ def test_eidx_from_the_row_offsets_is_the_oracles(monkeypatch, case,
         EB = int(indptr[40])
     rows, want, total = _oracle(indptr, f, EB)
     src, dst, rk, eidx, ve, tot, ovf = jax.device_get(jax.jit(
-        lambda ip, nb, r, fb: hop._expand_block(ip, nb, r, fb, EB, P, pid))(
+        lambda ip, nb, r, fb: _slots_by_windows(ip, nb, r, fb, EB, P, pid))(
         indptr, nbr, rank, f))
     n = min(total, EB)
     assert int(tot) == total and bool(ovf) == (total > EB)
@@ -221,6 +234,21 @@ def test_eidx_from_the_row_offsets_is_the_oracles(monkeypatch, case,
         assert total == 0
     if case == "exact":
         assert total == EB > 100 and not ovf
+    # the level body itself (algo/frontier.py): the far ends of exactly
+    # those slots marked, in trips of 64 where they tile the budget
+    from nebula_tpu.algo import frontier
+    marks, edges, over, trips, budget = jax.device_get(jax.jit(
+        lambda ip, nb, r, fb: frontier._level_marks(
+            lambda fn: fn, [{"indptr": ip, "nbr": nb, "rank": r, "props": {}}],
+            pid, fb, EB, P, vmax, None, (), None, 64))(indptr, nbr, rank, f))
+    far = np.zeros(P * vmax, bool)
+    far[(nbr[want] % P) * vmax + nbr[want] // P] = True
+    assert np.array_equal(marks, far)
+    assert int(edges) == total and bool(over) == (total > EB)
+    looped = EB % 64 == 0
+    assert (int(trips), int(budget)) == (
+        (-(-n // 64), EB // 64) if looped else (0, 0))
+    assert looped == (case != "exact")
 
 
 def test_row_offsets_broadcast_a_shards_csr_under_its_lanes():
