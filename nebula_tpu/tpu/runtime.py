@@ -1668,9 +1668,9 @@ class TpuRuntime:
     def _escalate(self, dev: DeviceSnapshot, dense: Sequence[int],
                   key_fn, build_fn, inputs_fn, stats: "TraverseStats",
                   n_hops: int = 1, uniform: bool = False,
-                  min_eb: Optional[int] = None,
                   fetch_keys: Optional[set] = None,
-                  kernel: str = "traverse"):
+                  kernel: str = "traverse",
+                  eb_cap: Optional[int] = None):
         """A solo statement's launch: the one whose single lane is this
         statement's seeds and whose program is the solo program, under
         the dispatch gate (`_gated_dispatch`, ISSUE 9), charged to the
@@ -1678,8 +1678,8 @@ class TpuRuntime:
         with self._gated_dispatch(kernel) as wait_us:
             res, info = self._escalate_locked(
                 dev, [dense], key_fn, build_fn, inputs_fn, wait_us,
-                n_hops=n_hops, uniform=uniform, min_eb=min_eb,
-                fetch_keys=fetch_keys, kernel=kernel)
+                n_hops=n_hops, uniform=uniform, fetch_keys=fetch_keys,
+                kernel=kernel, eb_cap=eb_cap)
             self._attribute(info, res, None, stats)
             return res
 
@@ -1866,10 +1866,9 @@ class TpuRuntime:
                          lane_dense: Sequence[Sequence[int]],
                          key_fn, build_fn, inputs_fn, wait_us: int,
                          n_hops: int = 1, uniform: bool = False,
-                         min_eb: Optional[int] = None,
                          fetch_keys: Optional[set] = None,
                          kernel: str = "traverse", lanes: bool = False,
-                         launcher_ctx=None):
+                         launcher_ctx=None, eb_cap: Optional[int] = None):
         """The power-of-two bucket escalation driver of every device
         program (traverse, hops, bfs), solo or lane-batched: seed
         bitmap put, jit cache, overflow-driven retry (SURVEY §7
@@ -1893,7 +1892,11 @@ class TpuRuntime:
         expansion), so a 3-hop GO's first hop does not pay the final
         hop's padding.  `uniform=True` keeps all hops at one size
         (capture_hops stacks frames along a hop axis; BFS compiles one
-        per-level body).
+        per-level body).  No budget climbs past `max_cap`, unless the
+        caller knows what one hop can expand at most and says so
+        (`eb_cap`: a BFS level never expands more than its block's padded
+        edge width, and its body carries nothing budget-wide but the
+        plan), which then bounds the ladder in `max_cap`'s place.
 
         Returns (res, info): the fetched result and the launch's facts
         (rungs, budgets, phase timings, gate wait) that `_attribute`
@@ -1908,13 +1911,8 @@ class TpuRuntime:
             # runs again on the one that replaced it (_on_live_snapshot)
             raise SnapshotRetired(
                 "device snapshot retired by a concurrent re-pin")
-        base = self.init_eb
-        if min_eb is not None:
-            # caller knows a static bound (e.g. BFS: one hop's expansion
-            # never exceeds the block's padded Emax) — start there and
-            # never climb the recompile ladder
-            base = min(max(base, min_eb), self.max_cap)
-        EBs = [base] * n_hops
+        cap = self.max_cap if eb_cap is None else eb_cap
+        EBs = [self.init_eb] * n_hops
         # cache key includes the seed-count (or lane-count) bucket: one
         # supernode query must not permanently inflate every later small
         # query of the same program to supernode-sized padded kernels.
@@ -2018,7 +2016,7 @@ class TpuRuntime:
             he = np.asarray(res["hop_edges"])
             need = he.reshape(-1, he.shape[-1]).max(axis=0)
             EBs = [e if need[h] <= e else
-                   min(max(2 * e, _pow2(int(need[h]))), self.max_cap)
+                   min(max(2 * e, _pow2(int(need[h]))), cap)
                    for h, e in enumerate(EBs)]
             if uniform:
                 EBs = [max(EBs)] * n_hops
@@ -2059,6 +2057,10 @@ class TpuRuntime:
                 m.inc("tpu_bfs_levels_bottom_up",
                       int(np.asarray(res["bottom_up"]).sum()))
                 m.inc("tpu_bfs_edges", edges)
+                # the most slots one part expanded in one level: how far
+                # past `max_cap` the deployment's levels run
+                m.add_value("tpu_bfs_widest_level_slots",
+                            float(np.asarray(res["hop_edges"]).max()))
                 m.inc("tpu_bfs_chunks_run", int(run.sum()))
                 m.inc("tpu_bfs_chunks_budget", int(budget.sum()))
                 m.inc("tpu_bfs_budget_slots", int(run.sum()) * fn.chunk
@@ -2123,6 +2125,9 @@ class TpuRuntime:
                 retries=attempt, shards=self.mesh_size, exchange_bytes=xbytes)
             if xbytes:
                 m.inc("tpu_all_to_all_bytes", xbytes)
+                if kernel == "bfs":
+                    # the BFS programs' share, apart from the traverses'
+                    m.inc("tpu_bfs_exchange_bytes", xbytes)
             # a shared launch is traced under the LAUNCHING member's
             # statement, whose context the launch suppressed: the launch
             # itself, from its seed prep to here
@@ -2651,6 +2656,7 @@ class TpuRuntime:
         the BFS only traverses mask-passing edges, matching the host
         oracle's filtered expansion.
         """
+        from ..algo.frontier import LEVEL_CHUNK
         from .bfs import build_bfs_fn, build_bfs_fn_local
         _, dev, stats, block_keys, (pred, pred_cols, pred_key), dense = \
             self._statement(store, space, srcs, etypes, direction,
@@ -2706,7 +2712,16 @@ class TpuRuntime:
             # made every level pay the widest level's padding.  The kernel
             # reports exact per-level counts, so the ladder jumps straight
             # to each level's bucket; the persistent bucket cache remembers
-            # the converged shape across runs.
+            # the converged shape across runs.  A level expands edges of the
+            # part, each once, so its budget never has to pass the widest
+            # block's padded edge width (in whole trips of the level loop,
+            # which a width they do not tile would run straight-line):
+            # that bounds the ladder here, not `max_cap`, under which a
+            # part of more than 2^24 edges had no budget to converge to.
+            width = max(int(dev.blocks[k].nbr.shape[-1]) for k in
+                        list(block_keys) + (rev_keys if have_rev else []))
+            if width > LEVEL_CHUNK:
+                width = -(-width // LEVEL_CHUNK) * LEVEL_CHUNK
             res = self._escalate(
                 dev, dense,
                 key_fn=lambda ebs: (space, dev.epoch, "bfs",
@@ -2715,7 +2730,7 @@ class TpuRuntime:
                                     hub_n, self._delta_sig(dev)),
                 build_fn=build,
                 inputs_fn=lambda ebs: (blocks_data,),
-                stats=stats, n_hops=max_steps, kernel="bfs")
+                stats=stats, n_hops=max_steps, kernel="bfs", eb_cap=width)
             stats.bottom_up = [bool(b) for b in res["bottom_up"]]
             if launch is not None:
                 launch["attrs"].update(levels=max_steps, eb=list(stats.e_cap),
