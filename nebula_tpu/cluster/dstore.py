@@ -718,15 +718,9 @@ class DistributedStore:
     # the runtime full-rebuilds. ----
 
     def _census_probe(self, space: str) -> Dict[int, tuple]:
-        """Per-part (epoch, writes_total, writes_from_me) fan-out."""
-        pids = self.sc.all_parts(space)
-        per = dict(self.sc.fanout(
-            space, {p: {"writer": self.writer_id} for p in pids},
-            "storage.part_stats"))
-        return {pid: (int(r.get("epoch", 0)),
-                      int(r.get("writes_total", 0)),
-                      int(r.get("writes_from", 0)))
-                for pid, r in per.items()}
+        """Per-part (epoch, writes_total, writes_from_me): one
+        `storage.probe` request a storaged host."""
+        return self.sc.probe(space, writer=self.writer_id)
 
     def delta_watch(self, space: str, cap: int = 65536) -> int:
         from ..graphstore.delta import DeltaLog
@@ -900,7 +894,11 @@ class _SpaceView:
 
     @property
     def epoch(self) -> int:
-        return self._ds.stats(self.name)["epoch"]
+        # asked anew at every read (nothing kept from one statement to
+        # the next): one `storage.probe` request a storaged host, the
+        # maximum over the hosts asked
+        return max((e for e, _t, _m in
+                    self._ds.sc.probe(self.name).values()), default=0)
 
     # -- device-plane vid dictionary (filled by build_csr_snapshot; the
     # runtime always pins BEFORE resolving seeds, so queries see the

@@ -260,7 +260,7 @@ def _recv_frame(sock: socket.socket
 # after a connection died mid-reply: the first send may have applied.
 _IDEMPOTENT_METHODS = {
     "raft", "meta.ready", "meta.heartbeat", "meta.part_map",
-    "storage.reconcile",
+    "storage.reconcile", "storage.probe",
 }
 _IDEMPOTENT_PREFIXES = (
     "storage.get_", "storage.scan_", "storage.index_scan",

@@ -28,6 +28,7 @@ from __future__ import annotations
 import threading
 import time
 from concurrent.futures import FIRST_COMPLETED, ThreadPoolExecutor, wait
+from functools import partial
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from ..graphstore.store import stable_vid_hash
@@ -291,12 +292,13 @@ class StorageClient:
             self.meta.refresh(force=True)
         raise StorageError(f"part {pid} of `{space}' unreachable: {last}")
 
-    def fanout(self, space: str, by_part: Dict[int, Dict[str, Any]],
-               method: str) -> List[Tuple[int, Any]]:
-        """Concurrent per-part calls; returns [(pid, result)] sorted.
+    def _scatter(self, jobs: Dict[Any, Callable[[], Any]]
+                 ) -> List[Tuple[Any, Any]]:
+        """Run `jobs` concurrently on the pool; returns [(key, result)]
+        sorted by key.
 
         The submitting thread's trace context and work-counter target
-        are re-established on each pool thread, so per-part spans and
+        are re-established on each pool thread, so the jobs' spans and
         RPC/wire-byte counts attribute to the query that fanned out."""
         tctx = _trace.current_ctx()
         wc = current_work()
@@ -304,27 +306,25 @@ class StorageClient:
         kill = _cancel.current_kill()
         dl = _cancel.current_deadline()
 
-        def run(pid, params):
+        def run(job):
             # cancel context rides to the pool thread like trace/work/
-            # cost do: the per-part call clamps its RPC timeouts and
-            # backoff to the statement budget, stops walking when
-            # killed, and attributes reply-envelope cost records to the
-            # plan node that fanned out
+            # cost do: the call clamps its RPC timeouts and backoff to
+            # the statement budget, stops walking when killed, and
+            # attributes reply-envelope cost records to the plan node
+            # that fanned out
             with _trace.use_ctx(tctx), use_work(wc), use_cost(cc), \
-                    _cancel.use_cancel(kill=kill, deadline=dl), \
-                    _trace.span(f"storage:{method}", part=pid,
-                                space=space):
-                return self._call_part(space, pid, method, params)
+                    _cancel.use_cancel(kill=kill, deadline=dl):
+                return job()
 
-        futs = {pid: self._pool.submit(run, pid, params)
-                for pid, params in by_part.items()}
+        futs = {key: self._pool.submit(run, job)
+                for key, job in jobs.items()}
         # kill-aware wait (ISSUE 5 satellite): KILL QUERY during the
         # fan-out must not block on a stalled partition until its RPC
         # timeout — poll the cancel context while waiting.  Context-
         # free callers (admin/balance paths) keep the single cheap
         # blocking collect instead of a 20Hz poll loop
         if kill is None and dl is None:
-            return [(pid, f.result()) for pid, f in sorted(futs.items())]
+            return [(key, f.result()) for key, f in sorted(futs.items())]
         pending = set(futs.values())
         try:
             while pending:
@@ -334,9 +334,83 @@ class StorageClient:
                     _cancel.check()
         except (_cancel.QueryKilled, _cancel.DeadlineExceeded):
             for f in pending:
-                f.cancel()          # unstarted parts never dispatch
+                f.cancel()          # unstarted jobs never dispatch
             raise
-        return [(pid, f.result()) for pid, f in sorted(futs.items())]
+        return [(key, f.result()) for key, f in sorted(futs.items())]
+
+    def fanout(self, space: str, by_part: Dict[int, Dict[str, Any]],
+               method: str) -> List[Tuple[int, Any]]:
+        """Concurrent per-part calls; returns [(pid, result)] sorted."""
+        def call(pid, params):
+            with _trace.span(f"storage:{method}", part=pid, space=space):
+                return self._call_part(space, pid, method, params)
+
+        return self._scatter({pid: partial(call, pid, params)
+                              for pid, params in by_part.items()})
+
+    def probe(self, space: str, writer: Any = None
+              ) -> Dict[int, Tuple[int, int, int]]:
+        """-> {pid: (epoch, writes_total, writes_from)}: the space's
+        epoch as each part's host holds it and, for an asking `writer`,
+        each part's write census (0, 0 without one).  ONE
+        `storage.probe` request a storaged HOST, not one a part: the
+        parts are grouped by the address `_call_part` would try first
+        (upstream's `clusterIdsToHosts`), a single host is asked on the
+        calling thread and several concurrently.  A host that refuses
+        (a dead socket, `not hosted here`, any `RpcError`) sends ITS
+        parts down the per-part `storage.part_stats` walk with its
+        leader hints and retries, so a failover window behaves as it
+        did when every part was asked by itself."""
+        method = "storage.probe"
+        params = {} if writer is None else {"writer": writer}
+        by_host: Dict[str, List[int]] = {}
+        walk: List[int] = []
+        for pid, replicas in enumerate(self.meta.parts_of(space)):
+            order = self._route(replicas, False)
+            if order:
+                by_host.setdefault(order[0], []).append(pid)
+            else:
+                walk.append(pid)        # no replica known: the walk refreshes
+
+        def ask(addr, pids):
+            with _trace.span(f"storage:{method}", peer=addr, space=space,
+                             parts=len(pids)):
+                t_call = time.monotonic()
+                try:
+                    r = self._client(addr).call(method, space=space,
+                                                parts=pids, **params)
+                except (RpcError, RpcConnError):
+                    return None
+                note_peer_latency(addr, time.monotonic() - t_call)
+                return r
+
+        if len(by_host) == 1:
+            replies = [(addr, ask(addr, pids))
+                       for addr, pids in by_host.items()]
+        else:
+            replies = self._scatter({addr: partial(ask, addr, pids)
+                                     for addr, pids in by_host.items()})
+        out: Dict[int, Tuple[int, int, int]] = {}
+        for addr, r in replies:
+            if r is None:
+                walk.extend(by_host[addr])
+                continue
+            epoch = int(r["epoch"])
+            census = {pid: (t, m) for pid, t, m in r.get("census") or ()}
+            for pid in by_host[addr]:
+                t, m = census.get(pid, (0, 0))
+                out[pid] = (epoch, int(t), int(m))
+        st = _stats()
+        st.inc("storage_probe_rpcs", len(by_host))
+        st.inc("storage_probe_parts", len(out))
+        if walk:
+            st.inc("storage_probe_fallback_parts", len(walk))
+            for pid, r in self.fanout(space, {p: dict(params) for p in walk},
+                                      "storage.part_stats"):
+                out[pid] = (int(r.get("epoch", 0)),
+                            int(r.get("writes_total", 0)),
+                            int(r.get("writes_from", 0)))
+        return out
 
     def all_parts(self, space: str) -> List[int]:
         return list(range(len(self.meta.parts_of(space))))

@@ -180,7 +180,8 @@ class StorageService:
         # per-part write census (device delta feed): applied raft
         # entries counted per writer token.  A graphd's delta log can
         # only trust its dirty keys if EVERY write since its watch came
-        # through it — rpc_part_stats ships (total, from-you) counts so
+        # through it — rpc_probe (and, down the per-part fallback,
+        # rpc_part_stats) ships (total, from-you) counts so
         # the client proves exactly that before skipping a re-pin.
         # Counts are apply-side (replayed on restart, replica-local);
         # a snapshot-install or failover skews them only toward
@@ -975,6 +976,35 @@ class StorageService:
         if p.get("detail"):
             out["detail"] = self.store.stats_detail(p["space"],
                                                     parts=[pid])
+        return out
+
+    def rpc_probe(self, p):
+        """What a freshness probe and a write census read, for a LIST
+        of this host's parts in one call: `{epoch, census: [[pid,
+        writes_total, writes_from], ...]}`, the census only for an
+        asking `writer`.  The epoch is read BEFORE any part's census
+        (as `rpc_part_stats` reads it: the delta feed's target may
+        under-state what the census covers, never over-state it), the
+        census lock is taken once, and no vertex or edge is counted.
+        Replica-readable like the plain `part_stats` (any live replica
+        answers, so device epoch checks survive a failover window); a
+        part that has no replica here refuses the whole request, and
+        the client sends this host's parts down the per-part walk."""
+        space = p["space"]
+        sp = self.meta.catalog.spaces.get(space)
+        for pid in p["parts"]:
+            part = self.parts.get((sp.space_id, pid)) if sp else None
+            if part is None or not part.alive:
+                raise RpcError(f"part {pid} of `{space}' not hosted here")
+        out = {"epoch": self.store.space(space).epoch}
+        if "writer" in p:
+            with self._census_lock:
+                rows = []
+                for pid in p["parts"]:
+                    c = self._write_census.get((space, pid)) or {}
+                    rows.append([pid, c.get("total", 0),
+                                 c.get(p["writer"], 0)])
+            out["census"] = rows
         return out
 
     def rpc_part_raft_info(self, p):

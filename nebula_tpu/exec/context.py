@@ -97,8 +97,9 @@ class QueryContext:
         cache = key = None
         from ..graphstore.store import GraphStore
         # Local stores only: the cluster _SpaceView's epoch property is
-        # a part_stats RPC fan-out, far costlier than the build it
-        # would save (and its CatalogProxy makes the TTL probe remote).
+        # a `storage.probe` RPC to every storaged host, far costlier
+        # than the build it would save (and its CatalogProxy makes the
+        # TTL probe remote).
         if tags is None and isinstance(self.store, GraphStore):
             # TTL rows go invisible by WALL CLOCK without an epoch bump —
             # a TTL'd space must rebuild every time.

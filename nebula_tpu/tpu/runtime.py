@@ -1049,7 +1049,7 @@ class TpuRuntime:
                     cur, "space_uid", None) != getattr(sd, "uid", None):
                 break
             # the freshness probe every dispatch pays: on a cluster
-            # store `sd.epoch` is a part_stats fan-out, one RPC per part
+            # store `sd.epoch` is one `storage.probe` RPC per storaged host
             with _t.span("tpu:snapshot_check", space=space):
                 fresh = self._served_epoch(cur) == sd.epoch
             if fresh:

@@ -493,8 +493,10 @@ def test_cluster_statement_budgets_close(traced_cluster):
     go = _latest("query:Go")
     probe = next(s for s in go["spans"]
                  if s["name"] == "tpu:snapshot_check")
-    fan = [s for s in go["spans"] if s["name"] == "storage:storage.part_stats"]
-    assert len(fan) == 8 and {s["psid"] for s in fan} == {probe["sid"]}
+    # one `storage.probe` request a storaged HOST (two here), not one a part
+    fan = [s for s in go["spans"] if s["name"] == "storage:storage.probe"]
+    assert len(fan) == 2 and {s["psid"] for s in fan} == {probe["sid"]}
+    assert sum(s["attrs"]["parts"] for s in fan) == 8
     served = [s for s in go["spans"] if s["name"].startswith("rpc.server:")]
     assert served and all(s["attrs"]["inbox_us"] >= 0 for s in served)
 
