@@ -2067,6 +2067,13 @@ class TpuRuntime:
                       + dev.num_parts * sum(
                           e for e, looped in zip(EBs, budget.any(axis=0))
                           if not looped))
+                # a sharded program's all_gather of the frontier bitmap,
+                # before each level that chooses its direction: from the
+                # shapes, like the exchange's bytes below
+                from .bfs import bfs_gather_bytes
+                m.inc("tpu_bfs_gather_bytes", bfs_gather_bytes(
+                    self.mesh_size, dev.vmax,
+                    getattr(fn, "gather_levels", 0)))
             elif "chunks_run" in res:
                 for k in _ENGAGEMENT:
                     m.inc(f"tpu_hop_{k}", int(res[k].sum()))
@@ -2666,9 +2673,10 @@ class TpuRuntime:
 
         with _t.span("tpu:launch", kernel="bfs") as launch:
             P = dev.num_parts
-            # direction-optimizing leg (single chip): each block's REVERSE
-            # twin rides along so dense levels can go bottom-up (a vertex
-            # scans its in-neighbors against the resident frontier bitmap).
+            # direction-optimizing leg, on one chip and on a mesh: each
+            # block's REVERSE twin rides along so dense levels can go
+            # bottom-up (a vertex scans its in-neighbors against the
+            # frontier bitmap, resident on one chip, gathered over a mesh).
             # 'both' already traverses both planes — no distinct reverse.
             rev_of = {"out": "in", "in": "out"}
             rev_keys = [(et, rev_of[d]) for et, d in block_keys
@@ -2677,8 +2685,7 @@ class TpuRuntime:
             # top-down while the plane holds anything (bfs.py: bottom-up
             # scans the reverse adjacency, which the merge does not model),
             # so an armed, empty plane changes no level's direction
-            have_rev = (self.local_mode
-                        and len(rev_keys) == len(block_keys)
+            have_rev = (len(rev_keys) == len(block_keys)
                         and all(rk in dev.blocks for rk in rev_keys))
             pnames = {n for n in pred_cols if not n.startswith("_")}
             _, blocks = self._block_leaves(dev, block_keys, pnames)
@@ -2704,7 +2711,7 @@ class TpuRuntime:
                                               hub_dense=hub_dense)
                 return build_bfs_fn(self.mesh, P, ebs, max_steps, dev.vmax,
                                     pred=pred, pred_cols=pred_cols,
-                                    hub_dense=hub_dense)
+                                    have_rev=have_rev, hub_dense=hub_dense)
 
             # Per-LEVEL edge budgets (like the traverse kernel's per-hop
             # buckets): a BFS's first and last levels examine orders of

@@ -5,7 +5,9 @@ takes a level bottom-up and on one that takes none; the `bottom_up` flags
 the program returns against a host replay of its switch rule, `hop_edges`
 by the direction each level took, the seven `tpu_bfs_*` counters by what
 the flags and `hop_edges` say, the `tpu:launch` span's attributes, and the
-sharded builder's all-false flags on a four-device virtual mesh.
+sharded builder's flags on a four-device virtual mesh: all false where
+every budget is one trip, the rule by trips where the levels loop (PR 45;
+test_bfs_mesh_direction.py has the rule's own cases).
 
 Since PR 42 a level body runs by need (algo/frontier.py `_level_marks`):
 the same levels and counts over budgets of one trip, several trips, a
@@ -74,6 +76,31 @@ def replay(n, src, level, entered, have_rev, parts=None):
             per_part.append(np.bincount(who % parts, weights=deg[who],
                                         minlength=parts).astype(np.int64))
     return (flags, edges, per_part) if parts else (flags, edges)
+
+
+def mesh_replay(n, src, dst, level, entered, parts, e_cap, chunk, have_rev=True):
+    """The sharded program's rule on the host: a level whose budget loops
+    goes bottom-up where the in-edges of the unvisited take fewer trips on
+    their fullest part than the frontier's out-edges on theirs, a
+    bottom-up trip weighed by the module's constant; a tie, and a budget
+    of one trip, stay top-down.  A vertex is its part's (`vid % parts`).
+    -> (flags, slots a level expands, (parts,) slots a level and part)."""
+    from nebula_tpu.tpu.bfs import BOTTOM_UP_TRIP_COST
+    out_deg, in_deg = np.bincount(src, minlength=n), np.bincount(dst, minlength=n)
+    flags, edges, per_part = [], [], []
+    for depth, (frontier, eb) in enumerate(zip(entered, e_cap), start=1):
+        unvisited = np.flatnonzero((level < 0) | (level >= depth))
+        td = np.bincount(frontier % parts, weights=out_deg[frontier],
+                         minlength=parts).astype(np.int64)
+        bu = np.bincount(unvisited % parts, weights=in_deg[unvisited],
+                         minlength=parts).astype(np.int64)
+        trips = [max(-(-int(min(x, eb)) // chunk) for x in pp) for pp in (td, bu)]
+        up = bool(have_rev and eb > chunk and not eb % chunk
+                  and trips[1] * BOTTOM_UP_TRIP_COST < trips[0])
+        flags.append(up)
+        per_part.append(bu if up else td)
+        edges.append(int(per_part[-1].sum()))
+    return flags, edges, per_part
 
 
 @pytest.fixture(scope="module")
@@ -272,7 +299,9 @@ def test_a_looped_bfs_moves_no_traverse_counter_and_its_span_says_its_trips(pinn
 @pytest.mark.parametrize("max_steps", [2, 5])
 def test_the_sharded_builder_says_every_level_went_top_down(max_steps):
     """Four virtual devices, one part each, as tests/unit/test_sharded.py
-    stands its mesh up: the same levels, every flag false."""
+    stands its mesh up: the same levels, every flag false (a budget of
+    2,048 slots is one trip of the module's constant: no level has a
+    choice)."""
     from nebula_tpu.tpu import TpuRuntime, make_mesh
     gen = loader.module("reference/generators", "knows_symmetric")
     mesh = loader.module("builders", "prebuilt_mesh")
@@ -439,7 +468,8 @@ def test_a_looped_program_holds_no_gather_as_wide_as_its_budget(pinned, mesh_of_
 def test_each_shard_runs_its_own_trips(mesh_of_four, start):
     """Four shards, trips of 64: the levels are the oracle's and a level's
     trips are the sum over the shards of what each shard's own expansion
-    fills (no shard waits for the fullest one)."""
+    fills (no shard waits for the fullest one), in the direction the
+    shards agreed on (PR 45: `mesh_replay`, the rule by trips)."""
     rt, store, n, src, dst = mesh_of_four
     P, chunk = 4, 64
     with trips_of(chunk, rt):
@@ -448,8 +478,8 @@ def test_each_shard_runs_its_own_trips(mesh_of_four, start):
     want, entered = numpy_bfs(n, src, dst, start, 5)
     vid = np.arange(n)
     assert np.array_equal(np.asarray(dist)[vid % P, vid // P], want)
-    flags, edges, per_part = replay(n, src, want, entered, False, P)
-    assert (st.bottom_up, st.hop_edges) == (flags, edges)
+    flags, edges, per_part = mesh_replay(n, src, dst, want, entered, P, st.e_cap, chunk)
+    assert (st.bottom_up, st.hop_edges) == (flags, edges) and any(flags)
     trips = sum(int(-(-min(x, e) // chunk)) for pp, e in zip(per_part, st.e_cap) for x in pp)
     assert st.chunks_run == moved["tpu_bfs_chunks_run"] == trips
     assert moved["tpu_bfs_budget_slots"] == trips * chunk

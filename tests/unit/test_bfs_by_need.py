@@ -2,8 +2,8 @@
 over what a level body can meet besides a plain CSR: an edge predicate
 that reads a property column, a degree-split snapshot with hub rows, and
 an armed delta plane that holds nothing, rows, or a tombstone; on one
-chip (where a dense level goes bottom-up) and on a mesh of two shards
-(eight for the hub store, which has eight parts).
+chip and on a mesh of two shards (eight for the hub store, which has
+eight parts), on both of which a dense level goes bottom-up.
 
 Every case runs twice through `TpuRuntime.bfs`: with a trip of 64 slots
 (an edge budget of 2,048 is 32 trips a level) and with a trip no budget
@@ -133,14 +133,23 @@ def test_levels_over_every_plane_by_need_and_straight_line(
         monkeypatch, flags, plane, parts):
     looped, P = _run(monkeypatch, flags, plane, parts, TRIP)
     whole, _ = _run(monkeypatch, flags, plane, parts, WHOLE)
-    assert looped.hop_edges == whole.hop_edges
-    assert looped.bottom_up == whole.bottom_up
-    assert looped.e_cap == whole.e_cap and looped.retries == whole.retries
-    if parts == 1 and plane not in ("rows", "tomb"):
-        assert any(looped.bottom_up)          # the bottom-up body ran too
+    if parts == 1:
+        assert looped.hop_edges == whole.hop_edges
+        assert looped.bottom_up == whole.bottom_up
+        assert looped.e_cap == whole.e_cap and looped.retries == whole.retries
+        if plane not in ("rows", "tomb"):
+            assert any(looped.bottom_up)      # the bottom-up body ran too
     else:
-        # no reverse blocks on a mesh; a plane that holds anything keeps
-        # every level top-down
+        # a mesh chooses a level's direction where its budget loops (PR 45):
+        # the straight-line program has no choice, and the levels both took
+        # top-down expand the same slots
+        assert not any(whole.bottom_up)
+        assert [(a, b) for a, b, up in zip(looped.hop_edges, whole.hop_edges,
+                                           looped.bottom_up) if not up and a != b] == []
+        if plane in ("plain", "pred", "armed"):
+            assert any(looped.bottom_up)      # a shard's bottom-up body ran
+    if plane in ("rows", "tomb"):
+        # a plane that holds anything keeps every level top-down
         assert not any(looped.bottom_up)
     # every level's budget is whole trips of 64, and no level fills it
     assert whole.chunks_run == whole.chunks_budget == 0

@@ -121,7 +121,8 @@ def test_a_mesh_launch_settles_the_counters_and_the_span_as_a_local_one(
     assert s.retries == 0 and looped and all(e % TRIP == 0 for e in s.e_cap)
     assert moved["tpu_bfs_runs"] == 1 and moved["tpu_bfs_levels"] == STEPS
     assert moved["tpu_bfs_levels_bottom_up"] == sum(s.bottom_up)
-    assert (parts > 1) <= (not any(s.bottom_up))
+    # a dense level goes bottom-up on one chip and, since PR 45, on a mesh
+    assert any(s.bottom_up)
     assert moved["tpu_bfs_edges"] == sum(s.hop_edges)
     assert moved["tpu_bfs_chunks_run"] == s.chunks_run > 0
     assert moved["tpu_bfs_chunks_budget"] == s.chunks_budget == \

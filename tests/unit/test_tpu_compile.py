@@ -301,38 +301,56 @@ def test_bfs_compiles(one_chip):
              _struct((P8, VMAX8), np.bool_, one_chip))
 
 
-def test_sharded_bfs_compiles_at_the_mesh_cells_size(topo):
+@pytest.mark.parametrize("have_rev", [True, False], ids=["either-way", "top-down"])
+def test_sharded_bfs_compiles_at_the_mesh_cells_size(topo, have_rev):
     """FIND SHORTEST PATH over a graph one chip refuses (the cell
     `snb-sf300-paths-proxy.bfs5-4chip`, PR 43): one part of 1,500,000
     vertices and 50,331,648 padded slots a chip, the last level's budget
     the part's whole width, which is over the traverse ladder's 2^24.
     The level loops carry one flat bitmap, so what a level needs beside
     the pinned part is its plan (4 bytes a slot, twice), and every
-    level ends in ONE all-to-all."""
+    level ends in ONE all-to-all.
+
+    With the reverse blocks (the cell's own program since PR 45) the
+    four levels whose budgets loop choose their direction: one
+    conditional each, which holds the two loops and no plan (a plan's
+    running maximum over 50 M slots compiled for 54 s inside a branch:
+    the whole program must stay near the top-down one's seconds), and
+    before it the gather of the frontier bitmap and the reduction that
+    settles the choice, a collective each."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
     from nebula_tpu.algo.frontier import LEVEL_CHUNK
-    from nebula_tpu.tpu.bfs import bfs_exchange_bytes, build_bfs_fn
+    from nebula_tpu.tpu.bfs import (bfs_exchange_bytes, bfs_gather_bytes,
+                                    build_bfs_fn)
     vmax, width = 1_500_000, 50_331_648
     assert width > 1 << 24 and width % LEVEL_CHUNK == 0
     mesh = Mesh(np.asarray(topo.devices[:P4]), ("part",))
     part = NamedSharding(mesh, PartitionSpec("part"))
     fn = build_bfs_fn(mesh, P4, (2048, 1 << 14, 1 << 18, 1 << 24, width),
-                      5, vmax)
+                      5, vmax, have_rev=have_rev)
     compiled, secs = _compile(
-        fn, (_block(P4, vmax, width, part, props=()),),
+        fn, (_block(P4, vmax, width, part, props=(), rev=have_rev),),
         _struct((P4, vmax), np.bool_, part))
     assert secs < 120, f"the sharded BFS took {secs:.0f}s to compile"
     text = compiled.as_text()
-    assert text.count(" all-to-all(") == 5 and " all-reduce(" not in text
+    choose = 4 * have_rev
+    assert fn.gather_levels == choose
+    assert text.count(" all-to-all(") == 5
+    assert text.count(" conditional(") == choose
+    # the compiler may turn the small all-gather into an all-reduce
+    assert text.count(" all-gather(") + text.count(" all-reduce(") == 2 * choose
     assert f"u32[{P4},1,{-(-vmax // 32)}]" in text
     assert bfs_exchange_bytes(P4, vmax, 5) == 5 * P4 * P4 * -(-vmax // 32) * 4
+    assert bfs_gather_bytes(P4, vmax, choose) == choose * P4 * P4 * -(-vmax // 32) * 4
     ma = compiled.memory_analysis()
     # a chip's share: the part's row offsets and neighbour ids are the
-    # arguments (no predicate, so neither `rank` nor a column; 4 bytes a
-    # slot and a vertex, the seed bitmap, some tiling), and the
-    # temporaries stay within three budget-wide int32 arrays
-    assert 4 * width < ma.argument_size_in_bytes < 1.1 * 4 * (width + vmax)
+    # arguments, once a direction pinned (no predicate, so neither `rank`
+    # nor a column; 4 bytes a slot and a vertex, the seed bitmap, some
+    # tiling), and the temporaries stay within three budget-wide int32
+    # arrays whichever way a level goes
+    ways = 1 + have_rev
+    assert ways * 4 * width < ma.argument_size_in_bytes < ways * 1.1 * 4 * (width + vmax)
     assert ma.temp_size_in_bytes < 3 * 4 * width, ma.temp_size_in_bytes
 
 
