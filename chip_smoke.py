@@ -222,7 +222,7 @@ def phase_a(sm: Smoke) -> None:
     cluster = None
     try:
         # -- data from the seed, twice over: CSVs → the reference store
-        # (tools/ldbc_import, as bench.py builds its small graph) and
+        # (tools/ldbc_import) and
         # the same rows → INSERT statements for the cluster
         ppath, kpath, lpath, n_pv, n_ke, n_le = write_snb_csvs(
             tmp, a.small_persons, a.small_degree, seed=a.seed)

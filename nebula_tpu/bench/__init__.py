@@ -1,3 +1,4 @@
-"""Benchmark harness (SURVEY §7 step 8): synthetic LDBC-SNB-shaped data
-generation + the CPU-vs-TPU measurement loop behind the repo-root bench.py."""
+"""Synthetic LDBC-SNB-shaped data generation (SURVEY §7 step 8) for
+`chip_smoke.py`, `__graft_entry__.py` and the tests; the benchmark itself
+is `benchmarks/run.py`."""
 from .datagen import make_social_graph  # noqa: F401

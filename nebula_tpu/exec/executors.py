@@ -479,7 +479,7 @@ def _traverse_device(node, qctx, ectx, ds, ci, sp, etypes, direction,
     last = d0[ridx]
     path: List[np.ndarray] = []       # per-hop frame indices, path-major
     pending = 0
-    from ..tpu.runtime import join_frontier_trails, trail_distinct_keep
+    from ..tpu.assemble import join_frontier_trails, trail_distinct_keep
     for h in range(max_hop):
         if ridx.size == 0:
             break

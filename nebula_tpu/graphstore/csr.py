@@ -176,7 +176,7 @@ def decode_prop_column_np(pt: PropType, raw: "np.ndarray",
     already has its host dtype is returned as it came, not copied.
     `has_null` is the answer to the one question the numeric fast paths
     ask (does any slot hold the kind's NULL sentinel?) where the pass
-    that built `raw` has it (tpu/runtime.py `_join_halves`); without it
+    that built `raw` has it (tpu/assemble.py `_join_halves`); without it
     the column is scanned here."""
     if pt in (PropType.FLOAT, PropType.DOUBLE):
         a = raw.astype(np.float64, copy=False)

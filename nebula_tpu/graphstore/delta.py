@@ -363,7 +363,7 @@ class HostDelta:
     def _kill_caches(self) -> None:
         # position/existence masks and the dense→vid decode array are
         # cached per snapshot object — a vertex change must kill them
-        # (tpu/match_agg._exists_flat, runtime._d2v: the latter can go
+        # (tpu/match_agg._exists_flat, assemble._d2v: the latter can go
         # stale WITHOUT a length change when a None slot gains a vid)
         for attr in ("_exists_flat", "_d2v_arr"):
             if hasattr(self.snap, attr):

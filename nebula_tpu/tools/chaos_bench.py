@@ -1,5 +1,5 @@
 """chaos-bench — seeded fault-schedule runner over a live LocalCluster
-(ISSUE 5; the fault-tolerance mirror of write_bench.py).
+(ISSUE 5).
 
 Each schedule arms a deterministic `FaultSchedule` (utils/failpoints:
 every trigger decision is drawn from `random.Random(f"{seed}:{site}")`)
@@ -26,9 +26,7 @@ the same seed.  Usage:
     python -m nebula_tpu.tools.chaos_bench                 # all schedules
     python -m nebula_tpu.tools.chaos_bench --schedule reply_loss --seed 606
 
-Emits one JSON object on stdout (CI-diffable, like write_bench);
-bench.py folds recovery-time + amplification into its `fault_recovery`
-block.
+Emits one JSON object on stdout (CI-diffable).
 """
 from __future__ import annotations
 
@@ -262,7 +260,7 @@ SCHEDULES = {
 
 def run(schedules=None, seed=None, writes: int = 40) -> dict:
     """Run the named schedules (default: all); returns per-schedule
-    metrics plus the aggregate bench.py folds into `fault_recovery`.
+    metrics plus their aggregate (`fault_recovery`).
     A broken invariant raises AFTER printing its reproducer line."""
     names = list(schedules or SCHEDULES)
     out = {"writes_per_schedule": writes, "schedules": {}}

@@ -15,7 +15,8 @@ reference: src/storage/query, src/clients/storage, src/graph/executor
   * a predicate compiler lowering nGQL expression subtrees to jnp mask
     functions with exact three-valued-logic semantics (exprjit.py);
   * a runtime with power-of-two bucket escalation for dynamic frontier /
-    expansion sizes (runtime.py);
+    expansion sizes (runtime.py), the driver of what crosses back
+    (fetch.py) and of what the caller gets of it (assemble.py);
   * the `TpuTraverse` fused plan node: executor + optimizer rule
     (traverse.py).
 

@@ -225,7 +225,7 @@ def split_halves(col: np.ndarray) -> np.ndarray:
     codes, temporal) and a `float64` alike travel as their bits, so a
     carried value comes back to the bit on every backend.  hop.py
     `take_halves` gathers a pair, `join_halves` rebuilds a gathered
-    slot's 64-bit value for a predicate, runtime.py `_join_halves`
+    slot's 64-bit value for a predicate, assemble.py `_join_halves`
     joins fetched halves on the host."""
     if col.dtype.itemsize != 8 or sys.byteorder != "little":
         raise TypeError(f"not a 64-bit little-endian column: {col.dtype}")

@@ -747,10 +747,16 @@ def yieldable(e: "E.Expr") -> bool:
 
 
 def eval_yield_column_np(e: "E.Expr", b: Dict[str, Any]) -> "np.ndarray":
-    """eval_yield_column, columnar: returns numpy arrays (object dtype
-    for vids/strings, native dtype for numeric prop columns) with no
-    per-element tolist — the ColumnarDataSet fast path.  `b["props"]`
-    must hold numpy arrays (decode_prop_column_np)."""
+    """Evaluate one absorbed YIELD column over a materialized block,
+    columnar: returns numpy arrays (object dtype for vids/strings,
+    native dtype for numeric prop columns) with no per-element tolist —
+    the ColumnarDataSet fast path.
+
+    b: {"et", "etype" (signed), "n", "sv", "dv", "rr", "props"} from
+    tpu/assemble.py `_block_columns`, `b["props"]` numpy arrays
+    (decode_prop_column_np).  For reverse ("in") blocks etype < 0 and
+    sv is the frontier vertex — the PHYSICAL edge is dv→sv, matching
+    Edge(sv, dv, etype=-id) built by the row materializer."""
     import numpy as np
 
     from ..core.value import NULL_UNKNOWN_PROP
@@ -789,47 +795,5 @@ def eval_yield_column_np(e: "E.Expr", b: Dict[str, Any]) -> "np.ndarray":
         col = b["props"].get(pname)
         if col is None:
             return _const(NULL_UNKNOWN_PROP)
-        return col
-    raise CannotCompile(f"yield not columnar: {e.kind}")
-
-
-def eval_yield_column(e: "E.Expr", b: Dict[str, Any]) -> List[Any]:
-    """Evaluate one absorbed YIELD column over a materialized block.
-
-    b: {"et", "etype" (signed), "n", "sv", "dv", "rr", "props"} from
-    TpuRuntime._block_columns.  For reverse ("in") blocks etype < 0 and
-    sv is the frontier vertex — the PHYSICAL edge is dv→sv, matching
-    Edge(sv, dv, etype=-id) built by the row materializer.
-    """
-    from ..core.value import NULL_UNKNOWN_PROP
-    n = b["n"]
-    fwd = b["etype"] >= 0
-    if e.kind == "literal":
-        return [e.value] * n
-    if e.kind == "function":
-        name = e.name
-        if name == "src":       # physical source
-            return (b["sv"] if fwd else b["dv"]).tolist()
-        if name == "dst":
-            return (b["dv"] if fwd else b["sv"]).tolist()
-        if name == "rank":
-            return b["rr"].tolist()
-        if name == "type":
-            return [b["et"]] * n
-        if name == "typeid":
-            return [b["etype"]] * n
-    if e.kind == "edge_prop":
-        pname = e.name
-        if pname == "_src":
-            return (b["sv"] if fwd else b["dv"]).tolist()
-        if pname == "_dst":
-            return (b["dv"] if fwd else b["sv"]).tolist()
-        if pname == "_rank":
-            return b["rr"].tolist()
-        if pname == "_type":
-            return [b["et"]] * n
-        col = b["props"].get(pname)
-        if col is None:
-            return [NULL_UNKNOWN_PROP] * n
         return col
     raise CannotCompile(f"yield not columnar: {e.kind}")

@@ -598,7 +598,7 @@ def _compact_cap(cols, keep, n, EB: int, tail: int = 0,
     Why: capture arrays are EB-padded and EB is sized for the worst hop
     (millions of slots); fetching them wholesale ships mostly padding
     (~2 GB/query at north-star shape).  With kept entries compacted to a
-    prefix the host fetches only [:kmax] slices (runtime._fetch).
+    prefix the host fetches only [:kmax] slices (fetch.py `Fetcher.fetch`).
     The scatter is order-preserving, so the (part, src)-contiguous
     ascending-eidx invariant the host materializers rely on survives.
 
@@ -1029,7 +1029,7 @@ def build_traverse_fn(mesh, P: int, EB, steps: int, n_blocks: int, *,
     is uniform over a variable-length expansion, unlike GO's final-step
     WHERE) and the edge frame of every hop is captured — cap arrays gain
     a hop axis, lead + (steps, n_blocks, EB).  The host assembles
-    trail-semantics paths from the layered frames (runtime.py).
+    trail-semantics paths from the layered frames (assemble.py).
     """
     ebs = _norm_ebs(EB, steps, capture_hops)
     hubs_c, hub_owner, hub_local = _hub_consts(hub_dense, P)

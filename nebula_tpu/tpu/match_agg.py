@@ -49,6 +49,7 @@ from ..core.value import DataSet, Vertex, is_null
 from ..exec.executors import executor, _make_edge
 from ..query import optimizer as opt
 from ..query.plan import PlanNode
+from .assemble import _d2v, join_frontier_trails, trail_distinct_keep
 from .device import TpuUnavailable, note_host_fallback
 from .exprjit import (CannotCompile, compile_vertex_predicate_np,
                       vertex_compilable)
@@ -454,7 +455,6 @@ def _tpu_match_agg(node, qctx, ectx, space):
 
 
 def _device_match_agg(node, qctx, ectx, a, rt):
-    from .runtime import _d2v, join_frontier_trails, trail_distinct_keep
     sp = a["space"]
     store = qctx.store
     try:

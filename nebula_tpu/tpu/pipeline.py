@@ -58,6 +58,8 @@ from ..utils import trace
 from ..utils.failpoints import FailpointError, fail
 from ..utils.config import define_flag, get_config
 from ..utils.stats import stats
+from .assemble import (HopFrame, TraverseStats, _d2v, join_frontier_trails,
+                       trail_distinct_keep)
 from .device import TpuUnavailable, note_host_fallback
 from .exprjit import (CannotCompile, compilable,
                       compile_vertex_predicate_np, vertex_compilable)
@@ -655,10 +657,8 @@ class _Runner:
         self.store, self.sd = store, sd
         self.dev = rt.pin(store, space)
         self.snap = self.dev.host
-        from .runtime import _d2v
         self.d2v = _d2v(self.snap)
         self.regs: List[ColumnarFrame] = []
-        from .runtime import TraverseStats
         self.stats = TraverseStats()
 
     # -- ops -------------------------------------------------------------
@@ -800,7 +800,6 @@ class _Runner:
                 op["direction"], steps, edge_filter=op["edge_filter"])
             self._merge_stats(st)
         else:
-            from .runtime import HopFrame
             frames = [HopFrame.empty() for _ in range(steps)]
 
         tracker = getattr(self.ectx, "tracker", None)
@@ -818,7 +817,6 @@ class _Runner:
             if min_hop == 0:
                 em_ord.append(sidx.copy())
                 em_dst.append(seed_dense.copy())
-            from .runtime import join_frontier_trails, trail_distinct_keep
             for h in range(steps):
                 if last.size == 0 or frames[h].n == 0:
                     break
@@ -851,7 +849,6 @@ class _Runner:
 
         # fixed-length (possibly merged) chain: assemble trails hop by
         # hop, pruning each mid position by its absorbed AppendVertices
-        from .runtime import join_frontier_trails
         sidx = np.arange(n_seeds, dtype=np.int64)
         vcols = [seed_dense]
         path: List[np.ndarray] = []

@@ -1,16 +1,17 @@
-"""TpuRuntime: snapshot pinning lifecycle + traversal dispatch.
+"""TpuRuntime: what is pinned, and what runs.
 
 Owns the mesh, the per-space DeviceSnapshots (epoch-checked against the
-host store: a write bumps the space epoch, the next traversal re-pins —
-the serve-epoch-N-while-building-N+1 model of SURVEY §7 hard-part #6 in
-its simplest correct form), the jit cache keyed by bucket configuration,
-and the power-of-two escalation loop around the hop kernel.
+host store: a write bumps the space epoch, the next traversal applies
+the delta or re-pins — the serve-epoch-N-while-building-N+1 model of
+SURVEY §7 hard-part #6 in its simplest correct form) with their delta
+planes, the jit cache keyed by bucket configuration, the seed put, the
+dispatch gate, the power-of-two escalation driver around every device
+program, and the statement entries (`traverse`, `traverse_hops`, `bfs`).
 
-The host materialization contract: the device returns (src, dst, eidx,
-and rank where something reads it) per block, kept entries compacted to
-a prefix; property decode happens on host straight out of
-the numpy CsrSnapshot columns at eidx — properties cross HBM only when
-a predicate needs them.
+It is the driver of two modules that know nothing of it: what crosses
+back from the device after a launch is `fetch.py`'s (`Fetcher`), and
+what the caller gets of it (rows, columns, hop frames, and the result
+types `TraverseStats` / `HopFrame`) is `assemble.py`'s.
 """
 from __future__ import annotations
 
@@ -19,7 +20,7 @@ import logging
 import os
 import threading
 import time
-from concurrent.futures import ThreadPoolExecutor, wait as futures_wait
+from concurrent.futures import ThreadPoolExecutor
 from contextlib import contextmanager, nullcontext
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -29,22 +30,20 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
 from ..core import expr as E
-from ..core.value import ColumnarDataSet, Edge
-from ..graphstore.csr import (NUMERIC_KINDS, build_snapshot,
-                              decode_prop_column, decode_prop_column_np)
+from ..graphstore.csr import build_snapshot
 from ..graphstore.delta import (DeltaOverflow, DeltaUnsupported, HostDelta,
-                                fold_base, pad_edge_width,
-                                pow2 as _delta_pow2)
+                                fold_base, pad_edge_width, pow2)
 from ..graphstore.store import GraphStore
-from ..native.kernels import join_halves as native_join_halves
 from ..utils import trace as _t
 from ..utils.config import get_config
 from ..utils.stats import stats as _metrics
+from . import assemble
+from .assemble import HopFrame, TraverseStats
 from .device import (DeviceDelta, DeviceSnapshot, SnapshotRetired,
                      TpuUnavailable, make_mesh, mesh_lanes, mesh_parts, note_host_fallback,
                      pin_snapshot, put_delta_blocks)
-from .exprjit import (CannotCompile, compile_predicate, eval_yield_column,
-                      eval_yield_column_np)
+from .exprjit import compile_predicate
+from .fetch import _ENGAGEMENT, Fetcher
 from .hop import a2a_payload_bytes, build_traverse_fn
 
 
@@ -85,158 +84,19 @@ def _on_live_snapshot(fn):
     return run
 
 
-def _pow2(n: int) -> int:
-    p = 1
-    while p < n:
-        p <<= 1
-    return p
-
-
-def _d2v(host) -> np.ndarray:
-    """Cached dense-id → vid array for batch vid decode (shared by the
-    GO materializer and the MATCH frame builder).  INT64 when every vid
-    is an int (the common case — object-array gathers over millions of
-    result edges cost ~10× an int64 gather), object otherwise."""
-    arr = getattr(host, "_d2v_arr", None)
-    if arr is None or len(arr) != len(host.dense_to_vid):
-        d2v = host.dense_to_vid
-        # gate on an ACTUAL int vid: np.asarray would happily parse
-        # digit STRINGS ('12' → 12), silently retyping FIXED_STRING
-        # results — a space's vids are homogeneous, so one sample
-        # decides (None slots are deleted vids → object path)
-        sample = next((v for v in d2v if v is not None), None)
-        if isinstance(sample, int) and not isinstance(sample, bool):
-            try:
-                arr = np.asarray(d2v, dtype=np.int64)
-            except (TypeError, ValueError, OverflowError):
-                arr = np.asarray(d2v, dtype=object)
-        else:
-            arr = np.asarray(d2v, dtype=object)
-        # sequential-int-vid spaces (LDBC-style imports, the array
-        # ingest path) have dense == vid: one cached pass here lets the
-        # materializers skip a multi-million-row identity gather per
-        # query (~0.65 s at north-star scale on the bench host).
-        # Identity flag is published BEFORE the array: a concurrent
-        # reader that sees the cached array must also see the flag.
-        host._d2v_identity = bool(
-            arr.dtype.kind == "i"
-            and (arr == np.arange(len(arr), dtype=arr.dtype)).all())
-        host._d2v_arr = arr
-    return arr
-
-
-def _cap_keys_for_yields(yields, device_props=()) -> Optional[set]:
-    """Which capture arrays a yield list reads: a subset of {'src',
-    'dst','rank','eidx'} plus 'prop:<name>' for props the kernel
-    gathers on device, or None (fetch everything) when a yield isn't
-    fully recognized.  Mirrors eval_yield_column_np's access pattern."""
-    if yields is None:
-        return None
-    need = set()
-    for e, _ in yields:
-        for x in E.walk(e):
-            k = x.kind
-            # exactly the kinds the fusion gate (exprjit.yieldable)
-            # admits — anything else means this walker is stale vs the
-            # eval surface, so fetch everything
-            if k in ("literal", "function", "edge_prop", "edge"):
-                if k == "function":
-                    name = getattr(x, "name", "")
-                    if name == "src":
-                        need.add("src")
-                    elif name == "dst":
-                        need.add("dst")
-                    elif name == "rank":
-                        need.add("rank")
-                    elif name in ("type", "typeid"):
-                        pass             # per-block constants
-                    else:
-                        return None      # unknown function: fetch all
-                elif k == "edge_prop":
-                    if x.name == "_rank":
-                        need.add("rank")
-                    elif x.name == "_src":
-                        need.add("src")
-                    elif x.name == "_dst":
-                        need.add("dst")
-                    elif x.name == "_type":
-                        pass             # per-block constant
-                    elif x.name in device_props:
-                        need.add("prop:" + x.name)
-                    else:
-                        need.add("eidx")
-            else:
-                return None              # unmodeled expr: fetch all
-    return need
-
-
-def _join_halves(parts, dtype) -> Tuple[np.ndarray, bool]:
-    """Fetched pieces of a property column's 32-bit halves, each
-    `(2, n)` (device.py `split_halves`), as ONE owned 64-bit column of
-    `dtype`, and whether any slot of it holds the kind's NULL sentinel:
-    the join rides the pass that concatenates the pieces, and the
-    decode's one question rides the join (native/kernels.py
-    `join_halves`: one pass a piece)."""
-    out = np.empty(sum(a.shape[-1] for a in parts), dtype)
-    return out, any([native_join_halves(a, o)
-                     for a, o in _piece_slices(parts, out)])
-
-
-def _piece_slices(parts, out):
-    """Each piece beside the slice of `out` it fills, in slot order."""
-    at = 0
-    for a in parts:
-        to = at + a.shape[-1]
-        yield a, out[at:to]
-        at = to
-
-
-def _cat_parts(parts, dtype=None):
-    """Concatenate per-part kept-prefix slices of a capture array (the
-    device compacts kept entries to the front of each part row) —
-    contiguous slices instead of a 2D fancy gather, preserving
-    (part, slot) order.
-    Always returns an owned array: a view of the K-padded capture
-    buffer must not escape into long-lived results (it would pin the
-    whole bucket for a handful of rows)."""
-    if dtype is not None:
-        if len(parts) > 1:
-            return np.concatenate(parts, dtype=dtype)   # one pass
-        return parts[0].astype(dtype)
-    if len(parts) > 1:
-        return np.concatenate(parts)
-    return parts[0].copy()
-
-
-def _whole(pieces) -> np.ndarray:
-    """A fetched capture row (its pieces in slot order) as one array."""
-    return pieces[0] if len(pieces) == 1 else np.concatenate(pieces, axis=-1)
-
-
-def _pieces(rows, perms=None):
-    """The fetched rows of one capture column, each its pieces in slot
-    order (`_fetch`), as one flat list of pieces; `perms` re-orders
-    each row first (the delta plane's canonical CSR order)."""
-    if perms is None:
-        return [a for pieces in rows for a in pieces]
-    return [_whole(pieces) if pm is None else _whole(pieces)[..., pm]
-            for pieces, pm in zip(rows, perms)]
-
-
-def _cat_rows(rows, perms=None, dtype=None):
-    """The fetched rows of an identity column (src, dst, rank, eidx) as
-    one owned array of `dtype`."""
-    return _cat_parts(_pieces(rows, perms), dtype)
-
-
-# Row assembly hands a LARGE statement's piece-passes to a few threads:
-# a pass writes a disjoint slice of its column, the native join holds no
-# GIL and neither does numpy's typed copy, so the pieces of a block's
-# columns (parts x columns of them) are independent tasks.  Under
-# POOL_MIN_ROWS kept rows the handoff costs more than it buys and the
-# serial passes run (PERF.md section 6, PR 40, has the sweep on the
-# chip's host).  One pool a process, made at the first statement that
-# needs it; none on a host with one core.
+# Which host threads a statement's row assembly may use is decided
+# here, and handed to tpu/assemble.py as `_pool_for`: assembly hands a
+# LARGE block's piece-passes to a few threads (a pass writes a disjoint
+# slice of its column, the native join holds no GIL and neither does
+# numpy's typed copy, so the pieces of a block's columns are independent
+# tasks).  Under POOL_MIN_ROWS kept rows the handoff costs more than it
+# buys and the serial passes run (PERF.md section 6, PR 40, has the
+# sweep on the chip's host).  One pool a process, made at the first
+# statement that needs it; none on a host with one core.
+# POOL_MIN_ROWS, _POOL_WIDTH and _assembly_pool are the only names this
+# module keeps for another's sake: tests/benchmark/test_one_pass.py
+# (frozen) patches and calls them HERE, so they are read here, at call
+# time, and not in the module that does the assembling.
 POOL_MIN_ROWS = 1 << 20
 _POOL_WIDTH = min(4, os.cpu_count() or 1)
 _pool_lock = threading.Lock()
@@ -253,63 +113,10 @@ def _assembly_pool() -> Optional[ThreadPoolExecutor]:
     return _pool
 
 
-def _fill(piece, out) -> bool:
-    """One piece-pass into its slice of a column: a property column's
-    halves joined (-> the NULL answer), an identity column's piece
-    copied into the column's dtype."""
-    if piece.ndim == 2:
-        return native_join_halves(piece, out)
-    out[:] = piece
-    return False
-
-
-def _cat_side_by_side(pool, columns):
-    """`columns` ([(pieces, dtype)], each a flat piece list as `_pieces`
-    gives it) assembled by `pool`, every piece a task: -> [(column,
-    NULL answer)] as `_cat_parts` and `_join_halves` would give them one
-    after another.  A pass that raises is the statement's error, once
-    every other pass has ended."""
-    outs = [np.empty(sum(a.shape[-1] for a in parts),
-                     parts[0].dtype if dtype is None else dtype)
-            for parts, dtype in columns]
-    tasks = [[pool.submit(_fill, a, o) for a, o in _piece_slices(parts, out)]
-             for (parts, _), out in zip(columns, outs)]
-    futures_wait([f for fs in tasks for f in fs])
-    return [(out, any([f.result() for f in fs]))
-            for out, fs in zip(outs, tasks)]
-
-
-def _merged_gather(col, de, name: str, p, e):
-    """Column `col` (P, Emax) of a block at part(s) `p` and captured
-    edge indices `e`; with a live delta entry `de`, the entries from
-    Emax on are the view's numpy mirror's (a delta row carries the
-    virtual eidx Emax + slot).  Two gathers: the base column is never
-    copied to be extended."""
-    emax = col.shape[1]
-    if np.ndim(p) == 0:
-        col = col[p]            # one part: a row view, then a 1-D take
-        late = None if de is None else e >= emax
-        if late is None or not late.any():
-            return col[e]
-        got = col[np.minimum(e, emax - 1)]
-        got[late] = de["np"]["d_props"][name][p, e[late] - emax]
-        return got
-    late = None if de is None else e >= emax
-    if late is None or not late.any():
-        return col[p, e]
-    got = col[p, np.minimum(e, emax - 1)]
-    got[late] = de["np"]["d_props"][name][p[late], e[late] - emax]
-    return got
-
-
-def _delta_rows_of(dview, bk):
-    """The delta view's entry for block `bk` where the plane HOLDS rows
-    of it, else None: what the host steps of a live view follow (the
-    identity columns in the fetch, the per-part re-sort, the mirror
-    decode).  Tombstones alone need none of them: a dropped base row
-    leaves the others in their order."""
-    e = None if dview is None else dview[1].get(bk)
-    return e if e is not None and any(e["rows"]) else None
+def _pool_for(n_rows: int) -> Optional[ThreadPoolExecutor]:
+    """The pool a block of `n_rows` kept rows is assembled by, or None
+    for the serial passes."""
+    return _assembly_pool() if n_rows >= POOL_MIN_ROWS else None
 
 
 class _DispatchGate:
@@ -377,459 +184,6 @@ class _DispatchGate:
             return bool(self._writer or self._writers_waiting)
 
 
-# Result keys of a traverse program (hop.py `_traverse`) that say how far
-# its by-need loops and member plans engaged, lead + (steps,); summed
-# they are the TraverseStats fields of the same names.  A BFS program
-# (bfs.py) returns the first two for its level loops.
-_ENGAGEMENT = ("chunks_run", "chunks_budget", "plan_run", "plan_budget")
-
-# The result leaves some caller reads, the only ones `_fetch` brings to
-# the host besides the capture: the ladder's counts and flags, the kept
-# counts, the work counters, BFS's depths and the direction each of its
-# levels took.  The post-final `frontier` bitmap and its `fcount` stay on
-# the device and die with the rung (vmax bools a part: more bytes than a
-# mean four-chip statement's rows).
-_FETCHED = ("hop_edges", "ovf_expand", "kcount", "frontier_sizes",
-            "dist", "bottom_up") + _ENGAGEMENT
-
-# How a capture leaves the device (`_fetch`).  A ROW is one index of a
-# capture array's lead + (nb,) axes with its own kept count; kept
-# entries are a prefix of their row (hop.py `_compact_cap`), so only
-# prefixes are shipped, cut by programs that depend on the capture's
-# shape and the columns read alone and are compiled when the traverse
-# program first runs for those columns (`TpuRuntime._warm_fetch`),
-# never when a kept size is first met.
-#
-# W < 2 * SLICE_MAX, i.e. a hop budget of at most SLICE_MAX (the served
-# statements' 8,192 and 65,536 slots; budgets are powers of two, and an
-# armed delta plane's tail widens a capture by less than its budget, so
-# the plane never moves a capture to the other taker): ONE slice of
-# every row, `v[..., :k]`, k a power of two from SLICE_MIN up (the
-# whole width last), speculated from the program's last run.
-#
-# Wider: every row apart, in pieces cut on the device that holds it, so
-# the bytes follow each row's own count (not the fullest row's, rounded
-# up, for all).  What the chips charged when the constants below were
-# settled (PERF.md section 6, PR 31, has the tables): a piece 0.6 ms on
-# the four-chip host (its launch and a transfer a column) to 3 ms on
-# one chip however small it is (there most of it the split of a whole
-# 64-bit operand before the slice, which no capture holds since PR 35:
-# a property column is its 32-bit halves, `(2, size)` a piece), a
-# byte 0.3 to 0.5 ns, and a second round trip waits behind whatever
-# another session has on the chips.  Hence: a row comes in ONE piece of
-# the smallest of PIECES that holds it unless a second piece saves
-# PIECE_WORTH slots; and a row that last kept at most SPEC_ROWS comes
-# speculatively, one piece of at most SPEC_SLOTS, with the meta (the
-# median statement of the four-chip cell then makes one round trip),
-# while a longer row is not guessed at (a wrong guess would cost more
-# than the round trip, a hundredth of its transfer).
-SLICE_MIN, SLICE_MAX = 1 << 7, 1 << 16
-PIECES = tuple(1 << i for i in range(11, 22))
-PIECE_WORTH = 1 << 16
-SPEC_SLOTS, SPEC_ROWS = 1 << 15, 1 << 17
-
-
-@functools.partial(jax.jit, static_argnames="k")
-def _head(cap, k: int):
-    return {n: v[..., :k] for n, v in cap.items()}
-
-
-@functools.partial(jax.jit, static_argnames="size")
-def _piece(cap, at, size: int):
-    """`size` slots of one row of each of these capture columns, from
-    `at` = the row's index and the first slot, both traced: one
-    executable a capture shape, column set and size.  A start past
-    W - size is clamped to it (`lax.dynamic_slice`).  A column comes
-    flat, `(size,)`; a property column (one axis more, its halves,
-    before the slots) as `(2, size)`: ONE array and one transfer a
-    column either way."""
-    row = [at[i] for i in range(at.shape[0] - 1)]
-
-    def cut(v):
-        halves = (2,) * (v.ndim - at.shape[0])
-        return jax.lax.dynamic_slice(
-            v, row + [jnp.int32(0)] * len(halves) + [at[-1]],
-            (1,) * len(row) + halves + (size,)).reshape(halves + (size,))
-    return {n: cut(v) for n, v in cap.items()}
-
-
-def _nbytes(tree) -> int:
-    return sum(a.nbytes for a in jax.tree.leaves(tree))
-
-
-def _taker(cap_dev, want=None):
-    """The way this capture's rows leave the device, by its width; of
-    its columns the host takes those in `want` (all of them if None)."""
-    wide = next(iter(cap_dev.values())).shape[-1] >= 2 * SLICE_MAX
-    return (_Pieces if wide else _Heads)(cap_dev, want)
-
-
-class _Heads:
-    """The kept prefixes of a capture whose hop budget is at most
-    SLICE_MAX slots (narrower than twice that with a delta plane's
-    tail), as one slice of every row.  `speculate(counts)` and `ask(counts)`
-    return the device arrays that cover rows of these kept counts (the
-    last run's, this run's), or None where there is nothing to ask for
-    beyond what was asked before; `got` takes them once on the host;
-    `rows` is the fetched capture: per column an object array over the
-    rows, of each row's pieces in slot order, trimmed to its kept
-    count (a property column's pieces are its halves, `(2, n)`, which
-    `_join_halves` joins).  The programs run over the wanted columns
-    together (one launch, not one a column), so they are compiled for a
-    program's key AND the columns its statement reads
-    (`TpuRuntime._warm_fetch`)."""
-
-    def __init__(self, cap_dev, want=None):
-        self.dev = cap_dev
-        self.want = [n for n in cap_dev if want is None or n in want]
-        self.W = next(iter(cap_dev.values())).shape[-1]
-        # the axes that index a row, lead + (nb,): all but the slots,
-        # and but a property column's halves
-        self.nrow = min(v.ndim for v in cap_dev.values()) - 1
-        self.k = 0
-        self.host: Dict[str, np.ndarray] = {}
-        self.nbytes = 0
-
-    def item_bytes(self) -> int:
-        """Bytes of one kept entry over the wanted columns (a property
-        column's two halves: 8)."""
-        return sum(self.dev[n].dtype.itemsize * (self.dev[n].ndim - self.nrow)
-                   for n in self.want)
-
-    def _k(self, n: int) -> int:
-        return min(self.W, max(SLICE_MIN, _pow2(n)))
-
-    def warm(self):
-        cols = {n: self.dev[n] for n in self.want}
-        for k in sorted({self._k(1 << i)
-                         for i in range(self.W.bit_length() + 1)}):
-            _head(cols, k)
-
-    def ask(self, counts):
-        k = self._k(int(np.max(counts, initial=0)))
-        if k <= self.k:
-            return None
-        self.k = k
-        return _head({n: self.dev[n] for n in self.want}, k)
-
-    speculate = ask
-
-    def got(self, host):
-        self.host = host
-        self.nbytes += _nbytes(host)
-
-    def rows(self, kc):
-        cap = {}
-        for n, a in self.host.items():
-            col = cap[n] = np.empty(kc.shape, object)
-            for idx in np.ndindex(kc.shape):
-                col[idx] = [a[idx][..., :kc[idx]]]
-        return cap
-
-
-class _Pieces(_Heads):
-    """The same of a wider capture, every row in pieces cut on the
-    device that holds it: a sharded column is read shard by shard
-    (`addressable_shards`), so no slice crosses chips and `device_get`
-    assembles nothing.  `ask` cuts only what lies past the pieces
-    already asked for: an undershot speculation fetches a tail, never
-    the prefix again."""
-
-    def __init__(self, cap_dev, want=None):
-        super().__init__(cap_dev, want)
-        # where its rows lie in the whole -> a shard's wanted columns
-        self.shards: Dict[Tuple, Dict[str, Any]] = {}
-        for n in self.want:
-            for s in cap_dev[n].addressable_shards:
-                if s.replica_id == 0:
-                    base = tuple(sl.start or 0
-                                 for sl in s.index[:self.nrow])
-                    self.shards.setdefault(base, {})[n] = s.data
-        self.sizes = [c for c in PIECES if c <= self.W]
-        self.have: Dict[Tuple, int] = {}    # row -> slots asked for
-        # of each piece asked for: its row, and that it holds the row's
-        # slots [slot, slot + c) from its own `skip` on
-        self.asked: List[Tuple] = []
-        self.host: List[Dict[str, np.ndarray]] = []
-
-    def _size(self, n: int) -> int:
-        """The smallest piece that holds n slots (the largest if none)."""
-        return next((c for c in self.sizes if c >= n), self.sizes[-1])
-
-    def _cut(self, out, cols, idx, row, slot, c):
-        start = min(slot, self.W - c)
-        out.append(_piece(cols, np.asarray(idx + (start,), np.int32), c))
-        self.asked.append((row, slot, slot - start, c))
-        self.have[row] = slot + c
-
-    def _rows(self):
-        for base, cols in self.shards.items():
-            lead = next(iter(cols.values())).shape[:self.nrow]
-            for idx in np.ndindex(lead):
-                yield cols, idx, tuple(b + i for b, i in zip(base, idx))
-
-    def warm(self):
-        at = np.zeros(self.nrow + 1, np.int32)
-        for cols in self.shards.values():
-            for c in self.sizes:
-                _piece(cols, at, c)
-
-    def speculate(self, counts):
-        counts = np.broadcast_to(counts, next(
-            iter(self.dev.values())).shape[:self.nrow])
-        out = []
-        for cols, idx, row in self._rows():
-            if 0 < counts[row] <= SPEC_ROWS:
-                self._cut(out, cols, idx, row, 0,
-                          self._size(min(int(counts[row]), SPEC_SLOTS)))
-        return out or None
-
-    def ask(self, counts):
-        out = []
-        for cols, idx, row in self._rows():
-            slot, kept = self.have.get(row, 0), int(counts[row])
-            while slot < kept:
-                c = self._size(kept - slot)
-                half = c // 2
-                if half in self.sizes and kept - slot > half and \
-                        half - self._size(kept - slot - half) >= PIECE_WORTH:
-                    c = half
-                self._cut(out, cols, idx, row, slot, c)
-                slot += c
-        return out or None
-
-    def got(self, host):
-        self.host.extend(host)
-        self.nbytes += _nbytes(host)
-
-    def rows(self, kc):
-        cap = {n: np.empty(kc.shape, object) for n in self.want}
-        for col in cap.values():
-            for row in np.ndindex(kc.shape):
-                col[row] = []
-        for (row, slot, skip, c), piece in zip(self.asked, self.host):
-            end = skip + min(c, int(kc[row]) - slot)
-            if end > skip:
-                for n, col in cap.items():
-                    col[row].append(piece[n][..., skip:end])
-        return cap
-
-
-class TraverseStats:
-    __slots__ = ("hop_edges", "frontier_sizes", "result_edges", "f_cap",
-                 "e_cap", "retries", "device_s", "steps",
-                 "pin_s", "put_s", "fetch_s", "mat_s", "total_s",
-                 "compiles", "hbm_bytes", "segments", "queue_s",
-                 "shards", "exchange_bytes", "chunks_run",
-                 "chunks_budget", "plan_run", "plan_budget",
-                 "fetch_bytes", "fetch_bytes_kept", "bottom_up")
-
-    def __init__(self):
-        self.hop_edges: List[int] = []
-        self.frontier_sizes: List[int] = []   # popcount entering each hop
-        self.result_edges = 0
-        self.f_cap = 0
-        self.e_cap = 0
-        self.retries = 0
-        self.device_s = 0.0
-        self.steps = 0
-        # per-phase wall time (PROFILE device-plane fields)
-        self.pin_s = 0.0
-        self.put_s = 0.0
-        self.fetch_s = 0.0
-        self.mat_s = 0.0
-        self.total_s = 0.0
-        # kernel-ledger fields (ISSUE 8): fresh XLA compiles this run
-        # paid for (vs jit-cache hits) and the HBM high-water at
-        # dispatch time; `segments` carries per-segment rows for fused
-        # pipelines (tpu/pipeline.py fills it)
-        self.compiles = 0
-        self.hbm_bytes = 0
-        self.segments: List[dict] = []
-        # dispatch-gate wait before the kernel could run (ISSUE 9):
-        # the queue-wait half of the wait-vs-run decomposition
-        self.queue_s = 0.0
-        # mesh facts (PR 17): part-axis shards this dispatch spanned and
-        # the bit-packed frontier all_to_all payload it moved (0 in
-        # single-chip local mode — there is no exchange)
-        self.shards = 1
-        self.exchange_bytes = 0
-        # by-need engagement (PR 25, hop.py _by_need): loop trips the
-        # hops' per-slot stages ran and the trips their edge budgets
-        # hold, summed over hops and parts; both 0 when every hop's
-        # budget fits one chunk (straight-line program)
-        self.chunks_run = 0
-        self.chunks_budget = 0
-        # member-plan engagement (PR 29, hop.py _expand_plan): scatter
-        # updates the hops' expansion plans issued and what plans over
-        # every local vertex issue, summed over hops, blocks and parts;
-        # both 0 when every bitmap is narrow enough for the whole-bitmap
-        # plan
-        self.plan_run = 0
-        self.plan_budget = 0
-        # bytes the launch's fetches brought to the host, and those of
-        # them that are kept capture entries (`_fetch`)
-        self.fetch_bytes = 0
-        self.fetch_bytes_kept = 0
-        # a BFS's levels that went bottom-up (bfs.py's switch), one flag
-        # a level; empty for every other program
-        self.bottom_up: List[bool] = []
-
-    def edges_traversed(self) -> int:
-        return int(sum(self.hop_edges))
-
-
-class HopFrame:
-    """One hop's captured edge set, columnar, indexed for path assembly.
-
-    src/dst: (n,) int64 dense vertex ids in capture order (block-major,
-    then part, then per-src CSR slot order — matching the host
-    get_neighbors iteration).  Edge OBJECTS are decoded lazily: the
-    vectorized trail assembly touches only the entries that land on an
-    emitted path, and the full `.edges` object array is built only for
-    the DFS consumers (algorithms.py) that ask for it.
-
-    Trail-dedup identity is columnar too: (key_et, key_s, key_d, rank)
-    is the canonical physical-edge key (reverse-direction copies of one
-    logical edge canonicalize equal), compared component-wise — no
-    per-edge Python hashing.
-    """
-    __slots__ = ("src", "dst", "rank", "n", "order", "_us", "_ustart",
-                 "_ucnt", "key_et", "key_s", "key_d",
-                 "_segs", "_decode_seg", "_eobjs", "_edone", "_all_done")
-
-    @classmethod
-    def empty(cls) -> "HopFrame":
-        f = cls()
-        f.src = np.empty((0,), np.int64)
-        f.dst = np.empty((0,), np.int64)
-        f.rank = np.empty((0,), np.int64)
-        f.key_et = np.empty((0,), np.int64)
-        f.key_s = np.empty((0,), np.int64)
-        f.key_d = np.empty((0,), np.int64)
-        f.n = 0
-        f.order = np.empty((0,), np.int64)
-        f._us = np.empty((0,), np.int64)
-        f._ustart = np.empty((0,), np.int64)
-        f._ucnt = np.empty((0,), np.int64)
-        f._segs = []
-        f._decode_seg = None
-        f._eobjs = np.empty((0,), object)
-        f._edone = None
-        f._all_done = True
-        return f
-
-    @classmethod
-    def build(cls, src, dst, rank, key_et, key_s, key_d, segs,
-              decode_seg) -> "HopFrame":
-        """segs: list of (seg_start, seg_end, payload); decode_seg(
-        payload, offsets) -> list[Edge] decodes a segment's entries at
-        `offsets` (segment-relative)."""
-        if src is None or src.size == 0:
-            return cls.empty()
-        f = cls()
-        f.src, f.dst, f.rank = src, dst, rank
-        f.key_et, f.key_s, f.key_d = key_et, key_s, key_d
-        f.n = src.size
-        f.order = np.argsort(src, kind="stable")
-        ss = src[f.order]
-        starts = np.flatnonzero(np.concatenate(
-            [[True], ss[1:] != ss[:-1]]))
-        f._us = ss[starts]
-        f._ustart = starts
-        f._ucnt = np.diff(np.concatenate([starts, [ss.size]]))
-        f._segs = segs
-        f._decode_seg = decode_seg
-        f._eobjs = None
-        f._edone = None
-        f._all_done = False
-        return f
-
-    def out_edges(self, dense_id: int):
-        """Indices (into src/dst/edges) of this hop's edges out of
-        dense_id, in CSR order."""
-        p = np.searchsorted(self._us, dense_id)
-        if p >= self._us.size or self._us[p] != dense_id:
-            return ()
-        return self.order[self._ustart[p]:self._ustart[p]
-                          + self._ucnt[p]]
-
-    def src_slices(self):
-        """(us, ustart, ucnt): sorted unique srcs with their slice into
-        `order` — the vectorized join's lookup table."""
-        return self._us, self._ustart, self._ucnt
-
-    def decode(self, idx: np.ndarray) -> np.ndarray:
-        """Edge objects for frame indices `idx` (object array, aligned
-        with idx).  Decodes each entry at most once across calls."""
-        if self._eobjs is None:
-            self._eobjs = np.full((self.n,), None, dtype=object)
-            self._edone = np.zeros((self.n,), bool)
-        eo = self._eobjs
-        if idx.size:
-            uniq = np.unique(idx)
-            need = uniq[~self._edone[uniq]]
-            for (s0, s1, payload) in self._segs:
-                m = need[(need >= s0) & (need < s1)]
-                if m.size == 0:
-                    continue
-                eo[m] = self._decode_seg(payload, m - s0)
-                self._edone[m] = True
-        return eo[idx]
-
-    @property
-    def edges(self) -> np.ndarray:
-        """All Edge objects (decodes the whole frame once) — the DFS
-        consumers' (algorithms.py) contract.  O(1) once fully decoded
-        (ADVICE r3: per-access `_edone.all()` made DFS replay O(n²))."""
-        if not self._all_done:
-            self.decode(np.arange(self.n, dtype=np.int64))
-            self._all_done = True
-        return self._eobjs
-
-
-def join_frontier_trails(fr: "HopFrame", last: np.ndarray
-                         ) -> Tuple[np.ndarray, np.ndarray]:
-    """One searchsorted join of per-trail endpoints against a frame's
-    src index.  Returns (parent, fidx): for every (trail, edge)
-    continuation, the trail's index into `last` and the frame entry —
-    in frame CSR order within each trail.  Shared by the unfused MATCH
-    Traverse executor and the fused TpuMatchAgg assembly (single
-    source for the join's edge cases)."""
-    us, ustart, ucnt = fr.src_slices()
-    p = np.searchsorted(us, last)
-    p = np.minimum(p, max(us.size - 1, 0))
-    hit = us[p] == last
-    cnt = np.where(hit, ucnt[p], 0)
-    start = np.where(hit, ustart[p], 0)
-    ends = np.cumsum(cnt)
-    total = int(ends[-1]) if cnt.size else 0
-    if total == 0:
-        return (np.empty(0, np.int64), np.empty(0, np.int64))
-    k = np.arange(total, dtype=np.int64)
-    parent = np.searchsorted(ends, k, side="right")
-    within = k - (ends[parent] - cnt[parent])
-    fidx = fr.order[start[parent] + within]
-    return parent, fidx
-
-
-def trail_distinct_keep(frames: List["HopFrame"], path: List[np.ndarray],
-                        parent: np.ndarray, fr: "HopFrame",
-                        fidx: np.ndarray) -> np.ndarray:
-    """Relationship-uniqueness mask: for each candidate continuation,
-    compare the new edge's canonical key against every earlier hop of
-    its trail (componentwise over the frames' key columns)."""
-    keep = np.ones(fidx.size, bool)
-    for eh, pe in enumerate(path):
-        pf = frames[eh]
-        pidx = pe[parent]
-        keep &= ~((pf.key_et[pidx] == fr.key_et[fidx])
-                  & (pf.key_s[pidx] == fr.key_s[fidx])
-                  & (pf.key_d[pidx] == fr.key_d[fidx])
-                  & (pf.rank[pidx] == fr.rank[fidx]))
-    return keep
-
-
 class TpuRuntime:
     """One per process; holds the mesh and all pinned spaces."""
 
@@ -845,13 +199,9 @@ class TpuRuntime:
         self.snapshots: Dict[str, DeviceSnapshot] = {}
         # program key → (the program, bytes of its 64-bit operands)
         self._fns: Dict[Tuple, Any] = {}
-        # program key → last kept-prefix fetch size: arms the
-        # speculative single-phase result fetch (one device round trip
-        # instead of two for repeat query shapes); in-memory only
-        self._kmax: Dict[Tuple, int] = {}
-        # (program key, columns fetched) whose fetch programs are
-        # compiled (`_warm_fetch`); pruned with _kmax
-        self._fetch_warm: set = set()
+        # what crosses back after a launch, and what it remembers
+        # from one launch of a program to the next (fetch.py)
+        self._fetcher = Fetcher()
         # seed-bitmap builder programs (bounded separately from _fns:
         # space-keyed pruning does not reach these target/vmax keys) and
         # the (key, pad bucket) pairs already compiled — the warm call
@@ -918,8 +268,7 @@ class TpuRuntime:
                 dev.delete_buffers()
             self.snapshots.clear()
             self._fns.clear()
-            self._kmax.clear()
-            self._fetch_warm.clear()
+            self._fetcher.forget()
             self._seed_fns.clear()
             self._seed_warm.clear()
             self.mesh = mesh
@@ -1015,7 +364,7 @@ class TpuRuntime:
         its limit once the snapshot is pinned (None = no limit set)."""
         width = max((b.nbr.shape[1] for b in snap.blocks.values()),
                     default=0)
-        cap = max(_delta_pow2(-(-width // self.DELTA_EDGE_SHARE)),
+        cap = max(pow2(-(-width // self.DELTA_EDGE_SHARE)),
                   self.DELTA_MIN_EDGES)
         if headroom is not None:
             # the buffers' bytes are linear in the capacity: one slot of
@@ -1534,10 +883,7 @@ class TpuRuntime:
                 old.delete_buffers()
             self._fns = {k: v for k, v in self._fns.items()
                          if k[0] != space}
-            self._kmax = {k: v for k, v in self._kmax.items()
-                          if k[0] != space}
-            self._fetch_warm = {w for w in self._fetch_warm
-                                if w[0][0] != space}
+            self._fetcher.forget(space)
             self._buckets = {k: v for k, v in self._buckets.items()
                              if k[0][0] != space}
         finally:
@@ -1638,7 +984,7 @@ class TpuRuntime:
             raise ValueError(
                 f"dense seed id {top} out of range for snapshot "
                 f"(P={P}, vmax={vmax})")
-        shape: Tuple[int, ...] = (_pow2(max([len(d) for d in ds] + [1])),)
+        shape: Tuple[int, ...] = (pow2(max([len(d) for d in ds] + [1])),)
         # lanes × shards grid: the frontier stack is sharded over BOTH
         # mesh axes — each device owns its lane rows of its partition's
         # bitmap.  On a legacy 1-D ('part',) mesh the lane dimension
@@ -1649,7 +995,7 @@ class TpuRuntime:
             # evenly over the lane-axis rows: pad to Lm × pow2 lanes
             # (Lm=1 in local mode reduces to the plain pow2 bucket)
             Lm = max(self.mesh_lanes, 1)
-            shape = (Lm * _pow2(max(-(-len(ds) // Lm), 1)),) + shape
+            shape = (Lm * pow2(max(-(-len(ds) // Lm), 1)),) + shape
             spec = PartitionSpec(
                 "lane" if "lane" in self.mesh.axis_names else None, "part")
         pad = np.full(shape, -1, np.int64)
@@ -1846,22 +1192,6 @@ class TpuRuntime:
         self._attribute(tk.info, tk.res, tk.lane, stats, tk.form_wait_us)
         return {"cap": {k: v[tk.lane] for k, v in tk.res["cap"].items()}}
 
-    @staticmethod
-    @contextmanager
-    def _phase(phases: list, name: str, **attrs):
-        """One device phase of a launch: a span LIVE where a trace is
-        active (a solo statement's: the benchmark's trace reduction
-        labels idle gaps by the spans open at a gap's midpoint and the
-        phase ledger folds them) and a (name, perf_counter start,
-        seconds, attrs) record in the launch's phase list, which a
-        shared launch's members replay into their own traces
-        (_attribute): nothing is traced on the launcher's thread while
-        the launch runs."""
-        t0 = time.perf_counter()
-        with _t.span(name, **attrs):
-            yield
-        phases.append((name, t0, time.perf_counter() - t0, attrs))
-
     def _escalate_locked(self, dev: DeviceSnapshot,
                          lane_dense: Sequence[Sequence[int]],
                          key_fn, build_fn, inputs_fn, wait_us: int,
@@ -1921,9 +1251,9 @@ class TpuRuntime:
         # (per-part expansion vs whole-graph expansion)
         if lanes:
             bkey = (key_fn(()) + ("lanes", self._mesh_key()),
-                    _pow2(max(len(lane_dense), 1)))
+                    pow2(max(len(lane_dense), 1)))
         else:
-            bkey = (key_fn(()), _pow2(max(len(set(lane_dense[0])), 1)))
+            bkey = (key_fn(()), pow2(max(len(set(lane_dense[0])), 1)))
         prev = self._buckets.get(bkey)
         if prev is not None:
             # value kept as (0, ebs) for cache-file compat (slot 0 was
@@ -1940,11 +1270,11 @@ class TpuRuntime:
             "refetches": 0, "gate_wait_us": wait_us, "phases": [],
             "fetch_bytes": 0, "fetch_bytes_kept": 0}
         phases, rungs = info["phases"], info["rungs"]
-        with self._phase(phases, "tpu:seed_prep"):
+        with _t.phase(phases, "tpu:seed_prep"):
             seed_pad, seed_fn = self._seed_frontier_prep(
                 dev, lane_dense, lanes)
         L = seed_pad.shape[0] if lanes else 1
-        with self._phase(phases, "device:put"), \
+        with _t.phase(phases, "device:put"), \
                 self._collective_launch():
             frontier = seed_fn(seed_pad)
         info["put_s"] = phases[-1][2]
@@ -1984,23 +1314,23 @@ class TpuRuntime:
             # (a shared launch's are suppressed: _attribute)
             if wc is not None:
                 wc.add("device_dispatches")
-            with self._phase(phases, "device:dispatch", eb=list(EBs),
-                             attempt=attempt), \
+            with _t.phase(phases, "device:dispatch", eb=list(EBs),
+                          attempt=attempt), \
                     self._collective_launch():
                 res = fn(*inputs_fn(ebs), frontier)
                 jax.block_until_ready(res)
             info["device_s"] = phases[-1][2]
             rungs.append((int(info["device_s"] * 1e6), compiled))
             if "cap" in res:
-                self._warm_fetch(res["cap"], key, fetch_keys, phases)
+                self._fetcher.warm(res["cap"], key, fetch_keys, phases)
             # the rung's device buffers are released after the fetch
             # has timed itself, and the release times itself too: it
             # waits its turn for the GIL and is no part of the fetch.  A
             # failed rung's capture is so dropped BEFORE the larger rung
             # runs: holding both nearly doubles peak HBM and can fail
             # the retry
-            host, held = self._fetch(res, key, fetch_keys, info)
-            with self._phase(phases, "device:release"):
+            host, held = self._fetcher.fetch(res, key, fetch_keys, info)
+            with _t.phase(phases, "device:release"):
                 res = held = None
             res = host
             if not res["ovf_expand"].any():
@@ -2016,7 +1346,7 @@ class TpuRuntime:
             he = np.asarray(res["hop_edges"])
             need = he.reshape(-1, he.shape[-1]).max(axis=0)
             EBs = [e if need[h] <= e else
-                   min(max(2 * e, _pow2(int(need[h]))), cap)
+                   min(max(2 * e, pow2(int(need[h]))), cap)
                    for h, e in enumerate(EBs)]
             if uniform:
                 EBs = [max(EBs)] * n_hops
@@ -2026,7 +1356,7 @@ class TpuRuntime:
         # the launch's accounting, once a converged launch: a phase of
         # its own, so that neither a statement's root nor a shared
         # launch's members keep it as unexplained time
-        with self._phase(phases, "tpu:launch_account"):
+        with _t.phase(phases, "tpu:launch_account"):
             info["retries"], info["ebs"] = attempt, list(EBs)
             if self._buckets.get(bkey) != (0, ebs):
                 self._buckets[bkey] = (0, ebs)
@@ -2151,86 +1481,6 @@ class TpuRuntime:
                             shards=self.mesh_size,
                             **({"lanes": L} if lanes else {}))
             return res, info
-
-    def _warm_fetch(self, cap_dev, key, fetch_keys: Optional[set],
-                    phases: list):
-        """Compile the fetch programs of this capture (every slice or
-        piece size its width admits, on each device that holds a shard)
-        when its program first runs for these columns, outside every
-        timed phase: no statement meets one for the first time through
-        the size of what it kept.  The one statement that does the
-        compiling carries it as `tpu:fetch_warm`."""
-        wk = (key, None if fetch_keys is None else frozenset(fetch_keys))
-        if wk not in self._fetch_warm:
-            with self._phase(phases, "tpu:fetch_warm"):
-                _taker(cap_dev, fetch_keys).warm()
-            if len(self._fetch_warm) > 4096:
-                self._fetch_warm.clear()
-            self._fetch_warm.add(wk)
-
-    def _fetch(self, res, key, fetch_keys: Optional[set], info):
-        """Bring one rung's result to the host: -> (the host result,
-        what this frame still held of the device's); the launch's `info`
-        takes its phases, undershoots, seconds and bytes.  It times
-        itself, as its last statement, and hands the device references it
-        took (the leaves, the slices cut of the capture) back to the
-        caller, who holds the device result too: releasing device buffers
-        waits its turn (tens of ms under eight sessions), is no part of
-        the fetch and is timed by the caller as `device:release`.  The
-        spans of phase `fetch` cover the clock from end to end:
-        `device:fetch` the two transfers (the first with the taker's
-        set-up, the second nested), `device:fetch.rows` the host's side
-        of a kept capture (the pieces asked for by its kept counts, cut
-        on the device and assembled into rows).
-
-        What comes: the leaves a caller reads (`_FETCHED`) and, of the
-        capture columns the yields read, each row's kept prefix
-        (`_Heads`, `_Pieces`): the transfer follows the rows kept, not
-        the edge budget nor the fullest row.  Two-phase on a program's
-        first run, and on every run of a wide capture: the small meta
-        first, then the prefixes its kept counts name.  SPECULATIVE
-        single-phase for the slices after it: what the last run of this
-        program (`key`) kept bounds the slice, and both phases collapse
-        into ONE device_get.  An undershoot (kept grew past the
-        speculation) falls back to the exact refetch and is the one
-        refetch counted; an overshoot ships at most what the last run
-        needed.  An overflowed rung returns meta alone, a speculative
-        slice dropped."""
-        t0 = time.perf_counter()
-        phases = info["phases"]
-        take = first = more = None
-        with self._phase(phases, "device:fetch"):
-            meta = {k: res[k] for k in _FETCHED if k in res}
-            if "cap" in res:
-                take = _taker(res["cap"], fetch_keys)
-                spec = self._kmax.get(key)
-                first = None if spec is None else take.speculate(spec)
-            host, got = jax.device_get((meta, first))
-            if first is not None:
-                take.got(got)
-        info["fetch_bytes"] += _nbytes(host)
-        info["refetches"] = 0
-        if take is not None and not host["ovf_expand"].any():
-            with self._phase(phases, "device:fetch.rows"):
-                kc = host["kcount"]
-                more = take.ask(kc)
-                if more is not None:
-                    # the capture's own fetch: the second phase where
-                    # nothing was speculated, else a refetch
-                    info["refetches"] = int(first is not None)
-                    with self._phase(phases, "device:fetch",
-                                     refetch=first is not None):
-                        take.got(jax.device_get(more))
-                host["cap"] = take.rows(kc)
-                host["cap"]["kcount"] = kc
-                info["fetch_bytes_kept"] += int(kc.sum()) * take.item_bytes()
-                self._kmax[key] = kc
-                while len(self._kmax) > 512:
-                    self._kmax.pop(next(iter(self._kmax)))
-        if take is not None:
-            info["fetch_bytes"] += take.nbytes
-        info["fetch_s"] = time.perf_counter() - t0
-        return host, (meta, take, first, more)
 
     @staticmethod
     def _attribute(info, res, lane: Optional[int],
@@ -2368,7 +1618,7 @@ class TpuRuntime:
                                                prop_names | set(yield_cols))
             blocks_data = tuple(blocks)
             if fetch_keys is not None and any(
-                    _delta_rows_of(dview, bk) for bk in block_keys):
+                    assemble._delta_rows_of(dview, bk) for bk in block_keys):
                 # delta rows interleave with base rows in canonical CSR
                 # order at materialize time — the host re-sort needs every
                 # identity column regardless of what the yields read; a
@@ -2480,7 +1730,7 @@ class TpuRuntime:
         # fetch only the capture arrays the yields actually read (each
         # is a kept-sized column); what none of them reads the program
         # need not carry either (`_run_traverse`: rank)
-        fetch_keys = (_cap_keys_for_yields(yields, yield_cols)
+        fetch_keys = (assemble._cap_keys_for_yields(yields, yield_cols)
                       if capture else None)
         if fetch_keys is not None and fetch_keys & {"src", "dst"} \
                 and any(d == "in" for _, d in block_keys):
@@ -2497,12 +1747,13 @@ class TpuRuntime:
 
         with self._materialising(stats):
             if yields is not None:
-                rows = self._materialize_yields(
+                rows = assemble._materialize_yields(
                     store, space, dev, block_keys, res["cap"], yields,
-                    dview=dview)
+                    _pool_for, dview=dview)
             else:
-                rows = self._materialize(store, space, dev, block_keys,
-                                         res["cap"], dview=dview)
+                rows = assemble._materialize(
+                    store, space, dev, block_keys, res["cap"], _pool_for,
+                    dview=dview)
         stats.result_edges = len(rows)
         stats.total_s = time.perf_counter() - t_start
         return rows, stats
@@ -2540,112 +1791,12 @@ class TpuRuntime:
         res, dview = self._run_traverse(
             space, dev, dense, block_keys, pred, stats, max_hop, "hops")
         with self._materialising(stats):
-            frames = self._build_frames(store, space, dev, block_keys,
-                                        res["cap"], max_hop, dview=dview)
+            frames = assemble._build_frames(
+                store, space, dev, block_keys, res["cap"], max_hop,
+                dview=dview)
         stats.result_edges = sum(f.n for f in frames)
         stats.total_s = time.perf_counter() - t_start
         return frames, stats
-
-    def _build_frames(self, store: GraphStore, space: str,
-                      dev: DeviceSnapshot, block_keys, cap, steps: int,
-                      dview=None) -> List["HopFrame"]:
-        """cap arrays are (P, steps, nb, EB); one columnar HopFrame per
-        hop.  NO Edge objects are built here — frames carry dense-id and
-        canonical-key columns, plus a per-segment decode closure that
-        materializes Edge objects only for the entries the assembly
-        actually emits (VERDICT r2 item 4)."""
-        host = dev.host
-        d2v_arr = _d2v(host)
-        d2v_id = host._d2v_identity
-        etype_ids = {et: store.catalog.get_edge(space, et).edge_type
-                     for et, _ in block_keys}
-        def make_decode(et, dirn, sgn):
-            hb = host.blocks[(et, dirn)]
-            de = _delta_rows_of(dview, (et, dirn))
-
-            def decode_seg(payload, offs):
-                ss, dd, rr, ee, sel_p = payload
-                ss, dd = ss[offs], dd[offs]
-                rr, ee, sp = rr[offs], ee[offs], sel_p[offs]
-                props = {n: decode_prop_column(
-                    hb.prop_types[n],
-                    _merged_gather(hb.props[n], de, n, sp, ee), host.pool)
-                    for n in hb.props}
-                sv = ss if d2v_id else d2v_arr[ss]
-                dvv = dd if d2v_id else d2v_arr[dd]
-                names = list(props)
-                cols = [props[n] for n in names]
-                rrl = rr.tolist()
-                return [Edge(s, d, et, rrl[i],
-                             {n: c[i] for n, c in zip(names, cols)},
-                             etype=sgn)
-                        for i, (s, d) in enumerate(zip(sv.tolist(),
-                                                       dvv.tolist()))]
-            return decode_seg
-
-        def decode_seg(payload_dec, offs):
-            payload, dec = payload_dec
-            return dec(payload, offs)
-
-        frames = []
-        P = cap["kcount"].shape[0]
-        for h in range(steps):
-            srcs, dsts, rks = [], [], []
-            ket, ks, kd = [], [], []
-            segs = []
-            pos = 0
-            for bi, (et, dirn) in enumerate(block_keys):
-                kc = cap["kcount"][:, h, bi]        # (P,)
-                # kept entries are a device-compacted prefix per part
-                # row: per-part slice concat preserves the (part, slot)
-                # order nonzero gave — per (part, src) the kept slots
-                # stay contiguous ascending eidx, so the concat below is
-                # already (src-stable) CSR order
-                pids = [p for p in range(kc.shape[0]) if kc[p] > 0]
-                if not pids:
-                    continue
-                perms = None
-                de = _delta_rows_of(dview, (et, dirn))
-                if de is not None:
-                    perms = self._delta_perms(
-                        cap["src"][:, h], cap["dst"][:, h],
-                        cap["rank"][:, h], bi, pids, P,
-                        d2v_arr, d2v_id, de["rows"])
-
-                def catp(name, dtype=None):
-                    with _t.span("device:materialise.concat", col=name):
-                        return _cat_rows(
-                            [cap[name][p, h, bi] for p in pids], perms, dtype)
-
-                ss = catp("src", np.int64)
-                dd = catp("dst", np.int64)
-                rr = catp("rank", np.int64)
-                ee = catp("eidx")
-                sel_p = np.repeat(np.asarray(pids, np.int64),
-                                  [int(kc[p]) for p in pids])
-                eid = etype_ids[et]
-                sgn = eid if dirn == "out" else -eid
-                srcs.append(ss)
-                dsts.append(dd)
-                rks.append(rr)
-                # canonical physical-edge key: out/in copies of one
-                # logical edge compare equal (trail dedup currency)
-                ket.append(np.full(ss.size, eid, np.int64))
-                ks.append(ss if dirn == "out" else dd)
-                kd.append(dd if dirn == "out" else ss)
-                segs.append((pos, pos + ss.size,
-                             ((ss, dd, rr, ee, sel_p),
-                              make_decode(et, dirn, sgn))))
-                pos += ss.size
-            if not srcs:
-                frames.append(HopFrame.empty())
-                continue
-            frames.append(HopFrame.build(
-                np.concatenate(srcs), np.concatenate(dsts),
-                np.concatenate(rks), np.concatenate(ket),
-                np.concatenate(ks), np.concatenate(kd),
-                segs, decode_seg))
-        return frames
 
     # -- BFS (FIND SHORTEST PATH device plane) ---------------------------
 
@@ -2745,231 +1896,3 @@ class TpuRuntime:
                                        chunks_run=stats.chunks_run,
                                        chunks_budget=stats.chunks_budget)
         return res["dist"], stats
-
-    # -- host materialization --------------------------------------------
-
-    @staticmethod
-    def _delta_perms(cap_src, cap_dst, cap_rank, bi, pids, P,
-                     d2v_arr, d2v_id, rows):
-        """Per-part permutations restoring canonical CSR slot order over
-        the merged base+delta capture: within a part, base rows sit in
-        (local_src, rank, dst_key) order and delta rows are appended —
-        the union must interleave exactly where a full rebuild would
-        have placed the new rows.  dst_key matches native.kernels.
-        dst_sort_key: the vid itself for int vids, code-point string
-        order otherwise (np.unique ordinals preserve it).  Keys are
-        unique per live edge, so the sort is deterministic.  A part
-        whose delta buffer holds no row (`rows[p]` == 0) keeps its
-        order: None in its place; None for all when no part needs one."""
-        perms = []
-        for p in pids:
-            if not rows[p]:
-                perms.append(None)
-                continue
-            s_ = _whole(cap_src[p, bi]).astype(np.int64)
-            d_ = _whole(cap_dst[p, bi]).astype(np.int64)
-            r_ = _whole(cap_rank[p, bi])
-            if d2v_id:
-                dk = d_
-            else:
-                dk = d2v_arr[d_]
-                if dk.dtype == object:
-                    dk = dk.astype("U")
-            perms.append(np.lexsort((dk, r_, s_ // P)))
-        return perms if any(pm is not None for pm in perms) else None
-
-    def _block_columns(self, store: GraphStore, space: str,
-                       dev: DeviceSnapshot, block_keys, cap,
-                       prop_names: Optional[Sequence[str]] = None,
-                       as_np: bool = False, dview=None):
-        """Vectorized gather of the captured final-hop edge set.
-
-        Yields per-block dicts of flat numpy/object arrays: sv/dv (vids),
-        rr (ranks), decoded prop columns — no per-edge Python loop; vid
-        decode is one fancy-index into the dense→vid array and prop
-        decode is batched per column (VERDICT r1 'weak #3' fix).
-
-        With a live delta view (`dview`, grabbed at dispatch assembly)
-        the merged rows are re-sorted per part into canonical CSR order
-        and delta-row props decode from the view's numpy mirror at
-        virtual eidx = Emax + slot.
-        """
-        host = dev.host
-        d2v_arr = _d2v(host)
-        d2v_id = host._d2v_identity
-        etype_ids = {et: store.catalog.get_edge(space, et).edge_type
-                     for et, _ in block_keys}
-        kcount = cap["kcount"]              # (P, nb); arrays (P, nb, K)
-        P = kcount.shape[0]
-        # what the statement's assembly did, observed once at its end
-        # (`tpu_mat_*`): rows assembled and those whose pieces went side
-        # by side, numeric columns decoded and those whose NULL answer
-        # the assembling pass gave
-        rows = pooled_rows = numeric_cols = one_pass_cols = 0
-        for bi, (et, dirn) in enumerate(block_keys):
-            hb = host.blocks[(et, dirn)]
-            de = _delta_rows_of(dview, (et, dirn))
-            # kept entries are a device-compacted PREFIX per part row —
-            # selection is contiguous slices, not a 2D fancy gather
-            # (nonzero + fancy indexing cost ~60% of materialization at
-            # north-star scale)
-            kc = kcount[:, bi]
-            pids = [p for p in range(P) if kc[p] > 0]
-            if not pids:
-                continue
-            n_rows = int(sum(int(kc[p]) for p in pids))
-            perms = None
-            if de is not None:
-                perms = self._delta_perms(
-                    cap["src"], cap["dst"], cap["rank"], bi, pids, P,
-                    d2v_arr, d2v_id, de["rows"])
-
-            def vids(name, dense):
-                if dense is None or d2v_id:
-                    return dense
-                with _t.span("device:materialise.decode", col=name):
-                    return d2v_arr[dense]
-
-            # arrays the caller's yields never read were not fetched
-            # (fetch_keys) — and are not assembled here either; a
-            # device-gathered yield column is fetched ready-made, its
-            # halves joined as the pieces are concatenated
-            names = [n for n in dict.fromkeys(
-                hb.props if prop_names is None else prop_names)
-                if n in hb.props]
-            want = [(k, dt) for k, dt in (("src", np.int64),
-                                          ("dst", np.int64), ("rank", None))
-                    if k in cap]
-            want += [("prop:" + n, hb.props[n].dtype) for n in names
-                     if ("prop:" + n) in cap]
-            got, pooled = self._assemble(cap, bi, pids, perms, want, n_rows)
-            rows += n_rows
-            pooled_rows += n_rows * pooled
-            ss, dd, rr = (got.get(k, (None,))[0]
-                          for k in ("src", "dst", "rank"))
-            props = {}
-            ee_parts = None
-            for n in names:
-                pt = hb.prop_types[n]
-                if ("prop:" + n) in cap:
-                    raw, has_null = got["prop:" + n]
-                elif "eidx" in cap:
-                    # the host column at the captured eidx
-                    raw, has_null = None, None
-                else:
-                    continue
-                with _t.span("device:materialise.decode", col=n):
-                    if raw is None:
-                        if ee_parts is None:
-                            ee_parts = [_whole(cap["eidx"][p, bi])
-                                        for p in pids]
-                            if perms is not None:
-                                ee_parts = [
-                                    a if pm is None else a[pm]
-                                    for a, pm in zip(ee_parts, perms)]
-                        raw = [_merged_gather(hb.props[n], de, n, p, e)
-                               for p, e in zip(pids, ee_parts)]
-                        raw = np.concatenate(raw) if len(raw) > 1 else raw[0]
-                    if as_np:
-                        props[n] = decode_prop_column_np(
-                            pt, raw, host.pool, has_null)
-                        if pt in NUMERIC_KINDS:
-                            numeric_cols += 1
-                            one_pass_cols += has_null is not None
-                    else:
-                        props[n] = decode_prop_column(pt, raw, host.pool)
-            eid = etype_ids[et]
-            sv, dv = vids("src", ss), vids("dst", dd)
-            yield {"et": et, "dirn": dirn, "etype": eid if dirn == "out"
-                   else -eid, "n": n_rows, "sv": sv, "dv": dv,
-                   "rr": rr, "props": props,
-                   "prop_types": hb.prop_types}
-        m = _metrics()
-        m.add_value("tpu_mat_rows", rows)
-        m.add_value("tpu_mat_pooled_rows", pooled_rows)
-        m.add_value("tpu_mat_numeric_cols", numeric_cols)
-        m.add_value("tpu_mat_one_pass_cols", one_pass_cols)
-
-    @staticmethod
-    def _assemble(cap, bi, pids, perms, want, n_rows):
-        """The fetched pieces of block `bi`'s columns `want` ([(capture
-        key, host dtype)]) joined into owned columns: -> ({key: (column,
-        a property column's NULL answer)}, whether side by side).  One
-        after another, a span a column (`mat_concat`), as a rule; side
-        by side under ONE span where the statement is large
-        (`POOL_MIN_ROWS`) and the rows keep their order (a delta plane's
-        re-sort gathers a whole row first)."""
-        def pieces(key):
-            return _pieces([cap[key][p, bi] for p in pids], perms)
-
-        pool = (_assembly_pool()
-                if perms is None and n_rows >= POOL_MIN_ROWS else None)
-        if pool is not None:
-            with _t.span("device:materialise.concat", col="*",
-                         pooled=len(want)):
-                return dict(zip((k for k, _ in want), _cat_side_by_side(
-                    pool, [(pieces(k), dt) for k, dt in want]))), True
-        got = {}
-        for key, dt in want:
-            with _t.span("device:materialise.concat", col=key):
-                got[key] = (_join_halves(pieces(key), dt)
-                            if key.startswith("prop:")
-                            else (_cat_parts(pieces(key), dt), False))
-        return got, False
-
-    def _materialize(self, store: GraphStore, space: str,
-                     dev: DeviceSnapshot, block_keys, cap, dview=None
-                     ) -> List[Tuple[Any, Optional[Edge], Any]]:
-        """(src_vid, Edge, dst_vid) triples — Edge objects built in one
-        tight zip loop over pre-decoded columns."""
-        rows: List[Tuple[Any, Optional[Edge], Any]] = []
-        for b in self._block_columns(store, space, dev, block_keys, cap,
-                                     dview=dview):
-            et, etype = b["et"], b["etype"]
-            names = list(b["props"])
-            cols = [b["props"][n] for n in names]
-            rr = b["rr"].tolist()
-            for i, (sv, dv) in enumerate(zip(b["sv"].tolist(),
-                                             b["dv"].tolist())):
-                props = {n: c[i] for n, c in zip(names, cols)}
-                rows.append((sv, Edge(sv, dv, et, rr[i], props,
-                                      etype=etype), dv))
-        return rows
-
-    def _materialize_yields(self, store: GraphStore, space: str,
-                            dev: DeviceSnapshot, block_keys, cap,
-                            yields, dview=None) -> ColumnarDataSet:
-        """Final output as a lazy columnar DataSet (fused Project).
-
-        Columns are numpy arrays straight from the capture buffers; no
-        per-row Python objects are built here — the ColumnarDataSet
-        materializes rows only if the consumer crosses the row boundary
-        (VERDICT r2 item 3: device results stay columnar end-to-end)."""
-        needed = [x.name for e, _ in yields for x in E.walk(e)
-                  if x.kind == "edge_prop"]
-        per_block: List[List[np.ndarray]] = []
-        for b in self._block_columns(store, space, dev, block_keys, cap,
-                                     prop_names=needed, as_np=True,
-                                     dview=dview):
-            per_block.append([eval_yield_column_np(e, b)
-                              for e, _ in yields])
-        names = [alias for _, alias in yields]
-        if not per_block:
-            return ColumnarDataSet(
-                names, [np.empty(0, object) for _ in yields])
-        if len(per_block) == 1:
-            return ColumnarDataSet(names, per_block[0])
-
-        def _cat(j):
-            # ADVICE r3: int+float blocks (multi-etype GO) must not
-            # upcast to float64 — that silently turns 5 into 5.0 and
-            # diverges from the host path's exact per-element types.
-            # Mixed numeric kinds concatenate as object instead.
-            blks = [blk[j] for blk in per_block]
-            kinds = {b.dtype.kind for b in blks}
-            if len(kinds) > 1 and "O" not in kinds:
-                blks = [b.astype(object) for b in blks]
-            return np.concatenate(blks)
-
-        return ColumnarDataSet(names, [_cat(j)
-                                       for j in range(len(yields))])

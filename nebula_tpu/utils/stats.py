@@ -18,8 +18,8 @@ The observability layer (ISSUE 1) adds:
   * `WorkCounters` + `use_work`/`current_work` — per-query DETERMINISTIC
     work counts (edges traversed, frontier sizes, RPC calls, wire
     bytes, device dispatches).  Work counts are stable across noisy
-    VMs even when timings are not, so bench.py emits them as the
-    regression signal (VERDICT weak #8).
+    VMs even when timings are not: the regression signal a timing
+    cannot be (VERDICT weak #8).
 """
 from __future__ import annotations
 
@@ -350,8 +350,7 @@ class WorkCounters:
     unlike wall-clock timings on a noisy VM.  Threaded through the
     engine (ExecutionContext.work), the RPC client (calls + wire
     bytes), and the device runtime (dispatches, traversed edges,
-    per-hop frontier sizes); bench.py emits them as the noise-immune
-    regression signal."""
+    per-hop frontier sizes): the noise-immune regression signal."""
 
     __slots__ = ("edges_traversed", "frontier_sizes", "rpc_calls",
                  "wire_bytes_sent", "wire_bytes_recv",

@@ -52,6 +52,7 @@ import os
 import sys
 import threading
 import time
+from contextlib import contextmanager
 from typing import Any, Dict, List, Optional, Tuple
 
 from .stats import stats
@@ -231,6 +232,21 @@ def record_phase(name: str, start_s: float, dur_s: float, **attrs):
     replayed into each lane's trace); code that can should use a
     `with span(...)` instead, which is also a profiler annotation."""
     _append(name, int(start_s * 1e9), int(dur_s * 1e6), attrs)
+
+
+@contextmanager
+def phase(phases: list, name: str, **attrs):
+    """One device phase of a launch: a span LIVE where a trace is
+    active (a solo statement's: the benchmark's trace reduction labels
+    idle gaps by the spans open at a gap's midpoint and the phase ledger
+    folds them) and a (name, perf_counter start, seconds, attrs) record
+    in the launch's `phases` list, which a shared launch's members
+    replay into their own traces (tpu/runtime.py `_attribute`): nothing
+    is traced on the launcher's thread while the launch runs."""
+    t0 = time.perf_counter()
+    with span(name, **attrs):
+        yield
+    phases.append((name, t0, time.perf_counter() - t0, attrs))
 
 
 def mark(name: str, **attrs):

@@ -20,9 +20,10 @@ The acceptance claims under test:
     coordinator completes its next statement within seconds, not
     deadline-timeouts.
 
-Marked `chaos` + `slow`: NOT part of the tier-1 gate.  The fault-free
-fleet goodput curve lives in tools/overload_bench.py --fleet (bench.py
-`fleet` block), including the aggressor-tenant DWRR share proof.
+Marked `chaos` + `slow`: NOT part of the tier-1 gate.  The
+aggressor-tenant DWRR share is a unit contract
+(tests/unit/test_fleet.py); a fleet goodput curve is the benchmark's
+to draw (ROADMAP B7).
 """
 import threading
 import time

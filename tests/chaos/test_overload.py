@@ -12,10 +12,10 @@ The acceptance claims under test:
   * control statements (SHOW QUERIES) keep answering during
     saturation — the priority lane's proof.
 
-Marked `chaos` + `slow`: NOT part of the tier-1 gate.  The fault-free
-goodput curve lives in tools/overload_bench.py (bench.py `overload`
-block); the deadline-eviction and kill-eviction contracts are unit
-tests (tests/unit/test_admission.py).
+Marked `chaos` + `slow`: NOT part of the tier-1 gate.  The
+deadline-eviction and kill-eviction contracts are unit tests
+(tests/unit/test_admission.py); a goodput curve under overload is the
+benchmark's to draw (ROADMAP B7: an open-loop driver).
 """
 import threading
 import time

@@ -2,8 +2,8 @@ import os
 import sys
 
 # Tests run on a virtual 8-device CPU mesh so multi-chip sharding logic is
-# exercised without TPU hardware (chip_smoke.py and bench.py use the real
-# chip, one process per chip).
+# exercised without TPU hardware (chip_smoke.py and benchmarks/run.py use the
+# real chip, one process per chip).
 os.environ["JAX_PLATFORMS"] = "cpu"   # force: the session env may point at a real chip
 flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in flags:

@@ -9,7 +9,7 @@ interactive band, queued-statement deadline eviction), flight-recorder
 forced capture for killed/shed algo statements, live SHOW QUERIES
 per-iteration progress, the BFS refactor regression (device FIND
 SHORTEST PATH rows still byte-identical to the host oracle through the
-shared frontier steps), and the algo_bench tool.
+shared frontier steps).
 """
 import random
 import threading
@@ -563,21 +563,6 @@ def test_find_shortest_path_regression(mesh_n, where):
         list(map(repr, host.data.rows))
     if where is None:           # the filtered variant may prune to 0
         assert len(host.data.rows) > 0
-
-
-# -- bench tool -------------------------------------------------------------
-
-
-def test_algo_bench_suite_small(rt):
-    from nebula_tpu.tools.algo_bench import run_suite
-    res = run_suite(persons=400, degree=4, parts=P, repeats=1,
-                    tpu_runtime=rt)
-    for algo in ("pagerank", "wcc", "sssp"):
-        blk = res[algo]
-        assert blk["rows_match"], (algo, blk)
-        assert blk["device_s"] > 0 and blk["host_s"] > 0
-        assert blk["iterations"] >= 1
-    assert res["graph"]["persons"] == 400
 
 
 @pytest.mark.slow

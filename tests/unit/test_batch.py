@@ -217,9 +217,9 @@ def test_warm_lane_launch_fetches_once_and_counts_an_undershoot(
     `tpu_refetches`; a speculation that undershoots falls back to the
     exact refetch and counts one; rows equal the solo run's every
     time."""
-    from nebula_tpu.tpu import runtime
+    from nebula_tpu.tpu import fetch
     # no floor under the single slice: a speculation of 1 slot undershoots
-    monkeypatch.setattr(runtime, "SLICE_MIN", 1)
+    monkeypatch.setattr(fetch, "SLICE_MIN", 1)
     rt = TpuRuntime(make_mesh(1))       # no kept size known yet
     eng = device_engine(rt)
     seeds = [1, 2, 3, 5]
@@ -249,7 +249,7 @@ def test_warm_lane_launch_fetches_once_and_counts_an_undershoot(
                          - s0.get("tpu_refetches", 0))
 
     def lane_keys():
-        return [k for k in rt._kmax if "lanes" in k]
+        return [k for k in rt._fetcher.kmax if "lanes" in k]
     assert not lane_keys()
     cold, refetched = launch()
     # a first run knows no kept size: meta, then the capture's own fetch
@@ -258,11 +258,11 @@ def test_warm_lane_launch_fetches_once_and_counts_an_undershoot(
     warm, refetched = launch()
     assert all(f == [{}] for f in warm), warm
     assert refetched == 0
-    rt._kmax[lane_keys()[0]] = 1        # a speculation no lane fits in
+    rt._fetcher.kmax[lane_keys()[0]] = 1        # a speculation no lane fits in
     under, refetched = launch()
     assert all(f == [{}, {"refetch": True}] for f in under), under
     assert refetched == 1
-    assert rt._kmax[lane_keys()[0]].max() > 1
+    assert rt._fetcher.kmax[lane_keys()[0]].max() > 1
 
 
 def test_solo_statement_keeps_live_device_spans(clean, monkeypatch):
@@ -319,13 +319,14 @@ def test_solo_statement_keeps_live_device_spans(clean, monkeypatch):
 
 
 def test_fetch_times_itself(clean, monkeypatch):
-    """`tpu_fetch_s` and a statement's `fetch_s` are the seconds `_fetch`
+    """`tpu_fetch_s` and a statement's `fetch_s` are the seconds `Fetcher.fetch`
     measured itself, two-phase or speculative: the release of the
     rung's device buffers, which waits its turn under concurrent
     sessions (eight of them read 2.4 times the fetch on the chip when it
     was timed around the routine), comes after the routine's timer."""
     seen = []
-    real = TpuRuntime._fetch
+    from nebula_tpu.tpu.fetch import Fetcher
+    real = Fetcher.fetch
 
     def fetch(self, res, key, fetch_keys, info):
         got = real(self, res, key, fetch_keys, info)
@@ -333,7 +334,7 @@ def test_fetch_times_itself(clean, monkeypatch):
         assert "cap" in res and got is not res
         seen.append(info["fetch_s"])
         return got
-    monkeypatch.setattr(TpuRuntime, "_fetch", fetch)
+    monkeypatch.setattr(Fetcher, "fetch", fetch)
     st = batched_store()
     rt = TpuRuntime(make_mesh(1))
     s0 = stats().snapshot()
@@ -370,7 +371,7 @@ def test_bucket_jit_and_kept_size_keys_keep_their_form(clean):
     rt._buckets[(key(()), 2)] = (0, ebs)
     rows, ts = rt.traverse(st, "bt", [1, 2], ["E"], "out", 2)
     assert rows and ts.retries == 0 and ts.e_cap == list(ebs)
-    assert list(rt._fns) == [key(ebs)] and list(rt._kmax) == [key(ebs)]
+    assert list(rt._fns) == [key(ebs)] and list(rt._fetcher.kmax) == [key(ebs)]
     assert rt._buckets[(key(()), 2)] == (0, ebs)
 
 

@@ -80,9 +80,8 @@ def test_device_dispatch_failure_is_served_by_host_and_counted(
 @pytest.mark.parametrize("explicit_cpu", [False, True])
 def test_require_tpu_refuses_a_host_backend_nobody_asked_for(
         monkeypatch, explicit_cpu):
-    """`daemons.py graphd --tpu`, bench.py and multichip_bench start
-    through require_tpu: off-TPU they fail unless the operator set
-    JAX_PLATFORMS=cpu on purpose."""
+    """`daemons.py graphd --tpu` starts through require_tpu: off-TPU it
+    fails unless the operator set JAX_PLATFORMS=cpu on purpose."""
     from nebula_tpu.tpu.device import require_tpu
     if explicit_cpu:
         monkeypatch.setenv("JAX_PLATFORMS", "cpu")

@@ -15,12 +15,14 @@ from nebula_tpu.utils.stats import stats
 
 tpu = pytest.importorskip("nebula_tpu.tpu")
 from nebula_tpu.graphstore.delta import HostDelta, pow2      # noqa: E402
-from nebula_tpu.tpu import TpuRuntime, make_mesh, runtime    # noqa: E402
+from nebula_tpu.tpu import (TpuRuntime, fetch, make_mesh,    # noqa: E402
+                            runtime)
+from nebula_tpu.tpu.fetch import Fetcher                     # noqa: E402
 
 from test_delta import dev_rows, host_rows, store_p          # noqa: E402
 
 FLAG = "tpu_delta_max_edges"
-REAL_FETCH = TpuRuntime._fetch
+REAL_FETCH = Fetcher.fetch
 
 
 @pytest.fixture()
@@ -180,7 +182,7 @@ def _served(monkeypatch, flag):
     def fetch(self, res, key, fetch_keys, info):
         fetched.append(None if fetch_keys is None else tuple(sorted(fetch_keys)))
         return real(self, res, key, fetch_keys, info)
-    monkeypatch.setattr(TpuRuntime, "_fetch", fetch)
+    monkeypatch.setattr(Fetcher, "fetch", fetch)
     rt = TpuRuntime(make_mesh(1))
     eng, s, ex = snb_engine(rt)
     runs0 = stats().snapshot().get("tpu_kernel_runs", 0)
@@ -240,13 +242,13 @@ def test_the_planes_tail_does_not_move_a_capture_to_the_other_fetch(flags):
     (`_Heads`), as without a plane; pieces start at a budget of twice
     that."""
     import jax.numpy as jnp
-    for width, taker in ((1 << 16, runtime._Heads), ((1 << 16) + 1024, runtime._Heads),
-                         ((1 << 16) + (1 << 15), runtime._Heads), (1 << 17, runtime._Pieces),
-                         ((1 << 17) + 1024, runtime._Pieces)):
+    for width, taker in ((1 << 16, fetch._Heads), ((1 << 16) + 1024, fetch._Heads),
+                         ((1 << 16) + (1 << 15), fetch._Heads), (1 << 17, fetch._Pieces),
+                         ((1 << 17) + 1024, fetch._Pieces)):
         cap = {"dst": jnp.zeros((1, 1, width), jnp.int32)}
-        assert type(runtime._taker(cap)) is taker, width
+        assert type(fetch._taker(cap)) is taker, width
     # the slice sizes of a widened capture: the powers of two, then the whole width
-    heads = runtime._taker({"dst": jnp.zeros((1, 1, 8192 + 1024), jnp.int32)})
+    heads = fetch._taker({"dst": jnp.zeros((1, 1, 8192 + 1024), jnp.int32)})
     assert [heads._k(n) for n in (1, 200, 8192, 8193, 9216)] == [128, 256, 8192, 9216, 9216]
 
 

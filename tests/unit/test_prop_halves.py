@@ -4,7 +4,7 @@ array, a gather reads both halves by one index array (hop.py
 `take_halves`), a predicate's 64-bit value is rebuilt per gathered slot
 (`join_halves`), a carried column stays its halves through the capture
 and the fetch and is joined on the host as its pieces are concatenated
-(runtime.py `_join_halves`).
+(assemble.py `_join_halves`).
 
 Rows have to be the host engine's to the bit, whatever the values: the
 store here holds the ones a half could lose (an integer that needs the
@@ -32,7 +32,8 @@ from nebula_tpu.utils.config import get_config
 from nebula_tpu.utils.stats import stats
 
 tpu = pytest.importorskip("nebula_tpu.tpu")
-from nebula_tpu.tpu import TpuRuntime, make_mesh, runtime    # noqa: E402
+from nebula_tpu.tpu import (TpuRuntime, assemble, make_mesh,  # noqa: E402
+                            runtime)
 from nebula_tpu.tpu.device import (join_halves, nan_halves,   # noqa: E402
                                    split_halves)
 
@@ -296,13 +297,13 @@ def test_halves_join_to_the_bit(dtype):
         np.testing.assert_array_equal(back, col)
     # the fetched form: each row its pieces (2, n) in slot order
     rows = [[h[0][:, :5], h[0][:, 5:]], [h[1]]]
-    got, has_null = runtime._join_halves(runtime._pieces(rows), dtype)
+    got, has_null = assemble._join_halves(assemble._pieces(rows), dtype)
     assert has_null is True
     np.testing.assert_array_equal(got.view(np.int64),
                                   col.reshape(-1).view(np.int64))
     pm = np.random.default_rng(3).permutation(col.shape[1])
-    got, has_null = runtime._join_halves(
-        runtime._pieces(rows, [pm, None]), dtype)
+    got, has_null = assemble._join_halves(
+        assemble._pieces(rows, [pm, None]), dtype)
     assert has_null is True
     np.testing.assert_array_equal(
         got.view(np.int64),
@@ -402,7 +403,7 @@ def test_the_join_answers_the_decode_and_the_decode_copies_nothing(
     pt = PropType.INT64 if dtype == np.int64 else PropType.DOUBLE
     col = _column(dtype, 23, WHERE[where])
     parts = np.split(split_halves(col), CUTS, axis=-1)
-    got, has_null = runtime._join_halves(parts, dtype)
+    got, has_null = assemble._join_halves(parts, dtype)
     assert got.dtype == dtype and got.flags.owndata
     np.testing.assert_array_equal(got.view(np.int64), col.view(np.int64))
     assert has_null is _scan(col) is (where != "none")
@@ -438,8 +439,8 @@ def pool():
 
 
 def _serial(columns):
-    return [runtime._join_halves(parts, dt) if parts[0].ndim == 2
-            else (runtime._cat_parts(parts, dt), False)
+    return [assemble._join_halves(parts, dt) if parts[0].ndim == 2
+            else (assemble._cat_parts(parts, dt), False)
             for parts, dt in columns]
 
 
@@ -471,7 +472,7 @@ def test_side_by_side_is_the_serial_assembly(layout, pool):
             col = _column(src, n, [n - 1])
             whole = split_halves(col)
         columns.append((np.split(whole, np.cumsum(sizes)[:-1], axis=-1), dt))
-    got, want = runtime._cat_side_by_side(pool, columns), _serial(columns)
+    got, want = assemble._cat_side_by_side(pool, columns), _serial(columns)
     assert len(got) == len(want)
     for (g, gn), (w, wn), (parts, dt) in zip(got, want, columns):
         assert g.dtype == w.dtype == dt and g.flags.owndata
@@ -484,19 +485,19 @@ def test_a_failed_piece_pass_is_the_statements_error(pool, monkeypatch):
     have ended, and the pool assembles the next statement's columns."""
     col = _column(np.int64, 40, [])
     columns = [(np.split(split_halves(col), [9, 20, 33], axis=-1), np.int64)]
-    real, seen = runtime.native_join_halves, []
+    real, seen = assemble.native_join_halves, []
 
     def faulty(pair, out):
         seen.append(pair.shape[-1])
         if pair.shape[-1] == 11:
             raise MemoryError("a piece-pass failed")
         return real(pair, out)
-    monkeypatch.setattr(runtime, "native_join_halves", faulty)
+    monkeypatch.setattr(assemble, "native_join_halves", faulty)
     with pytest.raises(MemoryError, match="a piece-pass failed"):
-        runtime._cat_side_by_side(pool, columns)
+        assemble._cat_side_by_side(pool, columns)
     assert sorted(seen) == [7, 9, 11, 13]     # every pass ran to its end
-    monkeypatch.setattr(runtime, "native_join_halves", real)
-    (got, has_null), = runtime._cat_side_by_side(pool, columns)
+    monkeypatch.setattr(assemble, "native_join_halves", real)
+    (got, has_null), = assemble._cat_side_by_side(pool, columns)
     np.testing.assert_array_equal(got, col)
     assert has_null is False
 
@@ -516,7 +517,7 @@ def test_many_statements_share_the_pool(pool):
         columns = [(np.split(split_halves(col), [3, 50, 51, 120], axis=-1),
                     col.dtype)] * 2
         for _ in range(rounds):
-            for got, has_null in runtime._cat_side_by_side(pool, columns):
+            for got, has_null in assemble._cat_side_by_side(pool, columns):
                 if has_null is not True or not (
                         got.view(np.int64) == col.view(np.int64)).all():
                     wrong.append(t)

@@ -83,6 +83,33 @@ def run(cl, q, timeout=30.0):
     return r
 
 
+def wait_part_leaders(c, space, timeout=30.0):
+    """Every part of `space` led by ONE live replica, the same at two
+    readings 0.1 s apart (tests/chaos/harness.py `wait_part_leaders`,
+    and held): a write sent into an election spends `run`'s retries on
+    `part_leader_changed`, under a loaded machine all thirty seconds of
+    them."""
+    sid = c.storageds[0].meta.catalog.get_space(space).space_id
+    n_parts = len(c.meta_clients[0].parts_of(space))
+
+    def leaders():
+        led = []
+        for pid in range(n_parts):
+            who = [ss.my_addr for ss in c.storageds
+                   if (sid, pid) in ss.parts and ss.parts[(sid, pid)].is_leader()]
+            led.append(who[0] if len(who) == 1 else None)
+        return led
+    deadline = time.monotonic() + timeout
+    last = None
+    while time.monotonic() < deadline:
+        now = leaders()
+        if None not in now and now == last:
+            return
+        last = now
+        time.sleep(0.1)
+    raise AssertionError(f"{space}: no settled leader for every part: {last}")
+
+
 def served_cluster(tmp_path, n_storage, replica_factor, space):
     """A cluster whose graphd holds a device runtime with the delta
     plane armed; eight parts, 1 knows 2."""
@@ -97,6 +124,7 @@ def served_cluster(tmp_path, n_storage, replica_factor, space):
                        f"replica_factor={replica_factor}, vid_type=INT64)")
         assert r.error is None, r.error
         c.reconcile_storage()
+        wait_part_leaders(c, space)
         for q in [f"USE {space}", "CREATE TAG T()", "CREATE EDGE E(w int)",
                   "INSERT VERTEX T() VALUES " + ", ".join(f"{v}:()" for v in range(1, 9)),
                   "INSERT EDGE E(w) VALUES 1->2:(1)"]:
@@ -290,8 +318,10 @@ def test_a_stopped_hosts_parts_fall_back_to_the_per_part_walk(tmp_path):
         assert fell == len(lost) > 0 and parts == 8 - len(lost)
         assert len(got) == 8
         assert len(set(epochs())) == 1
-        # the write waits for the lost parts' elections; acknowledged,
-        # the next read sees it
+        # the write waits for the lost parts' elections (`run`'s retries
+        # are the backstop, not the wait); acknowledged, the next read
+        # sees it
+        wait_part_leaders(c, "fo")
         run(cl, "INSERT EDGE E(w) VALUES 1->3:(3)")
         assert friends(cl) == [2, 3]
         assert len(set(epochs())) == 1

@@ -410,8 +410,8 @@ def _emitted_metric_names():
     names.update({"storage_pushdown_scanned",
                   "storage_pushdown_shipped"})
     # the hop programs' engagement counters are one loop over the
-    # programs' result keys (tpu/runtime.py `_ENGAGEMENT`)
-    from nebula_tpu.tpu.runtime import _ENGAGEMENT
+    # programs' result keys (tpu/fetch.py `_ENGAGEMENT`)
+    from nebula_tpu.tpu.fetch import _ENGAGEMENT
     assert 'm.inc(f"tpu_hop_{k}"' in \
         (REPO / "nebula_tpu/tpu/runtime.py").read_text()
     names.update(f"tpu_hop_{k}" for k in _ENGAGEMENT)
@@ -457,10 +457,10 @@ def _emitted_span_names():
     with dynamic f-string segments (`{node.kind}`) normalized to `*`
     so `exec:{node.kind}` and the catalogue's `exec:*` compare equal.
     A device launch opens its phases' spans by name through
-    `TpuRuntime._phase(phases, name)`."""
+    `trace.phase(phases, name)`."""
     pat = re.compile(
-        r'(?:(?:trace|_trace|_t)\.(?:span|record_phase|mark|start_trace)\('
-        r'|\._phase\(phases,)\s*(f?)["\']([^"\']+)["\']')
+        r'(?:trace|_trace|_t)\.(?:(?:span|record_phase|mark|start_trace)\('
+        r'|phase\(phases,)\s*(f?)["\']([^"\']+)["\']')
     names = set()
     for p in (REPO / "nebula_tpu").rglob("*.py"):
         for isf, name in pat.findall(p.read_text()):

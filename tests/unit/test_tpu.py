@@ -1010,10 +1010,10 @@ def test_bottom_up_bfs_endpoint_predicate_parity():
 
 def test_non_identity_vid_decode(rt):
     """Spaces whose vids are NOT the dense ids must still decode through
-    the d2v gather — guards the identity fast path in runtime._d2v
+    the d2v gather — guards the identity fast path in assemble._d2v
     (sequential-int-vid spaces skip the gather; scattered vids may not).
     Covers both the GO materializer and the MATCH frame decode."""
-    from nebula_tpu.tpu.runtime import _d2v
+    from nebula_tpu.tpu.assemble import _d2v
     rng = random.Random(5)
     st = GraphStore()
     st.create_space("nid", partition_num=P, vid_type="INT64")
@@ -1239,7 +1239,7 @@ def test_speculative_fetch_round_trips_and_undershoot(rt):
     device_get (a device round trip saved per query); an undershoot —
     the kept set growing past the speculated prefix — falls back to the
     exact refetch with identical rows."""
-    from nebula_tpu.tpu import runtime as R
+    from nebula_tpu.tpu import fetch as R
     st = GraphStore()
     st.create_space("sf", partition_num=P, vid_type="INT64")
     st.catalog.create_tag("sf", "person", [PropDef("a", PropType.INT64)])

@@ -226,19 +226,19 @@ def test_served_go_with_an_armed_delta_plane_compiles(one_chip, filtered):
 @pytest.mark.parametrize("shape", [(P8, 1, 1 << 22), (1, 1, 1 << 19)],
                          ids=["proxy", "mesh-shard"])
 def test_fetch_pieces_compile(one_chip, shape):
-    """The fetch programs of a wide capture (runtime.py `_piece`: of
+    """The fetch programs of a wide capture (fetch.py `_piece`: of
     every column ONE dynamic_slice with the row and the first slot
     traced) at the proxy cells' shapes, every size of the ladder that
     fits: the one-chip cell's (8, 1, 2^22) columns and one shard of the
     four-chip cell's."""
-    from nebula_tpu.tpu import runtime
+    from nebula_tpu.tpu import fetch
     cap = {n: _struct(shape, np.int32, one_chip)
            for n in ("src", "dst", "rank", "eidx")}
     cap.update({n: _struct(shape[:-1] + (2, shape[-1]), dt, one_chip)
                 for n, dt in [("prop:w", np.uint32), ("prop:f", np.uint32)]})
     at = _struct((len(shape),), np.int32, one_chip)
-    for size in (c for c in runtime.PIECES if c <= shape[-1]):
-        compiled, secs = _compile(runtime._piece, cap, at, size)
+    for size in (c for c in fetch.PIECES if c <= shape[-1]):
+        compiled, secs = _compile(fetch._piece, cap, at, size)
         assert secs < 20
         # a property column is captured as its halves: no piece splits
         # a 268 MB operand whole before its slice any more
