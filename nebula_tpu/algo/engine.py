@@ -16,6 +16,21 @@ engine: between iterations the statement
   * emits `algo_iterations` / `algo_iter_us` and a `tpu:algo_iter`
     trace span per device dispatch.
 
+Around the loop a statement's other seconds have a span and a series
+each: `algo:prepare` / `algo_prepare_s` (the flat edge list
+and its destination sort, when they are built), `algo:put` /
+`algo_put_s` (the edge arrays' upload, once a graph, and every run's
+own arrays), `algo:assemble` / `algo_assemble_s` (`assemble_rows`);
+`algo_edge_visits{algo}` counts rows x iterations run, and the gauge
+`tpu_algo_bytes_resident` says what `_dev_cache` holds on the device.
+A device run that arrives with no trace active is rooted as
+`query:tpu.algo` (`tpu/runtime.py` `statement_root`), so its spans fold
+into the phase ledger like any other device statement's; and it keeps a
+`TraverseStats` as they do (its gate waits, kernel seconds, puts, the
+fetch of its final state, row assembly: `info["stats"]`), which the
+runtime settles into the process-wide device series once a statement
+(`TpuRuntime.algo_account`).
+
 Execution modes (the `mode` parameter): `auto` uses the device plane
 when a TpuRuntime serves the space and falls back to the numpy host
 oracles otherwise (`algo_fallback` counts why); `device` errors
@@ -31,10 +46,13 @@ sharded mesh is where the partitioned variant lands.
 from __future__ import annotations
 
 import time
+from contextlib import nullcontext
 from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from ..utils import trace
+from ..utils.stats import stats
 from . import ALGORITHMS, DEFAULT_MAX_ITER, REQUIRED, _DIRECTIONS, _MODES
 from .graph import AlgoGraph, blocks_for, build_algo_graph
 from .oracles import BIG, pagerank_np, sssp_np, wcc_np
@@ -114,13 +132,57 @@ def _algo_graph(snap, block_keys, weight_prop) -> AlgoGraph:
     ent = _lru_get(_graph_cache, key)
     if ent is not None:
         return ent[1]
-    g = build_algo_graph(snap, block_keys, weight_prop)
+    t0 = time.perf_counter()
+    with trace.span("algo:prepare"):
+        g = build_algo_graph(snap, block_keys, weight_prop)
+    stats().add_value("algo_prepare_s", time.perf_counter() - t0)
     _lru_put(_graph_cache, key, (snap, g))
     return g
 
 
+def _by_dst(g: AlgoGraph):
+    """`g.by_dst()`; the sort, where this call makes it, is part of
+    the graph's preparation."""
+    if g.sorted_by_dst():
+        return g.by_dst()
+    t0 = time.perf_counter()
+    with trace.span("algo:prepare"):
+        view = g.by_dst()
+    stats().add_value("algo_prepare_s", time.perf_counter() - t0)
+    return view
+
+
+def _put(dev0, *arrays):
+    """The arrays on the device, waited for: `algo:put` then holds the
+    transfer and not just its enqueue (the first iteration would wait
+    for it otherwise)."""
+    import jax
+    return jax.block_until_ready([jax.device_put(a, dev0) for a in arrays])
+
+
+def _put_done(st, t0: float):
+    """One `algo:put` span closed: its seconds in the series and in the
+    statement's own stats."""
+    dt = time.perf_counter() - t0
+    stats().add_value("algo_put_s", dt)
+    st.put_s += dt
+
+
+def _fetch_state(arr, st, g: AlgoGraph) -> np.ndarray:
+    """The final state array brought to the host, with the fetch's
+    seconds and bytes in the statement's stats: the entries of real
+    vertices are what row assembly keeps."""
+    t0 = time.perf_counter()
+    with trace.span("device:fetch"):
+        out = np.asarray(arr)
+    st.fetch_s += time.perf_counter() - t0
+    st.fetch_bytes += int(out.nbytes)
+    st.fetch_bytes_kept += g.n_vertices * out.itemsize
+    return out
+
+
 def _device_edges(rt, snap, block_keys, weight_prop,
-                  g: AlgoGraph) -> Dict[str, Any]:
+                  g: AlgoGraph, st) -> Dict[str, Any]:
     """Device-resident flat edge arrays, uploaded once per (snapshot,
     block set, weight) and reused by every iteration and every run.
 
@@ -128,21 +190,24 @@ def _device_edges(rt, snap, block_keys, weight_prop,
     and the min-combines pass indices_are_sorted — min is exactly
     order-independent, so the sort can never change WCC/SSSP
     results."""
-    import jax
     key = (id(snap), tuple(block_keys), weight_prop)
     ent = _lru_get(_dev_cache, key)
     if ent is not None:
         return ent[1]
-    order, esrc_s, edst_s = g.by_dst()
+    order, esrc_s, edst_s = _by_dst(g)
     dev0 = rt.mesh.devices.reshape(-1)[0]
-    arrs = {
-        "esrc": jax.device_put(esrc_s.astype(np.int32), dev0),
-        "edst": jax.device_put(edst_s.astype(np.int32), dev0),
-        "vmask": jax.device_put(g.vmask, dev0),
-    }
-    if g.weight is not None:
-        arrs["weight"] = jax.device_put(g.weight[order], dev0)
+    t0 = time.perf_counter()
+    with trace.span("algo:put"):
+        host = {"esrc": esrc_s.astype(np.int32),
+                "edst": edst_s.astype(np.int32), "vmask": g.vmask}
+        if g.weight is not None:
+            host["weight"] = g.weight[order]
+        arrs = dict(zip(host, _put(dev0, *host.values())))
+    _put_done(st, t0)
     _lru_put(_dev_cache, key, (snap, arrs))
+    stats().gauge("tpu_algo_bytes_resident",
+                  sum(int(a.nbytes) for _snap, held in _dev_cache.values()
+                      for a in held.values()))
     return arrs
 
 
@@ -198,17 +263,17 @@ def _effective_max_iter(func: str, params: Dict[str, Any],
 
 
 def _iterate(name: str, max_iter: int, live, body,
-             iter_us: Optional[List[int]] = None) -> int:
+             iter_us: Optional[List[int]] = None, n_edges: int = 0) -> int:
     """Drive `body(it) -> (active, converged)` with the per-iteration
     contract: cancel check (kill/deadline land HERE, between
     iterations), the `algo:iter` failpoint, the `tpu:algo_iter` span,
     `algo_*` metrics, and the live-progress stamp SHOW QUERIES
     renders.  Returns the iterations actually run; `iter_us` (when
-    given) collects per-iteration wall µs — the bench's A/B probe."""
+    given) collects per-iteration wall µs — the bench's A/B probe.
+    Every iteration visits each of the graph's `n_edges` rows:
+    `algo_edge_visits{algo}` counts them."""
     from ..utils import cancel as _cancel
-    from ..utils import trace
     from ..utils.failpoints import fail
-    from ..utils.stats import stats
     iters = 0
     for it in range(1, max_iter + 1):
         _cancel.check()
@@ -218,6 +283,7 @@ def _iterate(name: str, max_iter: int, live, body,
             active, converged = body(it)
         us = int((time.perf_counter() - t0) * 1e6)
         stats().inc_labeled("algo_iterations", {"algo": name})
+        stats().inc_labeled("algo_edge_visits", {"algo": name}, n_edges)
         stats().observe("algo_iter_us", us, {"algo": name})
         if iter_us is not None:
             iter_us.append(us)
@@ -235,88 +301,94 @@ def _iterate(name: str, max_iter: int, live, body,
 # -- device drivers ---------------------------------------------------------
 
 
-def _device_pagerank(rt, snap, block_keys, g, params, live,
+def _device_pagerank(rt, snap, block_keys, g, params, live, st,
                      iter_us=None):
-    import jax
     from . import kernels
-    dev = _device_edges(rt, snap, block_keys, None, g)
+    dev = _device_edges(rt, snap, block_keys, None, g, st)
     damping, tol = float(params["damping"]), float(params["tol"])
     step = kernels.pagerank_step(g.n_slots, damping, tol)
     n = float(max(g.n_vertices, 1))
-    outdeg = g.out_degree()
-    out_inv = np.zeros(g.n_slots)
-    nz = outdeg > 0
-    out_inv[nz] = 1.0 / outdeg[nz]
-    _order, esrc_s, _edst_s = g.by_dst()
     dev0 = rt.mesh.devices.reshape(-1)[0]
-    # per-edge 1/outdeg pre-gathered once (static within a run): the
-    # iteration kernel then needs ONE gather per edge, not two
-    out_inv_e = jax.device_put(out_inv[esrc_s], dev0)
-    dmask_d = jax.device_put(g.vmask & ~nz, dev0)
-    state = {"rank": jax.device_put(
-        np.where(g.vmask, 1.0 / n, 0.0), dev0)}
+    t0 = time.perf_counter()
+    with trace.span("algo:put"):
+        outdeg = g.out_degree()
+        out_inv = np.zeros(g.n_slots)
+        nz = outdeg > 0
+        out_inv[nz] = 1.0 / outdeg[nz]
+        _order, esrc_s, _edst_s = g.by_dst()
+        # per-edge 1/outdeg pre-gathered once (static within a run): the
+        # iteration kernel then needs ONE gather per edge, not two
+        out_inv_e, dmask_d, rank0 = _put(
+            dev0, out_inv[esrc_s], g.vmask & ~nz,
+            np.where(g.vmask, 1.0 / n, 0.0))
+    _put_done(st, t0)
+    state = {"rank": rank0}
     K = _effective_max_iter("pagerank", params, g)
 
     def body(it):
         (rank, delta, active), _us = rt.algo_dispatch(
             "algo.pagerank", step, state["rank"], dev["esrc"],
-            dev["edst"], out_inv_e, dmask_d, dev["vmask"], n)
+            dev["edst"], out_inv_e, dmask_d, dev["vmask"], n, stats=st)
         state["rank"] = rank
         return int(active), float(delta) < tol
 
-    iters = _iterate("pagerank", K, live, body, iter_us)
-    return np.asarray(state["rank"]), iters
+    iters = _iterate("pagerank", K, live, body, iter_us, g.n_edges)
+    return _fetch_state(state["rank"], st, g), iters
 
 
-def _device_wcc(rt, snap, block_keys, g, params, live, iter_us=None):
-    import jax
+def _device_wcc(rt, snap, block_keys, g, params, live, st,
+                iter_us=None):
     from . import kernels
-    dev = _device_edges(rt, snap, block_keys, None, g)
+    dev = _device_edges(rt, snap, block_keys, None, g, st)
     step = kernels.wcc_step(g.n_slots)
     dev0 = rt.mesh.devices.reshape(-1)[0]
-    label0 = np.where(g.vmask, np.arange(g.n_slots, dtype=np.int64),
-                      BIG)
-    state = {"label": jax.device_put(label0, dev0),
-             "active": dev["vmask"]}
+    t0 = time.perf_counter()
+    with trace.span("algo:put"):
+        (label0,) = _put(dev0, np.where(
+            g.vmask, np.arange(g.n_slots, dtype=np.int64), BIG))
+    _put_done(st, t0)
+    state = {"label": label0, "active": dev["vmask"]}
     K = _effective_max_iter("wcc", params, g)
 
     def body(it):
         (label, active, changed), _us = rt.algo_dispatch(
             "algo.wcc", step, state["label"], state["active"],
-            dev["esrc"], dev["edst"])
+            dev["esrc"], dev["edst"], stats=st)
         state["label"], state["active"] = label, active
         return int(changed), int(changed) == 0
 
-    iters = _iterate("wcc", K, live, body, iter_us)
-    return np.asarray(state["label"]), iters
+    iters = _iterate("wcc", K, live, body, iter_us, g.n_edges)
+    return _fetch_state(state["label"], st, g), iters
 
 
-def _device_sssp(rt, snap, block_keys, g, params, live, src_dense,
+def _device_sssp(rt, snap, block_keys, g, params, live, st, src_dense,
                  iter_us=None):
-    import jax
     from . import kernels
     weight_prop = params.get("weight")
-    dev = _device_edges(rt, snap, block_keys, weight_prop, g)
+    dev = _device_edges(rt, snap, block_keys, weight_prop, g, st)
     step = kernels.sssp_step(g.n_slots, weight_prop is not None)
     dev0 = rt.mesh.devices.reshape(-1)[0]
-    dist0 = np.full(g.n_slots, np.inf)
-    dist0[src_dense] = 0.0
-    front0 = np.zeros(g.n_slots, bool)
-    front0[src_dense] = True
-    state = {"dist": jax.device_put(dist0, dev0),
-             "front": jax.device_put(front0, dev0)}
+    t0 = time.perf_counter()
+    with trace.span("algo:put"):
+        dist0 = np.full(g.n_slots, np.inf)
+        dist0[src_dense] = 0.0
+        front0 = np.zeros(g.n_slots, bool)
+        front0[src_dense] = True
+        dist_d, front_d = _put(dev0, dist0, front0)
+    _put_done(st, t0)
+    state = {"dist": dist_d, "front": front_d}
     K = _effective_max_iter("sssp", params, g)
     extra = (dev["weight"],) if weight_prop is not None else ()
 
     def body(it):
         (dist, front, changed), _us = rt.algo_dispatch(
             "algo.sssp", step, state["dist"], state["front"],
-            dev["esrc"], dev["edst"], *extra)
+            dev["esrc"], dev["edst"], *extra, stats=st)
         state["dist"], state["front"] = dist, front
         return int(changed), int(changed) == 0
 
-    iters = _iterate("sssp", K, live, body, iter_us)
-    return np.asarray(state["dist"]), iters
+    iters = _iterate("sssp", K, live, body, iter_us, g.n_edges)
+    return _fetch_state(state["dist"], st, g), iters
 
 
 # -- row assembly (one code path for device AND host rows) ------------------
@@ -359,11 +431,27 @@ def run_algorithm(func: str, params: Dict[str, Any], snap, sd,
 
     -> (rows, info) where rows are full-width [vid, value] rows in the
     canonical vid order and info = {'mode', 'iterations', 'n_edges',
-    'n_vertices'}.  `iter_us` collects per-iteration wall µs on the
+    'n_vertices'}, and after a device run 'stats', the statement's
+    `TraverseStats`.  `iter_us` collects per-iteration wall µs on the
     device path (the bench's A/B probe); `on_fallback(cause)` is told
-    why an auto-mode device run failed before the oracle takes over."""
+    why an auto-mode device run failed before the oracle takes over.
+
+    With a runtime and no trace active (an embedded runtime: the
+    benchmark's proxy cell, a tool) the statement is rooted here as
+    `query:tpu.algo`, as the runtime's own entries are."""
+    if rt is None:
+        root = nullcontext()
+    else:
+        from ..tpu.runtime import statement_root
+        root = statement_root("algo", snap.space)
+    with root:
+        return _run_algorithm(func, params, snap, sd, rt, live,
+                              iter_us, on_fallback)
+
+
+def _run_algorithm(func, params, snap, sd, rt, live, iter_us,
+                   on_fallback):
     from ..utils import cancel as _cancel
-    from ..utils.stats import stats
 
     params = resolve_params(func, dict(params))
     direction = params.get("direction", "out")
@@ -405,20 +493,23 @@ def run_algorithm(func: str, params: Dict[str, Any], snap, sd,
         raise AlgoError("mode=device but no device runtime serves "
                         "this engine")
 
-    state, iters, ran_mode = None, 0, "host"
+    state, iters, ran_mode, st = None, 0, "host", None
+    t_start = time.perf_counter()
     if mode != "host" and rt is not None:
+        from ..tpu.assemble import TraverseStats
         from ..tpu.device import TpuUnavailable, note_host_fallback
         from ..tpu.traverse import _JAX_RT_ERRORS
+        st = TraverseStats()
         try:
             if func == "pagerank":
                 state, iters = _device_pagerank(
-                    rt, snap, block_keys, g, params, live, iter_us)
+                    rt, snap, block_keys, g, params, live, st, iter_us)
             elif func == "wcc":
                 state, iters = _device_wcc(
-                    rt, snap, block_keys, g, params, live, iter_us)
+                    rt, snap, block_keys, g, params, live, st, iter_us)
             else:
                 state, iters = _device_sssp(
-                    rt, snap, block_keys, g, params, live, src_dense,
+                    rt, snap, block_keys, g, params, live, st, src_dense,
                     iter_us)
             ran_mode = "device"
         except (TpuUnavailable,) + _JAX_RT_ERRORS as ex:
@@ -431,7 +522,7 @@ def run_algorithm(func: str, params: Dict[str, Any], snap, sd,
             cause = note_host_fallback(f"algo.{func}", ex)
             if on_fallback is not None:
                 on_fallback(cause)
-            state = None
+            state = st = None
 
     if state is None:                   # host oracle (mode or fallback)
         _cancel.check()
@@ -449,9 +540,19 @@ def run_algorithm(func: str, params: Dict[str, Any], snap, sd,
         _cancel.check()
 
     stats().inc_labeled("algo_runs", {"algo": func, "mode": ran_mode})
-    return assemble_rows(func, g, state), \
-        {"mode": ran_mode, "iterations": iters,
-         "n_edges": g.n_edges, "n_vertices": g.n_vertices}
+    t0 = time.perf_counter()
+    with trace.span("algo:assemble"):
+        rows = assemble_rows(func, g, state)
+    stats().add_value("algo_assemble_s", time.perf_counter() - t0)
+    info = {"mode": ran_mode, "iterations": iters,
+            "n_edges": g.n_edges, "n_vertices": g.n_vertices}
+    if st is not None:
+        st.steps, st.result_edges = iters, len(rows)
+        st.mat_s = time.perf_counter() - t0
+        st.total_s = time.perf_counter() - t_start
+        rt.algo_account(st)
+        info["stats"] = st
+    return rows, info
 
 
 # -- the executor entry point -----------------------------------------------

@@ -44,6 +44,10 @@ class AlgoGraph:
         return np.bincount(self.esrc, minlength=self.n_slots) \
             .astype(np.float64)
 
+    def sorted_by_dst(self) -> bool:
+        """Whether `by_dst` has its view already."""
+        return getattr(self, "_by_dst", None) is not None
+
     def by_dst(self):
         """Destination-sorted edge view (computed once, cached):
         -> (order, esrc_sorted, edst_sorted).  The device kernels run

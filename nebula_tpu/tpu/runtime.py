@@ -50,20 +50,30 @@ from .hop import a2a_payload_bytes, build_traverse_fn
 _log = logging.getLogger(__name__)
 
 
+@contextmanager
+def statement_root(entry: str, space):
+    """A device statement that arrives with no trace active (an embedded
+    runtime: `pin_prebuilt`, the tools, the benchmark's proxy cells) is
+    rooted as `query:tpu.<entry>`: the spans below are then live, fold
+    into the phase ledger when the root closes and reach `/traces`.
+    Under graphd the statement's root is active and none is opened; the
+    flag is the one graphd's root obeys."""
+    if _t.current_ctx() is not None or \
+            not get_config().get("enable_query_tracing"):
+        yield
+        return
+    with _t.start_trace(f"query:tpu.{entry}", service="tpu", space=space):
+        yield
+
+
 def _on_live_snapshot(fn):
     """The entry of a device statement (`traverse`, `traverse_hops`,
     `bfs`).  One that met a swap is served from the snapshot that
     replaced its own: `SnapshotRetired` (raised under the read gate,
     before anything ran) pins again and runs the statement anew.  Only a
     space that keeps being replaced under one statement (a few times
-    over) is handed to the caller's fallback.
-
-    A statement that arrives with no trace active (an embedded runtime:
-    `pin_prebuilt`, the tools, the benchmark's proxy cells) is rooted
-    HERE, around its retries too, as `query:tpu.<entry>`: the spans
-    below are then live, fold into the phase ledger when the root
-    closes and reach `/traces`.  Under graphd the statement's root is
-    active and none is opened; the flag is the one graphd's root obeys."""
+    over) is handed to the caller's fallback.  The statement is rooted
+    HERE (`statement_root`), around its retries too."""
     entry = fn.__name__
 
     def attempts(self, *args, **kw):
@@ -76,10 +86,7 @@ def _on_live_snapshot(fn):
 
     @functools.wraps(fn)
     def run(self, store, space, *args, **kw):
-        if _t.current_ctx() is not None or \
-                not get_config().get("enable_query_tracing"):
-            return attempts(self, store, space, *args, **kw)
-        with _t.start_trace(f"query:tpu.{entry}", service="tpu", space=space):
+        with statement_root(entry, space):
             return attempts(self, store, space, *args, **kw)
     return run
 
@@ -1094,23 +1101,30 @@ class TpuRuntime:
             self._launch_mutex.release()
             _metrics().add_value("tpu_collective_wait_s", wait_s)
 
-    def algo_dispatch(self, kernel: str, fn, *args):
+    def algo_dispatch(self, kernel: str, fn, *args,
+                      stats: Optional[TraverseStats] = None):
         """One gated single-shot device dispatch for the algo plane
         (ISSUE 13): a vertex-program ITERATION kernel has static
         full-graph shapes — no bucket escalation, no capture fetch —
         but it rides the same gate/accounting as every other device
-        program (_gated_dispatch) and additionally lands its run time
+        program (_gated_dispatch), counts as a kernel run
+        (`tpu_kernel_runs`) and additionally lands its run time
         in `tpu_dispatch_us{kernel}`, `device_us` and the SHOW QUERIES
-        decomposition.  Returns (result, dispatch_us)."""
+        decomposition; `stats`, the statement's own, gains the gate's
+        wait and the run.  Returns (result, dispatch_us)."""
         from ..utils.stats import current_cost, current_work
         from ..utils.workload import current_live
-        with self._gated_dispatch(kernel):
+        with self._gated_dispatch(kernel) as wait_us:
             t0 = time.perf_counter()
             with self._collective_launch():
                 res = fn(*args)
                 jax.block_until_ready(res)
             us = int((time.perf_counter() - t0) * 1e6)
+            _metrics().inc("tpu_kernel_runs")
             _metrics().observe("tpu_dispatch_us", us, {"kernel": kernel})
+            if stats is not None:
+                stats.device_s += us / 1e6
+                stats.queue_s += wait_us / 1e6
             cc = current_cost()
             if cc is not None:
                 cc.add("device_us", us)
@@ -1123,6 +1137,26 @@ class TpuRuntime:
             if wc is not None:
                 wc.add("device_dispatches")
             return res, us
+
+    def algo_account(self, st: TraverseStats):
+        """An algo statement's device phases, settled once a statement
+        into the series every device statement's launch settles
+        (`_escalate_locked`): the sums of its iterations' runs and gate
+        waits, its puts, the fetch of its final state with its bytes,
+        its row assembly.  Its launches have static shapes, so it never
+        escalates and never fetches twice."""
+        m = _metrics()
+        m.add_value("tpu_kernel_s", st.device_s)
+        m.add_value("tpu_put_s", st.put_s)
+        m.add_value("tpu_fetch_s", st.fetch_s)
+        m.add_value("tpu_queue_s", st.queue_s)
+        m.add_value("tpu_mat_s", st.mat_s)
+        # both stay 0: named, so that the counters exist in a process
+        # that runs algo statements alone and their rates read 0 there
+        m.inc("tpu_escalation_retries", 0)
+        m.inc("tpu_refetches", 0)
+        m.inc("tpu_fetch_bytes", st.fetch_bytes)
+        m.inc("tpu_fetch_bytes_kept", st.fetch_bytes_kept)
 
     # -- the escalation driver: solo and lane-batched launches -----------
 

@@ -42,7 +42,7 @@ opened where the statement ENTERS: by graphd (`query:<kind>`,
 `exec/engine.py` `statement_trace`) or, for a device statement that
 arrives with no trace active (an embedded `TpuRuntime`: `pin_prebuilt`,
 the tools, the benchmark's proxy cells), by the runtime's own entry
-(`query:tpu.<entry>`, `tpu/runtime.py` `_on_live_snapshot`); both obey
+(`query:tpu.<entry>`, `tpu/runtime.py` `statement_root`); both obey
 `enable_query_tracing`, and a statement never has two.
 """
 from __future__ import annotations
@@ -495,6 +495,12 @@ _PHASE_BY_PREFIX = (
     ("device:materialise.concat", "mat_concat"),
     ("device:materialise.decode", "mat_decode"),
     ("device:materialise", "materialise"),
+    # a `CALL algo.*` statement (`algo/engine.py`): an iteration's own
+    # time is its kernel's run (the gate's wait below it stays `queue`),
+    # the flat edge list's build and sort are the host's work, the
+    # uploads are puts and `assemble_rows` makes the result's rows
+    ("tpu:algo_iter", "dispatch"), ("algo:prepare", "exec"),
+    ("algo:put", "put"), ("algo:assemble", "materialise"),
     ("rpc:retry", None), ("rpc:breaker", None),
     ("storage:dedup_hit", None), ("storage:follower_read", None),
     (WRITE_ACKED, None),
